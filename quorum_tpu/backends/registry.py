@@ -17,6 +17,7 @@ from typing import Any, Callable, Iterable
 from quorum_tpu.backends.base import Backend
 from quorum_tpu.backends.http_backend import HttpBackend
 from quorum_tpu.config import BackendSpec, Config
+from quorum_tpu.devices import NoAcceleratorError
 
 logger = logging.getLogger(__name__)
 
@@ -28,6 +29,11 @@ class BackendRegistry:
         # BackendSpec each backend was constructed from (when known) — the
         # identity hot reload compares to decide reuse vs reconstruction.
         self._spec_by_name: dict[str, BackendSpec] = {}
+        # Configured backends that failed to construct: name → error text.
+        # Requests still degrade to the survivors (the reference's
+        # contract), but /health reports them and /ready stays 503 — a
+        # quorum missing a member must not look whole.
+        self.failed: dict[str, str] = {}
         for b in backends:
             self.add(b)
 
@@ -95,6 +101,28 @@ SCHEME_FACTORIES: dict[str, Callable[[BackendSpec], Backend]] = {
 }
 
 
+def _construct(reg: BackendRegistry, spec: BackendSpec) -> None:
+    """Construct ``spec``'s backend into ``reg``, or record why it could not
+    be: a backend that fails to construct (bad tpu:// model id, missing
+    weights, ...) must not take the whole server down with it — except for
+    :class:`NoAcceleratorError`, which no survivor can make up for."""
+    factory = SCHEME_FACTORIES.get(spec.scheme)
+    if factory is None:
+        logger.warning(
+            "Backend %s has unsupported URL scheme %r — skipped",
+            spec.name, spec.scheme)
+        reg.failed[spec.name] = f"unsupported URL scheme {spec.scheme!r}"
+        return
+    try:
+        reg.add(factory(spec), spec=spec)
+    except NoAcceleratorError:
+        raise
+    except Exception as e:
+        logger.exception(
+            "Failed to construct backend %s (%s) — skipped", spec.name, spec.url)
+        reg.failed[spec.name] = f"{type(e).__name__}: {e}"
+
+
 def build_registry(config: Config, **overrides: Any) -> BackendRegistry:
     """Construct backends for every *valid* (non-empty-url) configured backend.
 
@@ -106,18 +134,7 @@ def build_registry(config: Config, **overrides: Any) -> BackendRegistry:
         if spec.name in overrides:
             reg.add(overrides[spec.name])
             continue
-        factory = SCHEME_FACTORIES.get(spec.scheme)
-        if factory is None:
-            logger.warning(
-                "Backend %s has unsupported URL scheme %r — skipped", spec.name, spec.scheme
-            )
-            continue
-        try:
-            reg.add(factory(spec), spec=spec)
-        except Exception:
-            # A backend that fails to construct (bad tpu:// model id, missing
-            # weights, ...) must not take the whole server down with it.
-            logger.exception("Failed to construct backend %s (%s) — skipped", spec.name, spec.url)
+        _construct(reg, spec)
     for name, backend in overrides.items():
         if name not in reg:
             reg.add(backend)
@@ -150,18 +167,7 @@ def rebuild_registry(
                 and prev_spec.retries == spec.retries):
             reg.add(prev, spec=spec)
             continue
-        factory = SCHEME_FACTORIES.get(spec.scheme)
-        if factory is None:
-            logger.warning(
-                "Backend %s has unsupported URL scheme %r — skipped",
-                spec.name, spec.scheme)
-            continue
-        try:
-            reg.add(factory(spec), spec=spec)
-        except Exception:
-            logger.exception(
-                "Failed to construct backend %s (%s) — skipped",
-                spec.name, spec.url)
+        _construct(reg, spec)
     for name, backend in overrides.items():
         if name not in reg:
             reg.add(backend)

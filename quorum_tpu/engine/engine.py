@@ -136,6 +136,7 @@ from quorum_tpu.cache.prefix_store import (
     PrefixStore,
 )
 from quorum_tpu.compile_cache import enable_persistent_compile_cache
+from quorum_tpu.devices import device_report
 from quorum_tpu.models.init import init_params, init_params_sharded
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.transformer import (
@@ -147,6 +148,7 @@ from quorum_tpu.models.transformer import (
     prefill,
     prefill_segment,
 )
+from quorum_tpu.ops.flash_attention import tracing_program
 from quorum_tpu.ops.flash_decode import resolve_flash_decode
 from quorum_tpu.ops.sampling import (
     SamplerConfig,
@@ -1166,6 +1168,20 @@ class InferenceEngine:
                                               AXIS_TP)
 
         self._use_sp = dict(self.mesh.shape).get(AXIS_SP, 1) > 1
+        # Tensor-parallel engines hand their mesh to single-shot prefill so
+        # the Pallas kernel runs per tp shard (ops/flash_attention.py).
+        self._tp_mesh = (
+            self.mesh if dict(self.mesh.shape).get(AXIS_TP, 1) > 1 else None)
+        if self._flash == "tpu" and self._tp_mesh is not None:
+            # The opt-in decode kernel has no such wrapper: under tp>1 XLA
+            # refuses the unpartitionable Mosaic call, so the engine serves
+            # masked-dense decode and says so.
+            logger.info(
+                "attention-path program=decode kernel=flash_decode path=xla "
+                "reason=flash_decode=1 does not run under tp=%d (Mosaic "
+                "kernels cannot be auto-partitioned)",
+                self._tp_mesh.shape[AXIS_TP])
+            self._flash = ""
         # Prefill-group sequence parallelism (disagg=P+D&sp=S): the STAGING
         # cache shards its position axis over the prefill mesh's sp axis —
         # a 100k-token admission's staged KV occupies O(max_seq/sp) HBM per
@@ -1778,6 +1794,15 @@ class InferenceEngine:
         else:
             self._prefill_thread = None
         _ALL_ENGINES.add(self)
+        # Where this engine runs, said once: what /health repeats and what
+        # chip_smoke.py reads — a CPU engine must never pass for a TPU one.
+        rep = self.device_report = device_report(self.mesh)
+        logger.info(
+            "engine up: d_model=%d layers=%d members=%d slots=%d max_seq=%d "
+            "on platform=%s device_kind=%r device_count=%d mesh=%s",
+            self.spec.d_model, self.spec.n_layers, self.members,
+            self.n_slots, self.spec.max_seq, rep["platform"],
+            rep["device_kind"], rep["device_count"], rep["mesh"])
 
     def _build_params(self, mesh: Mesh, params, seed: int):
         """One device group's weight tree: shared by the decode mesh and
@@ -2228,6 +2253,7 @@ class InferenceEngine:
         spec = self.spec
 
         mesh = self.mesh if self._use_sp else None
+        tp_mesh = self._tp_mesh
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
         ens = self.ensemble
 
@@ -2236,13 +2262,14 @@ class InferenceEngine:
                   ck, cv, token_s, lengths_s, keys_s, temp_s, topp_s, topk_s,
                   pp_s, fp_s, counts_s, bias_s, live_s, budget_s, eos_s):
             # mesh is None whenever ens > 1 (sp is rejected with ensembles)
-            logits, ck, cv = _member_call(
-                ens,
-                lambda p, k, v: prefill(
-                    p, spec, tokens, lengths1, k, v, slot=slot, mesh=mesh,
-                    sp_impl=self.sp_impl),
-                params, ck, cv,
-            )
+            with tracing_program(f"admit/{bucket}"):
+                logits, ck, cv = _member_call(
+                    ens,
+                    lambda p, k, v: prefill(
+                        p, spec, tokens, lengths1, k, v, slot=slot,
+                        mesh=mesh, sp_impl=self.sp_impl, tp_mesh=tp_mesh),
+                    params, ck, cv,
+                )
             # First sampled token: no generated text yet → penalties are
             # zero; only the logit bias applies.
             adj = logits.astype(jnp.float32) + bias_row[None, :]
@@ -2308,6 +2335,7 @@ class InferenceEngine:
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
         n_s = self.n_slots
         mem = self.members
+        tp_mesh = self._tp_mesh
 
         def admit(params, tokens, lengths, slot, enables, seeds,
                   temps, topps, topks, pps, fps, bias_rows, budgets, eoss,
@@ -2317,10 +2345,11 @@ class InferenceEngine:
             # enables [M] bool; sampler knobs [M]; bias_rows [M, V].
             def one(p, tok, lens, k, v, gate):
                 return prefill(p, spec, tok, lens, k, v, slot=slot,
-                               write_gate=gate)
+                               write_gate=gate, tp_mesh=tp_mesh)
 
-            logits, ck, cv = jax.vmap(one)(
-                params, tokens, lengths, ck, cv, enables)
+            with tracing_program(f"admit_members/{bucket}"):
+                logits, ck, cv = jax.vmap(one)(
+                    params, tokens, lengths, ck, cv, enables)
             adj = logits[:, 0].astype(jnp.float32) + bias_rows  # [M, V]
             # Same PRNG stream as the single-model admit: sample the first
             # token with split row 1, carry row 0 — a member's stream is
@@ -2408,8 +2437,10 @@ class InferenceEngine:
             del enables
             p0 = jax.tree.map(lambda x: x[0], params)
             mini = jnp.zeros((ell, 1, kv, bucket, hd), dt)
-            logits, mini_k, mini_v = prefill(
-                p0, spec, tokens[0], lengths[0], mini, mini)
+            with tracing_program(f"admit_dedup/{bucket}"):
+                logits, mini_k, mini_v = prefill(
+                    p0, spec, tokens[0], lengths[0], mini, mini,
+                    tp_mesh=self._tp_mesh)
 
             if paged:
                 hp = -(-bucket // ps)
@@ -4435,6 +4466,7 @@ class InferenceEngine:
             "pending": pending,
             "queue_limit": self.max_pending,
             "rebuilds_total": self.n_rebuilds,
+            **self.device_report,
             # A draining engine still answers /health but must shed
             # /ready: the fleet rotates it out while residents finish.
             "draining": self.draining,

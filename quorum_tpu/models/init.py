@@ -16,31 +16,6 @@ from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.transformer import Params
 
 
-def _force_partitionable_threefry() -> None:
-    """Pin ``jax_threefry_partitionable`` ON (the default on current jax).
-
-    On jax 0.4.x the flag defaults OFF, and the non-partitionable threefry
-    lowering produces WRONG random values when a ``jax.random`` op is jitted
-    with a row-sharded ``out_shardings`` on a multi-axis mesh (reproduced on
-    0.4.37: ``normal(key, (V, D))`` under ``P("tp", None)`` on a dp2·sp2·tp2
-    mesh differs from the eager value on every element — the dp2·sp2·tp2
-    embed divergence `make dryrun` used to hit). The partitionable
-    implementation is sharding-invariant BY DESIGN, so the fused sharded
-    init (:func:`init_params_sharded` and friends) is correct on every mesh
-    shape, and old-jax boxes produce the same weights newer-jax boxes
-    already do. Flipped at import (before any seeded init or sampler trace)
-    so eager and jitted inits agree process-wide.
-    """
-    try:
-        if not jax.config.jax_threefry_partitionable:
-            jax.config.update("jax_threefry_partitionable", True)
-    except AttributeError:  # newer jax: flag retired, always partitionable
-        pass
-
-
-_force_partitionable_threefry()
-
-
 def init_params(spec: ModelSpec, seed: int = 0) -> Params:
     return init_params_from_key(spec, jax.random.PRNGKey(seed))
 

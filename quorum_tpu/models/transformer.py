@@ -220,8 +220,8 @@ def _moe_mlp_grouped(x, block, spec: ModelSpec, token_mask=None):
     # clamped in-bounds row and are zeroed by the mask below. (Not the
     # concatenate-a-zero-row + out-of-bounds-index idiom: gathering from a
     # concat of a batch-sharded token matrix with a replicated pad row
-    # miscompiles under GSPMD on jax 0.4.x — the partitioned gather reads
-    # the wrong shard — which was the PR 16 "MoE EP divergence" quarantine.)
+    # miscompiled under GSPMD — the partitioned gather read the wrong
+    # shard — which was the PR 16 "MoE EP divergence" quarantine.)
     pick_buf = jnp.full((e, cap), p, jnp.int32)
     pick_buf = pick_buf.at[e_p, c_p].set(
         jnp.arange(p, dtype=jnp.int32), mode="drop")
@@ -260,9 +260,18 @@ def _qkv(x, block, spec: ModelSpec):
     v = qeinsum("btd,dh->bth", x, block["wv"])
     if block.get("bq") is not None:
         q, k, v = q + block["bq"], k + block["bk"], v + block["bv"]
-    q = q.astype(x.dtype).reshape(b, t, spec.n_heads, spec.head_dim).transpose(0, 2, 1, 3)
-    k = k.astype(x.dtype).reshape(b, t, spec.n_kv_heads, spec.head_dim).transpose(0, 2, 1, 3)
-    v = v.astype(x.dtype).reshape(b, t, spec.n_kv_heads, spec.head_dim).transpose(0, 2, 1, 3)
+    # Keep the head split OUT of the projection dots. Fused into them,
+    # XLA:TPU (libtpu 0.0.34) wants the weights contraction-minor and, the
+    # layers being one scanned [L, D, H·hd] stack, copies all L layers of
+    # wq/wk/wv into that layout at the top of every decode program: 1.5 GiB
+    # of HBM temp at mistral-7b, which does not fit beside its bf16 weights
+    # on a 16 GB chip (compile-time RESOURCE_EXHAUSTED, PERF.md Findings).
+    # wo and the MLP weights, whose dots feed no reshape, get no such copy.
+    q, k, v = lax.optimization_barrier(
+        (q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype)))
+    q = q.reshape(b, t, spec.n_heads, spec.head_dim).transpose(0, 2, 1, 3)
+    k = k.reshape(b, t, spec.n_kv_heads, spec.head_dim).transpose(0, 2, 1, 3)
+    v = v.reshape(b, t, spec.n_kv_heads, spec.head_dim).transpose(0, 2, 1, 3)
     return q, k, v
 
 
@@ -332,6 +341,7 @@ def prefill(
     mesh=None,
     write_gate: jnp.ndarray | None = None,  # scalar bool: False → cache unchanged
     sp_impl: str = "ring",  # "ring" | "ulysses" — SP attention strategy
+    tp_mesh=None,  # the caller's mesh when heads are sharded over tp > 1
 ):
     """Process the full prompt; returns (last-token logits [B,V], cache_k, cache_v).
 
@@ -389,7 +399,8 @@ def prefill(
             # Flash kernel on TPU (causal + length mask fused, O(S) VMEM);
             # XLA-native reference path elsewhere.
             attn = flash_prefill_attention(q, k, v, lengths,
-                                           window=spec.sliding_window)
+                                           window=spec.sliding_window,
+                                           tp_mesh=tp_mesh)
         carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = (_moe_mlp(h2, block, spec, token_mask=moe_mask)

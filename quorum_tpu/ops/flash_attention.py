@@ -21,9 +21,12 @@ long-prompt prefill on TPU:
   - right-padding is masked via per-row ``lengths`` so bucketed batches share
     one compiled program (same contract as quorum_tpu.ops.attention).
 
-`flash_prefill_attention` falls back to the XLA-native reference path
-(quorum_tpu.ops.attention) off-TPU or for unsupported shapes; tests run the
-kernel in interpreter mode on CPU against that reference. The reference proxy
+`flash_prefill_attention` takes the XLA-native reference path
+(quorum_tpu.ops.attention) off-TPU or for unsupported shapes, and says which
+path it took and why: one INFO line per traced program
+(:func:`log_attention_path`). Tests run the kernel in interpreter mode on
+CPU against that reference; chip_smoke.py compiles it with Mosaic and
+compares on the chip. The reference proxy
 has no attention at all (models are remote HTTP calls,
 /root/reference/src/quorum/oai_proxy.py:182-192) — this kernel exists for the
 tpu:// backends' performance, not behavioral parity.
@@ -31,15 +34,52 @@ tpu:// backends' performance, not behavioral parity.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import logging
 import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
+
+_PROGRAM = contextvars.ContextVar("attention_program", default="direct")
+
+
+@contextlib.contextmanager
+def tracing_program(label: str):
+    """Name the program being TRACED (``admit/64``, ``admit_members/256``)
+    for the attention-path line. Entered inside the jitted function's Python
+    body, which runs only at trace time, so it costs nothing per call."""
+    token = _PROGRAM.set(label)
+    try:
+        yield
+    finally:
+        _PROGRAM.reset(token)
+
+
+def log_attention_path(kernel: str, refusal: str, *, interpret: bool,
+                       q_shape: tuple, kv_shape: tuple, block: int,
+                       window: int) -> None:
+    """The trace-time line that makes the kernel choice visible: ``pallas``
+    or ``xla`` and why, per traced program. chip_smoke.py asserts from these
+    lines that every single-shot prefill bucket ran the Mosaic-compiled
+    kernel and that nothing ran in interpret mode."""
+    logger.info(
+        "attention-path program=%s kernel=%s path=%s interpret=%s q=%s kv=%s "
+        "block=%d window=%d reason=%s",
+        _PROGRAM.get(), kernel, "xla" if refusal else "pallas", interpret,
+        "x".join(map(str, q_shape)), "x".join(map(str, kv_shape)), block,
+        window, refusal or ("interpret mode asked for" if interpret
+                            else "tpu backend, shapes tile"))
 
 # 512-tiles measured ~22% faster than XLA's fused attention at 16k tokens on
 # v5e (84.8 vs 108.8 ms; 128-tiles were on par) — grid overhead amortizes and
@@ -163,24 +203,34 @@ def _flash_call(
     )(lengths.reshape(b, 1), q, k, v)
 
 
-def flash_supported(q_shape: tuple, k_shape: tuple, block_q: int, block_k: int) -> bool:
+def flash_refusal(q_shape: tuple, k_shape: tuple, block_q: int,
+                  block_k: int, tp: int = 1) -> str:
+    """Why the kernel cannot take these shapes ('' = it can). ``tp`` > 1:
+    the call runs per tensor-parallel shard, so both head counts must split
+    over it (a replicated kv head would break the local q→kv head map)."""
     b, h, s_q, hd = q_shape
     n_kv, s_kv = k_shape[1], k_shape[2]
-    return (
-        s_q % block_q == 0
-        and s_kv % block_k == 0
-        and s_q >= block_q
-        and h % n_kv == 0
-        and hd % 8 == 0
-    )
+    if s_q % block_q or s_kv % block_k or s_q < block_q:
+        return (f"sequence {s_q}x{s_kv} does not tile by "
+                f"{block_q}x{block_k}")
+    if h % n_kv:
+        return f"{h} query heads do not group over {n_kv} kv heads"
+    if hd % 8:
+        return f"head_dim {hd} is not a multiple of 8"
+    if h % tp or n_kv % tp:
+        return f"heads {h}/{n_kv} do not split over tp={tp}"
+    return ""
 
 
-def flash_enabled() -> bool:
-    """Kernel path on TPU unless QUORUM_TPU_FLASH=0; off-TPU the XLA
-    reference path runs (interpret mode is for tests only — too slow to
-    serve with)."""
-    flag = os.environ.get("QUORUM_TPU_FLASH", "1")
-    return flag != "0" and jax.default_backend() == "tpu"
+def flash_disabled() -> str:
+    """Why the kernel is off in this process ('' = it is on): it runs on
+    TPU unless QUORUM_TPU_FLASH=0; off-TPU the XLA reference path runs
+    (interpret mode is for tests only — too slow to serve with)."""
+    if os.environ.get("QUORUM_TPU_FLASH", "1") == "0":
+        return "QUORUM_TPU_FLASH=0"
+    if jax.default_backend() != "tpu":
+        return f"platform is {jax.default_backend()}, not tpu"
+    return ""
 
 
 def flash_prefill_attention(
@@ -193,21 +243,38 @@ def flash_prefill_attention(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
     window: int = 0,
+    tp_mesh: Mesh | None = None,
 ) -> jnp.ndarray:
     """Causal, length-masked prefill attention (``window`` > 0 adds the
     sliding-window constraint); flash kernel when supported, XLA-native
-    reference otherwise. Returns [B, H, S, hd]."""
+    reference otherwise. Returns [B, H, S, hd].
+
+    ``tp_mesh``: the mesh of a tensor-parallel caller (heads sharded over
+    its ``tp`` axis). A Mosaic kernel has no partitioning rule — XLA refuses
+    to compile one inside a GSPMD-partitioned program — and heads are
+    independent, so the kernel runs under ``shard_map`` over ``tp``, each
+    shard on its own head slice."""
     # Clamp tiles to the sequence (buckets are powers of two, so they divide).
     block_q = min(block_q, q.shape[2])
     block_k = min(block_k, k.shape[2])
-    if (interpret or flash_enabled()) and flash_supported(
-        q.shape, k.shape, block_q, block_k
-    ):
-        return _flash_call(
-            q, k, v, jnp.asarray(lengths, jnp.int32),
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window,
-        )
+    tp = tp_mesh.shape["tp"] if tp_mesh is not None else 1
+    refusal = ("" if interpret else flash_disabled()) or flash_refusal(
+        q.shape, k.shape, block_q, block_k, tp)
+    log_attention_path("flash_prefill", refusal, interpret=interpret,
+                       q_shape=q.shape, kv_shape=k.shape, block=block_q,
+                       window=window)
+    if not refusal:
+        call = functools.partial(
+            _flash_call, block_q=block_q, block_k=block_k,
+            interpret=interpret, window=window)
+        if tp > 1:
+            # "tp" is parallel.mesh.AXIS_TP (not imported: parallel/ imports
+            # models/, which imports this module).
+            heads = P(None, "tp", None, None)
+            call = shard_map(call, mesh=tp_mesh,
+                             in_specs=(heads, heads, heads, P()),
+                             out_specs=heads, check_vma=False)
+        return call(q, k, v, jnp.asarray(lengths, jnp.int32))
     from quorum_tpu.ops.attention import prefill_attention
 
     return prefill_attention(q, k, v, lengths, window=window)

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from quorum_tpu.ops.flash_attention import log_attention_path
+
 NEG_INF = -1e30
 
 # Small default tile: decode histories start at the 128 bucket, and the
@@ -172,16 +174,19 @@ def _decode_call(q, k_cache, v_cache, lengths, *, block_k: int,
     return out.reshape(b, h, 1, hd)
 
 
-def flash_decode_supported(q_shape: tuple, k_shape: tuple, block_k: int) -> bool:
+def flash_decode_refusal(q_shape: tuple, k_shape: tuple, block_k: int) -> str:
+    """Why the kernel cannot take these shapes ('' = it can)."""
     b, h, s_q, hd = q_shape
     n_kv, t = k_shape[1], k_shape[2]
-    return (
-        s_q == 1
-        and h % n_kv == 0
-        and t % block_k == 0
-        and t >= block_k
-        and hd % 8 == 0
-    )
+    if s_q != 1:
+        return f"{s_q} query positions (decode attends one)"
+    if h % n_kv:
+        return f"{h} query heads do not group over {n_kv} kv heads"
+    if t % block_k or t < block_k:
+        return f"history {t} does not tile by {block_k}"
+    if hd % 8:
+        return f"head_dim {hd} is not a multiple of 8"
+    return ""
 
 
 def flash_decode_mode() -> str:
@@ -201,10 +206,6 @@ def flash_decode_mode() -> str:
     if flag == "interpret":
         return "interpret"
     return ""
-
-
-def flash_decode_enabled() -> bool:
-    return bool(flash_decode_mode())
 
 
 def parse_flash_decode(raw: str) -> str:
@@ -274,14 +275,21 @@ def flash_decode_attention(
     window: int = 0,
 ) -> jnp.ndarray:
     """Per-row-exact decode attention; Pallas kernel when supported, the
-    masked-dense reference (ops.attention.decode_attention) otherwise."""
+    masked-dense reference (ops.attention.decode_attention) otherwise.
+    Whether the kernel is wanted at all is the caller's decision
+    (:func:`resolve_flash_decode` per engine, :func:`flash_decode_mode` for
+    direct callers); this wrapper only refuses what cannot run here."""
     lengths = jnp.asarray(lengths)
     if lengths.ndim == 0:
         lengths = jnp.broadcast_to(lengths[None], (q.shape[0],))
     block_k = min(block_k, k_cache.shape[2])
-    if (interpret or flash_decode_enabled()) and flash_decode_supported(
-        q.shape, k_cache.shape, block_k
-    ):
+    refusal = (("" if interpret or jax.default_backend() == "tpu"
+                else f"platform is {jax.default_backend()}, not tpu")
+               or flash_decode_refusal(q.shape, k_cache.shape, block_k))
+    log_attention_path("flash_decode", refusal, interpret=interpret,
+                       q_shape=q.shape, kv_shape=k_cache.shape, block=block_k,
+                       window=window)
+    if not refusal:
         return _decode_call(q, k_cache, v_cache, lengths,
                             block_k=block_k, interpret=interpret,
                             window=window)

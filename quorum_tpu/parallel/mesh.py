@@ -25,6 +25,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from quorum_tpu.devices import serving_devices
+
 AXIS_DP = "dp"
 AXIS_PP = "pp"
 AXIS_SP = "sp"
@@ -55,7 +57,7 @@ def make_mesh(cfg: MeshConfig | None = None, devices=None) -> Mesh:
     (one activation ppermute per microbatch tick) are also neighbor hops.
     """
     if devices is None:
-        devices = jax.devices()
+        devices = serving_devices()
     cfg = cfg or MeshConfig(tp=len(devices))
     if cfg.n_devices > len(devices):
         raise ValueError(
@@ -68,7 +70,7 @@ def make_mesh(cfg: MeshConfig | None = None, devices=None) -> Mesh:
 
 def best_mesh(n_devices: int | None = None, *, want_dp: bool = False) -> Mesh:
     """A sensible default mesh: all devices on tp, or split dp×tp if asked."""
-    devices = jax.devices()
+    devices = serving_devices()
     n = len(devices) if n_devices is None else n_devices
     if want_dp and n % 2 == 0 and n > 1:
         return make_mesh(MeshConfig(dp=2, tp=n // 2), devices)
@@ -77,7 +79,7 @@ def best_mesh(n_devices: int | None = None, *, want_dp: bool = False) -> Mesh:
 
 def single_device_mesh() -> Mesh:
     """A 1×1×1 mesh — lets all code paths be mesh-agnostic."""
-    return make_mesh(MeshConfig(), jax.devices()[:1])
+    return make_mesh(MeshConfig(), serving_devices()[:1])
 
 
 def parse_disagg(raw: str) -> tuple[int, int]:
@@ -158,7 +160,7 @@ def disagg_meshes(n_prefill: int, n_decode: int, devices=None, *,
     spanning both (the handoff reshards on the fly when the two groups'
     layouts differ)."""
     if devices is None:
-        devices = jax.devices()
+        devices = serving_devices()
     need = n_prefill + n_decode
     if need > len(devices):
         raise ValueError(

@@ -37,13 +37,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax ≥ 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.ops.attention import attention, causal_mask
@@ -53,20 +48,6 @@ from quorum_tpu.parallel.mesh import AXIS_DP, AXIS_PP, AXIS_SP, AXIS_TP
 # NOTE: quorum_tpu.models.transformer is imported lazily inside functions —
 # the transformer itself imports quorum_tpu.parallel (ring attention), so a
 # module-level import here would be circular.
-
-
-def _pvary(tree, axes: tuple[str, ...]):
-    """Mark freshly-created arrays device-varying over ``axes`` (shard_map's
-    vma typing requires scan carries to match their varying outputs)."""
-    if not axes:
-        return tree
-    try:
-        return jax.lax.pcast(tree, axes, to="varying")
-    except (AttributeError, TypeError):
-        try:  # older jax spells it pvary
-            return jax.lax.pvary(tree, axes)
-        except AttributeError:
-            return tree  # pre-vma jax (< 0.5): no manual-varying typing
 
 
 def _check_pp_mesh(mesh: Mesh, spec: ModelSpec) -> int:
@@ -149,8 +130,8 @@ def _pipeline_blocks(blocks, xs, spec: ModelSpec, mesh: Mesh, remat: bool):
 
         # derive the carries from xs_local (inherits its dp vma), then mark
         # them pp-varying — the tick body makes them so (axis_index/ppermute)
-        cur0 = _pvary(xs_local[0] * 0, (AXIS_PP,))
-        out0 = _pvary(xs_local * 0, (AXIS_PP,))
+        cur0 = lax.pcast(xs_local[0] * 0, (AXIS_PP,), to="varying")
+        out0 = lax.pcast(xs_local * 0, (AXIS_PP,), to="varying")
         (_, outbuf), _ = lax.scan(
             tick, (cur0, out0), jnp.arange(n_micro + npp - 1))
         # only the last stage wrote anything; psum replicates it back to all
@@ -459,7 +440,7 @@ def staged_decode_chunk(
             live=jnp.zeros((sg,), bool),
             lens=jnp.zeros((sg,), jnp.int32),
         )
-        carry0 = (_pvary(bundle0, (AXIS_PP,)), _pvary(st0, (AXIS_PP,)),
+        carry0 = (*lax.pcast((bundle0, st0), (AXIS_PP,), to="varying"),
                   ck_l, cv_l)
         (_, st, ck_l, cv_l), _ = lax.scan(
             tick, carry0, jnp.arange(n_ticks))
@@ -486,7 +467,7 @@ def staged_decode_chunk(
         local, mesh=mesh,
         in_specs=(staged, cache_specs_k, cache_specs_v),
         out_specs=(cache_specs_k, cache_specs_v, rep_out),
-        check_rep=False,
+        check_vma=False,
     )
     cache_k, cache_v, out = fn(blocks, cache_k, cache_v)
     toks = out["toks"].T                       # [B, n_steps]

@@ -23,14 +23,9 @@ from __future__ import annotations
 
 from functools import partial
 
-import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax ≥ 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from quorum_tpu.ops.attention import NEG_INF
 from quorum_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
@@ -91,13 +86,7 @@ def _ring_local(q, k, v, lengths, *, axis: str, sp_size: int, _mesh_axes=()):
     acc0 = jnp.zeros((b, n_kv, g, s_local, hd), jnp.float32)
     # Mark the freshly-created carries as device-varying so the scan carry
     # type matches its (varying) outputs under shard_map's vma typing.
-    try:
-        m0, l0, acc0 = jax.lax.pcast((m0, l0, acc0), tuple(_mesh_axes), to="varying")
-    except (AttributeError, TypeError):
-        try:  # older jax spells it pvary
-            m0, l0, acc0 = jax.lax.pvary((m0, l0, acc0), tuple(_mesh_axes))
-        except AttributeError:
-            pass  # pre-vma jax (< 0.5): no varying-manual typing — no-op
+    m0, l0, acc0 = lax.pcast((m0, l0, acc0), tuple(_mesh_axes), to="varying")
     # sp_size-1 (compute + permute) steps, then one final compute with the
     # last-held block OUTSIDE the scan — the ring's last permutation would
     # only be thrown away, so it is never sent.

@@ -145,7 +145,7 @@ def param_partition_specs(
     ``K·hd``, so ``_fit_spec``'s divisibility check can't see head
     boundaries: 2 KV heads × hd=16 on tp=4 passes (32 % 4 == 0) but shards
     each KV head across two devices. Sub-head-sharded kv projections
-    miscompile under GSPMD on jax 0.4.x for batch-1 prefill (the engine's
+    miscompiled under GSPMD for batch-1 prefill (the engine's
     slot-mode admission path) — wrong logits, deterministic, mesh-dependent
     (dp=2×tp=4 yes, tp=4 no) — which was half of the PR 16 "MoE EP
     divergence" quarantine. Replicating mirrors ``kv_cache_sharding``'s GQA

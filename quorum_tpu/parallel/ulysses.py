@@ -33,12 +33,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax ≥ 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from quorum_tpu.ops.attention import prefill_attention
 from quorum_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP

@@ -53,6 +53,7 @@ from quorum_tpu.telemetry.recorder import RECORDER
 from quorum_tpu.backends.base import Backend, BackendError
 from quorum_tpu.backends.registry import BackendRegistry, build_registry
 from quorum_tpu.config import Config, load_config
+from quorum_tpu.devices import device_memory
 from quorum_tpu.server.asgi import (
     App,
     JSONResponse,
@@ -269,18 +270,25 @@ def create_app(
         engine runs TWO cooperating scheduler loops, and a dead
         decode-group loop must not report healthy because the prefill loop
         is still alive (or vice versa) — /ready then sheds whenever either
-        group would."""
+        group would.
+
+        A configured backend that failed to CONSTRUCT (registry.failed) is
+        ``degraded`` too, with its name and error in its own check row:
+        requests degrade to the survivors, but a quorum missing a member
+        must not report whole (and /ready stays 503)."""
         checks: list[dict] = []
         for name, engine in _distinct_engines(rt.reg, "health"):
             row = engine.health()
             row["backend"] = name
             checks.append(row)
-        status = "healthy"
+        failed = [{"backend": name, "constructed": False, "error": err}
+                  for name, err in rt.reg.failed.items()]
+        status = "degraded" if failed else "healthy"
         for row in checks:
             if (not row["scheduler_alive"]
                     or not row.get("prefill_scheduler_alive", True)
                     or not row["snapshot_worker_alive"]):
-                return "unhealthy", checks
+                return "unhealthy", checks + failed
             if (row["breaker"] != "closed"
                     or row["pending"] >= row["queue_limit"]
                     or row.get("draining")):
@@ -296,7 +304,7 @@ def create_app(
         if status == "healthy" and checks \
                 and slo_mod.burning_class() is not None:
             status = "degraded"
-        return status, checks
+        return status, checks + failed
 
     @app.route("GET", "/health", "/v1/health")
     async def health(request: Request) -> Response:
@@ -308,6 +316,13 @@ def create_app(
         status, checks = _engine_health()
         body: dict = {"status": status}
         if checks:
+            # Allocator readings ride /health only: /ready and the fleet's
+            # telemetry poll share _engine_health and stay host-side.
+            memory = {name: device_memory(engine.mesh) for name, engine
+                      in _distinct_engines(rt.reg, "health")}
+            for row in checks:
+                if memory.get(row["backend"]):
+                    row["device_memory"] = memory[row["backend"]]
             body["checks"] = checks
             # Per-class SLO accounting (good/breached by stage + burn
             # rate over the sliding window) — the degradation signal's
@@ -469,8 +484,8 @@ def create_app(
         status, checks = _engine_health()
         queue_depth = sum(int(row.get("pending", 0) or 0)
                           for row in checks)
-        breakers = {row["backend"]: row.get("breaker", "closed")
-                    for row in checks}
+        breakers = {row["backend"]: row["breaker"]
+                    for row in checks if "breaker" in row}
         latency = {name: engine.latency.snapshot()
                    for name, engine in _distinct_engines(reg, "latency")}
         prefix_store_bytes = 0

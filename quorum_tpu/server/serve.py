@@ -18,6 +18,7 @@ from typing import Any
 import h11
 
 from quorum_tpu.config import load_config
+from quorum_tpu.devices import NoAcceleratorError
 from quorum_tpu.observability import setup_aggregation_log
 from quorum_tpu.server.app import create_app
 
@@ -195,7 +196,11 @@ def main() -> None:
 
     initialize()
     cfg = load_config(args.config)
-    app = create_app(cfg, watch_config=True if args.watch else None)
+    try:
+        app = create_app(cfg, watch_config=True if args.watch else None)
+    except NoAcceleratorError as e:
+        # No fallback: a tpu:// config without a TPU does not start.
+        raise SystemExit(f"quorum_tpu.server.serve: {e}") from e
     try:
         asyncio.run(serve(app, args.host, args.port))
     except KeyboardInterrupt:

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 
 from quorum_tpu.ops.attention import prefill_attention
 from quorum_tpu.ops.flash_attention import (
+    flash_disabled,
     flash_prefill_attention,
-    flash_supported,
+    flash_refusal,
 )
 
 # Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
@@ -74,16 +75,40 @@ def test_flash_small_bucket_uses_clamped_blocks():
     assert_valid_rows_close(out, ref, lengths)
 
 
-def test_flash_supported_gates():
-    assert flash_supported((1, 4, 256, 64), (1, 2, 256, 64), 128, 128)
-    assert not flash_supported((1, 4, 100, 64), (1, 2, 100, 64), 128, 128)
-    assert not flash_supported((1, 3, 256, 64), (1, 2, 256, 64), 128, 128)
+def test_flash_refusal_gates():
+    assert not flash_refusal((1, 4, 256, 64), (1, 2, 256, 64), 128, 128)
+    assert flash_refusal((1, 4, 100, 64), (1, 2, 100, 64), 128, 128)
+    assert flash_refusal((1, 3, 256, 64), (1, 2, 256, 64), 128, 128)
+    # tensor-parallel callers: both head counts must split over tp
+    assert not flash_refusal((1, 8, 256, 64), (1, 4, 256, 64), 128, 128, tp=4)
+    assert flash_refusal((1, 8, 256, 64), (1, 2, 256, 64), 128, 128, tp=4)
 
 
 def test_prefill_uses_fallback_off_tpu():
     """On CPU (tests force JAX_PLATFORMS=cpu) the dispatcher must take the
     XLA reference path, not the kernel."""
-    from quorum_tpu.ops.flash_attention import flash_enabled
-
     assert jax.default_backend() == "cpu"
-    assert not flash_enabled()
+    assert "cpu" in flash_disabled()
+
+
+def test_flash_under_tensor_parallel_mesh_matches_reference():
+    """tp>1 callers: the kernel has no partitioning rule (XLA refuses a
+    Mosaic call inside a GSPMD-partitioned program), so it runs under
+    shard_map over tp, each shard on its own q/kv head slice — same numbers
+    as the unsharded reference, GQA grouping intact per shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from quorum_tpu.parallel import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(tp=4))
+    heads = NamedSharding(mesh, P(None, "tp", None, None))
+    q = jax.device_put(rand(0, (1, 8, 128, 64)), heads)
+    k = jax.device_put(rand(1, (1, 4, 128, 64)), heads)
+    v = jax.device_put(rand(2, (1, 4, 128, 64)), heads)
+    lengths = jnp.asarray([101], jnp.int32)
+    ref = prefill_attention(q, k, v, lengths, window=48)
+    out = jax.jit(lambda q, k, v: flash_prefill_attention(
+        q, k, v, lengths, block_q=64, block_k=64, interpret=True,
+        window=48, tp_mesh=mesh))(q, k, v)
+    assert out.sharding.spec == heads.spec
+    assert_valid_rows_close(np.asarray(out), np.asarray(ref), lengths)

@@ -16,7 +16,7 @@ from quorum_tpu.ops.attention import decode_attention
 from quorum_tpu.ops.flash_decode import (
     DEFAULT_BLOCK_K,
     flash_decode_attention,
-    flash_decode_supported,
+    flash_decode_refusal,
 )
 
 # Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
@@ -74,7 +74,7 @@ def test_unsupported_shapes_fall_back():
     ref = decode_attention(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-    assert not flash_decode_supported(q.shape, k.shape, 64)  # 96 % 64 != 0
+    assert flash_decode_refusal(q.shape, k.shape, 64)  # 96 % 64 != 0
 
 
 def test_under_vmap_members_axis():

@@ -73,8 +73,8 @@ def test_tp_dp_sharded_matches_single_device():
 
 # De-quarantined (PR 17): the PR 16 divergence was a GSPMD miscompile in
 # the grouped dispatch's expert-buffer gather (a gather from a concat of a
-# dp-sharded token matrix with a replicated pad row reads the wrong shard
-# on jax 0.4.x) — fixed in models/transformer.py by the clamp-index+mask
+# dp-sharded token matrix with a replicated pad row read the wrong shard)
+# — fixed in models/transformer.py by the clamp-index+mask
 # formulation.
 def test_moe_expert_parallel_matches_single_device():
     spec = resolve_spec("mixtral-tiny")  # 4 experts over tp=4
