@@ -1,0 +1,543 @@
+#!/usr/bin/env python3
+"""The benchmark's one command: a cell of BENCHMARK.json, measured on the chip.
+
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+This process never imports jax. It starts the system under test through its
+normal entry point (``python -m quorum_tpu.server.serve``) as the one child
+that holds the chip, drives ``/chat/completions`` with ``stream: true`` over
+a real socket, and reads the program's own counters and spans. Everything
+that belongs to one configuration, traffic mix or per-layer metric is a file
+found by the name in BENCHMARK.json (``configs/``, ``traffic/``,
+``layer_metrics/``); adding a cell adds files and entries and edits nothing.
+
+A run: start the server (weights from ``--seed``, on the device); warm up one
+request per program variant the mix can reach; start the cell's schedule
+``ramp_s`` before the window; measure ``--seconds``; let every request of the
+window finish while the schedule goes on; probe the served path for
+``correct``; stop the server; compare the probe with the plain reference on
+the freed chip. The last line of stdout is the result; earlier lines time the
+set-up steps and say what the run saw.
+
+What stops a run (exit 1, no result line, one ``benchmark FAILED: <step>:
+<reason>`` line on stderr) is what would stop it with ``--trace 0``: no
+chip, a server that does not get ready, a load generator that does not
+finish, a probe or a reference that cannot be made. Everything ``--trace 1``
+adds runs in ``traced.py`` under guards and limits of its own: a part that
+cannot be read leaves its metrics out and says so on stderr, and the run
+still prints its line and exits 0. A signal stops the children and then
+kills this process as that signal would (143 for SIGTERM).
+
+``--rehearsal`` runs the same code on the CPU at a tiny preset to debug the
+plumbing: its line is tagged and is no measurement.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+import shutil
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+T_START = time.monotonic()
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import e2e  # noqa: E402
+import loadgen  # noqa: E402
+import serving  # noqa: E402
+import traced  # noqa: E402
+
+READY_DEADLINE_S = 900.0
+WARMUP_REQUEST_DEADLINE_S = 600.0
+AFTER_WINDOW_DEADLINE_S = 240.0   # a scrape or a probe behind a profiler stop
+REFERENCE_LIMIT_S = 600.0
+# Served log-probabilities against the float32 reference, per position, in
+# nats: {bytes per weight: limits}. The served model computes in bf16
+# (relative rounding 2**-8 per operation) or in dynamic w8a8 int8 (weights and
+# activations each rounded to 1/254 of their row's largest value) through up
+# to 32 layers. On the chip the worst of a probe's positions differed by
+# 0.018-0.029 (bf16, 5 layers) and by 0.07-0.23 (int8, 32 layers: one prompt
+# in six reads near twice the others), the median over its positions by
+# 0.006-0.009 (bf16) and 0.020-0.046 (int8) -- PERF.md. A dropped layer, a
+# wrong mask, a wrong rotary pairing or a stale cache row moves every
+# position it touches by 0.5 and more. So two limits: no position may differ
+# by "max", which a fault passes at once, and the median over the positions
+# may not pass "median", set at about twice the largest median seen, which
+# noise's rare large position does not move and which an int8 computation of
+# a bf16 cell would pass.
+PROBE_TOL = {1: {"max": 0.5, "median": 0.1}, 2: {"max": 0.1, "median": 0.02}}
+REHEARSAL_FAULTS = ("profile", "reduce", "span", "first_token")
+
+_children: list = []  # whatever holds a process: .kill() ends it at once
+
+
+class Failed(Exception):
+    """A step no run, traced or not, can do without."""
+
+    def __init__(self, step: str, reason: str):
+        super().__init__(f"{step}: {reason}")
+
+
+def say(what: str, **kv) -> None:
+    print(json.dumps({"t": round(time.monotonic() - T_START, 3),
+                      "step": what, **kv}), flush=True)
+
+
+def on_signal(signum, _frame) -> None:
+    """Stop the children so that none keeps the chip, then die of the same
+    signal: a run the driver stops for time must look like one."""
+    print(f"benchmark FAILED: signal: stopped by signal {signum} after "
+          f"{time.monotonic() - T_START:.0f} s", file=sys.stderr, flush=True)
+    for c in _children:
+        c.kill()
+    signal.signal(signum, signal.SIG_DFL)
+    os.kill(os.getpid(), signum)
+
+
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def metrics_of(bench: dict, kind: str, cell: str) -> list[dict]:
+    return [m for m in bench[kind]
+            if "workloads" not in m or cell in m["workloads"]]
+
+
+class Child:
+    """A helper process (reference, trace reduction) with a time limit."""
+
+    def __init__(self, argv: list, env: dict):
+        self.proc = subprocess.Popen(
+            argv, stdout=subprocess.PIPE, stderr=subprocess.PIPE, env=env,
+            cwd=REPO, start_new_session=True, text=True)
+        _children.append(self)
+
+    def kill(self) -> None:
+        try:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        self.proc.wait()
+
+    def result(self, limit_s: float) -> tuple[int, str, str]:
+        """(exit code, stdout, end of stderr); 124 where the limit cut it."""
+        try:
+            out, err = self.proc.communicate(timeout=limit_s)
+            rc = self.proc.returncode
+        except subprocess.TimeoutExpired:
+            self.kill()
+            out, err = self.proc.communicate()
+            rc = 124
+        _children.remove(self)
+        return rc, out or "", (err or "")[-2000:]
+
+
+def warm_up(server, traffic: dict, div, seed: int) -> list:
+    """One request per program variant the mix can reach, one after another,
+    so each loads (first run of a checkout: compiles) before the ramp. A
+    request that times out while a cold program compiles is repeated."""
+    rng = random.Random(seed ^ 0x9E3779B9)
+    steps = []
+    for i, pair in enumerate(traffic["warmup"]):
+        p, c = loadgen.scale_pair(pair, div)
+        t_w = time.monotonic()
+        deadline = t_w + WARMUP_REQUEST_DEADLINE_S
+        attempts = 0
+        while True:
+            attempts += 1
+            rec = loadgen.Record(index=-1 - i, phase="warmup", due=0.0,
+                                 sent=None, prompt_tokens=p, max_tokens=c)
+            text = loadgen.prompt_text(rng, p, 900000 + i * 100 + attempts)
+            loadgen.stream_request(server.port, text, c, rec, t_w,
+                                   threading.Event())
+            if not e2e.failed(rec):
+                break
+            if server.proc.poll() is not None:
+                raise Failed("warm-up", "the server died: "
+                             + server.log_tail())
+            m = server.try_metrics()
+            if any(m.get("quorum_tpu_engine_" + k, 0) for k in (
+                    "failures_total", "rebuilds_total", "breaker_state")):
+                # not a timeout while a cold program compiled: the engine
+                # itself failed (a program that does not fit the chip, say)
+                raise Failed("warm-up", f"request {pair} failed in the "
+                             f"engine: {rec['error'] or rec['status']}; "
+                             + server.errors_in_log())
+            if time.monotonic() > deadline:
+                raise Failed("warm-up", f"request {pair} never answered: "
+                             f"{rec['error'] or rec['status']}")
+            time.sleep(1.0)
+        steps.append({"pair": [p, c], "seconds": round(
+            time.monotonic() - t_w, 3), "attempts": attempts})
+    return steps
+
+
+def wait_idle(server, timeout_s: float = 60.0) -> None:
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        m = server.try_metrics()
+        if m and not any(m.get("quorum_tpu_engine_" + k, 0) for k in (
+                "busy_slots", "pending", "admitting")):
+            return
+        time.sleep(0.2)
+
+
+def probe(server, backends: list, seed: int, vocab: int,
+          lengths: tuple) -> list:
+    """One greedy /completions request with logprobs per backend (per quorum
+    member), on the idle engine after the window: a seeded prompt of token
+    ids and some generated tokens with their served log-probabilities.
+    ``lengths`` are the traffic file's ``probe``, a prompt the cell's grid
+    holds: so the probe runs the programs the window ran (in ``longprompt``
+    two 512-token prefill segments, a tail segment and the 2048 decode
+    history bucket), not the smallest ones."""
+    rng = random.Random(seed ^ 0xC0FFEE)
+    n_prompt, n_new = lengths
+    out = []
+    for i, b in enumerate(backends):
+        prompt = [rng.randrange(3, vocab) for _ in range(n_prompt)]
+        status, text = server.patiently(
+            "POST", "/completions", {
+                "model": b["model"], "prompt": prompt, "temperature": 0,
+                "max_tokens": n_new, "logprobs": 0},
+            each_timeout=120.0, deadline_s=AFTER_WINDOW_DEADLINE_S)
+        if status != 200:
+            raise Failed("probe", f"{b['name']} answered {status}: "
+                         f"{text[:300]}")
+        lp = json.loads(text)["choices"][0]["logprobs"]
+        out.append({"backend": i, "prompt": prompt,
+                    "token_logprobs": lp["token_logprobs"],
+                    "tokens": lp["tokens"]})
+    return out
+
+
+def client_view(records: list, window_s: float) -> dict:
+    """What the window's clients saw besides the bounded metrics: kept on an
+    earlier line so that a later benchmark PR can judge whether any of it
+    repeats well enough to bound."""
+    win = e2e.window_records(records)
+    ttft = e2e.ttft_ms(win)
+    return {
+        "requests": len(win),
+        "ttft_mean_ms": sum(ttft) / len(ttft) if ttft else None,
+        "ttft_p50_ms": e2e.percentile(ttft, 0.5),
+        "ttft_p90_ms": e2e.percentile(ttft, 0.9),
+        "latency_p50_ms": e2e.percentile(e2e.latency_ms(win), 0.5),
+        "offered_tokens_per_s": e2e.tokens_in_window(records, window_s)
+        / window_s}
+
+
+def token_accounting(records: list, window: list, m_pre: dict,
+                     m_idle: dict) -> dict:
+    """Did every finished stream get the tokens it asked for? A single
+    backend's usage chunk says so per stream. The quorum merge forwards text
+    only, so there the engine's own count, from the scrape before the ramp
+    to the one at idle after the cool-down, stands for the members' streams:
+    requests the generator cut off after the window add to it, a stream that
+    met EOS under random weights takes a little away (about one token in
+    32000), so a run whose finished streams got visibly fewer tokens than
+    they asked for shows."""
+    short = [[r["index"], s["tokens"], r["max_tokens"]]
+             for r in window if not e2e.failed(r)
+             for key, s in r["streams"].items()
+             if key == "single" and s.get("tokens") is not None
+             and s["tokens"] != r["max_tokens"] and s.get("finish") != "stop"]
+    asked = sum(e2e.stream_tokens(r, s) for r in records
+                if not e2e.failed(r) for s in e2e.member_streams(r))
+    served = (m_idle.get("quorum_tpu_engine_tokens_total", 0.0)
+              - m_pre.get("quorum_tpu_engine_tokens_total", 0.0))
+    return {"asked_by_finished_requests": asked,
+            "engine_tokens_ramp_to_idle": served, "streams_short": short[:5],
+            "eos_stops_in_window": sum(
+                1 for r in window for s in e2e.member_streams(r)
+                if s.get("finish") == "stop"),
+            "ok": not short and served >= 0.99 * asked}
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="CPU, tiny preset: debugs this script, measures "
+                         "nothing")
+    ap.add_argument("--rate", type=float, default=None,
+                    help="builder's knee check only: override the traffic "
+                         "file's rate_per_s")
+    ap.add_argument("--keep-profile", action="store_true",
+                    help="builder's only: leave the reduced profile under "
+                         "--out, to cut a test fixture from")
+    ap.add_argument("--inject-fault", action="append", default=[],
+                    choices=REHEARSAL_FAULTS,
+                    help="rehearsal only: break one part of the traced run")
+    ap.add_argument("--out", default=None)
+    return ap.parse_args(argv)
+
+
+def run(args: argparse.Namespace) -> int:
+    if args.inject_fault and not (args.rehearsal and args.trace):
+        raise Failed("arguments", "--inject-fault is for a traced CPU "
+                     "rehearsal only")
+    if not os.path.isdir(os.path.join(REPO, "quorum_tpu")):
+        raise Failed("checkout", f"{REPO} holds no quorum_tpu/: the "
+                     "benchmark drives the checkout it sits in")
+    bench = load_json(os.path.join(REPO, "BENCHMARK.json"))
+    cell = next((w for w in bench["workloads"]
+                 if w["name"] == args.workload), None)
+    if cell is None:
+        raise Failed("arguments", f"no workload {args.workload!r} in "
+                     "BENCHMARK.json")
+    cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    cfg = load_json(os.path.join(REPO, cfg_entry["file"]))
+    traffic = load_json(os.path.join(HERE, "traffic",
+                                     cell["traffic"] + ".json"))
+    try:
+        loadgen.check_traffic(traffic)
+    except ValueError as e:
+        raise Failed("traffic file", str(e)) from None
+    if args.rate is not None:
+        traffic["rate_per_s"] = args.rate
+    window_s = float(args.seconds if args.seconds is not None
+                     else bench["run_seconds"])
+    out_dir = os.path.abspath(args.out or os.path.join(
+        HERE, "out", args.workload))
+    os.makedirs(out_dir, exist_ok=True)
+    # only the newest profile is kept, and only until it is reduced
+    shutil.rmtree(os.path.join(out_dir, "profiles"), ignore_errors=True)
+
+    side = cfg["rehearsal"] if args.rehearsal else cfg["serve"]
+    backends = side["backends"]
+    div = ({"prompt_div": side["prompt_div"],
+            "completion_div": side["completion_div"]}
+           if args.rehearsal else None)
+    vocab = 512 if args.rehearsal else cfg["vocab_size"]
+    want_platform = "cpu" if args.rehearsal else "tpu"
+    if args.rehearsal:
+        traffic["ramp_s"] = float(traffic["ramp_s"]) / side["ramp_div"]
+        if "rate_per_s" in traffic:  # enough requests in a 4 s window
+            traffic["rate_per_s"] *= side.get("rate_mul", 1)
+
+    env = dict(os.environ, PYTHONUNBUFFERED="1", PYTHONPATH=os.pathsep.join(
+        [REPO] + [p for p in [os.environ.get("PYTHONPATH")] if p]),
+        JAX_DEBUG_LOG_MODULES="jax._src.compiler",
+        QUORUM_TPU_TRACE_CAPACITY="4096")
+    # QUORUM_TPU_PROFILE_DIR would make the server trace every request: the
+    # one on-demand profile goes where the server puts it by default,
+    # profiles/ondemand/<time> under its working directory, which is out_dir.
+    env.pop("QUORUM_TPU_PROFILE_DIR", None)
+    env.pop("BENCH_RUN", None)
+    if args.rehearsal:
+        env.update(JAX_PLATFORMS="cpu", QUORUM_TPU_COMPILE_CACHE="0")
+    elif not env.get("JAX_COMPILATION_CACHE_DIR"):
+        # a fixed path inside the checkout: the path is part of the cache key
+        env["QUORUM_TPU_COMPILE_CACHE"] = os.path.join(HERE, "out",
+                                                       "jax_cache")
+    config_path = os.path.join(out_dir, "config.yaml")
+    serving.write_server_config(config_path, backends,
+                                cfg["serve"]["timeout_s"], args.seed)
+
+    # ---- set-up: server, weights, every program the mix can reach ----------
+    server = serving.Server(config_path, out_dir, env)
+    _children.append(server)
+    say("server spawned", pid=server.proc.pid)
+    try:
+        ready_s = server.wait_ready(READY_DEADLINE_S)
+    except RuntimeError as e:
+        raise Failed("server start", str(e)) from None
+    rows = server.health().get("checks") or []
+    if len(rows) != 1 or "platform" not in rows[0]:
+        raise Failed("server start", "expected one engine row with a "
+                     f"platform on /health: {rows}")
+    dev = rows[0]
+    if dev["platform"] != want_platform:
+        raise Failed("device", f"server reports platform "
+                     f"{dev['platform']!r}; this run requires "
+                     f"{want_platform!r}")
+    if not args.rehearsal and dev["device_count"] < cell["chips"]:
+        raise Failed("device", f"cell needs {cell['chips']} chip(s), jax "
+                     f"reports {dev['device_count']}")
+    peaks = load_json(os.path.join(HERE, "peaks.json")).get(
+        dev.get("device_kind"))
+    if peaks is None and not args.rehearsal:
+        raise Failed("device", f"device kind {dev.get('device_kind')!r} is "
+                     "not in benchmarks/peaks.json")
+    say("server ready", seconds_from_spawn=round(ready_s, 3),
+        device=dev.get("device_kind"), mesh=dev.get("mesh"))
+    warm = warm_up(server, traffic, div, args.seed)
+    hits, misses = serving.cache_log_counts(server.log_path)
+    say("warm-up done", requests=warm, compile_cache_hits=hits,
+        compile_cache_misses=misses)
+
+    # ---- ramp, window, cool-down ------------------------------------------
+    m_pre = server.metrics()
+    t0 = time.monotonic() + float(traffic["ramp_s"]) + 0.2
+    load = loadgen.LoadRun(server.port, traffic, args.seed, window_s, div)
+    load_err: list = []
+
+    def drive() -> None:
+        try:
+            load.run(t0)
+        except Exception as e:  # told below, as the load generator's failure
+            load_err.append(e)
+
+    driver = threading.Thread(target=drive, daemon=True)
+    driver.start()
+    # The traced run reads its counters, spans and client clocks over
+    # [0, read_until_s): up to where the profile starts, so that nothing the
+    # profiler's stop does to the server is in them. The profile itself ends
+    # with the window.
+    tracing = traced.Tracing(server, window_s, out_dir, args.inject_fault,
+                             cfg["intermediate_size"], args.keep_profile) \
+        if args.trace else None
+    read_until_s = tracing.read_until_s if tracing else window_s
+    time.sleep(max(0.0, t0 - time.monotonic()))
+    setup_s = time.monotonic() - T_START
+    scrapes = [server.try_metrics()]
+    log0 = serving.cache_log_total(server.log_path)
+    say("window starts", setup_s=round(setup_s, 3))
+    if tracing:
+        tracing.start_profile(t0)
+    for k in (1, 2, 3):
+        time.sleep(max(0.0, t0 + read_until_s * k / 3.0 - time.monotonic()))
+        scrapes.append(server.try_metrics())
+    log1 = serving.cache_log_total(server.log_path)
+    time.sleep(max(0.0, t0 + window_s - time.monotonic()))
+    say("window ends", read_until_s=read_until_s,
+        queue_wait_ms_by_third=[traced.queue_wait_ms(a, b)
+                                for a, b in zip(scrapes, scrapes[1:])],
+        pending_at_read_end=scrapes[-1].get("quorum_tpu_engine_pending"))
+    driver.join(timeout=loadgen.REQUEST_TIMEOUT_S + 60)
+    if driver.is_alive() or load_err:
+        raise Failed("load generator", f"did not finish: {load_err}")
+    records = load.records
+    if "first_token" in args.inject_fault:
+        traced.strip_first_token(e2e.window_records(records)[0])
+    window = e2e.window_records(records)
+    say("cool-down done", requests_total=len(records),
+        requests_in_window=len(window))
+
+    # ---- after the window: memory, spans, the probe -----------------------
+    wait_idle(server)
+    try:
+        m_idle = server.metrics(AFTER_WINDOW_DEADLINE_S)
+        health = server.health(AFTER_WINDOW_DEADLINE_S)["checks"][0]
+    except (RuntimeError, OSError, ValueError, LookupError) as e:
+        raise Failed("scrape after the window", repr(e)) from None
+    memory_peak = max([d.get("peak_bytes_in_use") or 0
+                       for d in health.get("device_memory") or []
+                       if d.get("in_mesh")] or [0])
+    if tracing:
+        tracing.fetch_spans(window)
+    # a rehearsal divides the probe's prompt like every prompt, so that it
+    # fits the tiny preset; the generated tokens stay
+    probes = probe(server, backends, args.seed, vocab,
+                   (loadgen.scale_pair(traffic["probe"], div)[0],
+                    traffic["probe"][1]))
+    if tracing:
+        tracing.wait_for_profile(t0 + window_s)
+    rc = server.stop()
+    _children.remove(server)
+    say("server stopped", exit_code=rc)
+
+    # ---- the reference on the freed chip, then the trace on the CPU --------
+    probe_path = os.path.join(out_dir, "probe.json")
+    with open(probe_path, "w") as f:
+        json.dump({"platform": want_platform, "probes": probes,
+                   "tol": PROBE_TOL[cfg["weight_bytes_per_param"]],
+                   "backends": [dict(b, url=b["url"].replace(
+                       "{seed}", str(serving.weight_seed(args.seed))))
+                       for b in backends]}, f)
+    ref_env = dict(env)
+    ref_env.pop("JAX_DEBUG_LOG_MODULES", None)
+    ref_child = Child([sys.executable, os.path.join(HERE, "reference_check.py"),
+                       probe_path], ref_env)
+    rc, ref_out, ref_err = ref_child.result(REFERENCE_LIMIT_S)
+    try:
+        ref = json.loads(ref_out.strip().splitlines()[-1])
+    except (ValueError, IndexError):
+        ref = None
+    if rc != 0 or not isinstance(ref, dict):
+        raise Failed("reference", f"the check exited {rc}: {ref_err}")
+    say("reference compared", **ref)
+    if tracing:
+        tracing.reduce_profile(Child, env)
+
+    # ---- correct ------------------------------------------------------------
+    n_failed = sum(1 for r in window if e2e.failed(r))
+    accounting = token_accounting(records, window, m_pre, m_idle)
+    say("token accounting", **accounting)
+    correct = (bool(ref["ok"]) and accounting["ok"] and n_failed == 0
+               and bool(window))
+    if not correct:
+        # the driver keeps the end of stderr of a run it refuses
+        print("benchmark NOT CORRECT: " + json.dumps({
+            "reference": ref, "token_accounting": accounting,
+            "requests_failed": [
+                {k: r.get(k) for k in ("index", "phase", "status", "error",
+                                       "end", "prompt_tokens", "max_tokens")}
+                for r in window if e2e.failed(r)][:5],
+            "requests_in_window": len(window)}), file=sys.stderr, flush=True)
+
+    # ---- metrics ------------------------------------------------------------
+    device = {"platform": dev["platform"], "kind": dev["device_kind"],
+              "count": dev["device_count"], "memory_peak_bytes": memory_peak}
+    result = {"correct": correct, "attempted": len(window),
+              "failed": n_failed, "metrics": {}, "device": device}
+    if tracing:
+        art = {"records": records, "window_s": window_s,
+               "read_until_s": read_until_s, "traffic": traffic,
+               "config": cfg, "m0": scrapes[0], "m1": scrapes[-1],
+               "log_compiles0": log0, "log_compiles1": log1,
+               "memory_peak_bytes": memory_peak, "peaks": peaks,
+               "chips": cell["chips"]}
+        say("traced parts", **tracing.report(
+            art, metrics_of(bench, "per_layer", args.workload), result))
+    else:
+        say("window client view", **client_view(records, window_s))
+        values = e2e.end_to_end(records, window_s, setup_s)
+        for m in metrics_of(bench, "end_to_end", args.workload):
+            if values.get(m["name"]) is None:
+                raise Failed("metrics", f"the window gives no {m['name']}")
+            result["metrics"][m["name"]] = {"value": values[m["name"]],
+                                            "unit": m["unit"]}
+    with open(os.path.join(out_dir, "records.json"), "w") as f:
+        json.dump({"records": records, "result": result}, f)
+    tag = ("[REHEARSAL on the CPU at tiny size - not a chip result] "
+           if args.rehearsal else "")
+    print(tag + json.dumps(result), flush=True)
+    return 0
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    for signum in (signal.SIGTERM, signal.SIGINT, signal.SIGHUP):
+        signal.signal(signum, on_signal)
+    try:
+        return run(args)
+    except Failed as e:
+        reason = str(e)
+    except Exception as e:  # a fault of the harness itself: say where
+        import traceback
+        where = [ln.strip() for ln in traceback.format_exc().splitlines()
+                 if ln.lstrip().startswith("File ")]
+        reason = f"harness: {e!r} at {where[-1] if where else '?'}"
+    # the line first: a child that is slow to stop must not cost the reason
+    print(f"benchmark FAILED: {reason}", file=sys.stderr, flush=True)
+    for c in list(_children):
+        c.kill()
+    return 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
