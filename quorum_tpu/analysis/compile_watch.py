@@ -20,6 +20,11 @@ two readings:
   family leak, an unhashable key component), exactly what the static
   ``recompile`` rules and compile_budget.json exist to prevent.
 
+A third reading serves the engine's turn accounting:
+:func:`thread_seconds`, the seconds the CALLING thread has spent inside
+backend compiles (a load from the persistent cache raises the same event),
+which the scheduler loop books as its turn's ``compile`` phase.
+
 Pure stdlib + jax; safe to import before backends exist.
 """
 
@@ -33,12 +38,15 @@ _lock = threading.Lock()
 _installed = False
 _warm = False
 _total = 0
+_local = threading.local()  # .seconds: this thread's compile time so far
 
 
 def _on_event_duration(event: str, duration: float, **_kw) -> None:
     global _total
     if event != COMPILE_EVENT:
         return
+    # jax reports the event on the thread that compiled
+    _local.seconds = thread_seconds() + duration
     with _lock:
         _total += 1
         warm = _warm
@@ -64,6 +72,12 @@ def compiles_total() -> int:
     """XLA compiles observed since install() (0 if never installed)."""
     with _lock:
         return _total
+
+
+def thread_seconds() -> float:
+    """Seconds the calling thread has spent in XLA backend compiles (built,
+    or loaded from the persistent cache) since it started."""
+    return getattr(_local, "seconds", 0.0)
 
 
 def mark_warm() -> None:

@@ -1105,6 +1105,8 @@ class TpuBackend:
             if lp_content is not None and i < len(req.lp):
                 pending_lp.append(self._lp_entry(t, req.lp[i], top_n))
             text = matcher.feed(detok.feed(t))
+            if text and req.t_delta is None:
+                req.mark_first_delta()  # the first token's path
             if text and lp_content is not None:
                 lp_content.extend(self._take_aligned(pending_lp, len(text)))
             pieces.append(text)
@@ -1839,6 +1841,10 @@ class TpuBackend:
                     more = pos < len(events) - 1
                     if kind == "text":
                         text, lp, ids = val
+                        if text and reqs[idx].t_delta is None:
+                            # the first token's path: past the producer
+                            # thread's detokenizer and the hop onto the loop
+                            reqs[idx].mark_first_delta()
                         out = oai.chunk(id=chunk_id, model=model,
                                         delta={"content": text}, index=idx)
                         if plan["logprobs"] >= 0:

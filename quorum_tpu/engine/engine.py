@@ -227,6 +227,14 @@ CONSTRAIN_ARENA_KEEP = 4096
 # fails alone (503-style GrammarArenaFull, retry after quiescence or with
 # an already-resident grammar) — never the co-batched streams.
 CONSTRAIN_ARENA_MAX = 8192
+# The parts of a scheduler turn (InferenceEngine._phase; docs/
+# observability.md "The engine's turn"): metrics() exports each as
+# turn_<phase>_seconds_total, a running profile shows each as an
+# ``engine.<phase>`` annotation on the scheduler thread's host line.
+# ``reap_block`` is every wait in a blocking host fetch: the oldest decode
+# chunk's, and inside ``admit`` a single-shot admission's first token.
+TURN_PHASES = ("idle", "sweep", "admit", "fill", "reap_block", "emit",
+               "compile")
 _CKPT_ENSEMBLE_ERROR = ("ensemble members are seeded random inits; a "
                         "checkpoint provides only one weight set")
 _CKPT_MEMBERS_ERROR = ("stacked members are seeded random inits; a "
@@ -381,7 +389,8 @@ class _Request:
         "trace", "t_submit", "tspans", "deadline", "expired", "grammar",
         "g_start", "dfa_host", "n_inflight", "spec_state", "rid",
         "priority", "tenant", "sched_class", "n_preempts", "replay",
-        "preempt_flag", "t_admit", "parked",
+        "preempt_flag", "t_admit", "parked", "parent", "path", "t_first",
+        "t_delta",
     )
 
     def __init__(self, prompt_ids, budget, sampler: SamplerConfig, seed, eos_id,
@@ -458,7 +467,8 @@ class _Request:
         self.lp: list = []
         # Request-scoped tracing: the server's trace (when this submission
         # happens inside a traced request context) rides along so the
-        # scheduler thread can append queue-wait/prefill/decode spans to it.
+        # scheduler thread can append queue-wait/prefill/decode spans to it,
+        # under ``parent``: the hop span that was open at the submission.
         self.trace = obs.current_trace()
         # Flight-recorder correlation id: the traced request's W3C
         # trace-id (the fleet plane's cross-tier key — router events,
@@ -477,6 +487,14 @@ class _Request:
             self.rid = tracecontext.new_trace_id()
             obs.TRACE_PROPAGATED.inc(source="engine")
         self.t_submit = time.perf_counter()
+        # The first token's path: perf_counter stamps set once (the first
+        # _emit on the scheduler thread; the backend's first non-empty
+        # delta), mirrored into the trace's row for this submission.
+        self.t_first: "float | None" = None
+        self.t_delta: "float | None" = None
+        self.path = (self.trace.open_member(member, self.t_submit)
+                     if self.trace is not None else None)
+        self.parent = self.path["span"] if self.path is not None else None
         self.tspans: dict = {}  # span kind -> (last span, turn count)
         # Prompt-lookup drafting state: the running token history and an
         # incrementally-maintained 2-gram → position index ("lagged": a pair
@@ -487,6 +505,38 @@ class _Request:
             (prompt_ids[n - 2], prompt_ids[n - 1]): n - 1
             for n in range(2, len(prompt_ids))
         }
+
+    def span(self, name: str, t0: float, t1: float, **meta):
+        """Record an engine span of this request on its trace (None when
+        untraced), under the hop span that submitted it."""
+        if self.trace is None:
+            return None
+        return self.trace.add_span_abs(name, t0, t1, self.parent, **meta)
+
+    def stamp(self, key: str, t: float) -> None:
+        """Set an instant of the trace's first-token row, once."""
+        if self.path is not None and self.path[key] is None:
+            self.path[key] = self.trace.rel(t)
+
+    def _stage_end(self, key: str, histogram, since: "float | None") -> float:
+        """A first-token stage ends now: observe it from ``since`` and stamp
+        the instant on the trace's row."""
+        now = time.perf_counter()
+        if since is not None:
+            histogram.observe(now - since)
+        self.stamp(key, now)
+        return now
+
+    def mark_first_token(self) -> None:
+        """The engine's first emitted token (scheduler thread)."""
+        self.t_first = self._stage_end(
+            "engine_first_token_s", obs.FIRST_TOKEN_PREFILL, self.t_admit)
+
+    def mark_first_delta(self) -> None:
+        """The backend's first non-empty content delta for this request
+        (whichever thread detokenizes it)."""
+        self.t_delta = self._stage_end(
+            "backend_first_delta_s", obs.FIRST_TOKEN_BACKEND, self.t_first)
 
     def begin_replay(self) -> int:
         """Park this request for a preemption resume: rewind every piece of
@@ -554,11 +604,11 @@ class _InflightChunk:
 
     __slots__ = ("payload", "active", "n_steps", "t0", "history", "depth",
                  "constrained", "n_chunks", "spec_turn", "drafted",
-                 "stacked", "family", "seq", "t_ready")
+                 "stacked", "family", "seq", "t_ready", "segs")
 
     def __init__(self, payload, active, n_steps, t0, history, depth,
                  constrained=False, n_chunks=1, spec_turn=False, drafted=0,
-                 stacked=None, family="", seq=0):
+                 stacked=None, family="", seq=0, segs=0):
         self.payload = payload
         self.active = active
         self.n_steps = n_steps
@@ -584,6 +634,9 @@ class _InflightChunk:
         # (the fused draft→verify scan emits it even at one turn; plain
         # chunk/verify payloads gain it in the reap's normalization).
         self.stacked = n_chunks > 1 if stacked is None else stacked
+        # Segment programs queued on the device ahead of this dispatch,
+        # since the dispatch before it (_book_segment_time).
+        self.segs = segs
         # Device-time attribution (telemetry/latency.py): the program-key
         # family this dispatch compiled under (compile_budget.json), its
         # flight-recorder sequence number, and the first stamp at which the
@@ -626,7 +679,7 @@ class _Admission:
     admission span can attribute cache effectiveness per tier."""
 
     __slots__ = ("req", "slot", "offset", "offset0", "restored", "t_start",
-                 "handed", "final_sent", "dead")
+                 "handed", "final_sent", "dead", "segments", "turn0", "wait0")
 
     def __init__(self, req: _Request, slot: int, offset: int = 0,
                  restored: int = 0):
@@ -645,6 +698,12 @@ class _Admission:
         self.handed = 0
         self.final_sent = False
         self.dead = False
+        # What the admission's ``prefill`` span waited for: segment programs
+        # dispatched so far, and the loop's turn count and running decode
+        # time as they stood at the first of them (_segment_dispatch).
+        self.segments = 0
+        self.turn0 = 0
+        self.wait0 = 0.0
 
 
 class _DraftRuntime:
@@ -1636,6 +1695,32 @@ class InferenceEngine:
         self._dispatch_seq = 0
         self._family_cache: dict = {}
         self.latency = LatencyModel(alpha=CHUNK_EWMA_ALPHA)
+        # The scheduler turn's phases (_phase): self seconds per phase, each
+        # loop thread's open phases with its last switch (_phase_switch).
+        self._turn_lock = threading.Lock()
+        self._turn_s = dict.fromkeys(TURN_PHASES, 0.0)
+        self._phase_open: dict[int, dict] = {}
+        # What a chunked admission's ``prefill`` span snapshots to say how
+        # much of it was waiting behind decode chunks: the decode loop's
+        # turn count, its running time inside _run_chunk, and of that the
+        # device's time on segment programs (_book_segment_time: segments
+        # queued since the last chunk dispatch, the landing stamp of the
+        # last chunk reaped, and a decode step's time in the last chunk
+        # that had no segment ahead of it).
+        self.n_turns = 0
+        self._chunk_s = 0.0
+        self._seg_s = 0.0
+        self._seg_queued = 0
+        self._ready_prev = 0.0
+        self._step_alone_s = 0.0
+        # Prefill programs dispatched (admit, member-admit, segment): prompt
+        # tokens they were asked to compute against tokens as padded to the
+        # program's rows x bucket; and over chunked admissions, the span
+        # from slot claim to register and its share behind decode chunks.
+        self.n_prefill_tokens = 0
+        self.n_prefill_padded = 0
+        self.prefill_span_s = 0.0
+        self.prefill_decode_wait_s = 0.0
 
         self._admit_cache: dict[int, object] = {}   # bucket → compiled admit
         self._decode_cache: dict[int, object] = {}  # n_steps → compiled chunk
@@ -2272,14 +2357,15 @@ class InferenceEngine:
                 )
             # First sampled token: no generated text yet → penalties are
             # zero; only the logit bias applies.
-            adj = logits.astype(jnp.float32) + bias_row[None, :]
-            key = jax.random.PRNGKey(seed)
-            key, sub = jax.random.split(key)
-            first = sample_token_rows(
-                adj, sub[None], temp1[None], topp1[None], topk1[None]
-            )[0]
-            lp_all = jax.nn.log_softmax(adj[0])
-            top_lp, top_ix = lax.top_k(lp_all, n_top)
+            with jax.named_scope("sample"):
+                adj = logits.astype(jnp.float32) + bias_row[None, :]
+                key = jax.random.PRNGKey(seed)
+                key, sub = jax.random.split(key)
+                first = sample_token_rows(
+                    adj, sub[None], temp1[None], topp1[None], topk1[None]
+                )[0]
+                lp_all = jax.nn.log_softmax(adj[0])
+                top_lp, top_ix = lax.top_k(lp_all, n_top)
             counts_row = jnp.zeros((spec.vocab_size,), jnp.int32).at[first].add(1)
             return (
                 first,
@@ -2355,12 +2441,13 @@ class InferenceEngine:
             # token with split row 1, carry row 0 — a member's stream is
             # token-for-token the stream a members=1 engine with that
             # member's seed would produce.
-            keys = jax.vmap(jax.random.PRNGKey)(seeds)          # [M, 2]
-            split = jax.vmap(jax.random.split)(keys)            # [M, 2, 2]
-            firsts = sample_token_rows(adj, split[:, 1], temps, topps, topks)
-            lp_all = jax.nn.log_softmax(adj)
-            top_lp, top_ix = lax.top_k(lp_all, n_top)
-            s_lp = jnp.take_along_axis(lp_all, firsts[:, None], 1)[:, 0]
+            with jax.named_scope("sample"):
+                keys = jax.vmap(jax.random.PRNGKey)(seeds)          # [M, 2]
+                split = jax.vmap(jax.random.split)(keys)            # [M, 2, 2]
+                firsts = sample_token_rows(adj, split[:, 1], temps, topps, topks)
+                lp_all = jax.nn.log_softmax(adj)
+                top_lp, top_ix = lax.top_k(lp_all, n_top)
+                s_lp = jnp.take_along_axis(lp_all, firsts[:, None], 1)[:, 0]
             rows = slot + n_s * jnp.arange(mem)  # flat state row per member
 
             def upd(arr, vals):
@@ -2477,12 +2564,13 @@ class InferenceEngine:
             adj = logits[0].astype(jnp.float32)[None, :] + bias_rows  # [M, V]
             # PRNG identical to _admit_fn_members: per-member seed, split
             # row 1 samples the first token, row 0 carries.
-            keys = jax.vmap(jax.random.PRNGKey)(seeds)
-            split = jax.vmap(jax.random.split)(keys)
-            firsts = sample_token_rows(adj, split[:, 1], temps, topps, topks)
-            lp_all = jax.nn.log_softmax(adj)
-            top_lp, top_ix = lax.top_k(lp_all, n_top)
-            s_lp = jnp.take_along_axis(lp_all, firsts[:, None], 1)[:, 0]
+            with jax.named_scope("sample"):
+                keys = jax.vmap(jax.random.PRNGKey)(seeds)
+                split = jax.vmap(jax.random.split)(keys)
+                firsts = sample_token_rows(adj, split[:, 1], temps, topps, topks)
+                lp_all = jax.nn.log_softmax(adj)
+                top_lp, top_ix = lax.top_k(lp_all, n_top)
+                s_lp = jnp.take_along_axis(lp_all, firsts[:, None], 1)[:, 0]
             rows = slot + n_s * jnp.arange(mem)
 
             def upd(arr, vals):
@@ -2897,9 +2985,7 @@ class InferenceEngine:
         self.prefix_store_hits += 1
         self.prefix_store_tokens_restored += n
         self.prefix_store_restore_s += t1 - t0
-        if req.trace is not None:
-            req.trace.add_span_abs("prefix-restore", t0, t1,
-                                   tokens=n, slot=slot)
+        req.span("prefix-restore", t0, t1, tokens=n, slot=slot)
 
     # ---- disaggregated serving: prefill loop + device↔device KV handoff ----
 
@@ -2978,10 +3064,9 @@ class InferenceEngine:
                 self.n_kv_handoffs += 1
                 self.kv_handoff_bytes += n_bytes
                 self.kv_handoff_s += dt
-            if adm.req.trace is not None:
-                adm.req.trace.add_span_abs(
-                    "kv-handoff", t0, time.perf_counter(), tokens=b,
-                    slot=adm.slot, bytes=n_bytes, route=route)
+            adm.req.span(
+                "kv-handoff", t0, time.perf_counter(), tokens=b,
+                slot=adm.slot, bytes=n_bytes, route=route)
             FLIGHT.record("handoff", rid=adm.req.rid, engine=self._tag,
                           loop="prefill" if self.disagg else "decode",
                           slot=adm.slot, tokens=b, bytes=n_bytes,
@@ -3152,11 +3237,9 @@ class InferenceEngine:
         if self._stage_state_ok():
             self.n_failures += len(reqs)
             for r in reqs:
-                if r.trace is not None:
-                    now = time.perf_counter()
-                    r.trace.add_span_abs("engine-failure", now, now,
-                                         error=type(exc).__name__,
-                                         contained=True)
+                now = time.perf_counter()
+                r.span("engine-failure", now, now,
+                       error=type(exc).__name__, contained=True)
                 r.out.put(("err", exc))
             return
         with self._cond:
@@ -3171,11 +3254,9 @@ class InferenceEngine:
         self._record_breaker_failure()
         self.n_failures += len(doomed)
         for r in doomed:
-            if r.trace is not None:
-                now = time.perf_counter()
-                r.trace.add_span_abs("engine-failure", now, now,
-                                     error=type(exc).__name__,
-                                     contained=True, group="prefill")
+            now = time.perf_counter()
+            r.span("engine-failure", now, now,
+                   error=type(exc).__name__, contained=True, group="prefill")
             r.out.put(("err", exc))
         if not self._stop:
             self._init_stage_state()
@@ -3208,7 +3289,8 @@ class InferenceEngine:
                     # Going idle: refresh the occupancy gauge so a
                     # drained prefill group reads 0, not the last burst.
                     obs.PREFILL_GROUP_ACTIVE.set(len(self._admitting))
-                    self._cond.wait()
+                    with self._phase("idle"):
+                        self._cond.wait()
                 stopping = self._stop
                 if stopping:
                     pending, self._pending = self._pending, []
@@ -3226,8 +3308,9 @@ class InferenceEngine:
                 return
             obs.PREFILL_GROUP_ACTIVE.set(len(self._admitting))
             try:
-                self._start_admissions()
-                self._step_admissions()
+                with self._phase("admit"):
+                    self._start_admissions()
+                    self._step_admissions()
             except Exception as e:  # fail open, prefill-group blast radius
                 try:
                     with self._cond:
@@ -4389,7 +4472,6 @@ class InferenceEngine:
                 # device counts and occupancy, plus the device↔device KV
                 # handoff accounting (quorum_tpu/cache/kv_transfer.py).
                 "disagg": 1 if self.disagg else 0,
-                "decode_pp": self.decode_pp,
                 "prefill_sp": self.prefill_sp,
                 "prefill_group_devices": (
                     int(self.prefill_mesh.devices.size) if self.disagg else 0),
@@ -4441,6 +4523,18 @@ class InferenceEngine:
                 # one a router-side proactive resume on a sibling).
                 "draining": 1 if self.draining else 0,
                 "drain_parked_total": self.n_drain_parked,
+                # Prefill programs as dispatched, and what chunked
+                # admissions' prefill spans waited for (_segment_dispatch).
+                "prefill_tokens_total": self.n_prefill_tokens,
+                "prefill_padded_tokens_total": self.n_prefill_padded,
+                "prefill_span_seconds_total": round(self.prefill_span_s, 6),
+                "prefill_decode_wait_seconds_total": round(
+                    self.prefill_decode_wait_s, 6),
+                # The scheduler turn by phase (_phase), open phases counted
+                # up to now: between two scrapes the phases of a colocated
+                # engine add up to the wall time between them.
+                **{f"turn_{name}_seconds_total": round(seconds, 6)
+                   for name, seconds in self._turn_seconds().items()},
             }
 
     def health(self) -> dict:
@@ -4541,7 +4635,8 @@ class InferenceEngine:
                         # batch size.
                         obs.DECODE_GROUP_ACTIVE.set(
                             sum(1 for r in self._slots if r is not None))
-                    self._cond.wait()
+                    with self._phase("idle"):
+                        self._cond.wait()
                 if self._stop and not (
                     (not self.disagg
                      and (self._pending or self._admitting))
@@ -4555,29 +4650,34 @@ class InferenceEngine:
                     # admissions were ended by the prefill loop's own exit.
                     self._handoffs.clear()
                     return
+            self.n_turns += 1
             try:
-                self._sweep_deadlines()
-                self._sweep_preemptions()
-                self._sweep_drain_parks()
-                if self.disagg:
-                    # The deferred decode-side state work the colocated
-                    # loop runs inside _start_admissions.
-                    self._flush_dfa_resets()
-                    self._maybe_reset_arena()
-                    self._dispatch_snapshots()
-                    self._drain_handoffs()
-                else:
-                    self._start_admissions()
-                    self._step_admissions()
-                    if self.zero_drain:
-                        # Reap-boundary injection: staged pieces write into
-                        # their claimed slots (chained behind the in-flight
-                        # ring, never draining it) and fully-staged
-                        # admissions register — the row joins the batch at
-                        # the very next ring fill.
+                with self._phase("sweep"):
+                    self._sweep_deadlines()
+                    self._sweep_preemptions()
+                    self._sweep_drain_parks()
+                with self._phase("admit"):
+                    if self.disagg:
+                        # The deferred decode-side state work the colocated
+                        # loop runs inside _start_admissions.
+                        self._flush_dfa_resets()
+                        self._maybe_reset_arena()
+                        self._dispatch_snapshots()
                         self._drain_handoffs()
+                    else:
+                        self._start_admissions()
+                        self._step_admissions()
+                        if self.zero_drain:
+                            # Reap-boundary injection: staged pieces write
+                            # into their claimed slots (chained behind the
+                            # in-flight ring, never draining it) and
+                            # fully-staged admissions register — the row
+                            # joins the batch at the very next ring fill.
+                            self._drain_handoffs()
                 if any(self._slots) or self._inflight:
+                    t_chunk = time.perf_counter()
                     self._run_chunk()
+                    self._chunk_s += time.perf_counter() - t_chunk
                 else:
                     # No decode work this turn (the clamped stream finished
                     # and/or the admission retired without activating):
@@ -4594,6 +4694,98 @@ class InferenceEngine:
                     # Keep the scheduler alive: waiting consumers were already
                     # failed or will fail fast on their next admission.
                     pass
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One part of a scheduler turn (TURN_PHASES), timed twice over: its
+        self time lands in the table metrics() exports, and a running
+        profile gets an ``engine.<name>`` annotation on the profiler's own
+        clock (a flag test when none runs). A phase opened inside another
+        suspends it, so the phases of a loop add up to its wall time."""
+        self._phase_switch(name)
+        try:
+            with jax.profiler.TraceAnnotation("engine." + name):
+                yield
+        finally:
+            self._phase_switch(None)
+
+    def _phase_switch(self, push: "str | None") -> None:
+        """Book the calling loop's time since its last switch to the phase
+        it had open, then open ``push`` inside it or (None) close it. Backend
+        compile seconds the thread spent in the slice (compile_watch) go to
+        ``compile``, not to the phase that met the new shape."""
+        with self._turn_lock:
+            st = self._phase_open.setdefault(
+                threading.get_ident(), {"open": [], "t": 0.0, "built": 0.0})
+            now = time.perf_counter()
+            built = compile_watch.thread_seconds()
+            if st["open"]:
+                dt = now - st["t"]
+                dc = min(dt, built - st["built"])
+                self._turn_s[st["open"][-1]] += dt - dc
+                self._turn_s["compile"] += dc
+            st["t"], st["built"] = now, built
+            if push is None:
+                st["open"].pop()
+            else:
+                st["open"].append(push)
+
+    def _turn_seconds(self) -> dict:
+        """The phase table with every open phase counted up to now."""
+        with self._turn_lock:
+            out = dict(self._turn_s)
+            now = time.perf_counter()
+            for st in self._phase_open.values():
+                if st["open"]:
+                    out[st["open"][-1]] += now - st["t"]
+        return out
+
+    def _prefill_dispatch(self, family: str, bucket: int, tokens: int,
+                          rows: int = 1):
+        """Count one prefill program execution: the prompt ``tokens`` it was
+        asked to compute, and tokens as the program computes them (``rows`` x
+        ``bucket``, the padding and absent members included). Returns the
+        ``engine.dispatch`` annotation that names the call in a running
+        profile; use as ``with``."""
+        self.n_prefill_tokens += tokens
+        self.n_prefill_padded += rows * bucket
+        return jax.profiler.TraceAnnotation("engine.dispatch", family=family,
+                                            bucket=bucket)
+
+    def _segment_dispatch(self, adms, family: str, bucket: int, tokens: int,
+                          rows: int = 1):
+        """:meth:`_prefill_dispatch` for a segment program advancing the
+        chunked admissions ``adms``; at an admission's first segment the
+        loop's turn count and decode-only time are snapshotted for its
+        span. A ``disagg`` engine's segments run on the prefill group, not
+        ahead of a decode chunk."""
+        if not self.disagg:
+            self._seg_queued += 1
+        for adm in adms:
+            if adm.segments == 0:
+                adm.turn0 = self.n_turns
+                adm.wait0 = self._chunk_s - self._seg_s
+            adm.segments += 1
+        return self._prefill_dispatch(family, bucket, tokens, rows)
+
+    def _book_segment_time(self, c: "_InflightChunk", t_ready: float,
+                           probed: bool) -> None:
+        """The device's time on segment programs, as the reaps see it. The
+        device runs what it is given in order, so a chunk lands ``own``
+        seconds after the chunk before it (or after its own dispatch, if
+        that was later); with segments queued ahead of it (``c.segs``),
+        what ``own`` holds beyond its steps at the pace of the last chunk
+        that ran alone is the segments'. A chunk the drain found landed
+        (``probed``) has no landing stamp of its own and books nothing."""
+        own = t_ready - max(self._ready_prev, c.t0)
+        self._ready_prev = t_ready
+        if probed:
+            return
+        steps = c.n_steps * c.n_chunks
+        if not c.segs:
+            self._step_alone_s = own / steps
+        elif self._step_alone_s:
+            self._seg_s += max(0.0, own - self._step_alone_s * steps)
 
     # Individual scheduler-turn spans recorded per request per kind before
     # coalescing kicks in: a multi-thousand-token generation must not fill
@@ -4622,7 +4814,7 @@ class InferenceEngine:
                     span.meta.get("occupancy", 0), meta["occupancy"])
             span.meta["coalesced_turns"] = count - self.TURN_SPAN_CAP + 1
         else:
-            span = trace.add_span_abs(name, t0, t1, **meta)
+            span = req.span(name, t0, t1, **meta)
         req.tspans[name] = (span, count)
 
     def _note_admitted(self, req: _Request) -> None:
@@ -4634,6 +4826,7 @@ class InferenceEngine:
         loop's register/reap events)."""
         now = time.perf_counter()
         req.t_admit = now
+        req.stamp("admit_s", now)
         if req.n_preempts == 0:
             # A resumed victim's submit→admit gap includes its previous
             # service time — not a queue wait; keep it out of the histogram
@@ -4643,9 +4836,7 @@ class InferenceEngine:
         FLIGHT.record("admit", rid=req.rid, engine=self._tag,
                       loop="prefill" if self.disagg else "decode",
                       queue_wait_s=round(now - req.t_submit, 6))
-        if req.trace is not None:
-            req.trace.add_span_abs("queue-wait", req.t_submit, now,
-                                   member=req.member)
+        req.span("queue-wait", req.t_submit, now, member=req.member)
 
     @staticmethod
     def _lcp(a: list[int], b: list[int]) -> int:
@@ -5052,22 +5243,30 @@ class InferenceEngine:
                               for r in live.values()}) == 1)
         faults.fire("engine.admit")
         t0 = time.perf_counter()
-        (firsts, s_lp, top_ix, top_lp,
-         self._ck, self._cv, self._token, self._lengths, self._keys,
-         self._temp, self._topp, self._topk,
-         self._pp, self._fp, self._counts, self._bias,
-         self._live, self._budget, self._eos,
-         ) = (self._dedup_admit_fn(bucket) if use_dedup
-              else self._admit_fn_members(bucket))(
-            self.params, tokens, lengths, np.int32(row), enables, seeds,
-            temps, topps, topks, pps, fps, bias_rows, budgets, eoss,
-            self._ck, self._cv, self._token, self._lengths, self._keys,
-            self._temp, self._topp, self._topk,
-            self._pp, self._fp, self._counts, self._bias,
-            self._live, self._budget, self._eos,
-        )
-        firsts, s_lp, top_ix, top_lp = _host_fetch(
-            firsts, s_lp, top_ix, top_lp)
+        # The dedup program prefills the one shared prompt once; the
+        # member-vmapped one computes a bucket per member, absent ones too.
+        n_asked = (len(next(iter(live.values())).prompt_ids) if use_dedup
+                   else sum(len(r.prompt_ids) for r in live.values()))
+        with self._prefill_dispatch("dedup" if use_dedup else "single_shot",
+                                    bucket, n_asked,
+                                    rows=1 if use_dedup else mem):
+            (firsts, s_lp, top_ix, top_lp,
+             self._ck, self._cv, self._token, self._lengths, self._keys,
+             self._temp, self._topp, self._topk,
+             self._pp, self._fp, self._counts, self._bias,
+             self._live, self._budget, self._eos,
+             ) = (self._dedup_admit_fn(bucket) if use_dedup
+                  else self._admit_fn_members(bucket))(
+                self.params, tokens, lengths, np.int32(row), enables, seeds,
+                temps, topps, topks, pps, fps, bias_rows, budgets, eoss,
+                self._ck, self._cv, self._token, self._lengths, self._keys,
+                self._temp, self._topp, self._topk,
+                self._pp, self._fp, self._counts, self._bias,
+                self._live, self._budget, self._eos,
+            )
+            with self._phase("reap_block"):  # the admit blocks on its token
+                firsts, s_lp, top_ix, top_lp = _host_fetch(
+                    firsts, s_lp, top_ix, top_lp)
         t1 = time.perf_counter()
         obs.PREFILL.observe(t1 - t0)
         self._observe_device_time("dedup" if use_dedup else "single_shot",
@@ -5079,15 +5278,13 @@ class InferenceEngine:
             obs.QUORUM_DEDUP_TOKENS.inc(saved)
         self.breaker.record_success()
         for m, req in live.items():
-            if req.trace is not None:
-                # reused/restored are structurally 0 here like the
-                # single-engine single-shot path (member reuse routes
-                # through a chunked admission); recorded so every
-                # admission span carries the cache-effectiveness attrs.
-                req.trace.add_span_abs(
-                    "prefill", t0, t1, tokens=len(req.prompt_ids),
-                    bucket=bucket, slot=row, coalesced=len(live),
-                    reused=0, restored=0, dedup=int(use_dedup))
+            # reused/restored are structurally 0 here like the
+            # single-engine single-shot path (member reuse routes
+            # through a chunked admission); recorded so every
+            # admission span carries the cache-effectiveness attrs.
+            req.span("prefill", t0, t1, tokens=len(req.prompt_ids),
+                     bucket=bucket, slot=row, coalesced=len(live),
+                     reused=0, restored=0, dedup=int(use_dedup))
         for m, req in live.items():
             flat = m * n_s + row
             self._resident[flat] = list(req.prompt_ids)
@@ -5199,7 +5396,8 @@ class InferenceEngine:
             # the prefill group computes the next segment.
             disps = {m: self._handoff_dispatch(adm, adm.offset)
                      for m, adm in batch.items()}
-            with self._attr_time("mseg"):
+            with self._attr_time("mseg"), self._segment_dispatch(
+                    batch.values(), "mseg", bucket, int(n_valids.sum()), mem):
                 self._sck, self._scv = self._seg_fn_members(bucket, history)(
                     self.prefill_params, tokens, offsets, n_valids, slots,
                     enables, self._sck, self._scv,
@@ -5212,7 +5410,8 @@ class InferenceEngine:
                         adm, self._handoff_dispatch(adm, adm.offset),
                         final=True)
             return
-        with self._attr_time("mseg"):
+        with self._attr_time("mseg"), self._segment_dispatch(
+                batch.values(), "mseg", bucket, int(n_valids.sum()), mem):
             self._ck, self._cv = self._seg_fn_members(bucket, history)(
                 self.params, tokens, offsets, n_valids, slots, enables,
                 self._ck, self._cv,
@@ -5262,15 +5461,25 @@ class InferenceEngine:
         # include the decode turns interleaved between segments — that IS
         # the latency the admitted request experienced.
         obs.PREFILL.observe(t1 - adm.t_start)
-        if req.trace is not None:
-            # Per-request cache effectiveness on the admission span:
-            # ``reused`` is the total prefix the admission skipped
-            # (offset0), ``restored`` the portion that came host→device
-            # from the prefix store rather than sitting slot-resident.
-            req.trace.add_span_abs(
-                "prefill", adm.t_start, t1, tokens=len(prompt),
-                slot=adm.slot, chunked=True, reused=adm.offset0,
-                restored=adm.restored)
+        # What the span waited for: the part the loop spent inside
+        # _run_chunk since the first segment went out, less the device's
+        # time on segment programs there: other rows' decode chunks. The
+        # rest is segments, register and sweeps. A ``disagg`` admission
+        # waits for no decode chunk.
+        span_s = t1 - adm.t_start
+        wait_s = (self._chunk_s - self._seg_s - adm.wait0
+                  if adm.segments and not self.disagg else 0.0)
+        self.prefill_span_s += span_s
+        self.prefill_decode_wait_s += wait_s
+        # Per-request cache effectiveness on the admission span:
+        # ``reused`` is the total prefix the admission skipped
+        # (offset0), ``restored`` the portion that came host→device
+        # from the prefix store rather than sitting slot-resident.
+        req.span("prefill", adm.t_start, t1, tokens=len(prompt),
+                 slot=adm.slot, chunked=True, reused=adm.offset0,
+                 restored=adm.restored, segments=adm.segments,
+                 turns=self.n_turns - adm.turn0 + 1 if adm.segments else 0,
+                 decode_wait_ms=round(wait_s * 1000, 3))
         with self._cond:
             self._slots[adm.slot] = req
         self._release_admission(adm)
@@ -5322,7 +5531,8 @@ class InferenceEngine:
                     # is already resident and the overlap is with the
                     # decode ring's own megachunks instead.)
                     disp = self._handoff_dispatch(adm, adm.offset)
-                    with self._attr_time("seg"):
+                    with self._attr_time("seg"), self._segment_dispatch(
+                            [adm], "seg", bucket, len(seg)):
                         self._sck, self._scv = self._seg_fn(bucket, history)(
                             self.prefill_params, tokens,
                             np.int32(adm.offset), np.int32(len(seg)),
@@ -5341,7 +5551,8 @@ class InferenceEngine:
                 continue
             try:
                 faults.fire("engine.prefill_segment")
-                with self._attr_time("seg"):
+                with self._attr_time("seg"), self._segment_dispatch(
+                        [adm], "seg", bucket, len(seg)):
                     self._ck, self._cv = self._seg_fn(bucket, history)(
                         self.params, tokens, np.int32(adm.offset),
                         np.int32(len(seg)),
@@ -5384,43 +5595,44 @@ class InferenceEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :n_prompt] = req.prompt_ids
         bias = req.bias_row if req.bias_row is not None else self._zero_bias
-        (first, s_lp, top_ix, top_lp,
-         self._ck, self._cv, self._token, self._lengths, self._keys,
-         self._temp, self._topp, self._topk,
-         self._pp, self._fp, self._counts, self._bias,
-         self._live, self._budget, self._eos) = self._admit_fn(bucket)(
-            self.params,
-            tokens,
-            np.asarray([n_prompt], np.int32),
-            np.int32(slot),
-            np.int32(req.seed),
-            np.float32(req.temperature),
-            np.float32(req.top_p),
-            np.int32(req.top_k),
-            np.float32(req.pp),
-            np.float32(req.fp),
-            bias,
-            np.int32(req.budget),
-            np.int32(req.eos_id if req.eos_id is not None else -1),
-            self._ck, self._cv, self._token, self._lengths, self._keys,
-            self._temp, self._topp, self._topk,
-            self._pp, self._fp, self._counts, self._bias,
-            self._live, self._budget, self._eos,
-        )
-        first, s_lp, top_ix, top_lp = _host_fetch(first, s_lp, top_ix, top_lp)
+        with self._prefill_dispatch("single_shot", bucket, n_prompt):
+            (first, s_lp, top_ix, top_lp,
+             self._ck, self._cv, self._token, self._lengths, self._keys,
+             self._temp, self._topp, self._topk,
+             self._pp, self._fp, self._counts, self._bias,
+             self._live, self._budget, self._eos) = self._admit_fn(bucket)(
+                self.params,
+                tokens,
+                np.asarray([n_prompt], np.int32),
+                np.int32(slot),
+                np.int32(req.seed),
+                np.float32(req.temperature),
+                np.float32(req.top_p),
+                np.int32(req.top_k),
+                np.float32(req.pp),
+                np.float32(req.fp),
+                bias,
+                np.int32(req.budget),
+                np.int32(req.eos_id if req.eos_id is not None else -1),
+                self._ck, self._cv, self._token, self._lengths, self._keys,
+                self._temp, self._topp, self._topk,
+                self._pp, self._fp, self._counts, self._bias,
+                self._live, self._budget, self._eos,
+            )
+            with self._phase("reap_block"):  # the admit blocks on its token
+                first, s_lp, top_ix, top_lp = _host_fetch(
+                    first, s_lp, top_ix, top_lp)
         t1 = time.perf_counter()
         obs.PREFILL.observe(t1 - t0)
         # Honest device time: the single-shot admit blocks on its own
         # first-token fetch, so dispatch→fetch IS the program's span.
         self._observe_device_time("single_shot", t1 - t0)
         self.breaker.record_success()  # a half-open probe admitted cleanly
-        if req.trace is not None:
-            # reused/restored are structurally 0 on the single-shot path
-            # (reuse routes through a chunked admission); recorded anyway so
-            # every admission span carries the cache-effectiveness attrs.
-            req.trace.add_span_abs("prefill", t0, t1,
-                                   tokens=n_prompt, bucket=bucket, slot=slot,
-                                   reused=0, restored=0)
+        # reused/restored are structurally 0 on the single-shot path
+        # (reuse routes through a chunked admission); recorded anyway so
+        # every admission span carries the cache-effectiveness attrs.
+        req.span("prefill", t0, t1, tokens=n_prompt, bucket=bucket, slot=slot,
+                 reused=0, restored=0)
         if req.want_lp >= 0:
             req.lp.append((float(s_lp),
                            np.asarray(top_ix), np.asarray(top_lp)))
@@ -5462,9 +5674,8 @@ class InferenceEngine:
         in-flight device work masks the row out at the next boundary."""
         self.n_deadline_exceeded += 1
         obs.DEADLINE_EXCEEDED.inc(stage=stage)
-        if req.trace is not None:
-            now = time.perf_counter()
-            req.trace.add_span_abs("deadline-exceeded", now, now, stage=stage)
+        now = time.perf_counter()
+        req.span("deadline-exceeded", now, now, stage=stage)
         FLIGHT.record("deadline", rid=req.rid, engine=self._tag,
                       loop="decode", stage=stage)
         req.expired = True
@@ -5727,11 +5938,9 @@ class InferenceEngine:
         if self._device_state_ok():
             self.n_failures += len(reqs)
             for r in reqs:
-                if r.trace is not None:
-                    now = time.perf_counter()
-                    r.trace.add_span_abs("engine-failure", now, now,
-                                         error=type(exc).__name__,
-                                         contained=True)
+                now = time.perf_counter()
+                r.span("engine-failure", now, now,
+                       error=type(exc).__name__, contained=True)
                 r.out.put(("err", exc))
         else:
             self._fail_all(exc, doomed=reqs)
@@ -5810,7 +6019,8 @@ class InferenceEngine:
             self._run_chunk_steps()
 
     def _run_chunk_steps(self) -> None:
-        self._sweep_cancelled()
+        with self._phase("sweep"):
+            self._sweep_cancelled()
         if not self._active_rows():
             # No rows to clamp: discard any dangling clamp stamp so the
             # idle gap until the next admission never reads as stall.
@@ -5823,7 +6033,8 @@ class InferenceEngine:
         # then block on (only) the oldest dispatch. The device rolls
         # dispatch-to-dispatch while the host detokenizes, SSE-emits, and
         # schedules the next iteration.
-        self._fill_inflight()
+        with self._phase("fill"):
+            self._fill_inflight()
         if self._inflight:
             self._reap_oldest()
             # Incremental drain: dispatches behind the (blocking) oldest
@@ -6149,7 +6360,9 @@ class InferenceEngine:
             seq = self._next_seq()
             self._inflight.append(
                 _InflightChunk(payload, active, n_steps, t0, history, depth,
-                               constrained, n_chunks, family=fam, seq=seq))
+                               constrained, n_chunks, family=fam, seq=seq,
+                               segs=self._seg_queued))
+            self._seg_queued = 0
             FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                           seq=seq, family=fam, depth=depth, chunks=n_chunks,
                           steps=n_steps,
@@ -6238,7 +6451,8 @@ class InferenceEngine:
             _InflightChunk(payload, active, n_steps, t0, history, depth,
                            constrained, n_turns, spec_turn=True,
                            drafted=drafted, stacked=fused,
-                           family=fam, seq=seq))
+                           family=fam, seq=seq, segs=self._seg_queued))
+        self._seg_queued = 0
         FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                       seq=seq, family=fam, depth=depth, chunks=n_turns,
                       steps=n_steps, drafted=drafted,
@@ -6337,11 +6551,9 @@ class InferenceEngine:
         FLIGHT.dump("containment")
         self.n_failures += len(active)
         for _, r in active:
-            if r.trace is not None:
-                now = time.perf_counter()
-                r.trace.add_span_abs("engine-failure", now, now,
-                                     error=type(exc).__name__,
-                                     contained=True)
+            now = time.perf_counter()
+            r.span("engine-failure", now, now,
+                   error=type(exc).__name__, contained=True)
             r.out.put(("err", exc))
         with self._cond:
             for i, r in active:
@@ -6359,7 +6571,17 @@ class InferenceEngine:
         dispatch, so the interval matches the old dispatch+drain turn; the
         dispatch-to-reap latency is kept as the span's ``inflight`` attr."""
         c = self._inflight.popleft()
+        with self._phase("emit"):
+            self._deliver_chunk(c)
+
+    def _deliver_chunk(self, c: "_InflightChunk") -> None:
+        """The reap of one chunk, as the turn's ``emit`` phase: the blocking
+        fetch inside (``reap_block``, _emit_chunk) suspends it, so ``emit``
+        is the host's part — tokens to their consumers, then the turn's
+        accounting (histograms, recorder, spans) and the finished rows'
+        release."""
         t0 = time.perf_counter()
+        probed = c.t_ready is not None
         done, n_exec, delivered = self._emit_chunk(c)
         t1 = time.perf_counter()
         obs.DECODE_CHUNK.observe(t1 - t0)
@@ -6370,6 +6592,7 @@ class InferenceEngine:
         # the host-fetch time; zero NEW blocking syncs either way).
         t_ready = c.t_ready if c.t_ready is not None else t1
         self._observe_device_time(c.family or "unknown", t_ready - c.t0)
+        self._book_segment_time(c, t_ready, probed)
         FLIGHT.record("reap", engine=self._tag, loop="decode",
                       seq=c.seq, family=c.family or "unknown",
                       depth=c.depth, t_issue=round(c.t0, 6),
@@ -6571,7 +6794,8 @@ class InferenceEngine:
         delivery))`` — the trailing stats drive the speculative-turn
         accounting (accepted = delivered − 1 per executed turn)."""
         active, payload = c.active, c.payload
-        fetched = _host_fetch(*payload)
+        with self._phase("reap_block"):
+            fetched = _host_fetch(*payload)
         t_fetch = time.perf_counter()
         if c.t_ready is None:
             # First observation of the payload landed (the blocking path;
@@ -6704,6 +6928,8 @@ class InferenceEngine:
                 return True
             return False
         self.n_tokens += 1
+        if req.t_first is None:
+            req.mark_first_token()
         req.out.put(("tok", tok))
         if req.eos_id is not None and tok == req.eos_id:
             req.out.put(("end", "stop"))
@@ -6766,11 +6992,9 @@ class InferenceEngine:
         # doomed requests must never hang on their queues.
         self.n_failures += len(doomed)
         for r in doomed:
-            if r.trace is not None:
-                now = time.perf_counter()
-                r.trace.add_span_abs("engine-failure", now, now,
-                                     error=type(exc).__name__,
-                                     contained=False)
+            now = time.perf_counter()
+            r.span("engine-failure", now, now,
+                   error=type(exc).__name__, contained=False)
             r.out.put(("err", exc))
         # The failed call may have consumed its donated buffers; rebuild the
         # device state so the engine survives for subsequent requests — but

@@ -18,9 +18,10 @@ records into:
   - :class:`RequestTrace` — the request-scoped span recorder: every request
     gets one trace (id surfaced in ``X-Request-Id``) that the server,
     strategies, backends, and the engine scheduler append spans to
-    (queue-wait → prefill → decode → aggregate → sse-flush), plus wire-level
-    TTFT and per-token flush timings. Supersedes the round-1 ``PhaseTimer``
-    (kept as an alias — the API is a superset).
+    (queue-wait → prefill → decode → aggregate → sse-flush) that form a tree
+    under the root ``request`` span (``id`` / ``parent``), plus wire-level
+    TTFT, per-token flush timings, and the first token's path: one clock
+    read at each hand-over from the engine's submit to the wire.
   - :class:`TraceStore` — bounded ring buffer of completed traces plus the
     in-flight set, served as JSON from ``GET /debug/traces``.
   - :func:`validate_exposition` — a promtool-style pure-Python checker for
@@ -31,7 +32,8 @@ wraps a request in ``jax.profiler.trace`` so device timelines land in
 TensorBoard-readable traces — the TPU-native analog of a CPU profiler.
 :func:`profile_process` is the on-demand variant behind
 ``POST /debug/profile?seconds=N`` (single-flight — the jax profiler is
-process-global and cannot nest; concurrent requests get 409).
+process-global and cannot nest; concurrent requests get 409). The host
+plane of either capture holds the engine's ``engine.*`` annotations.
 
 The Prometheus primitive types (Histogram/Counter/Gauge/MetricsRegistry)
 and :func:`validate_exposition` moved to ``quorum_tpu.telemetry.metrics``
@@ -58,10 +60,6 @@ from quorum_tpu.telemetry.metrics import (  # noqa: F401  (re-exports)
     Gauge,
     Histogram,
     MetricsRegistry,
-    _esc_label,
-    _fmt_float,
-    _fmt_labels,
-    _split_labels,
     validate_exposition,
 )
 from quorum_tpu.telemetry.recorder import RECORDER
@@ -120,6 +118,30 @@ INTER_TOKEN = METRICS.histogram(
 QUEUE_WAIT = METRICS.histogram(
     "quorum_tpu_queue_wait_seconds",
     "Engine admission-queue wait (submit to slot claim).")
+# The first token's path (docs/observability.md "Where a first token's time
+# went"): one family per stage, observed where the stage ends. With
+# quorum_tpu_queue_wait_seconds (submit to admit) they partition a request's
+# TTFT from the engine submit on. A family per stage and not a ``stage``
+# label: scrapers that sum a family over its label sets would add stages up.
+FIRST_TOKEN_PREFILL = METRICS.histogram(
+    "quorum_tpu_first_token_prefill_seconds",
+    "Slot claim to the engine's first emitted token, per engine submission "
+    "(prefill work plus, for a chunked admission, the decode chunks it "
+    "waited behind and the chunk that sampled the token).")
+FIRST_TOKEN_BACKEND = METRICS.histogram(
+    "quorum_tpu_first_token_backend_seconds",
+    "Engine's first emitted token to the tpu:// backend's first non-empty "
+    "content delta, per engine submission (consumer thread wake-up, "
+    "detokenizer, stop matcher, the hop onto the event loop).")
+FIRST_TOKEN_STRATEGY = METRICS.histogram(
+    "quorum_tpu_first_token_strategy_seconds",
+    "Earliest member's first backend delta to the first content frame the "
+    "strategy hands to the SSE writer, per request (merge queue, thinking "
+    "filter, chunk encoding).")
+FIRST_TOKEN_WIRE = METRICS.histogram(
+    "quorum_tpu_first_token_wire_seconds",
+    "First content frame handed to the SSE writer to the first content "
+    "write on the wire, per request (write coalescing).")
 PREFILL = METRICS.histogram(
     "quorum_tpu_prefill_seconds",
     "Prompt prefill wall time (admission start to cache-complete; chunked "
@@ -509,21 +531,27 @@ RECORDER.on_drop = FLIGHT_RECORDER_DROPPED.inc
 MAX_SPANS = 512
 # Wire flush-timing budget per trace (ttft + the first N inter-token gaps).
 MAX_TOKEN_TIMES = 2048
+# add_span(parent=) default: the innermost open span of the calling context.
+_INHERIT: Any = object()
 
 
 class Span:
     """One timed phase inside a request. ``start``/``end`` are seconds
     relative to the trace's origin; ``meta`` carries small tags (backend,
-    bucket, occupancy...)."""
+    bucket, occupancy...). ``id`` is unique in its trace and ``parent`` is
+    the id of the span that caused this one (None for the root)."""
 
-    __slots__ = ("name", "start", "end", "meta")
+    __slots__ = ("name", "start", "end", "meta", "id", "parent")
 
     def __init__(self, name: str, start: float, end: float | None = None,
-                 meta: dict | None = None):
+                 meta: dict | None = None, id: int = 0,
+                 parent: int | None = None):
         self.name = name
         self.start = start
         self.end = end
         self.meta = meta or {}
+        self.id = id
+        self.parent = parent
 
     @property
     def duration(self) -> float | None:
@@ -531,6 +559,8 @@ class Span:
 
     def to_dict(self) -> dict:
         out = {
+            "id": self.id,
+            "parent": self.parent,
             "name": self.name,
             "start_s": round(self.start, 6),
             "end_s": None if self.end is None else round(self.end, 6),
@@ -545,11 +575,11 @@ class Span:
 class RequestTrace:
     """Span recorder for ONE request, appended to from any thread.
 
-    The server creates it per request; the engine scheduler, strategies, and
-    the SSE wire wrapper record into it through :func:`current_trace` /
-    direct references. Also the :class:`PhaseTimer` replacement: ``phase()``
-    (context manager), ``phases`` (name → accumulated seconds), ``total``
-    and ``log()`` keep the round-1 API."""
+    The server creates it per request and opens the root ``request`` span;
+    the engine scheduler, strategies, and the SSE wire wrapper record into
+    it through :func:`current_trace` / direct references. ``phases`` (name →
+    accumulated seconds), ``total`` and ``log()`` feed the one summary line
+    per request."""
 
     def __init__(self, request_id: str, mode: str = "",
                  trace_id: str = "", span_id: str = ""):
@@ -565,7 +595,14 @@ class RequestTrace:
         self.started_at = time.time()
         self._lock = threading.Lock()
         self.spans: list[Span] = []
+        self._next_span_id = 0
+        self.root_id: int | None = None
         self.dropped_spans = 0
+        # The first token's path: one row of instants per engine submission
+        # (stamped by the engine and the tpu:// backend through the row the
+        # submission holds), and the strategy's first content frame.
+        self.members: list[dict] = []
+        self.strategy_first_delta: float | None = None
         self.meta: dict = {"mode": mode} if mode else {}
         self.ttft: float | None = None
         self.token_times: list[float] = []  # wire flush times, rel. seconds
@@ -592,16 +629,27 @@ class RequestTrace:
     # -- spans ---------------------------------------------------------------
 
     def add_span(self, name: str, start: float, end: float | None = None,
-                 **meta: Any) -> Span:
+                 parent: Any = _INHERIT, **meta: Any) -> Span:
         """Record a span with trace-relative times (see :meth:`rel`).
+
+        ``parent`` is the id of the span that caused this one. Left out, it
+        is the innermost span this trace has open in the calling context
+        (:meth:`span`), else the root: a span recorded from a task or thread
+        that inherited no context still hangs under ``request``. Threads that
+        outlive the submitting context (the engine scheduler) pass the id
+        their submission was handed (:meth:`open_member`'s ``span``).
 
         Completed traces are immutable: a timed-out request's still-running
         device loop keeps calling in for minutes after the trace was
         published to /debug/traces — those late spans are counted in
         ``dropped_spans``, never appended (the returned detached span keeps
         callers' ``span.end = ...`` stamping harmless)."""
-        span = Span(name, start, end, meta or None)
+        if parent is _INHERIT:
+            parent = self.context_parent()
         with self._lock:
+            span = Span(name, start, end, meta or None,
+                        self._next_span_id, parent)
+            self._next_span_id += 1
             if self.duration is not None or len(self.spans) >= MAX_SPANS:
                 self.dropped_spans += 1
             else:
@@ -609,18 +657,92 @@ class RequestTrace:
         return span
 
     def add_span_abs(self, name: str, start_perf: float, end_perf: float,
-                     **meta: Any) -> Span:
+                     parent: Any = _INHERIT, **meta: Any) -> Span:
         """Record a span from two ``time.perf_counter()`` stamps."""
         return self.add_span(name, self.rel(start_perf), self.rel(end_perf),
-                             **meta)
+                             parent, **meta)
 
     @contextlib.contextmanager
     def span(self, name: str, **meta: Any) -> Iterator[Span]:
+        """An open span: until the block ends it is the default parent of
+        every span this context (and the tasks it creates) records."""
         s = self.add_span(name, self.now(), **meta)
+        token = _current_span.set((self, s.id))
         try:
             yield s
         finally:
             s.end = self.now()
+            # An async generator closed from another task cannot reset a
+            # token of the context it was opened in; nothing reads it then.
+            with contextlib.suppress(ValueError):
+                _current_span.reset(token)
+
+    def open_root(self, name: str = "request") -> Span:
+        """The span every other span of this trace descends from; open from
+        the trace's origin until :meth:`finish`."""
+        root = self.add_span(name, 0.0, parent=None)
+        self.root_id = root.id
+        return root
+
+    def context_parent(self) -> int | None:
+        cur = _current_span.get()
+        return cur[1] if cur is not None and cur[0] is self else self.root_id
+
+    # -- the first token's path ----------------------------------------------
+
+    def open_member(self, member: int, submit_perf: float) -> dict:
+        """One engine submission's row of first-token instants, opened in
+        the submitting context. The engine holds the row and stamps
+        ``admit_s`` and ``engine_first_token_s`` into it, the tpu:// backend
+        ``backend_first_delta_s``; ``span`` is the hop span open at the
+        submission, under which the submission's engine spans hang."""
+        row = {"member": member, "span": self.context_parent(),
+               "submit_s": self.rel(submit_perf), "admit_s": None,
+               "engine_first_token_s": None, "backend_first_delta_s": None}
+        with self._lock:
+            self.members.append(row)
+        return row
+
+    def _first_member(self) -> dict | None:
+        """The submission whose backend delta came first, if any has."""
+        done = [m for m in self.members
+                if m["backend_first_delta_s"] is not None]
+        return min(done, key=lambda m: m["backend_first_delta_s"],
+                   default=None)
+
+    def mark_strategy_delta(self) -> None:
+        """The strategy hands its first content frame to the SSE writer."""
+        if self.strategy_first_delta is not None:
+            return
+        t = self.strategy_first_delta = self.now()
+        first = self._first_member()
+        if first is not None:
+            FIRST_TOKEN_STRATEGY.observe(
+                max(0.0, t - first["backend_first_delta_s"]))
+
+    def first_token_path(self) -> dict:
+        """The instants of the first token's path as exported on
+        ``/debug/traces/<id>``, with the stages of the member whose delta
+        came first: they sum to ``wire_first_content_s``."""
+        def r(t):
+            return None if t is None else round(t, 6)
+
+        members = [{k: (v if k in ("member", "span") else r(v))
+                    for k, v in m.items()} for m in self.members]
+        out = {"members": members,
+               "strategy_first_delta_s": r(self.strategy_first_delta),
+               "wire_first_content_s": r(self.ttft), "stages_ms": None}
+        m = self._first_member()
+        if m is not None:
+            marks = [0.0, m["submit_s"], m["admit_s"],
+                     m["engine_first_token_s"], m["backend_first_delta_s"],
+                     self.strategy_first_delta, self.ttft]
+            if None not in marks:
+                out["stages_ms"] = {
+                    name: round((b - a) * 1000, 3) for name, a, b in zip(
+                        ("submit", "queue_wait", "prefill", "backend",
+                         "strategy", "wire"), marks, marks[1:])}
+        return out
 
     # -- wire timing ---------------------------------------------------------
 
@@ -640,6 +762,9 @@ class RequestTrace:
             if self.ttft is None:
                 self.ttft = t
                 TTFT.observe(t)
+                if self.strategy_first_delta is not None:
+                    FIRST_TOKEN_WIRE.observe(
+                        max(0.0, t - self.strategy_first_delta))
             else:
                 # Gap from the LAST content flush, tracked independently of
                 # the capped token_times list — past the cap each gap must
@@ -700,6 +825,7 @@ class RequestTrace:
                                    for t in self.token_times],
                 "spans": [s.to_dict() for s in spans],
                 "dropped_spans": self.dropped_spans,
+                "first_token_path": self.first_token_path(),
             }
             if self.trace_id:
                 out["trace_id"] = self.trace_id
@@ -730,27 +856,25 @@ class RequestTrace:
                 **({"meta": dict(self.meta)} if self.meta else {}),
             }
 
-    # -- PhaseTimer compatibility -------------------------------------------
+    # -- the per-request summary line ------------------------------------------
 
     @property
     def phases(self) -> dict[str, float]:
-        """Accumulated seconds per span name (closed spans only)."""
+        """Accumulated seconds per span name (closed spans only; the root
+        is ``total``)."""
         with self._lock:
             out: dict[str, float] = {}
             for s in self.spans:
-                if s.end is not None:
+                if s.end is not None and s.id != self.root_id:
                     out[s.name] = out.get(s.name, 0.0) + (s.end - s.start)
         return out
-
-    phase = span  # with timer.phase("fanout"): ... (round-1 API)
 
     @property
     def total(self) -> float:
         return self.duration if self.duration is not None else self.now()
 
     def log(self, mode: str, **extra: Any) -> None:
-        """One structured summary line per request (the round-1
-        ``PhaseTimer.log`` extended with ttft/tokens/queue visibility)."""
+        """One structured summary line per request: phases, ttft, tokens."""
         detail = " ".join(f"{k}={v}" for k, v in extra.items())
         phases = " ".join(f"{k}={v * 1000:.1f}ms"
                           for k, v in self.phases.items())
@@ -761,9 +885,6 @@ class RequestTrace:
             "request %s mode=%s total=%.1fms %s %s %s",
             self.request_id, mode, self.total * 1000, phases, wire, detail,
         )
-
-
-PhaseTimer = RequestTrace  # round-1 name; the API is a superset
 
 
 class TraceStore:
@@ -825,6 +946,11 @@ TRACES = TraceStore()
 
 _current_trace: contextvars.ContextVar[RequestTrace | None] = \
     contextvars.ContextVar("quorum_tpu_trace", default=None)
+
+
+# (trace, id) of the innermost span open in this context: RequestTrace.span.
+_current_span: contextvars.ContextVar[tuple[RequestTrace, int] | None] = \
+    contextvars.ContextVar("quorum_tpu_span", default=None)
 
 
 def current_trace() -> RequestTrace | None:

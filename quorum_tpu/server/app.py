@@ -137,21 +137,31 @@ async def _stream_with_role(
     first_chunk: dict[str, Any] | None,
     rest: AsyncIterator[dict[str, Any]],
     model: str,
+    trace: RequestTrace | None = None,
 ) -> AsyncIterator[bytes]:
     """Single-backend SSE normalization (oai_proxy.py:888-956 parity):
     synthetic role chunk first, duplicate upstream role-only chunk skipped,
-    trailing [DONE] guaranteed, MoreChunk runs coalesced per flush."""
+    trailing [DONE] guaranteed, MoreChunk runs coalesced per flush. The
+    first content frame entering the coalescer is the passthrough's
+    ``strategy_first_delta_s`` on ``trace``."""
     yield sse.encode_event(oai.chunk(id="chatcmpl-role", model=model, delta={"role": "assistant"}))
     co = _SSECoalescer()
+
+    def add(chunk: dict[str, Any]) -> bytes:
+        if (trace is not None and trace.strategy_first_delta is None
+                and oai.extract_delta_content(chunk)):
+            trace.mark_strategy_delta()
+        return co.add(chunk, sse.encode_event(chunk))
+
     try:
         if first_chunk is not None:
             delta = (first_chunk.get("choices") or [{}])[0].get("delta") or {}
             is_dup_role = bool(delta.get("role")) and not delta.get("content")
             if not is_dup_role:
-                if out := co.add(first_chunk, sse.encode_event(first_chunk)):
+                if out := add(first_chunk):
                     yield out
         async for chunk in rest:
-            if out := co.add(chunk, sse.encode_event(chunk)):
+            if out := add(chunk):
                 yield out
     except BackendError as e:
         # Mid-stream failure: flush anything buffered, then surface as an
@@ -385,7 +395,7 @@ def create_app(
                   "queue_limit", "decode_pipeline", "decode_loop",
                   "inflight_chunks",
                   "prefix_store_bytes", "prefix_store_entries",
-                  "disagg", "decode_pp", "prefill_sp",
+                  "disagg", "prefill_sp",
                   "prefill_group_devices", "decode_group_devices",
                   "prefill_group_active", "decode_group_active",
                   "zero_drain", "breaker_state",
@@ -733,6 +743,7 @@ def create_app(
         span_id = tracecontext.new_span_id()
         trace = TRACES.start(RequestTrace(rid, trace_id=trace_id,
                                           span_id=span_id))
+        trace.open_root()
         scope = contextlib.ExitStack()
         scope.enter_context(maybe_profile(rid))
         try:
@@ -891,7 +902,8 @@ def create_app(
                     parallel_stream(plan, body, headers, timeout,
                                     trace=trace)
                 )
-            return await _single_stream(targets[0], body, headers, timeout)
+            return await _single_stream(targets[0], body, headers, timeout,
+                                        trace)
 
         # Non-streaming. Parity: every backend is called even in non-parallel
         # mode (oai_proxy.py:1132-1137).
@@ -1145,7 +1157,8 @@ def create_app(
         yield sse.encode_done()
 
     async def _single_stream(
-        backend: Backend, body: dict[str, Any], headers: dict[str, str], timeout: float
+        backend: Backend, body: dict[str, Any], headers: dict[str, str],
+        timeout: float, trace: RequestTrace,
     ) -> Response:
         model = body.get("model") or backend.model or "unknown"
         stream = backend.stream(body, headers, timeout)
@@ -1159,6 +1172,7 @@ def create_app(
             # verbatim — stream and non-stream must present the same error
             # contract (docs/api.md error table).
             return _relay_backend_error(e)
-        return StreamingResponse(_stream_with_role(first_chunk, stream, model))
+        return StreamingResponse(
+            _stream_with_role(first_chunk, stream, model, trace))
 
     return app

@@ -159,6 +159,14 @@ async def parallel_stream(
     aggregation_logger.info("Starting streaming aggregation process")
     yield sse.encode_event(oai.role_chunk(PROXY_MODEL_NAME))
 
+    def content_frame(chunk: dict[str, Any]) -> bytes:
+        # The first frame with content handed to the SSE writer is the
+        # trace's strategy_first_delta_s: behind it lie the pump's queue
+        # hop, the thinking filter and this encoding.
+        if trace is not None:
+            trace.mark_strategy_delta()
+        return sse.encode_event(chunk)
+
     n = len(plan.backends)
     # Python filter by default; the native C++ twin is opt-in via
     # QUORUM_TPU_NATIVE=1 (measured slower for typical delta sizes — see
@@ -184,7 +192,7 @@ async def parallel_stream(
                 continue
             collected[index] += text
             if not plan.suppress_individual:
-                yield sse.encode_event(
+                yield content_frame(
                     oai.content_chunk(text, model=PROXY_MODEL_NAME, backend_index=index)
                 )
     finally:
@@ -238,12 +246,12 @@ async def parallel_stream(
                             text = (final_filter.feed(item)
                                     if plan.hide_final else item)
                             if text:
-                                yield sse.encode_event(oai.content_chunk(
+                                yield content_frame(oai.content_chunk(
                                     text, model=PROXY_MODEL_NAME,
                                     id=oai.PARALLEL_FINAL_ID))
                         tail = final_filter.flush() if plan.hide_final else ""
                     if tail:
-                        yield sse.encode_event(oai.content_chunk(
+                        yield content_frame(oai.content_chunk(
                             tail, model=PROXY_MODEL_NAME,
                             id=oai.PARALLEL_FINAL_ID))
                     yield sse.encode_event(oai.chunk(
@@ -275,7 +283,7 @@ async def parallel_stream(
                                 strategy=plan.strategy_name):
                     combined = plan.separator.join(text for _, text in labeled)
             aggregation_logger.info("Final aggregated streaming content: %s", combined)
-            yield sse.encode_event(oai.final_chunk(combined, model=PROXY_MODEL_NAME))
+            yield content_frame(oai.final_chunk(combined, model=PROXY_MODEL_NAME))
         else:
             yield sse.encode_event(
                 oai.error_chunk(

@@ -115,6 +115,22 @@ def two_backend_parallel_config(strategy: str = "concatenate", **strategy_overri
     }
 
 
+class StubRequest:
+    """What a stub engine's ``submit`` hands a TpuBackend: the fields of the
+    engine's request handle that the backend reads or stamps."""
+
+    parked = False
+    t_delta = None
+
+    def __init__(self, script=(), cancel=None):
+        self.script, self.cancel, self.lp = script, cancel, []
+
+    def mark_first_delta(self) -> None:
+        import time
+
+        self.t_delta = time.perf_counter()
+
+
 class ParallelStreamCollector:
     """Buckets a parallel quorum's SSE stream by chunk id: per-member
     ``chatcmpl-parallel-{i}`` content deltas into ``texts[i]`` and the

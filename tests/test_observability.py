@@ -9,7 +9,7 @@ import logging
 from tests.conftest import make_client, two_backend_parallel_config
 
 from quorum_tpu.backends.fake import FakeBackend
-from quorum_tpu.observability import PhaseTimer, setup_aggregation_log
+from quorum_tpu.observability import RequestTrace, setup_aggregation_log
 
 
 def test_setup_aggregation_log_writes_file(tmp_path):
@@ -22,13 +22,13 @@ def test_setup_aggregation_log_writes_file(tmp_path):
     assert len(logging.getLogger("aggregation").handlers) == n
 
 
-def test_phase_timer_accumulates():
-    t = PhaseTimer("req-x")
-    with t.phase("fanout"):
+def test_phases_accumulate_per_span_name():
+    t = RequestTrace("req-x")
+    with t.span("fanout"):
         pass
-    with t.phase("fanout"):
+    with t.span("fanout"):
         pass
-    with t.phase("combine"):
+    with t.span("combine"):
         pass
     assert set(t.phases) == {"fanout", "combine"}
     assert t.total >= t.phases["fanout"]

@@ -11,6 +11,7 @@ import asyncio
 import numpy as np
 import pytest
 
+from tests.conftest import StubRequest
 from quorum_tpu.backends.base import BackendError
 from quorum_tpu.backends.tpu_backend import TpuBackend
 from quorum_tpu.config import BackendSpec
@@ -259,12 +260,12 @@ class _MultiScriptEngine:
     def submit(self, prompt_ids, *, cancel=None, **kw):
         script = self.scripts[self._i]
         self._i += 1
-        return (script, cancel)
+        return StubRequest(script, cancel)
 
     def stream_results(self, req):
         import time
 
-        script, cancel = req
+        script, cancel = req.script, req.cancel
         try:
             for t in script:
                 if cancel is not None and cancel.is_set():
@@ -313,9 +314,7 @@ class _ParkingEngine:
         self.tokens = list(tokens)
 
     def submit(self, prompt_ids, *, cancel=None, **kw):
-        import types
-
-        return types.SimpleNamespace(parked=False, lp=[], cancel=cancel)
+        return StubRequest(cancel=cancel)
 
     def stream_results(self, req):
         yield from self.tokens

@@ -225,6 +225,27 @@ async def test_live_aux_endpoints_conform():
         check("ErrorResponse", bad.json())
 
 
+async def test_live_trace_conforms():
+    """A streamed request's /debug/traces/<id>: spans carry id and parent,
+    and first_token_path holds the instants and their stages."""
+    async with make_client(single_backend_config()) as client:
+        resp = await client.post(
+            "/v1/chat/completions", headers={"Authorization": "Bearer t"},
+            json={**BODY, "stream": True, "max_tokens": 4})
+        assert resp.status_code == 200
+        listing = await client.get("/debug/traces")
+        check("TraceList", listing.json())
+        got = await client.get(
+            f"/debug/traces/{resp.headers['x-request-id']}")
+        trace = got.json()
+    check("Trace", trace)
+    assert [s["name"] for s in trace["spans"]
+            if s["parent"] is None] == ["request"]
+    assert len(trace["first_token_path"]["members"]) == 1
+    assert set(trace["first_token_path"]["stages_ms"]) == {
+        "submit", "queue_wait", "prefill", "backend", "strategy", "wire"}
+
+
 @pytest.mark.parametrize("req,headers,status,err_type", [
     # tools → tpu:// rejection (documented 400 family)
     ({**BODY, "tools": [{"type": "function"}]},

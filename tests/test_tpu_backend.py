@@ -6,7 +6,7 @@ import json
 
 import pytest
 
-from tests.conftest import make_client
+from tests.conftest import StubRequest, make_client
 
 from quorum_tpu.backends.tpu_backend import TpuBackend, _StopMatcher
 from quorum_tpu.config import BackendSpec
@@ -303,11 +303,10 @@ class _ScriptedEngine:
     # before the first SSE byte): the stub has no queue, so submit just
     # captures the args and stream_results replays the script.
     def submit(self, prompt_ids, *, cancel=None, **kw):
-        return (prompt_ids, cancel)
+        return StubRequest(prompt_ids, cancel)
 
     def stream_results(self, req):
-        prompt_ids, cancel = req
-        yield from self.generate_stream(prompt_ids, cancel=cancel)
+        yield from self.generate_stream(req.script, cancel=req.cancel)
 
 
 def _byte_token(b: int) -> int:

@@ -190,19 +190,6 @@ def test_long_generation_coalesces_decode_spans():
     eng.shutdown()
 
 
-def test_phase_timer_alias_kept():
-    """PhaseTimer is the round-1 name for RequestTrace — old call sites
-    (timer.phase / .phases / .total / .log) must keep working."""
-    from quorum_tpu.observability import PhaseTimer, RequestTrace
-
-    assert PhaseTimer is RequestTrace
-    t = PhaseTimer("req-compat")
-    with t.phase("fanout"):
-        pass
-    assert "fanout" in t.phases
-    t.log("complete", status=200)  # must not raise
-
-
 @pytest.mark.parametrize("path", ["/debug/traces", "/v1/debug/traces"])
 async def test_debug_traces_served_on_both_prefixes(path):
     async with make_client(_two_tpu_config()) as client:
