@@ -22,7 +22,7 @@ from tests.conftest import make_client
 from quorum_tpu import observability as obs
 from quorum_tpu.analysis import budget, hlo_names
 from quorum_tpu.engine.engine import (TURN_PHASES, InferenceEngine,
-                                      prefill_bucket)
+                                      _SegmentRoom, prefill_bucket)
 from quorum_tpu.models import transformer as T
 from quorum_tpu.models.init import init_params
 from quorum_tpu.models.model_config import resolve_spec
@@ -194,6 +194,9 @@ def _traced_generate(eng, trace, prompt, n):
 
 def test_chunked_prefill_span_counts_its_decode_wait():
     eng = InferenceEngine(TINY, decode_chunk=4, n_slots=2, prefill_chunk=16)
+    # A segment a turn, as before either pace of the segment rule is timed
+    # (ISSUE 27): the span then holds the other row's decode chunks.
+    eng._segment_room = lambda: _SegmentRoom(0.0, 0.0)
     try:
         long_prompt = [(3 + 11 * i) % 500 for i in range(100)]
         eng.generate([9] + long_prompt[1:], max_new_tokens=2)  # compile

@@ -25,7 +25,7 @@ import pytest
 
 from quorum_tpu import faults
 from quorum_tpu.analysis import budget
-from quorum_tpu.engine.engine import InferenceEngine
+from quorum_tpu.engine.engine import InferenceEngine, _SegmentRoom
 from quorum_tpu.models.model_config import resolve_spec
 from quorum_tpu.ops.sampling import SamplerConfig
 
@@ -178,10 +178,14 @@ def test_drain_based_engine_accumulates_admission_stall():
     applies: a chunked admission under a live stream clamps the K=4·C=4
     ring to depth 1 across consecutive turns, and the stall counter
     records the window. (The zero_drain twin of this scenario is pinned
-    to 0.0 in the smoke above.)"""
+    to 0.0 in the smoke above.) A segment a turn, as before the segment
+    rule's paces are timed (ISSUE 27): an admission whose segments fit one
+    turn clamps the ring for that turn only, and the counter, which reads
+    between consecutive clamped turns, then books nothing."""
     eng = InferenceEngine(TINY, decode_chunk=4, n_slots=2,
                           decode_pipeline=4, decode_loop=4,
                           prefill_chunk=16, seed=11310)
+    eng._segment_room = lambda: _SegmentRoom(0.0, 0.0)
     try:
         churn_p = [(7 + 3 * i) % 500 for i in range(48)]
         eng.generate([9, 8, 7], max_new_tokens=8, sampler=GREEDY)  # warm
