@@ -11,6 +11,22 @@ count at these widths.
 
 from __future__ import annotations
 
+import sys
+
+import named
+
+
+def for_config(cfg: dict):
+    """The cost model of a configuration: this module (a dense decoder at
+    the file's widths) unless the file names its own,
+    ``"cost_model": "<name>"``, found as ``cost_models/<name>.py`` with the
+    same five functions (``decode_step``, ``prefill``, ``peak_ops``,
+    ``kv_bytes_per_token``, ``least_seconds``)."""
+    name = cfg.get("cost_model")
+    if name is None:
+        return sys.modules[__name__]
+    return named.load("cost_models", name)
+
 
 def shapes(cfg: dict) -> dict:
     d, f = cfg["hidden_size"], cfg["intermediate_size"]

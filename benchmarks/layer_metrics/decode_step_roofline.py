@@ -16,6 +16,7 @@ def read(art):
     rows = rows_per_chunk.read(art)
     if not measured or not rows:
         return None
-    ops, byts = cost_model.decode_step(art["config"], rows, mean_context(art))
-    least = cost_model.least_seconds(ops, byts, art["config"], art["peaks"])
+    model = cost_model.for_config(art["config"])
+    ops, byts = model.decode_step(art["config"], rows, mean_context(art))
+    least = model.least_seconds(ops, byts, art["config"], art["peaks"])
     return 100.0 * least * 1000.0 / measured

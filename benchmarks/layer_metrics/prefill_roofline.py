@@ -15,9 +15,10 @@ def read(art):
         return None
     grid = art["traffic"]["grid"]
     mean_prompt = sum(p for p, _ in grid) / len(grid)
+    model = cost_model.for_config(art["config"])
     least = 0.0
     for rows, count, _ in known:
-        ops, byts = cost_model.prefill(art["config"], rows, mean_prompt, 1)
-        least += count * cost_model.least_seconds(ops, byts, art["config"],
-                                                  art["peaks"])
+        ops, byts = model.prefill(art["config"], rows, mean_prompt, 1)
+        least += count * model.least_seconds(ops, byts, art["config"],
+                                             art["peaks"])
     return 100.0 * least / sum(sec for *_, sec in known)

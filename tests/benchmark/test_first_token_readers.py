@@ -109,18 +109,22 @@ def test_reader_with_nothing_observed_between_the_scrapes_reads_nothing(name):
 
 def test_new_metrics_are_listed_in_the_cells_the_issue_names():
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    quorum, chat, longprompt = CELLS
+    quorum, chat, longprompt = CELLS[:3]
     assert set(WANT) <= set(by_name)
     assert all(by_name[n]["source"] == "program_counter" for n in WANT)
-    assert by_name["loop_host_ms_per_chunk"]["workloads"] == CELLS
-    assert by_name["prefill_decode_wait_share"]["workloads"] == [longprompt]
-    assert by_name["ttft_engine_ms.open"]["workloads"] == [chat]
+
+    def lists(name, cells):  # a later PR's cell appends itself after these
+        return by_name[name]["workloads"][:len(cells)] == cells
+
+    assert lists("loop_host_ms_per_chunk", CELLS[:3])
+    assert lists("prefill_decode_wait_share", [longprompt])
+    assert lists("ttft_engine_ms.open", [chat])
     for name in ("ttft_engine_ms", "backend_first_delta_ms", "merge_hold_ms",
                  "prefill_pad_share"):
-        assert by_name[name]["workloads"] == [quorum, longprompt]
+        assert lists(name, [quorum, longprompt])
     # appended: what the benchmark had keeps its place at the head of the list
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[:16][-1] == "device_idle_share" and names[16:] == [
+    assert names[:16][-1] == "device_idle_share" and names[16:23] == [
         "ttft_engine_ms", "ttft_engine_ms.open", "backend_first_delta_ms",
         "merge_hold_ms", "prefill_decode_wait_share", "prefill_pad_share",
         "loop_host_ms_per_chunk"]
