@@ -5,7 +5,7 @@ A profile names device operations as XLA numbered them (``fusion.277``,
 without its metadata. The metadata is in XLA's text of the optimized
 module: every instruction there has ``metadata={op_name="jit(chunk)/…/
 mlp/dot_general"}``, and the ``jax.named_scope`` parts of
-``models/transformer.py`` (:data:`PARTS`) are components of that path. This
+``models/transformer.py`` and ``models/patterned.py`` (:data:`PARTS`) are components of that path. This
 module reads that text, from ``compiled.as_text()`` or from the files an
 ``--xla_dump_to`` run leaves behind::
 
@@ -31,8 +31,12 @@ import os
 import re
 import sys
 
+# a spec with a layer pattern (models/patterned.py) adds: attention by layer
+# kind, inside attn.core, and an expert layer's three parts
+PATTERNED = ("attn.window", "attn.full", "moe.router", "moe.experts",
+             "moe.shared")
 PARTS = ("embed", "norm", "attn.qkv", "attn.cache_write", "attn.core",
-         "attn.out", "mlp", "lm_head", "sample")
+         "attn.out", "mlp", "lm_head", "sample") + PATTERNED
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(

@@ -240,6 +240,7 @@ import logging
 import math
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, AsyncIterator
 
 import numpy as np
@@ -273,6 +274,10 @@ from quorum_tpu.ops.sampling import SamplerConfig
 from quorum_tpu.parallel.mesh import MeshConfig, make_mesh, single_device_mesh
 
 logger = logging.getLogger(__name__)
+
+# threads in asyncio's default executor at most, whatever the host:
+# ``min(32, os.cpu_count() + 4)`` (concurrent.futures.ThreadPoolExecutor)
+ASYNCIO_DEFAULT_POOL_MAX = 32
 
 
 def _parse_bytes_opt(name: str, raw: str) -> int:
@@ -1670,6 +1675,33 @@ class TpuBackend:
         return CompletionResult(
             backend_name=self.name, status_code=200, body=resp)
 
+    def _stream_pool(self) -> "ThreadPoolExecutor | None":
+        """Where the streams' producer threads run. A stream holds its
+        thread for its whole life (it blocks on the engine's queue), so a
+        pool with fewer threads than a backend has slot rows leaves rows
+        whose tokens nobody fetches: 32 rows over asyncio's default pool (17
+        threads on 13 cores) gave every stream its first token 11 s late
+        (PERF.md section 6, PR 30). That pool has ``min(32, cores + 4)``
+        threads, so on no host does it hold a backend of 32 rows and
+        anything else: such a backend gets a pool of the engine's own, twice
+        its rows wide, so that a full engine's next requests wait in its
+        queue, where the wait is counted, and not for a thread. The rule
+        reads the rows and not the host, so a configuration runs the same
+        way on every machine. (Smaller backends share the default pool,
+        also where their streams together outnumber it, as three stacked
+        members of 8 rows do on 13 cores: covering them moves the
+        benchmark's ``tpot_p50_ms`` by construction, PERF.md section 5
+        item 4.)"""
+        # (an engine that names no slots, a test's stand-in, has the default)
+        slots = getattr(self.engine, "n_slots", 0)
+        if slots < ASYNCIO_DEFAULT_POOL_MAX:
+            return None
+        if self.engine.stream_pool is None:
+            self.engine.stream_pool = ThreadPoolExecutor(
+                max_workers=2 * slots * self.engine.members,
+                thread_name_prefix="tpu-stream")
+        return self.engine.stream_pool
+
     async def stream(
         self, body: dict[str, Any], headers: dict[str, str], timeout: float
     ) -> AsyncIterator[dict[str, Any]]:
@@ -1807,7 +1839,8 @@ class TpuBackend:
             except Exception as e:  # normalized below on the consumer side
                 loop.call_soon_threadsafe(queue.put_nowait, ("err", idx, e))
 
-        producers = [loop.run_in_executor(None, produce, i, r)
+        pool = self._stream_pool()
+        producers = [loop.run_in_executor(pool, produce, i, r)
                      for i, r in enumerate(reqs)]
         # End-to-end deadline, matching complete()'s semantics: the engine
         # sweep is the enforcement (it delivers the DeadlineExceeded error

@@ -140,6 +140,8 @@ from quorum_tpu.compile_cache import enable_persistent_compile_cache
 from quorum_tpu.devices import device_report
 from quorum_tpu.models.init import init_params, init_params_sharded
 from quorum_tpu.models.model_config import ModelSpec
+from quorum_tpu.models.patterned import STATS as MOE_STATS
+from quorum_tpu.models.patterned import KindKV
 from quorum_tpu.models.transformer import (
     decode_chunk,
     decode_loop,
@@ -608,12 +610,15 @@ class _InflightChunk:
 
     __slots__ = ("payload", "active", "n_steps", "t0", "history", "depth",
                  "constrained", "n_chunks", "spec_turn", "drafted",
-                 "stacked", "family", "seq", "t_ready", "seg_tokens")
+                 "stacked", "family", "seq", "t_ready", "seg_tokens", "moe")
 
     def __init__(self, payload, active, n_steps, t0, history, depth,
                  constrained=False, n_chunks=1, spec_turn=False, drafted=0,
-                 stacked=None, family="", seq=0, seg_tokens=0):
+                 stacked=None, family="", seq=0, seg_tokens=0, moe=None):
         self.payload = payload
+        # A patterned spec's expert counters as they stood after this
+        # dispatch (_moe_snapshot): a device future the reap fetches.
+        self.moe = moe
         self.active = active
         self.n_steps = n_steps
         self.t0 = t0
@@ -1579,6 +1584,40 @@ class InferenceEngine:
         # scheduler's hot turn); on admission, a store match longer than the
         # slot-resident LCP is restored host→device and the admission rides
         # the chunked-prefill machinery with a nonzero offset.
+        if self.spec.layer_pattern:
+            # A spec with a layer pattern keeps a cache per layer kind
+            # (models/patterned.py): what reads or writes the cache as one
+            # [L, slots, K, max_seq, hd] rectangle, or runs the layers as one
+            # stack, does not compose with it yet (ROADMAP.md).
+            mesh_shape = dict(self.mesh.shape)
+            refused = [
+                ("kv_pages=1", self.kv_pages),
+                ("prefix_store", bool((prefix_store or "").strip())),
+                ("disagg=P+D / zero_drain=1 (kv_transfer)", self.staged),
+                ("kv_quant=int8", bool(self.kv_quant)),
+                ("quant=int8", bool(self.quant)),
+                ("spec_model= / spec_ckpt= (draft-model speculation)",
+                 draft_spec is not None),
+                ("sp>1 (ring or ulysses admission)", self._use_sp),
+                ("tp>1", mesh_shape.get(AXIS_TP, 1) > 1),
+                ("pp>1", self.decode_pp > 1),
+                ("members>1 / ensemble>1 (member stacking)",
+                 self.members > 1 or self.ensemble > 1),
+                (f"spec_decode={self.spec_decode} with a ring of "
+                 f"{self.spec.ring} under window + spec_decode + 1",
+                 self.spec_decode > 0 and self.spec.ring
+                 < self.spec.sliding_window + self.spec_decode + 1),
+            ]
+            for what, asked in refused:
+                if asked:
+                    raise ValueError(
+                        f"{what} does not compose with a layer_pattern spec "
+                        f"({self.spec.family}): its cache is kept per layer "
+                        "kind (a ring per window layer), which this option "
+                        "does not read or write yet")
+            # Slot-resident prefix reuse reads a row's first positions back:
+            # a window layer's ring holds the row's last ones.
+            self.prefix_cache = False
         mode = (prefix_store or "").strip().lower() or None
         if mode not in (None, "host"):
             raise ValueError(
@@ -1838,6 +1877,10 @@ class InferenceEngine:
         # ring-resident verify made both first-class ring entries, so this
         # is dispatches/request's denominator across spec on/off arms).
         self.n_decode_chunks = 0
+        self._moe_total = None  # a patterned spec's expert counters
+        # The backends' producer threads, where the default pool is too
+        # small for a backend's slots (tpu_backend._stream_pool).
+        self.stream_pool = None
         # Megachunk accounting: device-side chunk segments that produced at
         # least one delivered/overrun token, summed over megachunk (and
         # plain — they count 1) dispatches. decode_chunks_total keeps
@@ -2002,6 +2045,13 @@ class InferenceEngine:
                         mesh, P(*((None,) + tuple(s.spec)))),
                     sh, is_leaf=lambda x: isinstance(x, NamedSharding))
             return sh
+        if self.spec.layer_pattern:
+            # one [slots, K, T, hd] leaf per layer, by kind; the counters
+            # replicated (tp, pp, sp and members are refused above)
+            leaf = NamedSharding(mesh, P())
+            full = (leaf,) * len(self.spec.layers_of("G"))
+            window = (leaf,) * len(self.spec.layers_of("L"))
+            return KindKV(full, window, leaf), KindKV(full, window, None)
         sh = kv_cache_sharding(mesh, self.spec.n_kv_heads,
                                batch=self.n_slots, seq_shard=seq_shard)
         if self.kv_quant:
@@ -2026,6 +2076,10 @@ class InferenceEngine:
         materialization or transfer of the multi-GB buffer.
         """
         self._ck, self._cv = self._zero_cache(self._cache_sh)
+        # the expert counters ride the cache, so they start over with it;
+        # snapshots of the cache that was are not to be counted
+        self._moe_last = None
+        self._moe_noted = self._moe_seq = getattr(self, "_moe_seq", 0) + 1
         if self.kv_pages:
             # The zero-fill points every table entry at the sink page: all
             # host page accounting restarts from empty (rebuilds drop every
@@ -2115,8 +2169,12 @@ class InferenceEngine:
         key = ("zero_cache", id(shardings))
         fn = self._util_fns.get(key)
         if fn is None:
+            # a patterned spec's sharding is the (K side, V side) pair
+            # already: only its K side carries the expert counters
             fn = self._util_fns[key] = jax.jit(
-                zero_cache, out_shardings=(shardings, shardings))
+                zero_cache, out_shardings=(
+                    shardings if self.spec.layer_pattern
+                    else (shardings, shardings)))
         return fn()
 
     def _init_stage_state(self) -> None:
@@ -4462,6 +4520,85 @@ class InferenceEngine:
         err.retry_after = shed.retry_after
         raise err
 
+    # ---- a patterned spec's expert counters ---------------------------------
+    #
+    # The expert layers count on the device, into an int32 array that rides
+    # the K side of the cache through every program (models/patterned.py), so
+    # no program has an output more. The scheduler copies the array after a
+    # decode dispatch or a single-shot admit (a program of a few hundred
+    # bytes, never donated) and reads the copy with the tokens it fetches
+    # anyway; /metrics reads the host's running totals and never the device.
+
+    def _moe_snapshot(self):
+        if not self.spec.layer_pattern:
+            return None
+        fn = self._util_fns.get("moe_snapshot")
+        if fn is None:
+            fn = self._util_fns["moe_snapshot"] = jax.jit(lambda a: a + 0)
+        self._moe_seq += 1
+        return self._moe_seq, fn(self._ck.stats)
+
+    def _moe_note(self, snapshot) -> "int | None":
+        """Add a fetched snapshot to the totals; returns the picks that
+        fell on experts held here since the snapshot noted before (int32
+        counts wrap: the difference is taken modulo 2**32). A single-shot
+        admit reads its snapshot at once, ahead of the chunks dispatched
+        before it: theirs, older and already counted, give None."""
+        seq, counts = snapshot
+        if seq < self._moe_noted:
+            return None
+        self._moe_noted = seq
+        now = np.asarray(_host_fetch(counts)).astype(np.int64) & 0xFFFFFFFF
+        rise = (now if self._moe_last is None
+                else (now - self._moe_last) % (1 << 32))
+        self._moe_last = now
+        self._moe_total = rise if self._moe_total is None \
+            else self._moe_total + rise
+        return int(rise[:, :self.spec.held].sum())
+
+    def _moe_metrics(self) -> dict:
+        held = self.spec.held if self.spec.layer_pattern else 0
+        total = self._moe_total
+        if total is None:
+            total = np.zeros((0, held + len(MOE_STATS)), np.int64)
+        first = self.spec.first_dense
+        per_expert, rest = total[:, :held], total[:, held:]
+        return {
+            "moe_picks_total": int(rest[:, MOE_STATS.index("picks")].sum()),
+            "moe_picks_held_total": int(per_expert.sum()),
+            # picks on a held expert that no product computed: held picks
+            # less the rows the grouped products say they took
+            "moe_dropped_picks_total": int(
+                rest[:, MOE_STATS.index("dropped")].sum()),
+            # per layer the most-picked held expert's count, summed over the
+            # layers: over moe_picks_held_total / experts held it is how
+            # uneven the load on this chip's experts is
+            "moe_busiest_expert_picks_total": int(
+                per_expert.max(axis=1).sum()) if per_expert.size else 0,
+            # {labels: count}: one sample per expert layer and held expert
+            "moe_expert_picks_total": {
+                f'layer="{first + l}",expert="{self.spec.expert_first + e}"':
+                    int(n)
+                for l, row in enumerate(per_expert)
+                for e, n in enumerate(row)},
+            "moe_experts_held": held,
+            **{f"kv_cache_{kind}_bytes": n
+               for kind, n in self._kv_cache_bytes().items()},
+        }
+
+    def _kv_cache_bytes(self) -> dict:
+        """Bytes of the slot cache by layer kind, from the arrays the engine
+        holds (a donated array still says its shape): a spec without a
+        pattern has full layers only."""
+        def nbytes(tree) -> int:
+            return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+        ck, cv = getattr(self, "_ck", None), getattr(self, "_cv", None)
+        if isinstance(ck, KindKV):
+            return {"full": nbytes(ck.full) + nbytes(cv.full),
+                    "window": nbytes(ck.window) + nbytes(cv.window)}
+        return {"full": nbytes(ck) + nbytes(cv), "window": 0}
+
     def metrics(self) -> dict:
         """Scheduler/capacity snapshot for the server's /metrics endpoint."""
         with self._cond:
@@ -4577,6 +4714,7 @@ class InferenceEngine:
                 # engine add up to the wall time between them.
                 **{f"turn_{name}_seconds_total": round(seconds, 6)
                    for name, seconds in self._turn_seconds().items()},
+                **self._moe_metrics(),
             }
 
     def health(self) -> dict:
@@ -4603,6 +4741,7 @@ class InferenceEngine:
             "queue_limit": self.max_pending,
             "rebuilds_total": self.n_rebuilds,
             **self.device_report,
+            "kv_cache_bytes": self._kv_cache_bytes(),
             # A draining engine still answers /health but must shed
             # /ready: the fleet rotates it out while residents finish.
             "draining": self.draining,
@@ -4632,6 +4771,11 @@ class InferenceEngine:
         self._thread.join(timeout=timeout)
         if self._prefill_thread is not None:
             self._prefill_thread.join(timeout=timeout)
+        if self.stream_pool is not None:
+            # the backends' producer threads (tpu_backend._stream_pool):
+            # their streams have just ended
+            self.stream_pool.shutdown(wait=False)
+            self.stream_pool = None
         if self.prefix_store is not None:
             # Stop the snapshot worker (sentinel after any queued fetches)
             # and release the host copies with the device state below.
@@ -5710,9 +5854,12 @@ class InferenceEngine:
                 self._pp, self._fp, self._counts, self._bias,
                 self._live, self._budget, self._eos,
             )
+            moe = self._moe_snapshot()
             with self._phase("reap_block"):  # the admit blocks on its token
                 first, s_lp, top_ix, top_lp = _host_fetch(
                     first, s_lp, top_ix, top_lp)
+                picks = ({} if moe is None else
+                         {"picks_held": self._moe_note(moe)})
         t1 = time.perf_counter()
         obs.PREFILL.observe(t1 - t0)
         # Honest device time: the single-shot admit blocks on its own
@@ -5723,7 +5870,7 @@ class InferenceEngine:
         # (reuse routes through a chunked admission); recorded anyway so
         # every admission span carries the cache-effectiveness attrs.
         req.span("prefill", t0, t1, tokens=n_prompt, bucket=bucket, slot=slot,
-                 reused=0, restored=0)
+                 reused=0, restored=0, **picks)
         if req.want_lp >= 0:
             req.lp.append((float(s_lp),
                            np.asarray(top_ix), np.asarray(top_lp)))
@@ -6452,7 +6599,8 @@ class InferenceEngine:
             self._inflight.append(
                 _InflightChunk(payload, active, n_steps, t0, history, depth,
                                constrained, n_chunks, family=fam, seq=seq,
-                               seg_tokens=self._seg_queued))
+                               seg_tokens=self._seg_queued,
+                               moe=self._moe_snapshot()))
             self._seg_queued = 0
             FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                           seq=seq, family=fam, depth=depth, chunks=n_chunks,
@@ -6542,7 +6690,8 @@ class InferenceEngine:
             _InflightChunk(payload, active, n_steps, t0, history, depth,
                            constrained, n_turns, spec_turn=True,
                            drafted=drafted, stacked=fused,
-                           family=fam, seq=seq, seg_tokens=self._seg_queued))
+                           family=fam, seq=seq, seg_tokens=self._seg_queued,
+                           moe=self._moe_snapshot()))
         self._seg_queued = 0
         FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                       seq=seq, family=fam, depth=depth, chunks=n_turns,
@@ -6748,6 +6897,10 @@ class InferenceEngine:
             else (1 - CHUNK_EWMA_ALPHA) * self._chunk_ewma_s
             + CHUNK_EWMA_ALPHA * per_chunk)
         meta = {}
+        if c.moe is not None:
+            # of every program since the reap before: this chunk's rows,
+            # and the prefill segments dispatched ahead of it
+            meta["picks_held"] = c.moe
         if c.constrained:
             meta["constrained"] = sum(
                 1 for _, r in c.active if r.grammar is not None)
@@ -6887,6 +7040,9 @@ class InferenceEngine:
         active, payload = c.active, c.payload
         with self._phase("reap_block"):
             fetched = _host_fetch(*payload)
+            if c.moe is not None:
+                # landed with the payload: the copy ran right behind it
+                c.moe = self._moe_note(c.moe)
         t_fetch = time.perf_counter()
         if c.t_ready is None:
             # First observation of the payload landed (the blocking path;

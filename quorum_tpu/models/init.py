@@ -20,10 +20,83 @@ def init_params(spec: ModelSpec, seed: int = 0) -> Params:
     return init_params_from_key(spec, jax.random.PRNGKey(seed))
 
 
+def init_patterned_from_key(spec: ModelSpec, key) -> Params:
+    """A patterned spec's weights (models/patterned.py): one dict per layer
+    under ``layers``, every leaf with a leading dim of 1.
+
+    The scales give the residual stream the proportions of a deep model and
+    not of a shallow toy, whatever ``n_layers`` is cut to: embedding rows at
+    unit rms, and what each sub-layer writes into the stream scaled by
+    ``1/sqrt(2 * init_depth)`` (the GPT-2 / Megatron scaled initialisation),
+    so that one expert's output is a few percent of the stream and a
+    near-tie in the router's pick moves a served log-probability by little.
+    The blocks are post-norm, so that scale is the gain of the two norms (an
+    RMSNorm after ``wo`` or ``w_down`` undoes any scale on the matrix:
+    seeded at 1 each sub-layer would add a unit-rms vector, and one flipped
+    pick moved a log-probability by 0.1-0.3 on the chip, PERF.md section 6).
+    The router's selection bias is small and non-zero, so that the score and
+    the score-plus-bias differ."""
+    dt = jnp.dtype(spec.dtype)
+    D, V = spec.d_model, spec.vocab_size
+    H = spec.n_heads * spec.head_dim
+    K = spec.n_kv_heads * spec.head_dim
+    F, Fe, E, held = spec.d_ff, spec.d_ff_expert, spec.n_experts, spec.held
+    gain = (2.0 * (spec.init_depth or spec.n_layers)) ** -0.5
+
+    def w(k, *shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dt)
+
+    def layer(i: int, key) -> dict:
+        ks = iter(jax.random.split(key, 16))
+        out = {
+            "attn_norm_w": jnp.full((1, D), gain, dt),
+            "mlp_norm_w": jnp.full((1, D), gain, dt),
+            "wq": w(next(ks), 1, D, H, fan_in=D),
+            "wk": w(next(ks), 1, D, K, fan_in=D),
+            "wv": w(next(ks), 1, D, K, fan_in=D),
+            "wo": w(next(ks), 1, H, D, fan_in=H),
+            "q_norm_w": jnp.ones((1, spec.head_dim), dt),
+            "k_norm_w": jnp.ones((1, spec.head_dim), dt),
+        }
+        if i < spec.first_dense:
+            out.update(
+                w_gate=w(next(ks), 1, D, F, fan_in=D),
+                w_up=w(next(ks), 1, D, F, fan_in=D),
+                w_down=w(next(ks), 1, F, D, fan_in=F))
+            return out
+        out.update(
+            router=w(next(ks), 1, D, E, fan_in=D),
+            router_bias=(0.05 * jax.random.normal(next(ks), (1, E))
+                         ).astype(jnp.float32),
+            moe_w_gate=w(next(ks), 1, held, D, Fe, fan_in=D),
+            moe_w_up=w(next(ks), 1, held, D, Fe, fan_in=D),
+            moe_w_down=w(next(ks), 1, held, Fe, D, fan_in=Fe))
+        if spec.n_shared_experts:
+            Fs = Fe * spec.n_shared_experts
+            out["shared"] = {
+                "w_gate": w(next(ks), 1, D, Fs, fan_in=D),
+                "w_up": w(next(ks), 1, D, Fs, fan_in=D),
+                "w_down": w(next(ks), 1, Fs, D, fan_in=Fs)}
+        return out
+
+    k_emb, k_head, k_layers = jax.random.split(key, 3)
+    return {
+        "tok_emb": jax.random.normal(k_emb, (V, D), jnp.float32).astype(dt),
+        "final_norm_w": jnp.ones((D,), dt),
+        "lm_head": (None if spec.tied_lm_head
+                    else w(k_head, D, V, fan_in=D)),
+        "layers": {f"{i:02d}": layer(i, k) for i, k in enumerate(
+            jax.random.split(k_layers, spec.n_layers))},
+    }
+
+
 def init_params_from_key(spec: ModelSpec, key) -> Params:
     """Init from a PRNG key (traced-friendly: vmappable over stacked keys —
     how ensemble members materialize directly into their [M, …] slices)."""
     spec.validate()
+    if spec.layer_pattern:
+        return init_patterned_from_key(spec, key)
     dt = jnp.dtype(spec.dtype)
     keys = iter(jax.random.split(key, 32))
 

@@ -1,6 +1,10 @@
 """ModelSpec: one dataclass describes every supported decoder-only family.
 
-Presets cover the models named in BASELINE.json's configs. Architecture
+Presets cover the models named in BASELINE.json's configs and the
+benchmark's. A spec is uniform over its depth (one attention kind, one MLP
+kind, one window) unless it names a ``layer_pattern``: then each layer has
+its own attention kind, the leading layers a dense MLP and the rest experts,
+and a chip may hold a share of each layer's experts. Architecture
 hyperparameters match the public model cards; weights are randomly
 initialized unless a local checkpoint is provided (see
 quorum_tpu.models.hf_loader) — the framework's job is serving mechanics and
@@ -19,7 +23,7 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class ModelSpec:
-    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma"
+    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -29,6 +33,15 @@ class ModelSpec:
     d_ff: int = 14336
     max_seq: int = 4096
     sliding_window: int = 0        # >0: attend only the last W positions (mistral)
+    # A layer pattern ("" = every layer alike, which is every spec above the
+    # patterned family): one letter per layer, repeated over the depth, "L"
+    # a sliding-window layer (the last ``sliding_window`` positions, kept in
+    # a ring of ``ring`` positions per row) and "G" a full-attention
+    # layer (every position, no rotary embedding). A patterned spec runs
+    # models/patterned.py, which hard-codes the family's conventions:
+    # per-layer weights, a cache per layer kind, post-norm blocks, RMSNorm
+    # over each q and k head.
+    layer_pattern: str = ""
     norm: str = "rmsnorm"          # "rmsnorm" | "layernorm"
     norm_eps: float = 1e-5
     norm_offset: float = 0.0       # weight used as (offset + w); gemma: 1.0
@@ -54,11 +67,47 @@ class ModelSpec:
     # Grouped sparse-MoE expert capacity = cf·k·N/E tokens (see
     # transformer._moe_mlp_grouped); ≥ E/k means no pick can ever drop.
     moe_capacity_factor: float = 2.0
+    # Patterned family's expert layers: the first ``first_dense`` layers
+    # keep a dense MLP of d_ff, the rest route over ``n_experts`` of width
+    # ``d_ff_expert`` beside ``n_shared_experts`` always-on experts of that
+    # width. The router scores every expert on its own by a sigmoid, picks
+    # the top k by score + ``router_bias`` and weighs the picks
+    # ``router_scale * s_i / sum_picked s``. ``experts_held`` > 0: this chip
+    # holds experts [expert_first, expert_first + experts_held) of each
+    # expert layer, which further chips share; the router still scores all
+    # n_experts and what the absent experts would add is left out.
+    first_dense: int = 0
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    router_scale: float = 1.0
+    experts_held: int = 0
+    expert_first: int = 0
+    # Seeded init of the patterned family: the post-norms' gains are
+    # 1/sqrt(2 * init_depth), the published depth, whatever n_layers is cut
+    # to (models/init.py).
+    init_depth: int = 0
     dtype: str = "bfloat16"
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights are here."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def ring(self) -> int:
+        """Positions a window layer keeps per row: the power of two at or
+        above ``sliding_window``."""
+        return 1 << max(self.sliding_window - 1, 0).bit_length()
+
+    def attn_kind(self, layer: int) -> str:
+        return self.layer_pattern[layer % len(self.layer_pattern)]
+
+    def layers_of(self, kind: str) -> list[int]:
+        return [i for i in range(self.n_layers) if self.attn_kind(i) == kind]
 
     @property
     def gated_mlp(self) -> bool:
@@ -72,6 +121,22 @@ class ModelSpec:
         assert self.pos in ("rope", "learned")
         assert self.rope_scaling in ("", "llama3"), (
             f"unsupported rope_scaling {self.rope_scaling!r}")
+        if self.layer_pattern:
+            assert set(self.layer_pattern) <= {"L", "G"}, (
+                f"layer_pattern {self.layer_pattern!r}: L (window) and G "
+                "(full) only")
+            assert self.sliding_window > 0 or "L" not in self.layer_pattern, (
+                "a window layer needs sliding_window > 0")
+            assert self.ring <= self.max_seq
+            assert self.pos == "rope" and self.norm == "rmsnorm"
+            assert self.gated_mlp and not self.use_bias
+            assert 0 <= self.first_dense <= self.n_layers
+            if self.first_dense < self.n_layers:
+                assert self.n_experts > 0 and self.d_ff_expert > 0
+                assert self.experts_per_token <= self.n_experts
+                assert self.expert_first + self.held <= self.n_experts, (
+                    f"experts {self.expert_first}..{self.expert_first + self.held}"
+                    f" of {self.n_experts}")
         return self
 
 
@@ -149,6 +214,20 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         n_kv_heads=8, head_dim=128, d_ff=14336, max_seq=8192, rope_theta=1000000.0,
         n_experts=8, experts_per_token=2, tied_lm_head=False,
     ),
+    # K-EXAONE-236B-A23B (LGAI-EXAONE, model_type exaone_moe): "LLLG" window
+    # and full layers, a dense first layer, then 128 sigmoid-routed experts
+    # (8 picked, scaled 2.5) beside one shared expert; full layers carry no
+    # rotary embedding, q and k heads are normalised, blocks are post-norm
+    # (the EXAONE 4.0 conventions). 471 GB in bf16: served as one chip's
+    # share, ``?n_layers=8&experts_held=16&vocab_size=19200``
+    # (docs/tpu_backends.md). The multi-token-prediction layer is not loaded.
+    "k-exaone-236b-a23b": ModelSpec(
+        family="exaone_moe", vocab_size=153600, d_model=6144, n_layers=48,
+        n_heads=64, n_kv_heads=8, head_dim=128, d_ff=18432, max_seq=4096,
+        rope_theta=1000000.0, sliding_window=128, layer_pattern="LLLG",
+        tied_lm_head=False, n_experts=128, experts_per_token=8, first_dense=1,
+        d_ff_expert=2048, n_shared_experts=1, router_scale=2.5, init_depth=48,
+    ),
     # Scaled-down test/dev presets (CPU-fast, same code paths)
     "gpt2-tiny": _gpt2(vocab_size=512, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, head_dim=16, d_ff=128, max_seq=128),
@@ -160,6 +239,15 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         family="mixtral", vocab_size=512, d_model=64, n_layers=2, n_heads=4,
         n_kv_heads=2, head_dim=16, d_ff=128, max_seq=128, n_experts=4,
         experts_per_token=2, tied_lm_head=False,
+    ),
+    # the k-exaone family at a size the CPU runs: a dense layer and two
+    # "LLLG" periods, 16 experts of which 4 are held, top-4, window 8
+    "k-exaone-tiny": ModelSpec(
+        family="exaone_moe", vocab_size=512, d_model=64, n_layers=8,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=192, max_seq=128,
+        sliding_window=8, layer_pattern="LLLG", tied_lm_head=False,
+        n_experts=16, experts_per_token=4, first_dense=1, d_ff_expert=32,
+        n_shared_experts=1, router_scale=2.5, experts_held=4, init_depth=48,
     ),
     "gemma-tiny": ModelSpec(
         family="gemma", vocab_size=512, d_model=64, n_layers=2, n_heads=4,

@@ -1,0 +1,565 @@
+"""The forward pass of a spec with a ``layer_pattern``: each layer has its own
+attention kind, the first layers a dense MLP and the rest experts, and the
+cache is kept by layer kind.
+
+What differs from models/transformer.py, whose entry points hand a patterned
+spec over to the functions of the same name here:
+
+  - **Per-layer weights.** ``params["layers"]["00"]…`` hold one layer each
+    (every leaf with a leading dim of 1, so the sharding table's axes fit);
+    the depth loop is written out, so a layer's attention kind, MLP kind and
+    cache are static where the program is compiled.
+  - **A cache per layer kind** (:class:`KindKV`): a full-attention layer keeps
+    ``[slots, K, max_seq, hd]``; a window layer keeps a ring of ``spec.ring``
+    positions per row, written at ``position mod ring`` and read whole under
+    a mask made from the absolute position each ring entry holds. The K side
+    also carries the expert layers' counters, so that they ride the cache
+    through every program and cost no output of their own.
+  - **Expert layers over a held share.** The router scores all ``n_experts``
+    in float32 and picks the top k; only the picks that fall on the experts
+    held here are computed, beside the shared expert, and no pick is dropped.
+  - The family's conventions, written in and not chosen by the spec:
+    post-norm blocks (``h + norm(f(h))``), RMSNorm over each q and k head,
+    rotary embedding on the window layers only, a sigmoid router.
+  - **The residual stream is float32**; each sub-layer computes in the spec's
+    dtype and its normalised output is added in float32. The router reads the
+    float32 stream: with a bfloat16 stream (rounded to 2**-9 at each of 16
+    adds) the 8th and 9th scores swapped in one (position, layer) of twenty
+    against the float32 reference, and one swapped pick moves a served
+    log-probability by 0.03 and more (PERF.md section 6, PR 30).
+
+Members, ensembles, paging, int8 and sequence parallelism do not reach these
+functions: the engine refuses them for a patterned spec at start-up.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from quorum_tpu.models.model_config import ModelSpec
+from quorum_tpu.ops.attention import attention, decode_attention
+from quorum_tpu.ops.flash_attention import flash_prefill_attention
+from quorum_tpu.ops.norms import rmsnorm
+from quorum_tpu.ops.rotary import rope_cos_sin_for
+
+# Rows up to which the held experts run densely over every row (a decode
+# step: the step is bound by the experts' bytes, and at 32 rows 87 % of 16
+# held experts are picked anyway); above it picks are grouped by expert,
+# which is what a prefill's rows need: 512 tokens put one expert's worth of
+# picks on 16 held experts, a sixteenth of the dense form's products.
+DENSE_ROWS = 64
+# Rows of a grouped tile: the picks on held experts are sorted by expert into
+# tiles that each belong to one expert, and as many tiles are multiplied as
+# the picks fill, so the work follows the load whatever its skew and no
+# buffer can overflow. (XLA's ragged product on this chip walks 512-row
+# tiles, one visit a group: at the 30-odd rows a held expert gets of a
+# 512-token segment that is the dense form's cost again.)
+TILE = 128
+# The counters' columns after the held experts' own: picks made (k a real
+# token), and picks on a held expert that no product computed: held picks
+# less the rows the products say they took.
+STATS = ("picks", "dropped")
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class KindKV:
+    """One side (K or V) of a patterned spec's cache.
+
+    ``full``: one ``[slots, K, max_seq, hd]`` array per full-attention layer.
+    ``window``: one ``[slots, K, ring, hd]`` ring per window layer.
+    ``stats``: on the K side, int32 ``[expert layers, held + len(STATS)]``,
+    counted up by every program since the cache was made: picks per held
+    expert, then :data:`STATS`. None on the V side."""
+
+    full: tuple
+    window: tuple
+    stats: Any = None
+
+
+def init_cache(spec: ModelSpec, batch: int, dtype=None):
+    dt = jnp.dtype(dtype or spec.dtype)
+    rows = (batch, spec.n_kv_heads)
+    n_sparse = spec.n_layers - spec.first_dense
+
+    def side(stats):
+        return KindKV(
+            tuple(jnp.zeros(rows + (spec.max_seq, spec.head_dim), dt)
+                  for _ in spec.layers_of("G")),
+            tuple(jnp.zeros(rows + (spec.ring, spec.head_dim), dt)
+                  for _ in spec.layers_of("L")),
+            stats)
+
+    return (side(jnp.zeros((n_sparse, spec.held + len(STATS)), jnp.int32)),
+            side(None))
+
+
+def layer_of(params, i: int):
+    """Layer ``i``'s leaves without their leading dim of 1."""
+    return jax.tree.map(lambda a: a[0], params["layers"][f"{i:02d}"])
+
+
+# ---- the ring ---------------------------------------------------------------
+
+
+def ring_positions(last, ring: int):
+    """The absolute position each ring entry holds once positions 0..``last``
+    have been written: ``[..., ring]``, negative where nothing was written
+    (``last`` < 0 included)."""
+    last = jnp.asarray(last)[..., None]
+    return last - ((last - jnp.arange(ring)) % ring)
+
+
+def ring_write(ring_kv, value, offset, n_valid):
+    """Write positions ``offset .. offset + n_valid - 1`` of ``value``
+    ``[B, K, T, hd]`` into ``ring_kv`` ``[B, K, R, hd]``: entry ``j`` takes
+    the newest of them that is ``j`` mod R and keeps what it holds where there
+    is none, so padding past ``n_valid`` is never written and ``T`` may be
+    larger or smaller than the ring."""
+    r, t = ring_kv.shape[2], value.shape[2]
+    held = ring_positions(offset + n_valid - 1, r)            # [B, R]
+    take = held >= offset[:, None]
+    src = jnp.clip(held - offset[:, None], 0, t - 1)
+    new = jnp.take_along_axis(value, src[:, None, :, None], axis=2)
+    return jnp.where(take[:, None, :, None], new.astype(ring_kv.dtype),
+                     ring_kv)
+
+
+def _rows_of(cache, slot, n: int):
+    """``n`` rows of a ``[slots, K, T, hd]`` cache from row ``slot``."""
+    return lax.dynamic_slice_in_dim(cache, slot, n, axis=0)
+
+
+def _block_window_attn(q, k_new, v_new, ring_k, ring_v, pos, window: int):
+    """Window attention of a block of new positions ``pos`` ``[B, T]`` over
+    the ring as it stood before the block (positions up to ``pos[:, 0] - 1``)
+    and the block itself."""
+    r = ring_k.shape[2]
+    held = ring_positions(pos[:, 0] - 1, r)                   # [B, R]
+    key_pos = jnp.concatenate([held, pos], axis=1)[:, None, :]
+    key_ok = jnp.concatenate(
+        [held >= 0, jnp.ones(pos.shape, bool)], axis=1)[:, None, :]
+    at = pos[:, :, None]
+    keep = key_ok & (key_pos <= at) & (key_pos > at - window)  # [B, T, R+T]
+    keys = jnp.concatenate([ring_k.astype(q.dtype), k_new], axis=2)
+    vals = jnp.concatenate([ring_v.astype(q.dtype), v_new], axis=2)
+    return attention(q, keys, vals, keep[:, None, None, :, :])
+
+
+# ---- one layer's parts --------------------------------------------------------
+
+
+def _rope(x, cos, sin, pos):
+    """x ``[B, h, T, hd]`` rotated by ``pos`` ``[B, T]``: the pairs
+    ``(x[i], x[i + hd/2])``, as ops.rotary.apply_rope."""
+    c, s = cos[pos][:, None], sin[pos][:, None]
+    d2 = x.shape[-1] // 2
+    x1 = x[..., :d2].astype(jnp.float32)
+    x2 = x[..., d2:].astype(jnp.float32)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
+def _qkv(h, lyr, spec: ModelSpec, kind: str, cos, sin, pos):
+    from quorum_tpu.models.transformer import _qkv as project
+
+    q, k, v = project(h.astype(jnp.dtype(spec.dtype)), lyr, spec)
+    with jax.named_scope("norm"):
+        q = rmsnorm(q, lyr["q_norm_w"], spec.norm_eps)
+        k = rmsnorm(k, lyr["k_norm_w"], spec.norm_eps)
+    if kind == "L":
+        q, k = _rope(q, cos, sin, pos), _rope(k, cos, sin, pos)
+    return q, k, v
+
+
+def _sub(x, w, fn, spec: ModelSpec):
+    """One residual sub-layer on the float32 stream ``x``, its output
+    normalised before the add; ``fn`` takes the stream and casts what it
+    feeds to a matrix product."""
+    from quorum_tpu.models.transformer import _norm
+
+    return x + _norm(fn(x).astype(jnp.float32), w.astype(jnp.float32), None,
+                     spec)
+
+
+def _head(params, spec: ModelSpec, x):
+    """Logits of the float32 stream ``x``: the final norm in float32, the
+    head's product in the spec's dtype."""
+    from quorum_tpu.models import transformer as tr
+
+    x = tr._final_norm(params, spec, x).astype(jnp.dtype(spec.dtype))
+    return tr._unembed(params, spec, x)
+
+
+def _route(x, lyr, spec: ModelSpec):
+    """Scores and the pick, in float32: ``(weights [N, k], experts [N, k])``
+    for x ``[N, D]``."""
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         lyr["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(s + lyr["router_bias"].astype(jnp.float32),
+                           spec.experts_per_token)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        return spec.router_scale * w / jnp.sum(w, -1, keepdims=True), idx
+
+
+def _experts_dense(x, lyr, w_held):
+    """Every held expert over every row; ``w_held`` ``[N, held]`` is zero
+    where a row did not pick the expert."""
+    from quorum_tpu.models.quant import qeinsum
+
+    gate = qeinsum("nd,edf->enf", x, lyr["moe_w_gate"])
+    up = qeinsum("nd,edf->enf", x, lyr["moe_w_up"])
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = qeinsum("enf,efd->end", h, lyr["moe_w_down"])
+    return jnp.einsum("ne,end->nd", w_held.astype(out.dtype), out)
+
+
+def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on):
+    """The picks that fall on held experts, sorted by expert into tiles of
+    :data:`TILE` rows, an expert's picks filling whole tiles of its own; a
+    loop over the tiles that hold any multiplies each by its expert's
+    matrices and adds the weighted rows to their tokens. ``w_pick`` /
+    ``local`` / ``on`` ``[N, k]``: a pick's weight, its expert's index among
+    the held, and whether it counts. Returns ``(out [N, D] float32, rows the
+    products took)``."""
+    n, d = x.shape
+    k, held = spec.experts_per_token, spec.held
+    p = n * k
+    e_p = jnp.where(on, local, held).reshape(p)             # held: not here
+    oh = jax.nn.one_hot(e_p, held, dtype=jnp.int32)            # [P, held]
+    rank = jnp.take_along_axis(jnp.cumsum(oh, axis=0) - 1,
+                               jnp.minimum(e_p, held - 1)[:, None], 1)[:, 0]
+    tiles_of = -(-jnp.sum(oh, axis=0) // TILE)                 # [held]
+    ends = jnp.cumsum(tiles_of)
+    # a token picks an expert once, so at most min(k, held) of its picks
+    # land here; every expert may leave one tile part-filled
+    max_tiles = n * min(k, held) // TILE + held
+    row = ((ends - tiles_of)[jnp.minimum(e_p, held - 1)] * TILE + rank)
+    row = jnp.where(e_p < held, row, max_tiles * TILE)
+    pick_of_row = jnp.full((max_tiles * TILE,), p, jnp.int32).at[row].set(
+        jnp.arange(p, dtype=jnp.int32), mode="drop")
+    tok_of_row = jnp.where(pick_of_row < p, pick_of_row // k, n)
+    w_of_row = jnp.where(pick_of_row < p,
+                         w_pick.reshape(p)[jnp.minimum(pick_of_row, p - 1)],
+                         0.0)
+    expert_of_tile = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(max_tiles), side="right"),
+        held - 1)
+
+    def matrix(name, e):
+        return lax.dynamic_index_in_dim(lyr[name], e, 0, keepdims=False)
+
+    def tile(i, carry):
+        out, taken = carry
+        e = expert_of_tile[i]
+        tok = lax.dynamic_slice_in_dim(tok_of_row, i * TILE, TILE)
+        w = lax.dynamic_slice_in_dim(w_of_row, i * TILE, TILE)
+        rows = x[jnp.minimum(tok, n - 1)]
+        gate = jnp.dot(rows, matrix("moe_w_gate", e),
+                       preferred_element_type=jnp.float32)
+        up = jnp.dot(rows, matrix("moe_w_up", e),
+                     preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        y = jnp.dot(h, matrix("moe_w_down", e),
+                    preferred_element_type=jnp.float32)
+        # an empty row's token is n: its add falls outside and is dropped
+        return (out.at[tok].add(y * w[:, None], mode="drop"),
+                taken + jnp.sum(tok < n))
+
+    return lax.fori_loop(0, ends[-1], tile,
+                         (jnp.zeros((n, d), jnp.float32), jnp.int32(0)))
+
+
+def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
+    """An expert layer on x ``[B, T, D]``: the routed part over the experts
+    held here plus the shared expert. Returns ``(out, counts)``, the counts
+    one row of ``KindKV.stats``; ``token_ok`` ``[B, T]`` keeps padding and
+    idle rows out of the counts (and out of the tiles). ``dense`` None
+    chooses by the rows."""
+    from quorum_tpu.models.transformer import _dense_mlp_core
+
+    b, t, d = x.shape
+    n = b * t
+    ok = token_ok.reshape(n, 1)
+    # the router reads the stream as it is (float32); the experts its cast
+    w_pick, idx = _route(x.reshape(n, d), lyr, spec)
+    x = x.astype(jnp.dtype(spec.dtype))
+    xf = x.reshape(n, d)
+    local = idx - spec.expert_first
+    on = (local >= 0) & (local < spec.held) & ok
+    with jax.named_scope("moe.experts"):
+        one_hot = jax.nn.one_hot(jnp.where(on, local, spec.held), spec.held,
+                                 dtype=jnp.float32)            # [N, k, held]
+        per_expert = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)
+
+        computed = held_picks = jnp.sum(per_expert)
+        if dense if dense is not None else n <= DENSE_ROWS:
+            w_held = jnp.einsum("nk,nke->ne", w_pick, one_hot)
+            routed = _experts_dense(xf, lyr, w_held)
+        else:
+            routed, computed = _experts_grouped(xf, lyr, spec, w_pick, local,
+                                                on)
+    out = routed.astype(x.dtype).reshape(b, t, d)
+    if spec.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            out = out + _dense_mlp_core(x, lyr["shared"], spec)
+    counts = jnp.concatenate([
+        per_expert,
+        (jnp.sum(ok) * spec.experts_per_token).astype(jnp.int32)[None],
+        (held_picks - computed)[None]])
+    return out, counts
+
+
+def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list,
+         dense: bool | None = None):
+    from quorum_tpu.models.transformer import _dense_mlp
+
+    if i < spec.first_dense:
+        return _dense_mlp(x.astype(jnp.dtype(spec.dtype)), lyr, spec)
+    out, c = moe_layer(x, lyr, spec, token_ok, dense)
+    counts.append(c)
+    return out
+
+
+def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
+            attend, token_ok, dense: bool | None = None):
+    """The depth loop, written out, shared by the four served paths:
+    ``attend(h, lyr, kind, ck, cv) -> (attention output, ck, cv)`` is what
+    differs between them. ``x`` is the float32 stream; returns it with the
+    two caches, the K side's counters counted up."""
+    from quorum_tpu.models import transformer as tr
+
+    caches = {"G": [list(cache_k.full), list(cache_v.full)],
+              "L": [list(cache_k.window), list(cache_v.window)]}
+    seen = {"G": 0, "L": 0}
+    counts: list = []
+    for i in range(spec.n_layers):
+        lyr = layer_of(params, i)
+        kind = spec.attn_kind(i)
+        j, (ks, vs) = seen[kind], caches[kind]
+        seen[kind] += 1
+
+        def attn(h):
+            out, ks[j], vs[j] = attend(h, lyr, kind, ks[j], vs[j])
+            return tr._attn_out(out, lyr, jnp.dtype(spec.dtype))
+
+        x = _sub(x, lyr["attn_norm_w"], attn, spec)
+        x = _sub(x, lyr["mlp_norm_w"],
+                 lambda h: _mlp(h, lyr, spec, i, token_ok, counts, dense),
+                 spec)
+    stats = cache_k.stats
+    if counts and stats is not None:
+        stats = stats + jnp.stack(counts)
+    return (x, KindKV(tuple(caches["G"][0]), tuple(caches["L"][0]), stats),
+            KindKV(tuple(caches["G"][1]), tuple(caches["L"][1]), None))
+
+
+def _scope(kind: str):
+    return jax.named_scope("attn.window" if kind == "L" else "attn.full")
+
+
+# ---- the four served paths ----------------------------------------------------
+
+
+def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
+            slot=None):
+    """Single-shot admission: attention over the prompt itself, a full layer's
+    keys and values written from position 0, a window layer's last ring's
+    worth written into its ring. As transformer.prefill."""
+    from quorum_tpu.models import transformer as tr
+
+    b, t = tokens.shape
+    row = slot if slot is not None else 0
+    pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+    x = tr._embed(params, spec, tokens, pos[0]).astype(jnp.float32)
+    cos, sin = rope_cos_sin_for(spec)
+    token_ok = pos < lengths[:, None]
+    zero = jnp.zeros((b,), jnp.int32)
+
+    def attend(h, lyr, kind, ck, cv):
+        q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
+        with jax.named_scope("attn.core"), _scope(kind):
+            out = flash_prefill_attention(
+                q, k, v, lengths,
+                window=spec.sliding_window if kind == "L" else 0)
+        with jax.named_scope("attn.cache_write"):
+            if kind == "G":
+                return (out, tr._prefill_write(ck, k, row, None),
+                        tr._prefill_write(cv, v, row, None))
+            return (out,) + tuple(
+                lax.dynamic_update_slice_in_dim(
+                    c, ring_write(_rows_of(c, row, b), new, zero, lengths),
+                    row, axis=0)
+                for c, new in ((ck, k), (cv, v)))
+
+    x, cache_k, cache_v = _layers(params, spec, x, cache_k, cache_v, attend,
+                                  token_ok)
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return _head(params, spec, last), cache_k, cache_v
+
+
+def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
+                    cache_k, cache_v, slot, history=None):
+    """Chunked prefill of positions [offset, offset + T) of one slot. A full
+    layer writes the segment and attends over the row's first ``history``
+    positions; a window layer attends over its ring as the segments before
+    left it (the up to window - 1 positions before ``offset``) and the
+    segment itself, then writes the segment's real positions into the ring.
+    As transformer.prefill_segment."""
+    from quorum_tpu.models import transformer as tr
+
+    _, t = tokens.shape
+    hist = spec.max_seq if history is None else min(history, spec.max_seq)
+    pos = (offset + jnp.arange(t))[None, :]
+    x = tr._embed(params, spec, tokens, pos[0]).astype(jnp.float32)
+    cos, sin = rope_cos_sin_for(spec)
+    causal = (jnp.arange(hist)[None, :] <= pos[0][:, None])[None, None, None]
+    token_ok = (jnp.arange(t) < n_valid)[None, :]
+    off1, valid1 = offset[None], n_valid[None]
+
+    def attend(h, lyr, kind, ck, cv):
+        q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
+        if kind == "G":
+            with jax.named_scope("attn.cache_write"):
+                ck = lax.dynamic_update_slice(
+                    ck, k.astype(ck.dtype), (slot, 0, offset, 0))
+                cv = lax.dynamic_update_slice(
+                    cv, v.astype(cv.dtype), (slot, 0, offset, 0))
+            with jax.named_scope("attn.core"), _scope(kind):
+                size = (1, spec.n_kv_heads, hist, spec.head_dim)
+                out = attention(
+                    q, lax.dynamic_slice(ck, (slot, 0, 0, 0), size),
+                    lax.dynamic_slice(cv, (slot, 0, 0, 0), size), causal)
+            return out, ck, cv
+        rk, rv = _rows_of(ck, slot, 1), _rows_of(cv, slot, 1)
+        with jax.named_scope("attn.core"), _scope(kind):
+            out = _block_window_attn(q, k, v, rk, rv, pos,
+                                     spec.sliding_window)
+        with jax.named_scope("attn.cache_write"):
+            return (out,
+                    lax.dynamic_update_slice_in_dim(
+                        ck, ring_write(rk, k, off1, valid1), slot, axis=0),
+                    lax.dynamic_update_slice_in_dim(
+                        cv, ring_write(rv, v, off1, valid1), slot, axis=0))
+
+    return _layers(params, spec, x, cache_k, cache_v, attend, token_ok)[1:]
+
+
+def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
+                       write_mask=None, history=None):
+    """One position per row: a full layer writes at the row's position and
+    reads its first ``history``; a window layer writes at position mod ring
+    and reads the ring whole. As transformer.decode_step_blocks, but over
+    ``params`` (the layers are not a stack)."""
+    b = x.shape[0]
+    cos, sin = rope_cos_sin_for(spec)
+    allow = jnp.ones((b,), bool) if write_mask is None else write_mask
+    pos = lengths[:, None]
+    hist = (history if history is not None and history < spec.max_seq
+            else spec.max_seq)
+
+    def write_row(cache_row, new_row, idx, ok):
+        old = lax.dynamic_slice(cache_row, (0, idx, 0), new_row.shape)
+        return lax.dynamic_update_slice(
+            cache_row, jnp.where(ok, new_row, old), (0, idx, 0))
+
+    write = jax.vmap(write_row)
+    held = ring_positions(lengths, spec.ring)                  # [B, R]
+    ring_keep = ((held >= 0) & (held > pos - spec.sliding_window)
+                 )[:, None, None, None, :]
+
+    def attend(h, lyr, kind, ck, cv):
+        q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
+        at = lengths if kind == "G" else lengths % spec.ring
+        with jax.named_scope("attn.cache_write"):
+            ck = write(ck, k.astype(ck.dtype), at, allow)
+            cv = write(cv, v.astype(cv.dtype), at, allow)
+        with jax.named_scope("attn.core"), _scope(kind):
+            if kind == "G":
+                out = decode_attention(
+                    q, lax.slice_in_dim(ck, 0, hist, axis=2),
+                    lax.slice_in_dim(cv, 0, hist, axis=2), lengths + 1)
+            else:
+                out = attention(q, ck, cv, ring_keep)
+        return out, ck, cv
+
+    return _layers(params, spec, x, cache_k, cache_v, attend,
+                   allow[:, None])
+
+
+def decode_step(params, spec: ModelSpec, token, lengths, cache_k, cache_v,
+                write_mask=None, history=None):
+    from quorum_tpu.models import transformer as tr
+
+    x = tr.decode_token_embed(params, spec, token, lengths)
+    x, cache_k, cache_v = decode_step_blocks(
+        params, spec, x.astype(jnp.float32), lengths, cache_k, cache_v,
+        write_mask=write_mask, history=history)
+    return _head(params, spec, x[:, 0, :]), cache_k, cache_v
+
+
+def decode_multi(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
+                 write_mask=None, history=None):
+    """T positions per row in one forward (speculative verification). A
+    window layer attends over its ring as it stood and the T new positions,
+    then writes them; positions past ``max_seq`` are dropped. The experts
+    run densely, as in the one-position step whose tokens this has to
+    reproduce. As transformer.decode_multi."""
+    from quorum_tpu.models import transformer as tr
+
+    b, t = tokens.shape
+    pos = lengths[:, None] + jnp.arange(t)[None, :]
+    rope_pos = jnp.minimum(pos, spec.max_seq - 1)
+    with jax.named_scope("embed"):
+        x = tr._emb_rows(params["tok_emb"], tokens, jnp.float32)
+    cos, sin = rope_cos_sin_for(spec)
+    hist = spec.max_seq if history is None else min(history, spec.max_seq)
+    allow = jnp.ones((b,), bool) if write_mask is None else write_mask
+    n_write = jnp.where(allow, jnp.clip(spec.max_seq - lengths, 0, t), 0)
+    keep_full = (jnp.arange(hist)[None, None, :] <= pos[:, :, None]
+                 )[:, None, None, :, :]
+
+    def write_full(cache_row, new_row, idx, n):
+        # positions idx .. idx + n - 1 of new_row [K, T, hd]; the rest of the
+        # touched span keeps what it held (and a span past max_seq is moved
+        # back, its values rolled with it)
+        delta = jnp.maximum(idx + t - spec.max_seq, 0)
+        old = lax.dynamic_slice(cache_row, (0, idx - delta, 0), new_row.shape)
+        at = jnp.arange(t) - delta
+        keep = ((at >= 0) & (at < n))[None, :, None]
+        return lax.dynamic_update_slice(
+            cache_row, jnp.where(keep, jnp.roll(new_row, delta, axis=1), old),
+            (0, idx - delta, 0))
+
+    write = jax.vmap(write_full)
+
+    def attend(h, lyr, kind, ck, cv):
+        q, k, v = _qkv(h, lyr, spec, kind, cos, sin, rope_pos)
+        if kind == "G":
+            with jax.named_scope("attn.cache_write"):
+                ck = write(ck, k.astype(ck.dtype), lengths, n_write)
+                cv = write(cv, v.astype(cv.dtype), lengths, n_write)
+            with jax.named_scope("attn.core"), _scope(kind):
+                out = attention(
+                    q, lax.slice_in_dim(ck, 0, hist, axis=2),
+                    lax.slice_in_dim(cv, 0, hist, axis=2), keep_full)
+            return out, ck, cv
+        with jax.named_scope("attn.core"), _scope(kind):
+            out = _block_window_attn(q, k, v, ck, cv, pos,
+                                     spec.sliding_window)
+        with jax.named_scope("attn.cache_write"):
+            return (out, ring_write(ck, k, lengths, n_write),
+                    ring_write(cv, v, lengths, n_write))
+
+    x, cache_k, cache_v = _layers(
+        params, spec, x, cache_k, cache_v, attend,
+        jnp.broadcast_to(allow[:, None], (b, t)), dense=True)
+    return _head(params, spec, x), cache_k, cache_v

@@ -19,6 +19,11 @@ TPU-first design choices (SURVEY.md §7):
 Parameter pytree layout (leaf names are what the sharding table in
 quorum_tpu.parallel.sharding keys on):
 
+A spec with a ``layer_pattern`` (a kind of attention and of MLP per layer, a
+cache per layer kind) runs the functions of the same names in
+models/patterned.py: each entry point here hands it over in its first line,
+and a spec without a pattern compiles what it always compiled.
+
   tok_emb [V, D] · pos_emb [max_seq, D]? · final_norm_w/b [D] · lm_head [D, V]?
   blocks: attn_norm_w/b [L,D] · wq [L,D,H·hd] · wk/wv [L,D,K·hd] · wo [L,H·hd,D]
           bq/bk/bv/bo? · mlp_norm_w/b [L,D]
@@ -43,6 +48,7 @@ from quorum_tpu.cache.paging import (
     page_write_seg,
     page_write_step,
 )
+from quorum_tpu.models import patterned
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.quant import is_quantized, qeinsum
 from quorum_tpu.ops.attention import (
@@ -129,8 +135,7 @@ def _maybe(block: Params, name: str, layer_slice):
     return None if v is None else layer_slice(v)
 
 
-@jax.named_scope("mlp")
-def _dense_mlp(x, block, spec: ModelSpec):
+def _dense_mlp_core(x, block, spec: ModelSpec):
     if spec.gated_mlp:
         gate = qeinsum("btd,df->btf", x, block["w_gate"])
         up = qeinsum("btd,df->btf", x, block["w_up"])
@@ -147,6 +152,11 @@ def _dense_mlp(x, block, spec: ModelSpec):
     if block.get("b_down") is not None:
         out = out + block["b_down"]
     return out.astype(x.dtype)
+
+
+# the patterned family's shared expert is the same product under a scope of
+# its own (models/patterned.py)
+_dense_mlp = jax.named_scope("mlp")(_dense_mlp_core)
 
 
 def _moe_router(x, block, spec: ModelSpec):
@@ -382,6 +392,9 @@ def prefill(
     the K/V written to the cache is unchanged (the cache's seq axis stays
     replicated, so decode is sp-agnostic).
     """
+    if spec.layer_pattern:
+        return patterned.prefill(params, spec, tokens, lengths, cache_k,
+                                 cache_v, slot=slot)
     b, t = tokens.shape
     cache_row = slot if slot is not None else 0
     if mesh is not None and spec.sliding_window > 0 and sp_impl == "ring":
@@ -474,6 +487,10 @@ def prefill_segment(
     rows out of MoE expert capacity (they'd otherwise evict real tokens'
     picks from the fixed-size expert buffers).
     """
+    if spec.layer_pattern:
+        return patterned.prefill_segment(params, spec, tokens, offset,
+                                         n_valid, cache_k, cache_v, slot,
+                                         history=history)
     b, t = tokens.shape
     hist = spec.max_seq if history is None else min(history, spec.max_seq)
     positions = offset + jnp.arange(t)
@@ -577,6 +594,10 @@ def decode_step(
     resolves its backend's ``flash_decode=`` knob once and threads it
     through every decode program); ``None`` keeps the process-env gate
     (``flash_decode_mode()``) for direct callers and tests."""
+    if spec.layer_pattern:
+        return patterned.decode_step(params, spec, token, lengths, cache_k,
+                                     cache_v, write_mask=write_mask,
+                                     history=history)
     x = decode_token_embed(params, spec, token, lengths)
     x, cache_k, cache_v = decode_step_blocks(
         params["blocks"], spec, x, lengths, cache_k, cache_v,
@@ -886,6 +907,10 @@ def decode_multi(
     on-device budget (always ≤ the remaining window), so a dropped
     position is never one that gets accepted.
     """
+    if spec.layer_pattern:
+        return patterned.decode_multi(params, spec, tokens, lengths, cache_k,
+                                      cache_v, write_mask=write_mask,
+                                      history=history)
     b, t = tokens.shape
     pos = lengths[:, None] + jnp.arange(t)[None, :]              # [B,T]
     with jax.named_scope("embed"):
@@ -1012,6 +1037,10 @@ def _layer_body(carry_x, block, spec: ModelSpec, positions, cos, sin, attn_fn,
 
 def _scan_layers(params, spec: ModelSpec, tokens, attn_fn, remat: bool,
                  lengths=None, unembed: bool = True):
+    if spec.layer_pattern:
+        raise NotImplementedError(
+            "a spec with a layer_pattern has no cache-free forward pass "
+            "(embeddings, scoring, training): it is served through the cache")
     b, t = tokens.shape
     positions = jnp.arange(t)
     x = _embed(params, spec, tokens, positions)
@@ -1117,7 +1146,13 @@ def init_cache(spec: ModelSpec, batch: int, dtype=None, kv_quant: str | None = N
     scales)`` — HALF the cache HBM capacity and half the bytes every decode
     step streams from the history window (decode attention contracts
     natively in int8, ops.attention.decode_attention_q8). At llama-3-8b /
-    8k window the bf16 cache is 1.07 GB per slot; int8 is 0.54 GB."""
+    8k window the bf16 cache is 1.07 GB per slot; int8 is 0.54 GB.
+
+    A spec with a ``layer_pattern`` gets a cache per layer kind instead
+    (models/patterned.py: ``KindKV``), in bf16 only."""
+    if spec.layer_pattern:
+        assert kv_quant is None, "a patterned spec's cache is not quantized"
+        return patterned.init_cache(spec, batch, dtype)
     dt = jnp.dtype(dtype or spec.dtype)
     shape = (spec.n_layers, batch, spec.n_kv_heads, spec.max_seq, spec.head_dim)
     if kv_quant == "int8":

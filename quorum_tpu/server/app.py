@@ -401,7 +401,8 @@ def create_app(
                   "zero_drain", "breaker_state",
                   "kv_pages", "kv_page_size",
                   "kv_pages_allocated", "kv_pages_free",
-                  "qos", "draining")
+                  "qos", "draining", "moe_experts_held",
+                  "kv_cache_full_bytes", "kv_cache_window_bytes")
         # One snapshot per distinct engine (_distinct_engines). Each
         # family's TYPE line appears exactly once, with all its samples
         # grouped — the Prometheus text format rejects repeated TYPE lines.
@@ -412,9 +413,13 @@ def create_app(
                 kind = "gauge" if key in gauges else "counter"
                 lines.append(f"# TYPE quorum_tpu_engine_{key} {kind}")
                 for name, m in snapshots:
-                    lines.append(
-                        f'quorum_tpu_engine_{key}{{backend="{name}"}} {m[key]}'
-                    )
+                    # a dict is a family with labels of its own: {labels: n}
+                    samples = (m[key] if isinstance(m[key], dict)
+                               else {"": m[key]})
+                    lines.extend(
+                        f'quorum_tpu_engine_{key}{{backend="{name}"'
+                        f'{"," if labels else ""}{labels}}} {value}'
+                        for labels, value in samples.items())
         # Latency histogram families (request duration, TTFT, inter-token,
         # queue wait, prefill, decode chunk) — recorded by the tracing spine
         # across server/strategy/engine layers (observability.METRICS).

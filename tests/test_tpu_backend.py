@@ -357,3 +357,36 @@ async def test_stream_timeout_is_end_to_end_not_per_delta():
         async for _ in b.stream(body, {}, 0.5):
             pass
     assert time.monotonic() - t0 < 3.0
+
+
+async def test_an_engine_with_more_rows_than_the_default_pool_drains_every_stream():
+    """A stream holds a thread for its whole life. asyncio's default pool has
+    min(32, cores + 4) threads: a backend of 32 rows, which that pool holds
+    on no host, gets a pool of the engine's own, shared by the backends that
+    share the engine; a smaller one keeps the default pool, whatever the
+    host's cores. As many streams at once as there are rows all deliver."""
+    from quorum_tpu.backends.tpu_backend import ASYNCIO_DEFAULT_POOL_MAX
+
+    few = TpuBackend.from_spec(
+        BackendSpec(name="F", url="tpu://llama-tiny?slots=2&seed=41", model="t"))
+    assert few._stream_pool() is None
+    rows = ASYNCIO_DEFAULT_POOL_MAX
+    url = f"tpu://llama-tiny?slots={rows}&seed=42"
+    a = TpuBackend.from_spec(BackendSpec(name="A", url=url, model="t"))
+    b = TpuBackend.from_spec(BackendSpec(name="B", url=url, model="t"))
+    assert a.engine is b.engine
+    pool = a._stream_pool()
+    assert pool is not None and pool is b._stream_pool()
+    assert pool._max_workers == 2 * rows
+
+    async def one(i: int) -> int:
+        body = {"model": "t", "max_tokens": 6, "temperature": 0,
+                "messages": [{"role": "user", "content": f"{i:03d} hello"}]}
+        n = 0
+        async for chunk in a.stream(body, {}, timeout=120):
+            n += bool(chunk["choices"][0]["delta"].get("content"))
+        return n
+
+    assert all(await asyncio.gather(*(one(i) for i in range(rows))))
+    a.engine.shutdown()
+    assert a.engine.stream_pool is None
