@@ -1,0 +1,204 @@
+"""What a decode chunk moves of its cache, read from the v5e compiler's text
+without a chip.
+
+libtpu compiles for a chip that is described and not attached
+(``jax.experimental.topologies``), so the optimized module of the engine's
+decode chunk (``transformer.decode_chunk``: a step loop around
+``decode_step``'s layer scan, the cache donated) can be read here: how many
+bytes of temporaries it takes (``memory_analysis().temp_size_in_bytes``; a
+second K and V shows there), and which operations inside the step loop
+allocate or copy an array as large as a whole cache side. The text has the
+names a device profile shows (``copy.128``), and ``hlo_names`` reads the
+same text for the layer part of each::
+
+    JAX_PLATFORMS=cpu python -m quorum_tpu.analysis.decode_static \
+        mistral-7b 'quant=int8&max_seq=1024&slots=12'
+    JAX_PLATFORMS=cpu python -m quorum_tpu.analysis.decode_static \
+        mistral-7b 'n_layers=5&max_seq=1024&slots=8&members=3'
+
+prints ``temp``, then one line per operation of a loop body whose result is
+a cache side or one layer's slab of it: computation, operation, opcode,
+shape, ``op_name``, and ``WHOLE-CACHE MOVE`` on those :func:`whole_cache_moves`
+lists. Nothing runs, so this gives no time; the scan's program does not
+depend on depth, and the full-depth int8 member compiles in some ten seconds.
+"""
+
+from __future__ import annotations
+
+import math
+import os
+import re
+import sys
+from urllib.parse import parse_qsl
+
+from quorum_tpu.analysis.hlo_names import _COMPUTATION, _INSTRUCTION, _OP_NAME
+
+_SHAPE = re.compile(r"=\s+([a-z]+\d*)\[([\d,]*)\]")
+_CALLED = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_MOVES = ("copy", "copy-done", "AllocateBuffer")
+_NO_DEVICE_OP = ("get-tuple-element", "bitcast", "parameter", "tuple")
+
+
+def v5e_device():
+    """One described v5e chip to compile for (raises where libtpu cannot
+    describe the topology; ``v5e:1x1`` is refused, so one of 2x2)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+
+
+def compile_decode_chunk(spec, device, *, rows: int, members: int = 1,
+                         n_steps: int = 8, history: int | None = 512,
+                         quant: str | None = None):
+    """Compile ``decode_chunk`` (greedy sampling, cache donated) for
+    ``device`` on shapes alone, as the engine runs it: ``members`` > 1 folds
+    the rows member-major and vmaps ``decode_step`` over stacked weights and
+    caches (``engine._stacked_rows_call``). Returns the compiled program:
+    ``as_text()`` and ``memory_analysis()`` are what this module reads.
+
+    ``quant="int8"`` compiles the CPU's f32 form of the int8 products unless
+    ``QUORUM_TPU_QEINSUM_INT8=1`` is set (``quant._use_native_int8`` asks
+    for the default backend, which is the CPU here)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from quorum_tpu.engine.engine import _stacked_rows_call
+    from quorum_tpu.models.init import init_params_from_key
+    from quorum_tpu.models.quant import quantize_params
+    from quorum_tpu.models.transformer import (
+        decode_chunk,
+        decode_step,
+        init_cache,
+    )
+
+    def weights():
+        params = init_params_from_key(spec, jax.random.PRNGKey(0))
+        return quantize_params(params) if quant == "int8" else params
+
+    def stacked(shape):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(
+                ((members,) if members > 1 else ()) + s.shape, s.dtype), shape)
+
+    def greedy(logits, live, carry):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), carry, ()
+
+    def chunk(params, token, lengths, live, budget, eos, ck, cv):
+        model_call = None
+        if members > 1:
+            def model_call(ck, cv, tok, pos, wm):
+                return _stacked_rows_call(
+                    members, rows,
+                    lambda p, k, v, t, ps, w: decode_step(
+                        p, spec, t, ps, k, v, write_mask=w, history=history),
+                    params, ck, cv, tok, pos, wm)
+        return decode_chunk(params, spec, n_steps, token, lengths, live,
+                            budget, eos, ck, cv, greedy, (), history=history,
+                            model_call=model_call)
+
+    n = rows * members
+    ck, cv = jax.eval_shape(lambda: init_cache(spec, rows))
+    args = (stacked(jax.eval_shape(weights)),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            stacked(ck), stacked(cv))
+    one_chip = SingleDeviceSharding(device)
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args)
+    return jax.jit(chunk, donate_argnums=(6, 7)).lower(*args).compile()
+
+
+def _computations(text: str) -> "dict[str, list[str]]":
+    out: dict[str, list[str]] = {}
+    lines: list[str] = []
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            lines = out.setdefault(head.group(1), [])
+        else:
+            lines.append(line)
+    return out
+
+
+def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
+    """``(computation, operation, opcode, shape, op_name)`` for every
+    instruction whose result has one of ``sizes`` elements in a ``while``
+    body of an optimized module's text, or in a computation such a body
+    calls (a nested loop, a branch): what runs once a decode step, or once a
+    layer of a step. Fused computations are left out: their instructions are
+    no device operations of their own."""
+    comps = _computations(text)
+    bodies = {m.group(1) for lines in comps.values() for line in lines
+              for m in re.finditer(r"body=%?([\w.\-]+)", line)}
+    reached, todo = set(), sorted(bodies)
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps or "fused_computation" in name:
+            continue
+        reached.add(name)
+        for line in comps[name]:
+            for one, many in _CALLED.findall(line):
+                todo += [one] if one else re.findall(r"[\w.\-]+", many)
+    out = []
+    for name in sorted(reached):
+        for line in comps[name]:
+            found, shape = _INSTRUCTION.match(line), _SHAPE.search(line)
+            if not (found and shape and shape.group(2)):
+                continue
+            dims = [int(d) for d in shape.group(2).split(",")]
+            opcode = found.group(2)
+            if math.prod(dims) in sizes and opcode not in _NO_DEVICE_OP:
+                if opcode == "custom-call":
+                    target = re.search(r'custom_call_target="([^"]*)"', line)
+                    opcode = target.group(1) if target else opcode
+                op_name = _OP_NAME.search(line)
+                out.append((name, found.group(1), opcode,
+                            f"{shape.group(1)}[{shape.group(2)}]",
+                            op_name.group(1) if op_name else ""))
+    return out
+
+
+def whole_cache_moves(text: str, cache_elements: int) -> "list[tuple]":
+    """The rows of :func:`loop_body_ops` that allocate or copy an array as
+    large as a whole cache side inside the step loop: a ``copy``, a fusion
+    the compiler named for its copy, an ``AllocateBuffer``. An in-place
+    update of the carried cache (a scatter, a dynamic-update-slice fusion)
+    has the cache's shape too and moves only what it writes: not listed."""
+    return [row for row in loop_body_ops(text, {cache_elements})
+            if row[2] in _MOVES or (row[2] == "fusion" and "copy" in row[1])]
+
+
+def main(argv: "list[str]") -> int:
+    if not argv:
+        print(__doc__, file=sys.stderr)
+        return 2
+    os.environ.setdefault("QUORUM_TPU_QEINSUM_INT8", "1")
+    from quorum_tpu.models.model_config import resolve_spec
+
+    options = dict(parse_qsl("".join(argv[1:2])))
+    spec = resolve_spec(argv[0], options)
+    rows, members = int(options.get("slots", 8)), int(options.get("members", 1))
+    compiled = compile_decode_chunk(
+        spec, v5e_device(), rows=rows, members=members,
+        history=int(options.get("history", 512)), quant=options.get("quant"))
+    text = compiled.as_text()
+    side = (members * spec.n_layers * rows * spec.n_kv_heads * spec.max_seq
+            * spec.head_dim)
+    print(f"temp\t{compiled.memory_analysis().temp_size_in_bytes / 1e9:.4f} GB")
+    moves = whole_cache_moves(text, side)
+    for row in loop_body_ops(text, {side, side // spec.n_layers}):
+        print(*row, "WHOLE-CACHE MOVE" if row in moves else "", sep="\t")
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main(sys.argv[1:]))

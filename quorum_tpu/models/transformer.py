@@ -4,7 +4,10 @@ TPU-first design choices (SURVEY.md §7):
 
   - **Scanned layers**: all per-layer weights are stacked with a leading
     ``n_layers`` dim and the depth loop is one ``lax.scan`` — compile time and
-    HLO size are O(1) in depth, and XLA pipelines the layers.
+    HLO size are O(1) in depth, and XLA pipelines the layers. The decode
+    step's scan carries the hidden states AND the KV cache, which each layer
+    updates in place (``decode_step_blocks``); the prefill-side scans still
+    take the cache as ``xs`` and stack it back as ``ys``.
   - **Static shapes everywhere**: prompts are right-padded to a bucket length
     and masked by ``lengths``; the KV cache is a preallocated ``max_seq``
     buffer indexed by position *data*. One compiled program per (batch,
@@ -569,8 +572,8 @@ def decode_step(
     spec: ModelSpec,
     token: jnp.ndarray,    # [B] current token ids
     lengths: jnp.ndarray,  # [B] #tokens already in cache (current token's position)
-    cache_k: jnp.ndarray,  # [L, B, K, max_seq, hd] (donated by the engine's jit)
-    cache_v: jnp.ndarray,
+    cache_k: jnp.ndarray,  # [L, B, K, max_seq, hd] (donated by the engine's jit
+    cache_v: jnp.ndarray,  #   and updated in place inside the program)
     write_mask: jnp.ndarray | None = None,  # [B] bool: rows allowed to write
     history: int | None = None,  # static: attend over cache[:history] only
     flash: str | None = None,  # "" off / "tpu" / "interpret"; None = env gate
@@ -638,53 +641,65 @@ def decode_step_blocks(
     stack; the pipeline-staged decode path (parallel/pipeline.py) runs it
     per stage on that stage's ``L/pp`` layer shard, which is what keeps the
     two schedules' per-layer math identical. Returns
-    ``(x, cache_k, cache_v)`` with ``x`` still pre-final-norm."""
+    ``(x, cache_k, cache_v)`` with ``x`` still pre-final-norm.
+
+    A dense cache (bf16 array or int8 tuple) rides the scan's **carry**, the
+    layer index counting within the slice given: each layer writes its
+    ``[B, K, hd]`` rows into the carried buffer with one scatter a leaf and
+    reads its history window back out of it, so nothing of the cache's size
+    is sliced out as ``xs``, stacked back as ``ys`` or copied into the
+    caller's step loop (four cache-sized copies a step on a v5e: PERF.md §5;
+    ``analysis/decode_static.py`` shows them in the compiler's text). The
+    scatter is one operation for all rows because a member ``vmap`` turns
+    per-row ``dynamic_update_slice``s into scatters the compiler will not
+    chain in place. A paged pool (cache/paging.py) writes and gathers through
+    its own page table, and keeps the ``xs``/``ys`` form."""
     b = x.shape[0]
     flash_mode = flash_decode_mode() if flash is None else flash
     cos, sin = rope_cos_sin_for(spec)
-
-    def write_row(cache_row, new_row, idx, allow):
-        # cache_row [K, max_seq, hd] (or [K, max_seq] scale), new_row likewise
-        start = (0, idx, 0)[: cache_row.ndim]
-        old = lax.dynamic_slice(cache_row, start, new_row.shape)
-        return lax.dynamic_update_slice(
-            cache_row, jnp.where(allow, new_row, old), start)
-
     allow = (jnp.ones((b,), bool) if write_mask is None else write_mask)
-    write = jax.vmap(write_row, in_axes=(0, 0, 0, 0))  # over batch
+    hist = spec.max_seq if history is None else min(history, spec.max_seq)
+    rows = jnp.arange(b)
+
+    def write_leaf(leaf, new, layer):
+        # leaf [L', B, K, max_seq, hd] (or [L', B, K, max_seq] scale), new
+        # [B, K, hd] (or [B, K]). A masked row writes back what it holds;
+        # ``clip`` is the start-clamping of a dynamic_update_slice.
+        at = leaf.at[layer, rows, :, lengths]
+        keep = allow.reshape((b,) + (1,) * (new.ndim - 1))
+        return at.set(jnp.where(keep, new, at.get(mode="clip")), mode="clip",
+                      indices_are_sorted=True, unique_indices=True)
 
     @jax.named_scope("attn.cache_write")
-    def step_write(cache, value):
+    def step_write(cache, value, layer):
         # value [B, K, 1, hd] at each row's own position
         if kv_is_paged(cache):
             return page_write_step(cache, value, lengths, allow, spec.max_seq)
         if kv_is_q8(cache):
             c8, cs = cache
             q8, s = _kv_quantize(value)
-            return (write(c8, q8, lengths, allow),
-                    write(cs, s.astype(cs.dtype), lengths, allow))
-        return write(cache, value.astype(cache.dtype), lengths, allow)
+            return (write_leaf(c8, q8[:, :, 0], layer),
+                    write_leaf(cs, s[:, :, 0].astype(cs.dtype), layer))
+        return write_leaf(cache, value[:, :, 0].astype(cache.dtype), layer)
 
-    def step_read(cache):
+    def read_leaf(leaf, layer):
+        # The prefix that can hold valid entries (the write above landed at
+        # lengths < hist), of the carried and already written buffer. The
+        # mask ki < lengths+1 already excludes the tail; the slice stops it
+        # being READ.
+        sizes = (1,) + leaf.shape[1:3] + (hist,) + leaf.shape[4:]
+        return lax.dynamic_slice(
+            leaf, (layer,) + (0,) * (leaf.ndim - 1), sizes)[0]
+
+    def step_read(cache, layer):
         if kv_is_paged(cache):
             # Gather the history window's pages into the dense [B, K, hist,
             # hd] layout — attention (int8 / flash / XLA) runs unchanged on
             # the gathered window.
-            hist = (history if history is not None and history < spec.max_seq
-                    else spec.max_seq)
             return page_read(cache, hist)
-        if history is not None and history < spec.max_seq:
-            # Read only the prefix that can hold valid entries (the write
-            # above landed at lengths < history). The mask ki < lengths+1
-            # already excludes the tail; the slice stops it being READ.
-            if kv_is_q8(cache):
-                return (lax.slice_in_dim(cache[0], 0, history, axis=2),
-                        lax.slice_in_dim(cache[1], 0, history, axis=2))
-            return lax.slice_in_dim(cache, 0, history, axis=2)
-        return cache
+        return jax.tree.map(lambda leaf: read_leaf(leaf, layer), cache)
 
-    def body(carry_x, per_layer):
-        block, ck, cv = per_layer
+    def layer_step(carry_x, block, ck, cv, layer):
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)  # q [B,H,1,hd], k/v [B,K,1,hd]
         if spec.pos == "rope":
@@ -692,12 +707,12 @@ def decode_step_blocks(
             rope_row = jax.vmap(lambda xr, p: apply_rope(xr[None], cos, sin, p[None])[0])
             q = rope_row(q, lengths)
             k = rope_row(k, lengths)
-        new_ck = step_write(ck, k)
-        new_cv = step_write(cv, v)
+        ck = step_write(ck, k, layer)
+        cv = step_write(cv, v, layer)
         with jax.named_scope("attn.core"):
-            read_k = step_read(new_ck)
-            read_v = step_read(new_cv)
-            if kv_is_q8(new_ck):
+            read_k = step_read(ck, layer)
+            read_v = step_read(cv, layer)
+            if kv_is_q8(ck):
                 # Native int8 q·K / p·V over the quantized cache: HALF the
                 # cache bytes per step, no dequantized HBM copy.
                 attn = decode_attention_q8(
@@ -720,10 +735,24 @@ def decode_step_blocks(
         carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = _moe_mlp(h2, block, spec) if spec.is_moe else _dense_mlp(h2, block, spec)
-        carry_x = carry_x + mlp
-        return carry_x, (new_ck, new_cv)
+        return carry_x + mlp, ck, cv
 
-    x, (cache_k, cache_v) = lax.scan(body, x, (blocks, cache_k, cache_v))
+    if kv_is_paged(cache_k):
+        def paged_body(carry_x, per_layer):
+            carry_x, ck, cv = layer_step(carry_x, *per_layer, None)
+            return carry_x, (ck, cv)
+
+        x, (cache_k, cache_v) = lax.scan(
+            paged_body, x, (blocks, cache_k, cache_v))
+        return x, cache_k, cache_v
+
+    def body(carry, per_layer):
+        block, layer = per_layer
+        return layer_step(carry[0], block, carry[1], carry[2], layer), None
+
+    n_layers = jax.tree.leaves(cache_k)[0].shape[0]
+    (x, cache_k, cache_v), _ = lax.scan(
+        body, (x, cache_k, cache_v), (blocks, jnp.arange(n_layers)))
     return x, cache_k, cache_v
 
 

@@ -1,0 +1,308 @@
+"""The dense decode step carries its cache through the layer scan and writes
+it in place (models/transformer.decode_step_blocks).
+
+The oracle is the body that was there before: the cache as the scan's ``xs``,
+written per row by a vmapped ``dynamic_update_slice`` and stacked back as
+``ys``. It is kept here as the plain reference. No arithmetic moved, so logits
+and both caches must be equal bit for bit, after one step and after an 8-step
+``decode_chunk``.
+
+The static test reads the v5e compiler's text (analysis/decode_static.py):
+only that text shows whether a cache-sized copy or allocation sits inside the
+step loop; the CPU's text does not (its scatter copies).
+"""
+
+import dataclasses
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from quorum_tpu.analysis import decode_static
+from quorum_tpu.engine.engine import _stacked_rows_call
+from quorum_tpu.models import transformer as tr
+from quorum_tpu.models.init import init_params
+from quorum_tpu.models.model_config import MODEL_PRESETS
+from quorum_tpu.models.quant import quantize_params
+
+TINY = dataclasses.replace(MODEL_PRESETS["llama-tiny"], max_seq=64)
+N_STEPS = 8
+
+
+def oracle_step_blocks(blocks, spec, x, lengths, cache_k, cache_v,
+                       write_mask=None, history=None, flash=None):
+    """``decode_step_blocks`` as it was: cache as ``xs``, stacked as ``ys``."""
+    del flash
+    b = x.shape[0]
+    cos, sin = tr.rope_cos_sin_for(spec)
+
+    def write_row(cache_row, new_row, idx, allow):
+        start = (0, idx, 0)[: cache_row.ndim]
+        old = lax.dynamic_slice(cache_row, start, new_row.shape)
+        return lax.dynamic_update_slice(
+            cache_row, jnp.where(allow, new_row, old), start)
+
+    allow = (jnp.ones((b,), bool) if write_mask is None else write_mask)
+    write = jax.vmap(write_row, in_axes=(0, 0, 0, 0))
+
+    def step_write(cache, value):
+        if tr.kv_is_q8(cache):
+            c8, cs = cache
+            q8, s = tr._kv_quantize(value)
+            return (write(c8, q8, lengths, allow),
+                    write(cs, s.astype(cs.dtype), lengths, allow))
+        return write(cache, value.astype(cache.dtype), lengths, allow)
+
+    def step_read(cache):
+        if history is not None and history < spec.max_seq:
+            return jax.tree.map(
+                lambda a: lax.slice_in_dim(a, 0, history, axis=2), cache)
+        return cache
+
+    def body(carry_x, per_layer):
+        block, ck, cv = per_layer
+        h = tr._norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"),
+                     spec)
+        q, k, v = tr._qkv(h, block, spec)
+        if spec.pos == "rope":
+            rope_row = jax.vmap(
+                lambda xr, p: tr.apply_rope(xr[None], cos, sin, p[None])[0])
+            q = rope_row(q, lengths)
+            k = rope_row(k, lengths)
+        new_ck = step_write(ck, k)
+        new_cv = step_write(cv, v)
+        read_k = step_read(new_ck)
+        read_v = step_read(new_cv)
+        if tr.kv_is_q8(new_ck):
+            attn = tr.decode_attention_q8(
+                q, read_k[0], read_k[1], read_v[0], read_v[1], lengths + 1,
+                window=spec.sliding_window)
+        else:
+            attn = tr.decode_attention(q, read_k, read_v, lengths + 1,
+                                       window=spec.sliding_window)
+        carry_x = carry_x + tr._attn_out(attn, block, carry_x.dtype)
+        h2 = tr._norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"),
+                      spec)
+        mlp = (tr._moe_mlp(h2, block, spec) if spec.is_moe
+               else tr._dense_mlp(h2, block, spec))
+        return carry_x + mlp, (new_ck, new_cv)
+
+    x, (cache_k, cache_v) = lax.scan(body, x, (blocks, cache_k, cache_v))
+    return x, cache_k, cache_v
+
+
+def filled_cache(spec, rows, seed, kv_quant=None, members=1):
+    """A cache with something at every position, position 0 included: a
+    write that lands where it should not changes a value."""
+    rng = np.random.default_rng(seed)
+    lead = (members,) if members > 1 else ()
+
+    def fill(leaf):
+        shape = lead + leaf.shape
+        if leaf.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        if leaf.dtype == jnp.float32:  # an int8 cache's scales
+            return jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
+        return jnp.asarray(rng.normal(size=shape), leaf.dtype)
+
+    return jax.tree.map(fill, tr.init_cache(spec, rows, kv_quant=kv_quant))
+
+
+def greedy(logits, live, carry):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), carry, ()
+
+
+@dataclasses.dataclass(frozen=True)
+class Case:
+    spec: object = TINY
+    quant: bool = False
+    kv_quant: "str | None" = None
+    lengths: tuple = (5, 5, 5)
+    live: tuple = (True, True, True)
+    history: "int | None" = None
+    budget: tuple = (64, 64, 64)
+    members: int = 1
+    shard: "tuple | None" = None  # (first layer, last, first row, last)
+
+
+CASES = {
+    "bf16_weights": Case(),
+    "int8_weights": Case(quant=True),
+    "rows_at_different_lengths": Case(lengths=(3, 17, 40)),
+    "dead_row_over_a_live_prompts_position_0": Case(
+        lengths=(9, 21, 30), live=(True, False, True)),
+    "all_rows_dead": Case(live=(False, False, False)),
+    "history_below_max_seq": Case(lengths=(3, 11, 20), history=32),
+    "history_equal_to_max_seq": Case(lengths=(3, 11, 50), history=64),
+    "row_finishes_mid_chunk": Case(lengths=(3, 17, 40), budget=(64, 3, 64)),
+    "three_members_under_vmap": Case(
+        lengths=(3, 17, 4, 30, 9, 2), live=(True, True, True, False, True, True),
+        budget=(64, 64, 2, 64, 64, 64), history=32, members=3),
+    "three_members_int8_weights": Case(
+        quant=True, lengths=(3, 17, 4, 30, 9, 2), live=(True,) * 6,
+        budget=(64,) * 6, members=3),
+    "int8_cache_tuple_leaves": Case(
+        kv_quant="int8", lengths=(3, 17, 40), live=(True, False, True),
+        history=48),
+    "int8_cache_three_members": Case(
+        kv_quant="int8", lengths=(3, 17, 4, 30, 9, 2), live=(True,) * 6,
+        budget=(64,) * 6, members=3),
+    "layer_shard_and_row_slab_of_a_pipeline_stage": Case(
+        spec=dataclasses.replace(TINY, n_layers=4), lengths=(7, 2),
+        live=(True, False), history=32, shard=(2, 4, 1, 3)),
+    "learned_positions_layernorm_bias": Case(
+        spec=dataclasses.replace(MODEL_PRESETS["gpt2-tiny"], max_seq=64),
+        lengths=(3, 17, 40)),
+    "expert_layers": Case(
+        spec=dataclasses.replace(MODEL_PRESETS["mixtral-tiny"], max_seq=64),
+        lengths=(3, 17, 40), history=48),
+    "sliding_window": Case(
+        spec=dataclasses.replace(TINY, sliding_window=8), lengths=(3, 17, 40)),
+}
+
+
+def run_case(case: Case):
+    """One step's ``(logits, cache_k, cache_v)`` and an 8-step chunk's whole
+    result, through whatever ``tr.decode_step_blocks`` is at the moment."""
+    spec, mem = case.spec, case.members
+    n = len(case.lengths)
+    rows = n // mem
+    one = [init_params(spec, seed=s) for s in range(mem)]
+    if case.quant:
+        one = [quantize_params(p) for p in one]
+    params = one[0] if mem == 1 else jax.tree.map(
+        lambda *leaves: jnp.stack(leaves), *one)
+    # a pipeline stage's slab holds more rows than the tick's group
+    ck, cv = filled_cache(spec, case.shard[3] + 1 if case.shard else rows, 7,
+                          case.kv_quant, mem)
+    token = jnp.arange(3, 3 + n, dtype=jnp.int32)
+    lengths = jnp.asarray(case.lengths, jnp.int32)
+    live = jnp.asarray(case.live)
+    budget = jnp.asarray(case.budget, jnp.int32)
+    eos = jnp.full((n,), -1, jnp.int32)
+
+    if case.shard:
+        # parallel/pipeline.py: a stage's layers, the tick's rows of its slab
+        l0, l1, r0, r1 = case.shard
+        blocks = jax.tree.map(lambda a: a[l0:l1], params["blocks"])
+        cut = lambda a: a[l0:l1, r0:r1]  # noqa: E731
+        x = tr.decode_token_embed(params, spec, token, lengths)
+
+        def stage(x, ck, cv, lens):
+            return tr.decode_step_blocks(blocks, spec, x, lens, ck, cv,
+                                         write_mask=live, history=case.history)
+
+        def stage_chunk(x, ck, cv):
+            for i in range(N_STEPS):
+                x, ck, cv = stage(x, ck, cv, lengths + i * live)
+            return x, ck, cv
+
+        args = (x, cut(ck), cut(cv))
+        return jax.jit(stage)(*args, lengths), jax.jit(stage_chunk)(*args)
+
+    def step_of(p, k, v, t, ps, w):
+        return tr.decode_step(p, spec, t, ps, k, v, write_mask=w,
+                              history=case.history)
+
+    def model_call(ck, cv, tok, pos, wm):
+        if mem == 1:
+            return step_of(params, ck, cv, tok, pos, wm)
+        return _stacked_rows_call(mem, rows, step_of, params, ck, cv, tok,
+                                  pos, wm)
+
+    def chunk(ck, cv):
+        return tr.decode_chunk(params, spec, N_STEPS, token, lengths, live,
+                               budget, eos, ck, cv, greedy, (),
+                               history=case.history, model_call=model_call)
+
+    step = jax.jit(lambda ck, cv: model_call(
+        ck, cv, token, jnp.where(live, lengths, 0), live))
+    return step(ck, cv), jax.jit(chunk)(ck, cv)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_in_place_step_equals_the_stacked_body_bit_for_bit(name, monkeypatch):
+    got = run_case(CASES[name])
+    monkeypatch.setattr(tr, "decode_step_blocks", oracle_step_blocks)
+    want = run_case(CASES[name])
+    flat_got, tree_got = jax.tree.flatten(got)
+    flat_want, tree_want = jax.tree.flatten(want)
+    assert tree_got == tree_want
+    for g, w in zip(flat_got, flat_want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_the_cases_move_what_they_say():
+    """The oracle itself: a live row's write lands at its own position, a
+    dead row's cache is left as it was, position 0 included."""
+    case = CASES["dead_row_over_a_live_prompts_position_0"]
+    before = filled_cache(case.spec, 3, 7)[0]
+    (_, after, _), chunk = run_case(case)
+    assert not np.array_equal(after[:, 0, :, 9], before[:, 0, :, 9])
+    np.testing.assert_array_equal(after[:, 1], before[:, 1])
+    np.testing.assert_array_equal(np.asarray(chunk[5])[:, 1], before[:, 1])
+    assert chunk[2].tolist() == [N_STEPS, 0, N_STEPS]
+
+
+# ---- the v5e compiler's text -------------------------------------------------
+
+COMPILE_LIMIT_S = 240.0
+
+
+def within(seconds, fn, *args, **kw):
+    """``fn``'s result, or a skip when it raises or takes longer: a worker
+    that cannot load libtpu must not hang the file."""
+    box = {}
+
+    def work():
+        try:
+            box["value"] = fn(*args, **kw)
+        except Exception as e:  # noqa: BLE001 - reported as the skip's reason
+            box["error"] = e
+
+    thread = threading.Thread(target=work, daemon=True)
+    thread.start()
+    thread.join(seconds)
+    if thread.is_alive():
+        pytest.skip(f"{fn.__name__} did not end within {seconds:.0f} s")
+    if "error" in box:
+        pytest.skip(f"{fn.__name__}: {box['error']!r}")
+    return box["value"]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    return within(60.0, decode_static.v5e_device)
+
+
+# the cells' shapes at a cut depth: the scan's program does not depend on it
+STATIC = {
+    "chat_12_rows_of_1024_int8": dict(quant="int8", rows=12, members=1),
+    "quorum_3_members_of_8_rows_bf16": dict(quant=None, rows=8, members=3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STATIC))
+def test_no_whole_cache_copy_or_allocation_in_the_step_loop(
+        name, v5e, monkeypatch):
+    shape = STATIC[name]
+    spec = dataclasses.replace(
+        MODEL_PRESETS["mistral-7b"], n_layers=2, max_seq=1024).validate()
+    monkeypatch.setenv("QUORUM_TPU_QEINSUM_INT8", "1")  # the chip's products
+    side = (shape["members"] * spec.n_layers * shape["rows"]
+            * spec.n_kv_heads * spec.max_seq * spec.head_dim)
+
+    def program(step_blocks):
+        monkeypatch.setattr(tr, "decode_step_blocks", step_blocks)
+        compiled = within(COMPILE_LIMIT_S, decode_static.compile_decode_chunk,
+                          spec, v5e, history=512, **shape)
+        return decode_static.whole_cache_moves(compiled.as_text(), side)
+
+    new = tr.decode_step_blocks
+    assert program(oracle_step_blocks), \
+        "the reader finds nothing in the body it was written against"
+    assert not program(new)
