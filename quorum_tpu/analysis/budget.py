@@ -45,25 +45,12 @@ def classify_decode_key(key) -> str:
     """Family name for one ``engine._decode_cache`` key; raises
     :class:`UnbudgetedProgramKey` on an unknown or shape-drifted key."""
     if isinstance(key, tuple) and key:
-        if key[0] == "pp":
-            # Pipeline-staged decode variants: the unstaged key with a
-            # leading "pp" tag (engine._decode_key — a staged program can
-            # never share a family with its unstaged twin).
-            rest = key[1:]
-            if rest and rest[0] == "loop":
-                fam = ("pp_loop_dfa" if len(rest) > 2 and rest[2] == "dfa"
-                       else "pp_loop")
-            elif rest and rest[0] == "dfa":
-                fam = "pp_dfa"
-            else:
-                fam = "pp_plain"
-            return _check_len("decode_cache", fam, key)
         if key[0] == "paged":
             # Paged-KV decode variants (kv_pages=1): the dense key with a
             # leading "paged" tag — table-gather attention can never share
-            # a compiled program with its rectangular twin. pp and
-            # spec_model are rejected under kv_pages, so the paged families
-            # are exactly the non-pp, non-spec_loop dense set.
+            # a compiled program with its rectangular twin. spec_model is
+            # rejected under kv_pages, so the paged families are exactly
+            # the non-spec_loop dense set.
             rest = key[1:]
             if rest and rest[0] == "loop":
                 fam = ("paged_loop_dfa" if len(rest) > 2 and rest[2] == "dfa"

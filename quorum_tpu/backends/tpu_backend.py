@@ -52,8 +52,8 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    exhaustion sheds at admission (503 + Retry-After),
                    never mid-stream. Structural (part of the engine cache
                    key); composes with kv_quant=int8, members=M, tp= and
-                   prompt-lookup spec_decode; rejected with pp>1,
-                   ensemble>1, sp>1 and draft-model speculation. See
+                   prompt-lookup spec_decode; rejected with sp>1 and
+                   draft-model speculation. See
                    docs/tpu_backends.md for the interaction matrix
   kv_page_size=    tokens per KV page (default: prefill_chunk, else
                    min(64, max_seq)); power of two dividing max_seq
@@ -69,7 +69,7 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    divisible by sp)
                    prefill as ring attention with the prompt sequence
                    sharded over the sp axis (long-context serving)
-  seed=            weight-init seed (distinct seeds ≈ distinct ensemble members)
+  seed=            weight-init seed (distinct seeds ≈ distinct quorum members)
   decode_chunk=    tokens per device dispatch (default 8)
   decode_pipeline= decode-dispatch ring depth (default 2): the scheduler
                    keeps up to K decode chunks in flight on the device and
@@ -167,20 +167,13 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    cache bytes each long-context decode step streams.
                    Orthogonal to quant= (compose both for the smallest
                    footprint)
-  ensemble=M       on-device logit-ensemble decoding (default 1 = off): M
-                   independently-seeded weight sets (seed..seed+M-1) decode
-                   ONE shared stream — every step averages the M members'
-                   next-token logits on device before sampling. A true deep
-                   ensemble (one consensus completion), vs the strategy
-                   layer's text-level concatenation/aggregation of M
-                   separate completions
   members=M        stacked fan-out (default 1 = off): backends whose URLs
   member=i         agree on ``members=M`` (and the base seed/spec) share ONE
                    engine holding M independently-seeded weight sets
                    (seed..seed+M-1) stacked [M, …] on device; ``member=i``
                    selects which weight set serves THIS backend. Each member
                    keeps its own slots/sampler state and produces its own
-                   stream (unlike ``ensemble``), but every decode chunk —
+                   stream, but every decode chunk —
                    and coalesced same-bucket admissions — advance ALL
                    members in one dispatch: an N-model quorum pays N× the
                    compute, not N× the per-chunk host turnaround
@@ -209,9 +202,9 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    reclaimed under churn (docs/prefix_cache.md). Holds the
                    cache's NATIVE representation, so kv_quant=int8 halves
                    host bytes too. Structural (applies when this backend
-                   constructs the engine); rejected with members=/
-                   ensemble=/sp>1 and with prefill_chunk too small to
-                   chunk (the restore rides chunked prefill)
+                   constructs the engine); rejected with members=/sp>1
+                   and with prefill_chunk too small to chunk (the restore
+                   rides chunked prefill)
   prefix_store_bytes=  host byte budget for the store (default 1g);
                    accepts a plain byte count or a k/m/g binary suffix
                    (e.g. 512m). Least-recently-used chunks evict past it
@@ -298,6 +291,16 @@ def _parse_bytes_opt(name: str, raw: str) -> int:
     if out < 1:
         raise ValueError(f"invalid {name}={raw!r} (must be positive)")
     return out
+
+
+# URL options that once selected a decode program and no longer exist, with
+# what serves their purpose: a URL that still sets one must fail at config
+# time, not quietly serve one unsharded model.
+_REMOVED_OPTIONS = (
+    ("ensemble", "members=M backends under the concatenate or aggregate "
+                 "strategy (one stacked engine, M streams)"),
+    ("pp", "tp= to shard a served model (pp remains the training axis)"),
+)
 
 
 def _parse_bool_opt(name: str, raw: str) -> bool:
@@ -519,7 +522,7 @@ class TpuBackend:
         self.default_max_tokens = default_max_tokens
         self.decode_chunk = decode_chunk  # None → engine default
         # Sampling-RNG offset: ckpt backends share one set of weights, so
-        # ensemble diversity must come from the sampler stream, not the init
+        # quorum diversity must come from the sampler stream, not the init
         # seed. Offset 0 for random-init backends (their weights differ).
         self.rng_offset = rng_offset
         self.tokenizer = get_tokenizer(engine.spec.vocab_size, tokenizer_path)
@@ -531,7 +534,11 @@ class TpuBackend:
         tp = int(opts.get("tp", 1))
         dp = int(opts.get("dp", 1))
         sp = int(opts.get("sp", 1))
-        pp = int(opts.get("pp", 1))
+        for name, instead in _REMOVED_OPTIONS:
+            if opts.get(name, "1").strip() != "1":
+                raise ValueError(
+                    f"{name}={opts[name]}: the {name}= option was removed "
+                    f"in PR 32 — use {instead}")
         zero_drain = _parse_bool_opt(
             "zero_drain", opts.get("zero_drain", "0"))
         if zero_drain and opts.get("disagg"):
@@ -544,14 +551,6 @@ class TpuBackend:
                 "disaggregated admissions already run on their own device "
                 "group with the ring at full depth — zero-drain is "
                 "structural there (drop one knob)")
-        if zero_drain and pp > 1:
-            # Same config-time discipline (the engine re-checks): the
-            # staged-injection write lands one stage's KV shard from
-            # outside the stage ring.
-            raise ValueError(
-                "pp>1 does not compose with zero_drain=1: use "
-                "disagg=P+D&pp=K (the handoff feeds stage-sharded rows) "
-                "or drop one knob")
         prefill_mesh = None
         if opts.get("disagg"):
             from quorum_tpu.parallel.mesh import disagg_meshes, parse_disagg
@@ -559,12 +558,11 @@ class TpuBackend:
             # Structural split into two disjoint device groups. dp= stays
             # a contradiction (groups are data-disjoint by construction —
             # scale requests with the replica tier, docs/scaling.md);
-            # tp=/sp=/pp= became the INTRA-group factorization: tp shards
+            # tp=/sp= are the INTRA-group factorization: tp shards
             # weights+KV within both groups, sp scales the prefill group
-            # (sequence-parallel staging for 100k+-token admissions), pp
-            # stages the decode group's layers (models bigger than one
-            # group's HBM). group_mesh_configs rejects every non-factoring
-            # combination with the reason, at config time.
+            # (sequence-parallel staging for 100k+-token admissions).
+            # group_mesh_configs rejects every non-factoring combination
+            # with the reason, at config time.
             n_p, n_d = parse_disagg(opts["disagg"])
             if dp > 1:
                 raise ValueError(
@@ -572,9 +570,9 @@ class TpuBackend:
                     "construction; dp= does not compose with it (scale "
                     "request throughput with the replica tier instead)")
             prefill_mesh, mesh = disagg_meshes(
-                n_p, n_d, tp=tp if "tp" in opts else None, sp=sp, pp=pp)
-        elif tp * dp * sp * pp > 1:
-            mesh = make_mesh(MeshConfig(dp=dp, sp=sp, tp=tp, pp=pp))
+                n_p, n_d, tp=tp if "tp" in opts else None, sp=sp)
+        elif tp * dp * sp > 1:
+            mesh = make_mesh(MeshConfig(dp=dp, sp=sp, tp=tp))
         else:
             mesh = single_device_mesh()
         ckpt = opts.get("ckpt", "")
@@ -610,7 +608,6 @@ class TpuBackend:
             kv_quant=opts.get("kv_quant") or None,
             prefix_cache=_parse_bool_opt(
                 "prefix_cache", opts.get("prefix_cache", "1")),
-            ensemble=int(opts.get("ensemble", 1)),
             sp_impl=opts.get("sp_impl", "ring"),
             # Paged KV slot memory (structural: part of the engine cache
             # key — a dense URL never shares a paged engine). Geometry
@@ -701,7 +698,7 @@ class TpuBackend:
                     "backends: they configure the stacked members=N init, "
                     "and members=N does not apply to ckpt= (one loaded "
                     "weight set; use seed= for sampling diversity)")
-            # seed= still differentiates ensemble members: it offsets the
+            # seed= still differentiates quorum members: it offsets the
             # sampling RNG (weights are shared — one checkpoint on device).
             rng_offset = int(opts.get("seed", 0))
             # Real weights from a local HF checkpoint dir; its tokenizer files

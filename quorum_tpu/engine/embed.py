@@ -14,7 +14,7 @@ state, no KV cache, no scheduler involvement. Programs are jitted per
 (batch bucket, sequence bucket) and cached on the engine instance; inputs
 pad to power-of-two buckets so arbitrary request shapes reuse a handful of
 compiled programs (the same discipline as the engine's prefill buckets).
-Stacked-members / ensemble engines carry a leading member axis on every
+Stacked-members engines carry a leading member axis on every
 param leaf; the backend's member index selects one weight set inside the
 jitted program (no host-side copy). Quantized engines work unchanged —
 the transformer dequantizes per-leaf via ``qeinsum``.
@@ -58,7 +58,7 @@ def _embed_fn(engine, b_bucket: int, t_bucket: int):
     if fn is not None:
         return fn
     spec = engine.spec
-    stacked = engine.members > 1 or engine.ensemble > 1
+    stacked = engine.members > 1
 
     def run(params, tokens, lengths, member):
         if stacked:

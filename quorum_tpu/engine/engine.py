@@ -40,8 +40,7 @@ concurrent requests):
     sets live ``[M, …]`` on ONE engine; every decode chunk, coalesced
     admission (single-shot or chunked segment), and speculative-verify step
     advances ALL members in a single member-vmapped program — N models'
-    streams for one host turnaround per dispatch. Distinct from
-    ``ensemble=M`` (one consensus stream from averaged logits).
+    streams for one host turnaround per dispatch.
   - **Tiered prefix caching**: each slot's resident token prefix is reusable
     zero-copy (tier 0); with ``prefix_store=host`` the engine additionally
     snapshots released slots' KV prefixes to a chunk-granular host-RAM
@@ -241,8 +240,6 @@ CONSTRAIN_ARENA_MAX = 8192
 # chunk's, and inside ``admit`` a single-shot admission's first token.
 TURN_PHASES = ("idle", "sweep", "admit", "fill", "reap_block", "emit",
                "compile")
-_CKPT_ENSEMBLE_ERROR = ("ensemble members are seeded random inits; a "
-                        "checkpoint provides only one weight set")
 _CKPT_MEMBERS_ERROR = ("stacked members are seeded random inits; a "
                        "checkpoint provides only one weight set")
 
@@ -331,22 +328,6 @@ def _host_fetch(*arrays):
     out = jax.device_get(  # qlint: allow-sync(the one blocking read per dispatch)
         tuple(gather(x) for x in arrays))
     return tuple(out) if len(arrays) > 1 else out[0]
-
-
-def _member_call(ens: int, fn, params, ck, cv, *, mean: bool = True):
-    """Run a model call member-vmapped when ``ens`` > 1.
-
-    ``fn(params, ck, cv)`` is the single-model call. With an ensemble, every
-    arg carries a leading member axis and the call is vmapped; when ``mean``
-    (the logit-returning calls), the members' logits are averaged in f32 —
-    the consensus distribution every sample draws from."""
-    if ens == 1:
-        return fn(params, ck, cv)
-    out = jax.vmap(fn)(params, ck, cv)
-    if not mean:
-        return out
-    logits, ck, cv = out
-    return jnp.mean(logits.astype(jnp.float32), axis=0), ck, cv
 
 
 def _stacked_rows_call(mem: int, n_s: int, fn, params, ck, cv, *rows):
@@ -1105,7 +1086,6 @@ class InferenceEngine:
         prefix_store: str | None = None,
         prefix_store_bytes: int = DEFAULT_PREFIX_STORE_BYTES,
         prefix_store_chunk: int = 0,
-        ensemble: int = 1,
         members: int = 1,
         kv_quant: str | None = None,
         draft_spec: ModelSpec | None = None,
@@ -1155,15 +1135,6 @@ class InferenceEngine:
         # contracts natively in int8 (transformer.py / ops.attention).
         # Orthogonal to weight quant= (compose freely).
         self.kv_quant = kv_quant or None
-        # On-device logit-ensemble decoding: M independently-seeded weight
-        # sets decode ONE shared stream — every model call is vmapped over a
-        # leading member axis (params and KV caches are [M, …]) and the M
-        # members' next-token logits are averaged on device before sampling.
-        # A true deep ensemble: one completion whose every token is the
-        # consensus of M models — impossible in the reference architecture,
-        # where members are separate HTTP services whose finished texts can
-        # only be concatenated or re-summarized.
-        self.ensemble = max(1, int(ensemble))
         # Stacked fan-out members: M independently-seeded weight sets serve
         # M *separate* streams from ONE set of compiled programs — params and
         # KV caches carry a leading member axis ([M, …], model calls vmapped
@@ -1171,8 +1142,7 @@ class InferenceEngine:
         # in a single dispatch. This is what makes an N-model quorum on one
         # chip cost N× the *compute*, not N× the dispatch: three co-located
         # engines each pay their own host turnaround per chunk, while a
-        # stacked engine pays one. (Distinct from ``ensemble``, which decodes
-        # ONE consensus stream from averaged logits.) The reference cannot
+        # stacked engine pays one. The reference cannot
         # express this at all — its "members" are separate HTTP services
         # (/root/reference/src/quorum/oai_proxy.py:182-192).
         self.members = max(1, int(members))
@@ -1259,8 +1229,7 @@ class InferenceEngine:
         # ring attention with the prompt sharded over the sp axis. Chunked
         # admission is disabled there — the ring IS the long-prompt answer
         # (O(T/sp) attention memory per device, one compiled program).
-        from quorum_tpu.parallel.mesh import (AXIS_DP, AXIS_PP, AXIS_SP,
-                                              AXIS_TP)
+        from quorum_tpu.parallel.mesh import AXIS_PP, AXIS_SP, AXIS_TP
 
         self._use_sp = dict(self.mesh.shape).get(AXIS_SP, 1) > 1
         # Tensor-parallel engines hand their mesh to single-shot prefill so
@@ -1306,63 +1275,15 @@ class InferenceEngine:
                     "segment and register on the decode group — the "
                     "single-shot admit program samples its first token "
                     "inside prefill, on the wrong device group")
-        # Pipeline-staged decode (pp>1 on the decode mesh — colocated
-        # ``pp=K`` or the disagg decode group's ``disagg=P+D&pp=K``): stage
-        # s holds layers [s·L/pp, (s+1)·L/pp) and those layers' KV shard,
-        # and the slot batch splits into pp row groups that flow stage→
-        # stage as the pipeline's microbatches (parallel/pipeline.py
-        # staged_decode_chunk/_loop) — a model whose weight+KV footprint
-        # exceeds one group's HBM still serves with the ring full. Every
-        # invalid combination rejects HERE with the reason, at config time
-        # — never at first dispatch.
-        self.decode_pp = dict(self.mesh.shape).get(AXIS_PP, 1)
-        if self.decode_pp > 1:
-            npp = self.decode_pp
-            if zero_drain:
-                raise ValueError(
-                    "pp>1 does not compose with zero_drain=1: staged-"
-                    "injection admissions write one stage's KV shard from "
-                    "outside the stage ring — use disagg=P+D&pp=K (the "
-                    "handoff feeds stage-sharded rows) or drop one knob")
-            if self._use_sp:
-                raise ValueError(
-                    "pp>1 does not compose with sp>1 on the decode mesh: "
-                    "the staged row-group schedule owns the non-tp axes — "
-                    "under disagg, sp= shards the PREFILL group instead")
-            mesh_shape = dict(self.mesh.shape)
-            if mesh_shape.get(AXIS_TP, 1) > 1 or mesh_shape.get(AXIS_DP, 1) > 1:
-                # Same contract group_mesh_configs enforces for the disagg
-                # decode group: the staged shard_map partitions over pp
-                # only, so a tp/dp axis beside it would be silently
-                # replicated per stage (full weight+KV copy per device) —
-                # exactly the HBM blow-up pp exists to avoid.
-                raise ValueError(
-                    f"pipeline-staged decode runs tp=1/dp=1 within each "
-                    f"stage (pp={npp} with tp="
-                    f"{mesh_shape.get(AXIS_TP, 1)}, dp="
-                    f"{mesh_shape.get(AXIS_DP, 1)} on the decode mesh): "
-                    "make pp the whole group, or drop one knob")
-            if self.members > 1 or self.ensemble > 1:
-                raise ValueError(
-                    "pp>1 does not compose with members/ensemble engines: "
-                    "the staged decode program is not member-vmapped — run "
-                    "separate cells or drop one knob")
-            if self.spec_decode > 0:
-                raise ValueError(
-                    "pp>1 does not compose with spec_decode/spec_model: "
-                    "verify turns run the full layer stack in one program, "
-                    "which is exactly what a staged decode group cannot "
-                    "hold — drop one knob")
-            if self.spec.n_layers % npp:
-                raise ValueError(
-                    f"pp={npp} does not divide n_layers="
-                    f"{self.spec.n_layers}: stages hold equal contiguous "
-                    "layer shards — pick a dividing pp or pad the model")
-            if self.n_slots % npp:
-                raise ValueError(
-                    f"pp={npp} does not divide slots={self.n_slots}: the "
-                    "slot batch splits into pp row groups (the pipeline's "
-                    "microbatches) — pick slots as a multiple of pp")
+        # A served model shards its weights over tp (and its prompts over
+        # sp); pp is the training axis (parallel/pipeline.py) and no decode
+        # program runs over it.
+        mesh_pp = dict(self.mesh.shape).get(AXIS_PP, 1)
+        if mesh_pp > 1:
+            raise ValueError(
+                f"the decode mesh has pp={mesh_pp}: "
+                "pipeline-staged decode was removed in PR 32 — shard a "
+                "served model with tp= (pp remains the training axis)")
         if sp_impl not in ("ring", "ulysses"):
             raise ValueError(
                 f"unknown sp_impl {sp_impl!r} (ring or ulysses)")
@@ -1422,18 +1343,7 @@ class InferenceEngine:
         # the chunked path into the staging cache and reaches its decode
         # slot through the handoff/injection queue + register.
         self.staged = self.disagg or self.zero_drain
-        if self.ensemble > 1:
-            if self._use_sp:
-                raise ValueError(
-                    "ensemble decoding does not compose with sp>1 "
-                    "(ring attention inside the member vmap)")
-            if params is not None:
-                raise ValueError(_CKPT_ENSEMBLE_ERROR)
         if self.members > 1:
-            if self.ensemble > 1:
-                raise ValueError(
-                    "members (stacked fan-out streams) and ensemble "
-                    "(consensus decoding) are mutually exclusive")
             if self._use_sp:
                 raise ValueError(
                     "members does not compose with sp>1 "
@@ -1450,12 +1360,6 @@ class InferenceEngine:
             raise ValueError(
                 f"unknown member_seeds {member_seeds!r} (distinct or shared)")
         self.member_seeds = member_seeds
-        if member_seeds == "shared" and self.ensemble > 1:
-            raise ValueError(
-                "member_seeds=shared does not compose with ensemble>1: all "
-                f"{self.ensemble} consensus members would init identical "
-                "weights, so the averaged logits ARE member 0's logits — "
-                "consensus over M copies of one model is just the model")
         self.quorum_dedup = bool(quorum_dedup)
         if self.quorum_dedup:
             if self.members <= 1:
@@ -1501,25 +1405,6 @@ class InferenceEngine:
         self.kv_pool_pages = 0
         self._page_alloc: PageAllocator | None = None
         if self.kv_pages:
-            if self.decode_pp > 1:
-                raise ValueError(
-                    "kv_pages=1 does not compose with pp>1: the staged "
-                    "decode schedule shards the cache's layer axis across "
-                    "stages, and the page pool's layer axis would need a "
-                    "per-stage page table — drop one knob")
-            if self.ensemble > 1:
-                raise ValueError(
-                    "kv_pages=1 does not compose with ensemble>1: member m "
-                    "reads its history through its OWN pool copy — "
-                    "pool[m, table[m, slot]] — but the host allocator keeps "
-                    f"one page chain per slot group ({self.n_slots} "
-                    f"chains), not one per member row ({self.ensemble}x"
-                    f"{self.n_slots}), so per-member tables can never "
-                    "diverge. Stacked members=M share each slot group's "
-                    "history by construction (one prompt per group, one "
-                    "chain) and compose; consensus rows would need "
-                    "per-member chains — run ensemble cells dense or drop "
-                    "one knob")
             if draft_spec is not None:
                 raise ValueError(
                     "kv_pages=1 does not compose with a draft model "
@@ -1600,9 +1485,7 @@ class InferenceEngine:
                  draft_spec is not None),
                 ("sp>1 (ring or ulysses admission)", self._use_sp),
                 ("tp>1", mesh_shape.get(AXIS_TP, 1) > 1),
-                ("pp>1", self.decode_pp > 1),
-                ("members>1 / ensemble>1 (member stacking)",
-                 self.members > 1 or self.ensemble > 1),
+                ("members>1 (member stacking)", self.members > 1),
                 (f"spec_decode={self.spec_decode} with a ring of "
                  f"{self.spec.ring} under window + spec_decode + 1",
                  self.spec_decode > 0 and self.spec.ring
@@ -1630,10 +1513,6 @@ class InferenceEngine:
                     "stacked cache carries a member axis the single-slot "
                     "snapshot/restore programs do not address — run "
                     "separate engines or drop prefix_store")
-            if self.ensemble > 1:
-                raise ValueError(
-                    "prefix_store does not compose with ensemble>1 (the "
-                    "member-stacked cache is not snapshot/restored)")
             if self._use_sp:
                 raise ValueError(
                     "prefix_store does not compose with sp>1: sequence-"
@@ -1928,13 +1807,13 @@ class InferenceEngine:
         # — fused with the verify into one on-device draft→verify scan
         # (_spec_loop_fn), so consecutive dispatches pipeline with no host
         # input. Subject to the same row-wise spec_draft_ok gating;
-        # excluded for stacked/ensemble engines — the draft runtime is not
+        # excluded for stacked engines — the draft runtime is not
         # member-vmapped.
         if draft_spec is not None:
-            if self.members > 1 or self.ensemble > 1:
+            if self.members > 1:
                 raise ValueError(
                     "draft-model decoding (spec_model=/spec_ckpt=) does "
-                    "not compose with members/ensemble engines")
+                    "not compose with members engines")
             if self.spec_decode <= 0:
                 raise ValueError(
                     "a draft model requires spec_decode > 0 (the backend "
@@ -1977,18 +1856,16 @@ class InferenceEngine:
         (under disagg) the prefill mesh — both groups must hold identical
         weights, so both run the same deterministic init/shard programs."""
         spec = self.spec
-        if self.members > 1 or self.ensemble > 1:
+        if self.members > 1:
             from quorum_tpu.models.init import init_params_ensemble_sharded
 
-            # Same stacked-init program for members and ensembles ([M, …]
-            # leaves, one seed per member, quant applied per member inside
-            # the init); only the *decode semantics* differ.
+            # The stacked-init program: [M, …] leaves, one seed per member,
+            # quant applied per member inside the init.
             # member_seeds=shared repeats ONE seed: every member holds
             # identical weights (one model, M sampling streams) — the
             # quorum_dedup precondition (docs/quorum.md).
-            stacked = max(self.members, self.ensemble)
-            seeds = ([seed] * stacked if self.member_seeds == "shared"
-                     else [seed + i for i in range(stacked)])
+            seeds = ([seed] * self.members if self.member_seeds == "shared"
+                     else [seed + i for i in range(self.members)])
             return init_params_ensemble_sharded(
                 spec, mesh, seeds, quant=self.quant)
         if params is not None:
@@ -2019,7 +1896,7 @@ class InferenceEngine:
         """Slot-cache sharding for one device group — the decode mesh's
         slot cache and the prefill mesh's staging cache share one chunk
         WIRE format even when their physical layouts differ (per-group
-        ``tp=``, an sp-sharded staging cache, a pp-staged decode cache:
+        ``tp=``, an sp-sharded staging cache:
         the handoff reshards on the fly, kv_transfer route="reshard").
         ``seq_shard`` shards the position axis over the mesh's sp axis —
         the disagg prefill group's staging cache under ``sp>1``.
@@ -2047,7 +1924,7 @@ class InferenceEngine:
             return sh
         if self.spec.layer_pattern:
             # one [slots, K, T, hd] leaf per layer, by kind; the counters
-            # replicated (tp, pp, sp and members are refused above)
+            # replicated (tp, sp and members are refused above)
             leaf = NamedSharding(mesh, P())
             full = (leaf,) * len(self.spec.layers_of("G"))
             window = (leaf,) * len(self.spec.layers_of("L"))
@@ -2057,7 +1934,7 @@ class InferenceEngine:
         if self.kv_quant:
             # (values, scales): the scale array drops the head_dim axis.
             sh = (sh, NamedSharding(mesh, P(*tuple(sh.spec)[:4])))
-        if self.ensemble > 1 or self.members > 1:
+        if self.members > 1:
             # member-stacked cache [M, L, S, K, T, hd]: member axis
             # vmapped, never sharded
             sh = jax.tree.map(
@@ -2137,7 +2014,6 @@ class InferenceEngine:
         Used for the decode cache and (under disagg) the staging cache.
         A PagedKV sharding tree selects the page-pool layout instead —
         staging caches always pass the dense shardings."""
-        stacked = max(self.ensemble, self.members)
         if isinstance(shardings, PagedKV):
             def zero_paged():
                 return init_paged_cache(
@@ -2156,9 +2032,9 @@ class InferenceEngine:
         def zero_cache():
             ck, cv = init_cache(self.spec, batch=self.n_slots,
                                 kv_quant=self.kv_quant)
-            if stacked > 1:
+            if self.members > 1:
                 stack = lambda x: jnp.zeros(  # noqa: E731
-                    (stacked,) + x.shape, x.dtype)
+                    (self.members,) + x.shape, x.dtype)
                 ck = jax.tree.map(stack, ck)
                 cv = jax.tree.map(stack, cv)
             return ck, cv
@@ -2438,21 +2314,15 @@ class InferenceEngine:
         mesh = self.mesh if self._use_sp else None
         tp_mesh = self._tp_mesh
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
-        ens = self.ensemble
 
         def admit(params, tokens, lengths1, slot, seed, temp1, topp1, topk1,
                   pp1, fp1, bias_row, budget1, eos1,
                   ck, cv, token_s, lengths_s, keys_s, temp_s, topp_s, topk_s,
                   pp_s, fp_s, counts_s, bias_s, live_s, budget_s, eos_s):
-            # mesh is None whenever ens > 1 (sp is rejected with ensembles)
             with tracing_program(f"admit/{bucket}"):
-                logits, ck, cv = _member_call(
-                    ens,
-                    lambda p, k, v: prefill(
-                        p, spec, tokens, lengths1, k, v, slot=slot,
-                        mesh=mesh, sp_impl=self.sp_impl, tp_mesh=tp_mesh),
-                    params, ck, cv,
-                )
+                logits, ck, cv = prefill(
+                    params, spec, tokens, lengths1, ck, cv, slot=slot,
+                    mesh=mesh, sp_impl=self.sp_impl, tp_mesh=tp_mesh)
             # First sampled token: no generated text yet → penalties are
             # zero; only the logit bias applies.
             with jax.named_scope("sample"):
@@ -2715,16 +2585,11 @@ class InferenceEngine:
         if fn is not None:
             return fn
         spec = self.spec
-        ens = self.ensemble
 
         def seg(params, tokens, offset, n_valid, slot, ck, cv):
-            return _member_call(
-                ens,
-                lambda p, k, v: prefill_segment(
-                    p, spec, tokens, offset, n_valid, k, v, slot,
-                    history=history),
-                params, ck, cv, mean=False,
-            )
+            return prefill_segment(
+                params, spec, tokens, offset, n_valid, ck, cv, slot,
+                history=history)
 
         fn = jax.jit(seg, donate_argnames=("ck", "cv"))
         self._admit_cache[("seg", bucket, history)] = fn
@@ -2797,7 +2662,7 @@ class InferenceEngine:
         length, generic over the cache pytree (bf16 arrays or int8
         (values, scales) pairs — the host store receives the native
         representation either way). Always unstacked: the prefix store
-        rejects members/ensemble engines at config time."""
+        rejects members engines at config time."""
         fn = self._admit_cache.get(("snap", n))
         if fn is None:
             fn = jax.jit(lambda ck, cv, slot, offset: kv_transfer.slice_rows(
@@ -3096,7 +2961,7 @@ class InferenceEngine:
         the same discipline the decode ring's payload chains rely on)."""
         fn = self._admit_cache.get(("hslice", n))
         if fn is None:
-            stacked = self.ensemble > 1 or self.members > 1
+            stacked = self.members > 1
             n_s = self.n_slots
 
             fn = jax.jit(lambda ck, cv, row, start: kv_transfer.slice_rows(
@@ -3111,7 +2976,7 @@ class InferenceEngine:
         on one thread) and donating the cache like every other writer."""
         fn = self._admit_cache.get(("hput", n))
         if fn is None:
-            stacked = self.ensemble > 1 or self.members > 1
+            stacked = self.members > 1
             n_s = self.n_slots
 
             def put(ck, cv, chunk, row, start):
@@ -3530,22 +3395,13 @@ class InferenceEngine:
         contract). Megachunk variants (``n_chunks`` > 1) live under their
         own "loop"-tagged keys, so a ``decode_loop=1`` engine can never
         compile one (the decode_loop=1 cache-key pin — same gating pattern
-        again).
-
-        Pipeline-staged engines (``decode_pp`` > 1) prefix every decode
-        key with ``"pp"`` — their programs embed the staged shard_map
-        schedule, so they can never share a cache entry (or a budget
-        family) with the unstaged variants; every pp==1 engine's keys stay
-        byte-for-byte the pre-pp tuples (the no-sharding-knob disagg
-        cache-key pin in tests/test_disagg.py)."""
+        again)."""
         if constrained:
             base = ("dfa", n_steps, want_lp, history, self._g_bucket)
         else:
             base = (n_steps, want_lp, history)
         if n_chunks > 1:
             base = ("loop", n_chunks) + base
-        if self.decode_pp > 1:
-            return ("pp",) + base
         if self.kv_pages:
             # Paged-layout programs gather K/V through the page table —
             # structurally different HLO, so they live under "paged"-tagged
@@ -3606,10 +3462,7 @@ class InferenceEngine:
         n_rows = self._rows
         n_s = self.n_slots
         vocab = spec.vocab_size
-        ens = self.ensemble
         mem = self.members
-        npp = self.decode_pp
-        mesh_pp = self.mesh
 
         def chunk_core(params, active, eos_s, ck, cv, token_s, lengths_s,
                        keys_s, temp_s, topp_s, topk_s, pp_s, fp_s, counts_s,
@@ -3635,12 +3488,9 @@ class InferenceEngine:
                         params, ck, cv, tok, pos, wm)
             else:
                 def model_call(ck, cv, tok, pos, wm):
-                    return _member_call(
-                        ens,
-                        lambda p, k, v: decode_step(
-                            p, spec, tok, pos, k, v, write_mask=wm,
-                            history=history, flash=flash),
-                        params, ck, cv)
+                    return decode_step(
+                        params, spec, tok, pos, ck, cv, write_mask=wm,
+                        history=history, flash=flash)
 
             def sample_fn(logits, live, carry):
                 if constrained:
@@ -3704,34 +3554,7 @@ class InferenceEngine:
 
             carry0 = ((keys_s, counts_s, dfa_s) if constrained
                       else (keys_s, counts_s))
-            if npp > 1:
-                # Pipeline-staged decode (decode_pp > 1): the same chunk/
-                # megachunk contracts scheduled as a row-group pipeline
-                # over the mesh's pp axis — stage s holds its L/pp layer
-                # shard + KV, rows flow stage→stage with one ppermute per
-                # tick, sampling (this very sample_fn, closed over as a
-                # replicated value) runs on the last stage
-                # (parallel/pipeline.py). members/ensemble/spec are
-                # rejected at config, so model_call is never needed here.
-                from quorum_tpu.parallel.pipeline import (
-                    staged_decode_chunk,
-                    staged_decode_loop,
-                )
-
-                if n_chunks > 1:
-                    (toks, n_valid, tok_end, live_end, budget_s, ck, cv,
-                     lengths_s, carry_out, aux) = staged_decode_loop(
-                        params, spec, mesh_pp, n_steps, n_chunks, token_s,
-                        lengths_s, live0, budget_s, eos_s, ck, cv,
-                        sample_fn, carry0, history=history, flash=flash)
-                else:
-                    (toks, _valid, n_valid, live_end, budget_s, ck, cv,
-                     lengths_s, carry_out, aux) = staged_decode_chunk(
-                        params, spec, mesh_pp, n_steps, token_s, lengths_s,
-                        live0, budget_s, eos_s, ck, cv, sample_fn, carry0,
-                        history=history, flash=flash)
-                    tok_end = toks[:, -1]
-            elif n_chunks > 1:
+            if n_chunks > 1:
                 # Megachunk: C chunk bodies fused in one program with an
                 # all-dead early exit; toks [C, B, n_steps], n_valid
                 # [C, B], aux leaves [C, n_steps, ...] — the reap drains
@@ -3858,7 +3681,6 @@ class InferenceEngine:
         spec = self.spec
         n_rows = self._rows  # flat rows (member-major on stacked engines)
         n_s = self.n_slots
-        ens = self.ensemble
         mem = self.members
         vocab = spec.vocab_size
         n_top = min(TOP_LOGPROBS, vocab)
@@ -3886,13 +3708,9 @@ class InferenceEngine:
                         history=history, clamp_writes=True),
                     params, ck, cv, tokens, pos, live)
             else:
-                logits, ck, cv = _member_call(
-                    ens,
-                    lambda p, k, v: decode_multi(
-                        p, spec, tokens, pos, k, v, write_mask=live,
-                        history=history, clamp_writes=True),
-                    params, ck, cv,
-                )  # [S, g+1, V]
+                logits, ck, cv = decode_multi(
+                    params, spec, tokens, pos, ck, cv, write_mask=live,
+                    history=history, clamp_writes=True)  # [S, g+1, V]
             lg_pos = jnp.moveaxis(logits, 1, 0).astype(jnp.float32)
             if constrained:
                 # Advance the DFA over the draft up front: states[j] masks
@@ -5972,16 +5790,11 @@ class InferenceEngine:
         (:meth:`_sweep_preemptions` — every ``_slots`` mutation that
         touches live device state stays on that thread's turn order).
 
-        Gated to ensemble == 1 engines: quorum rows co-batch one logical
-        request across weight sets, and parking a single member's row
-        would desynchronize the set. Stacked-member engines ARE eligible:
-        each member's requests live in their own row range
-        (``member * n_slots .. +n_slots``), so the victim search is
-        restricted to the head's member — replay bookkeeping is already
+        On a stacked-member engine each member's requests live in their
+        own row range (``member * n_slots .. +n_slots``), so the victim
+        search is restricted to the head's member — replay bookkeeping is
         per-request, so the park/resume cycle is member-local."""
         if not self.qos or head.cancel.is_set() or head.preempt_flag:
-            return
-        if self.ensemble != 1:
             return
         if any(b is head for _, _, b in self._preempt_pending):
             return  # one outstanding park order per beneficiary
@@ -6263,7 +6076,6 @@ class InferenceEngine:
             # No rows to clamp: discard any dangling clamp stamp so the
             # idle gap until the next admission never reads as stall.
             self._note_admission_clamp(False)
-            self._note_stage_occupancy([])  # drained stages read 0
             self._drain_inflight()
             return
         # Depth-K pipelined decode: top the ring up (speculative verify
@@ -6527,20 +6339,6 @@ class InferenceEngine:
             return None
         return out + [-1] * (len(d) - len(out))
 
-    def _note_stage_occupancy(self, active) -> None:
-        """Per-stage decode occupancy for pipeline-staged engines
-        (``quorum_tpu_decode_stage_occupancy{stage=}``): stage g's rows are
-        the contiguous row group [g·S/pp, (g+1)·S/pp) — its microbatch
-        slot in the staged ring (docs/scaling.md). Refreshed on every
-        dispatch and on the idle transition; a no-op at pp==1 (the family
-        keeps its bare 0 sample)."""
-        if self.decode_pp <= 1:
-            return
-        sg = self._rows // self.decode_pp
-        for g in range(self.decode_pp):
-            n = sum(1 for i, _ in active if g * sg <= i < (g + 1) * sg)
-            obs.DECODE_STAGE_OCCUPANCY.set(n, stage=str(g))
-
     def _fill_inflight(self) -> None:
         target = self._target_depth()
         while len(self._inflight) < target:
@@ -6611,7 +6409,6 @@ class InferenceEngine:
             if depth > 0:
                 self.n_overlapped += 1
             obs.PIPELINE_DEPTH.set(len(self._inflight))
-            self._note_stage_occupancy(active)
 
     def _try_spec_dispatch(self, active, g: int, ahead: int,
                            depth: int) -> str:
@@ -7330,7 +7127,6 @@ def get_engine(
     prefix_store: str | None = None,
     prefix_store_bytes: int = DEFAULT_PREFIX_STORE_BYTES,
     prefix_store_chunk: int = 0,
-    ensemble: int = 1,
     members: int = 1,
     kv_quant: str | None = None,
     draft_spec: ModelSpec | None = None,
@@ -7347,7 +7143,7 @@ def get_engine(
     quorum_dedup: bool = False,
 ) -> InferenceEngine:
     """Engines are keyed by weight identity (spec, seed, mesh, quant,
-    ensemble, members, draft model) plus the cache representation (kv_quant)
+    members, draft model) plus the cache representation (kv_quant)
     and the flash-decode gate (flash_decode — it selects which attention
     programs compile, and the PERF.md §5 A/B needs two backends in one
     process to genuinely run different kernels) — dispatch knobs like
@@ -7378,7 +7174,7 @@ def get_engine(
     # sp_impl is inert without an sp axis — normalize it out of the key so
     # equivalent configs share one engine (and one set of weights).
     sp_key = sp_impl if dict(mesh.shape).get(_SP, 1) > 1 else None
-    key = (spec, seed, quant or None, max(1, int(ensemble)),
+    key = (spec, seed, quant or None,
            max(1, int(members)), kv_quant or None,
            draft_spec, draft_seed, draft_ckpt, sp_key,
            resolve_flash_decode(flash_decode),
@@ -7424,7 +7220,6 @@ def get_engine(
                 prefix_cache=prefix_cache, prefix_store=prefix_store,
                 prefix_store_bytes=prefix_store_bytes,
                 prefix_store_chunk=prefix_store_chunk,
-                ensemble=ensemble,
                 members=members, kv_quant=kv_quant,
                 draft_spec=draft_spec, draft_seed=draft_seed,
                 draft_params=draft_params, sp_impl=sp_impl,
@@ -7459,7 +7254,6 @@ def get_engine_from_ckpt(
     prefix_store: str | None = None,
     prefix_store_bytes: int = DEFAULT_PREFIX_STORE_BYTES,
     prefix_store_chunk: int = 0,
-    ensemble: int = 1,
     kv_quant: str | None = None,
     draft_ckpt: str | None = None,
     sp_impl: str = "ring",
@@ -7474,18 +7268,11 @@ def get_engine_from_ckpt(
     draft checkpoint) so N backends pointing at one checkpoint with the
     same draft configuration share the loaded weights on device (a backend
     that adds spec_ckpt= constructs its own engine — and re-loads the
-    target).
-    ``ensemble`` > 1 is rejected (members are seeded random inits; a
-    checkpoint provides one weight set)."""
+    target)."""
     import os
 
     from quorum_tpu.models.hf_loader import load_hf_checkpoint
 
-    if ensemble > 1:
-        # Reject before touching the multi-GB checkpoint (and before the
-        # cache lookup — a warm single-model engine must not silently serve
-        # a URL that asked for an ensemble).
-        raise ValueError(_CKPT_ENSEMBLE_ERROR)
     mesh = mesh or single_device_mesh()
     resolved = os.path.realpath(ckpt_path)
     # Normalize: dtype=None and an explicit dtype equal to the default must
@@ -7524,7 +7311,6 @@ def get_engine_from_ckpt(
                 prefix_cache=prefix_cache, prefix_store=prefix_store,
                 prefix_store_bytes=prefix_store_bytes,
                 prefix_store_chunk=prefix_store_chunk,
-                ensemble=ensemble,
                 kv_quant=kv_quant,
                 draft_spec=draft_spec, draft_params=draft_params,
                 sp_impl=sp_impl, prefill_mesh=prefill_mesh,

@@ -34,7 +34,7 @@ def _score_fn(engine, b_bucket: int, t_bucket: int, top_k: int):
     if fn is not None:
         return fn
     spec = engine.spec
-    stacked = engine.members > 1 or engine.ensemble > 1
+    stacked = engine.members > 1
 
     def run(params, tokens, lengths, member):
         if stacked:

@@ -93,7 +93,7 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
 
 def init_params_from_key(spec: ModelSpec, key) -> Params:
     """Init from a PRNG key (traced-friendly: vmappable over stacked keys —
-    how ensemble members materialize directly into their [M, …] slices)."""
+    how stacked members materialize directly into their [M, …] slices)."""
     spec.validate()
     if spec.layer_pattern:
         return init_patterned_from_key(spec, key)
@@ -174,17 +174,17 @@ def init_params_sharded(spec: ModelSpec, mesh, seed: int = 0) -> Params:
 def init_params_ensemble_sharded(
     spec: ModelSpec, mesh, seeds: list[int], quant: str | None = None
 ) -> Params:
-    """Member-stacked parameters ``[M, …]`` for on-device logit-ensemble
-    decoding (engine ``ensemble=N``): each member is an independent seeded
+    """Member-stacked parameters ``[M, …]`` for a stacked engine
+    (``members=M``): each member is an independent seeded
     init, vmapped over stacked PRNG keys so every leaf materializes directly
     into its ``[M, …]`` slice — no per-member temporaries + stack copy
-    (which would transiently need ~2× the ensemble's weight HBM). The
+    (which would transiently need ~2× the stack's weight HBM). The
     member axis is replicated (vmapped, never communicated).
 
     ``quant="int8"`` fuses per-member quantization into the same program
     (scales reduce over the contraction axis, so the stacked tree's scales
     are exactly each member's own) — two int8 7B members fit one 16 GB
-    chip, a consensus ensemble a single device could never hold in bf16."""
+    chip."""
     from quorum_tpu.parallel.sharding import param_shardings
 
     keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])

@@ -28,7 +28,7 @@ spec over to the functions of the same name here:
     against the float32 reference, and one swapped pick moves a served
     log-probability by 0.03 and more (PERF.md section 6, PR 30).
 
-Members, ensembles, paging, int8 and sequence parallelism do not reach these
+Members, paging, int8 and sequence parallelism do not reach these
 functions: the engine refuses them for a patterned spec at start-up.
 """
 

@@ -613,8 +613,8 @@ def decode_step(
 def decode_token_embed(params: Params, spec: ModelSpec, token, lengths):
     """Embed one decode step's tokens: ``[B] → [B, 1, D]`` (scaled, plus the
     learned position embedding at each row's position when the spec uses
-    one). Shared by :func:`decode_step` and the pipeline-staged decode
-    path's stage 0 (parallel/pipeline.py)."""
+    one). Shared by :func:`decode_step` and the patterned decode step
+    (models/patterned.py)."""
     x = _emb_rows(params["tok_emb"], token, jnp.dtype(spec.dtype))[:, None, :]
     if spec.emb_scale != 1.0:  # gemma scales embeddings by sqrt(d_model)
         x = x * jnp.asarray(spec.emb_scale, x.dtype)
@@ -637,11 +637,8 @@ def decode_step_blocks(
     """The layer-scan core of :func:`decode_step` on pre-embedded hidden
     states: per-row K/V write at ``lengths``, history-bounded read,
     attention + MLP residual per layer — scanned over whatever layer slice
-    ``blocks``/``cache_[kv]`` carry. :func:`decode_step` runs it on the full
-    stack; the pipeline-staged decode path (parallel/pipeline.py) runs it
-    per stage on that stage's ``L/pp`` layer shard, which is what keeps the
-    two schedules' per-layer math identical. Returns
-    ``(x, cache_k, cache_v)`` with ``x`` still pre-final-norm.
+    ``blocks``/``cache_[kv]`` carry; :func:`decode_step` runs it on the full
+    stack. Returns ``(x, cache_k, cache_v)`` with ``x`` still pre-final-norm.
 
     A dense cache (bf16 array or int8 tuple) rides the scan's **carry**, the
     layer index counting within the slice given: each layer writes its

@@ -218,12 +218,6 @@ KV_PAGE_COW_COPIES = METRICS.counter(
     "prefix ended mid-page, so the partially-shared page was copied "
     "(one page) before the new tenant's suffix writes. Full pages "
     "alias by reference and never pay this.")
-DECODE_STAGE_OCCUPANCY = METRICS.gauge(
-    "quorum_tpu_decode_stage_occupancy",
-    "Active decode rows per pipeline-staged row group (pp>1 engines: "
-    "group g's rows are stage g's microbatch slot in the staged ring — "
-    "docs/scaling.md). Bare sample stays 0 on unstaged engines; "
-    "last-writer-wins across engines sharing the process.")
 PREFILL_GROUP_ACTIVE = METRICS.gauge(
     "quorum_tpu_prefill_group_active",
     "In-flight chunked admissions occupying the prefill device group "

@@ -102,47 +102,39 @@ def parse_disagg(raw: str) -> tuple[int, int]:
 
 
 def group_mesh_configs(n_prefill: int, n_decode: int, *,
-                       tp: int | None = None, sp: int = 1,
-                       pp: int = 1) -> tuple[MeshConfig, MeshConfig]:
+                       tp: int | None = None,
+                       sp: int = 1) -> tuple[MeshConfig, MeshConfig]:
     """Per-group mesh shapes for ``disagg=P+D`` with intra-group sharding.
 
     ``tp`` shards weights/KV within BOTH groups (``None`` = each group's
     whole device count, the pre-sharding default); ``sp`` scales the
     PREFILL group with sequence parallelism (100k+-token admission
-    contexts, staging KV sharded over sequence); ``pp`` stages the DECODE
-    group's layers into a pipeline (models bigger than one group's HBM —
-    parallel/pipeline.py's staged decode). Every invalid combination
-    fails here with the reason, at config time — never at first
-    dispatch."""
-    if sp < 1 or pp < 1 or (tp is not None and tp < 1):
+    contexts, staging KV sharded over sequence). Every invalid
+    combination fails here with the reason, at config time — never at
+    first dispatch."""
+    if sp < 1 or (tp is not None and tp < 1):
         raise ValueError(
-            f"invalid sharding knobs tp={tp} sp={sp} pp={pp} beside "
-            "disagg= (each must be >= 1)")
+            f"invalid sharding knobs tp={tp} sp={sp} beside disagg= "
+            "(each must be >= 1)")
     tp_p = tp if tp is not None else n_prefill // sp
-    tp_d = tp if tp is not None else n_decode // pp
+    tp_d = tp if tp is not None else n_decode
     if tp_p < 1 or sp * tp_p != n_prefill:
         raise ValueError(
             f"prefill group of disagg={n_prefill}+{n_decode} does not "
             f"factor as sp={sp} x tp={tp_p} ({sp * max(tp_p, 0)} != "
             f"{n_prefill} devices) — pick tp/sp whose product is the "
             "prefill group size, or resize the group")
-    if tp_d < 1 or pp * tp_d != n_decode:
+    if tp_d != n_decode:
         raise ValueError(
             f"decode group of disagg={n_prefill}+{n_decode} does not "
-            f"factor as pp={pp} x tp={tp_d} ({pp * max(tp_d, 0)} != "
-            f"{n_decode} devices) — pick tp/pp whose product is the "
-            "decode group size, or resize the group")
-    if pp > 1 and tp_d > 1:
-        raise ValueError(
-            f"pipeline-staged decode runs tp=1 within each stage "
-            f"(pp={pp} with tp={tp_d} in the decode group): make pp the "
-            "whole decode group, or drop one knob")
-    return MeshConfig(sp=sp, tp=tp_p), MeshConfig(pp=pp, tp=tp_d)
+            f"factor as tp={tp_d}: it is sharded by tp alone — pick tp "
+            "equal to the decode group size, or resize the group")
+    return MeshConfig(sp=sp, tp=tp_p), MeshConfig(tp=tp_d)
 
 
 def disagg_meshes(n_prefill: int, n_decode: int, devices=None, *,
-                  tp: int | None = None, sp: int = 1,
-                  pp: int = 1) -> tuple[Mesh, Mesh]:
+                  tp: int | None = None,
+                  sp: int = 1) -> tuple[Mesh, Mesh]:
     """Two DISJOINT device-group meshes for disaggregated serving
     (``tpu://…&disagg=P+D``): the first ``n_prefill`` devices become the
     prefill group's mesh, the next ``n_decode`` the decode group's.
@@ -153,7 +145,7 @@ def disagg_meshes(n_prefill: int, n_decode: int, devices=None, *,
     admission's KV prefix hands off device→device between them
     (quorum_tpu/cache/kv_transfer.py). With no sharding knobs tp is the
     only axis per group (the pre-sharding default — byte-for-byte the old
-    layout); ``tp=``/``sp=``/``pp=`` pick the intra-group factorization
+    layout); ``tp=``/``sp=`` pick the intra-group factorization
     (:func:`group_mesh_configs`). Either way the highest-traffic
     collectives stay nearest-neighbour inside each group, and the
     inter-group hop is the explicit KV handoff — never a GSPMD collective
@@ -166,8 +158,7 @@ def disagg_meshes(n_prefill: int, n_decode: int, devices=None, *,
         raise ValueError(
             f"disagg={n_prefill}+{n_decode} needs {need} devices, have "
             f"{len(devices)}")
-    pre_cfg, dec_cfg = group_mesh_configs(n_prefill, n_decode,
-                                          tp=tp, sp=sp, pp=pp)
+    pre_cfg, dec_cfg = group_mesh_configs(n_prefill, n_decode, tp=tp, sp=sp)
     prefill = make_mesh(pre_cfg, devices[:n_prefill])
     decode = make_mesh(dec_cfg, devices[n_prefill:n_prefill + n_decode])
     return prefill, decode

@@ -33,7 +33,6 @@ multi-chip functionality, driver-validated via ``dryrun_multichip``.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -177,349 +176,6 @@ def pipeline_forward_logits(
     x = out.reshape(b, t_len, -1)
     x = _final_norm(params, spec, x)
     return _unembed(params, spec, x)
-
-
-# ---- pipeline-staged DECODE (serving) --------------------------------------
-#
-# The inference twin of the training pipeline above, for models whose
-# weight+KV footprint exceeds one device group's HBM (ROADMAP item 4; MPMD
-# placement per PAPERS.md, the stage-pipelined decode shape Jupiter applies
-# at the edge). Stage s holds layers [s·L/pp, (s+1)·L/pp) AND those layers'
-# KV-cache shard (kv_cache_sharding shards the layer axis over pp); the
-# microbatch slots are DECODE ROWS: the engine's slot batch splits into pp
-# contiguous row groups, and at tick t stage s advances row group
-# (t−s) mod pp by one layer-stage — one ring ppermute per tick carries the
-# activation forward (stage s→s+1) and the freshly sampled token back
-# (last stage→0), so in steady state every stage is busy every tick and
-# each group emits one token per pp ticks. Everything — n_steps token
-# steps, sampling with the engine's full sampler closure (penalties,
-# logit bias, constrained-DFA masks, logprobs), on-device finish
-# accounting — runs inside ONE compiled program; under decode_loop=C the
-# tick scan nests inside the fused megachunk scan, so the staged schedule
-# keeps the decode_pipeline=K × decode_loop=C dispatch ring semantics of
-# the unstaged engine bit for bit (tests/test_pp_decode.py pins pp=2
-# token-for-token against a single-device engine).
-#
-# Per-layer math is decode_step's exactly: each stage runs
-# transformer.decode_step_blocks on its layer shard, embed/unembed run at
-# stage 0 / the last stage on replicated non-block params. Known
-# inefficiency, documented: every stage traces the unembed+sample block,
-# but a lax.cond on the stage index skips its execution off the last
-# stage. Also documented: the last stage runs sample_fn at FULL batch
-# width once per tick (the group's logits scattered into a zero [B,vocab]
-# lane) — pp× the unstaged path's sampler FLOPs, with all but the tick's
-# sg rows merged away. The full-width call is what keeps the engine's
-# row-indexed sampler closures (RNG key rows, DFA state rows, bias rows)
-# bit-identical to decode_chunk's without re-deriving a group-local
-# indexing contract; slot batches are small next to the layer stack, so
-# the win of a sliced sampler has not yet justified that second contract.
-
-
-def _row_groups(mesh: Mesh, batch: int) -> int:
-    npp = mesh.shape[AXIS_PP]
-    if batch % npp:
-        raise ValueError(
-            f"staged decode needs the slot batch ({batch}) divisible by "
-            f"pp={npp} (the row groups are the pipeline's microbatches)")
-    return npp
-
-
-def staged_decode_chunk(
-    params,
-    spec: ModelSpec,
-    mesh: Mesh,
-    n_steps: int,
-    token,    # [B] current token ids
-    lengths,  # [B]
-    live,     # [B] bool
-    budget,   # [B] int32
-    eos,      # [B] int32
-    cache_k,  # [L, B, K, max_seq, hd] — layer axis sharded over pp
-    cache_v,
-    sample_fn,
-    sample_carry,
-    history: int | None = None,
-    flash: str | None = None,
-):
-    """One pipeline-staged decode chunk; same contract as
-    :func:`quorum_tpu.models.transformer.decode_chunk` (tokens, per-row
-    ``n_valid``, on-device finish accounting, ``sample_fn`` carry/aux
-    threading), scheduled as a row-group pipeline over the mesh's ``pp``
-    axis. ``sample_fn`` may close over replicated engine state (sampler
-    knobs, bias rows, grammar tables) — closures enter shard_map as
-    replicated values."""
-    from quorum_tpu.models.transformer import (
-        decode_step_blocks,
-        decode_token_embed,
-        _final_norm,
-        _unembed,
-    )
-
-    npp = _row_groups(mesh, token.shape[0])
-    b = token.shape[0]
-    sg = b // npp
-    n_ticks = npp * n_steps + npp - 1
-    ring = [(i, (i + 1) % npp) for i in range(npp)]
-    blocks = params["blocks"]
-    other = {k: v for k, v in params.items() if k != "blocks"}
-
-    # Aux output shapes (logprob triples, masked-token counts, …) come from
-    # one abstract evaluation of the engine's sampler — trace-free, exactly
-    # the decode_loop skip-branch pattern.
-    aux_shapes = jax.eval_shape(
-        lambda lg, lv, c: sample_fn(lg, lv, c)[2],
-        jax.ShapeDtypeStruct((b, spec.vocab_size), jnp.float32),
-        jax.ShapeDtypeStruct((b,), jnp.bool_),
-        sample_carry,
-    )
-
-    def state0():
-        # The ONE source of truth for the scan's state pytree: local()'s
-        # st0 initialization and the shard_map out_specs (via eval_shape)
-        # both build from here, so they can never drift apart.
-        return dict(
-            live=live, budget=budget, lens=lengths, carry=sample_carry,
-            toks=jnp.zeros((n_steps, b), jnp.int32),
-            valid=jnp.zeros((n_steps, b), bool),
-            aux=tuple(jnp.zeros((n_steps,) + tuple(sh.shape), sh.dtype)
-                      for sh in jax.tree.leaves(aux_shapes)),
-        )
-
-    def local(blocks_local, ck_l, cv_l):
-        s = lax.axis_index(AXIS_PP)
-        is_first_stage = s == 0
-        is_last = s == npp - 1
-
-        def embed_group(tok_g, lens_g):
-            # ``other`` = the replicated non-block params (embed/unembed/
-            # final-norm live outside the staged region, like the training
-            # pipeline's).
-            return decode_token_embed(other, spec, tok_g, lens_g)
-
-        def slice_rows(arr, rows0, width=None):
-            w = sg if width is None else width
-            starts = (rows0,) + (0,) * (arr.ndim - 1)
-            sizes = (w,) + arr.shape[1:]
-            return lax.dynamic_slice(arr, starts, sizes)
-
-        def scat_rows(arr, val, rows0, gate):
-            starts = (rows0,) + (0,) * (arr.ndim - 1)
-            old = lax.dynamic_slice(arr, starts, val.shape)
-            return lax.dynamic_update_slice(
-                arr, jnp.where(gate, val, old), starts)
-
-        def tick(carry, t):
-            bundle, st, ck_l, cv_l = carry
-            rel = t - s
-            valid = (rel >= 0) & (rel < npp * n_steps)
-            g = rel % npp          # row group this stage advances this tick
-            k = rel // npp         # that group's token index in the chunk
-            rows0 = g * sg
-
-            # Stage 0 input: the group's chunk-entry state for its first
-            # token, else the token+state the LAST stage sampled last tick
-            # (the ring half of the ppermute). Later stages consume their
-            # predecessor's activation with the row state forwarded along.
-            first = rel < npp
-            init_tok = slice_rows(token, rows0)
-            init_live = slice_rows(live, rows0)
-            init_lens = slice_rows(lengths, rows0)
-            in_tok = jnp.where(first, init_tok, bundle["tok"])
-            in_live = jnp.where(first, init_live, bundle["live"])
-            in_lens = jnp.where(first, init_lens, bundle["lens"])
-            cur_tok = jnp.where(is_first_stage, in_tok, bundle["tok"])
-            cur_live = jnp.where(is_first_stage, in_live, bundle["live"])
-            cur_lens = jnp.where(is_first_stage, in_lens, bundle["lens"])
-            # Dead rows run the static batch lane at position 0, exactly as
-            # decode_chunk's `pos = where(lv, lens, 0)` does — keeps the
-            # two schedules' forwards (and their aux records) bit-equal.
-            pos_rows = jnp.where(cur_live, cur_lens, 0)
-            x0 = embed_group(in_tok, pos_rows)
-            x_in = jnp.where(is_first_stage, x0, bundle["x"])
-
-            # This stage's layers on its cache slab for the group's rows;
-            # fill/drain ticks run the same static-shape program with
-            # writes masked off (the training pipeline's idle-tick rule).
-            allow = cur_live & valid
-            ck_rows = jax.tree.map(
-                lambda a: lax.dynamic_slice_in_dim(a, rows0, sg, axis=1),
-                ck_l)
-            cv_rows = jax.tree.map(
-                lambda a: lax.dynamic_slice_in_dim(a, rows0, sg, axis=1),
-                cv_l)
-            y, ck_rows, cv_rows = decode_step_blocks(
-                blocks_local, spec, x_in, pos_rows, ck_rows, cv_rows,
-                write_mask=allow, history=history, flash=flash)
-            ck_l = jax.tree.map(
-                lambda a, r: lax.dynamic_update_slice_in_dim(
-                    a, r, rows0, axis=1), ck_l, ck_rows)
-            cv_l = jax.tree.map(
-                lambda a, r: lax.dynamic_update_slice_in_dim(
-                    a, r, rows0, axis=1), cv_l, cv_rows)
-
-            kc = jnp.clip(k, 0, n_steps - 1)
-
-            def do_sample(op):
-                st, y = op
-                h = _final_norm(other, spec, y)
-                logits_g = _unembed(other, spec, h[:, 0, :]).astype(
-                    jnp.float32)
-                logits_full = lax.dynamic_update_slice(
-                    jnp.zeros((b, spec.vocab_size), jnp.float32),
-                    logits_g, (rows0, jnp.int32(0)))
-                lv_full = scat_rows(jnp.zeros((b,), bool), allow, rows0,
-                                    True)
-                nxt_full, new_carry, aux = sample_fn(
-                    logits_full, lv_full, st["carry"])
-                # Merge ONLY this tick's group rows into the sampler carry
-                # (keys/counts/DFA are all row-indexed): every row's RNG
-                # chain splits exactly once per token, exactly as the
-                # unstaged chunk's batched split does.
-                rows_m = ((jnp.arange(b) >= rows0)
-                          & (jnp.arange(b) < rows0 + sg) & valid)
-
-                def merge(new, old):
-                    m = rows_m.reshape((b,) + (1,) * (new.ndim - 1))
-                    return jnp.where(m, new, old)
-
-                carry2 = jax.tree.map(merge, new_carry, st["carry"])
-                # decode_chunk's finish accounting, verbatim on the group.
-                nxt_g = slice_rows(nxt_full, rows0)
-                nxt_g = jnp.where(cur_live, nxt_g, cur_tok)
-                eos_g = slice_rows(eos, rows0)
-                bud_g = slice_rows(st["budget"], rows0)
-                lens_new = cur_lens + cur_live.astype(cur_lens.dtype)
-                bud_new = bud_g - cur_live.astype(bud_g.dtype)
-                fin = cur_live & ((nxt_g == eos_g) | (bud_new <= 0))
-                live_new = cur_live & ~fin
-                st2 = dict(st)
-                st2["carry"] = carry2
-                st2["live"] = scat_rows(st["live"], live_new, rows0, valid)
-                st2["budget"] = scat_rows(st["budget"], bud_new, rows0,
-                                          valid)
-                st2["lens"] = scat_rows(st["lens"], lens_new, rows0, valid)
-                old_t = lax.dynamic_slice(st["toks"], (kc, rows0), (1, sg))
-                st2["toks"] = lax.dynamic_update_slice(
-                    st["toks"], jnp.where(valid, nxt_g[None], old_t),
-                    (kc, rows0))
-                old_v = lax.dynamic_slice(st["valid"], (kc, rows0), (1, sg))
-                st2["valid"] = lax.dynamic_update_slice(
-                    st["valid"], jnp.where(valid, cur_live[None], old_v),
-                    (kc, rows0))
-                bufs = []
-                for buf, leaf in zip(st["aux"], jax.tree.leaves(aux)):
-                    if leaf.ndim and leaf.shape[0] == b:
-                        starts = (kc,) + (0,) * leaf.ndim
-                        oldb = lax.dynamic_slice(buf, starts,
-                                                 (1,) + leaf.shape)
-                        m = rows_m.reshape((b,) + (1,) * (leaf.ndim - 1))
-                        bufs.append(lax.dynamic_update_slice(
-                            buf, jnp.where(m, leaf, oldb[0])[None], starts))
-                    else:  # per-step scalar (masked-entry counts): sum the
-                        bufs.append(  # group ticks of token k together
-                            buf.at[kc].add(jnp.where(valid, leaf, 0)))
-                st2["aux"] = tuple(bufs)
-                return st2, nxt_g, live_new, lens_new
-
-            def skip_sample(op):
-                st, _y = op
-                return st, cur_tok, cur_live, cur_lens
-
-            st, out_tok, out_live, out_lens = lax.cond(
-                is_last, do_sample, skip_sample, (st, y))
-            out_bundle = {"x": y, "tok": out_tok, "live": out_live,
-                          "lens": out_lens}
-            out_bundle = jax.tree.map(
-                lambda v: lax.ppermute(v, AXIS_PP, ring), out_bundle)
-            return (out_bundle, st, ck_l, cv_l), None
-
-        st0 = state0()
-        bundle0 = dict(
-            x=jnp.zeros((sg, 1, spec.d_model), jnp.dtype(spec.dtype)),
-            tok=jnp.zeros((sg,), jnp.int32),
-            live=jnp.zeros((sg,), bool),
-            lens=jnp.zeros((sg,), jnp.int32),
-        )
-        carry0 = (*lax.pcast((bundle0, st0), (AXIS_PP,), to="varying"),
-                  ck_l, cv_l)
-        (_, st, ck_l, cv_l), _ = lax.scan(
-            tick, carry0, jnp.arange(n_ticks))
-
-        # Only the LAST stage's full-width state/output copies are
-        # authoritative (it owns sampling); psum-select replicates them
-        # back to every stage — the training pipeline's outbuf pattern.
-        def from_last(v):
-            if v.dtype == jnp.bool_:
-                z = lax.psum(jnp.where(is_last, v.astype(jnp.int32), 0),
-                             AXIS_PP)
-                return z.astype(jnp.bool_)
-            return lax.psum(jnp.where(is_last, v, jnp.zeros_like(v)),
-                            AXIS_PP)
-
-        out = jax.tree.map(from_last, st)
-        return ck_l, cv_l, out
-
-    staged = jax.tree.map(lambda _: P(AXIS_PP), blocks)
-    cache_specs_k = jax.tree.map(lambda _: P(AXIS_PP), cache_k)
-    cache_specs_v = jax.tree.map(lambda _: P(AXIS_PP), cache_v)
-    rep_out = jax.tree.map(lambda _: P(), jax.eval_shape(state0))
-    fn = shard_map(
-        local, mesh=mesh,
-        in_specs=(staged, cache_specs_k, cache_specs_v),
-        out_specs=(cache_specs_k, cache_specs_v, rep_out),
-        check_vma=False,
-    )
-    cache_k, cache_v, out = fn(blocks, cache_k, cache_v)
-    toks = out["toks"].T                       # [B, n_steps]
-    valid_t = out["valid"].T
-    n_valid = jnp.sum(valid_t.astype(jnp.int32), axis=1)
-    return (toks, valid_t, n_valid, out["live"], out["budget"],
-            cache_k, cache_v, out["lens"], out["carry"],
-            tuple(out["aux"]))
-
-
-def staged_decode_loop(
-    params,
-    spec: ModelSpec,
-    mesh: Mesh,
-    n_steps: int,
-    n_chunks: int,
-    token, lengths, live, budget, eos,
-    cache_k, cache_v,
-    sample_fn, sample_carry,
-    history: int | None = None,
-    flash: str | None = None,
-):
-    """Megachunk wrapper for the staged chunk — decode_loop's contract
-    (leading per-chunk axis on tokens/n_valid/aux, all-rows-finished early
-    exit, carry passthrough on skipped chunks) with the ppermute tick scan
-    nested inside the fused C-chunk scan: one dispatch, C×n_steps tokens,
-    the stage ring full the whole way."""
-    def run_chunk(op):
-        tok, lens, lv, bud, ck, cv, s_carry = op
-        (toks, _valid, n_valid, lv, bud, ck, cv, lens, s_carry, aux) = \
-            staged_decode_chunk(params, spec, mesh, n_steps, tok, lens, lv,
-                                bud, eos, ck, cv, sample_fn, s_carry,
-                                history=history, flash=flash)
-        return (toks[:, -1], lens, lv, bud, ck, cv, s_carry), \
-            (toks, n_valid, aux)
-
-    carry0 = (token, lengths, live, budget, cache_k, cache_v, sample_carry)
-    out_shapes = jax.eval_shape(lambda op: run_chunk(op)[1], carry0)
-
-    def skip_chunk(op):
-        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                             out_shapes)
-        return op, zeros
-
-    def body(carry, _):
-        return lax.cond(jnp.any(carry[2]), run_chunk, skip_chunk, carry)
-
-    carry, (toks, n_valid, aux) = lax.scan(body, carry0, None,
-                                           length=n_chunks)
-    token, lengths, live, budget, cache_k, cache_v, sample_carry = carry
-    return (toks, n_valid, token, live, budget, cache_k, cache_v, lengths,
-            sample_carry, aux)
 
 
 def pp_loss_fn(params, spec: ModelSpec, tokens, mesh, n_micro: int,

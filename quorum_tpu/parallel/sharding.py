@@ -32,8 +32,8 @@ LOGICAL_RULES: dict[str, str | None] = {
     "experts": AXIS_TP,    # expert parallelism shares the tp axis
     "vocab": AXIS_TP,
     # Scanned-layer leading dim: stage-sharded over pp (a no-op placement on
-    # every mesh whose pp axis is 1 — i.e. everything except the
-    # pipeline-staged decode group and the pp training mesh).
+    # every mesh whose pp axis is 1 — i.e. everything except the pp
+    # training mesh; the engine refuses a decode mesh with pp > 1).
     "layers": AXIS_PP,
     "pos": None,
 }
@@ -92,9 +92,8 @@ def kv_cache_sharding(mesh: Mesh, n_kv_heads: int, batch: int | None = None,
     count doesn't divide the tp axis (e.g. 2 KV heads on tp=4), the head axis
     is replicated — attention q·K still runs tp-sharded over query heads.
 
-    The leading layer axis shards over ``pp`` (a no-op except on the
-    pipeline-staged decode mesh, where each stage holds its own layers' KV —
-    the engine rejects ``pp`` that doesn't divide ``n_layers``).
+    The leading layer axis carries the ``pp`` rule of the weights' layer
+    axis, a no-op on every serving mesh (the engine refuses ``pp`` > 1).
 
     ``seq_shard=True`` additionally shards the position axis over ``sp`` —
     the disagg PREFILL group's staging cache under ``sp>1``: a 100k-token
@@ -137,7 +136,7 @@ def param_partition_specs(
     """PartitionSpec pytree matching a parameter pytree (same nesting).
 
     ``lead_axes`` prepends that many replicated dims to every leaf's spec —
-    used for member-stacked ensemble params ``[M, …]`` (the member axis is
+    used for member-stacked params ``[M, …]`` (the member axis is
     vmapped, never sharded).
 
     ``replicate_kv_heads`` replicates every leaf whose logical axes include

@@ -372,16 +372,13 @@ def interference(tokens: int = 64, chunk: int = 4, depth: int = 4,
 
 def sharded(tokens: int = 48, chunk: int = 4, depth: int = 2,
             loop: int = 2, repeats: int = 2) -> dict:
-    """Per-group sharding under disagg (ISSUE 14): three arms at the SAME
-    device count (4) — colocated ``tp=4``, ``disagg=2+2&tp=2`` (both
+    """Per-group sharding under disagg (ISSUE 14): two arms at the SAME
+    device count (4) — colocated ``tp=4`` and ``disagg=2+2&tp=2`` (both
     groups tp-sharded, the handoff resharding between the two layouts on
-    the fly), and ``disagg=2+2&pp=2`` (the decode group pipeline-staged:
-    stage s holds L/pp layers + their KV shard, rows flow stage→stage
-    inside the fused megachunk scan). Reports per arm: decode tok/s,
-    handoff bytes/s across the group boundary, dispatch counts, and the
-    per-family device-seconds attribution (the staged arm's decode time
-    lives under the ``pp_*`` families) — tokens asserted identical across
-    all arms (sharding moves bytes, never samples)."""
+    the fly). Reports per arm: decode tok/s, handoff bytes/s across the
+    group boundary, dispatch counts, and the per-family device-seconds
+    attribution — tokens asserted identical across the arms (sharding
+    moves bytes, never samples)."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax
@@ -402,15 +399,12 @@ def sharded(tokens: int = 48, chunk: int = 4, depth: int = 2,
               n_slots=2, prefill_chunk=16)
     out: dict = {"sharded_tokens": tokens, "sharded_devices": 4}
     streams: dict[str, list[int]] = {}
-    for tag in ("colocated_tp4", "disagg_tp2", "disagg_pp2"):
+    for tag in ("colocated_tp4", "disagg_tp2"):
         if tag == "colocated_tp4":
             eng = InferenceEngine(
                 spec, make_mesh(MeshConfig(tp=4), jax.devices()[:4]), **kw)
-        elif tag == "disagg_tp2":
-            pm, dm = disagg_meshes(2, 2, tp=2)
-            eng = InferenceEngine(spec, dm, prefill_mesh=pm, **kw)
         else:
-            pm, dm = disagg_meshes(2, 2, pp=2)
+            pm, dm = disagg_meshes(2, 2, tp=2)
             eng = InferenceEngine(spec, dm, prefill_mesh=pm, **kw)
         eng.generate(prompt, max_new_tokens=tokens, sampler=greedy)  # warm
         c0, b0 = eng.n_decode_chunks, eng.kv_handoff_bytes
@@ -429,15 +423,12 @@ def sharded(tokens: int = 48, chunk: int = 4, depth: int = 2,
         out[f"{pre}_handoff_bytes_per_s"] = round(
             handoff_b / max(1e-9, wall * repeats), 1)
         out[f"{pre}_handoff_bytes"] = handoff_b
-        # Per-family device-seconds: the staged arm's decode time lives
-        # under pp_loop/pp_plain; the handoff halves under hslice/hput.
+        # Per-family device-seconds: the handoff halves live under
+        # hslice/hput.
         out[f"{pre}_device_seconds"] = eng.latency.snapshot()
-        if tag == "disagg_pp2":
-            out[f"{pre}_decode_pp"] = eng.decode_pp
         eng.shutdown()
     out["sharded_tokens_match"] = (
-        streams["colocated_tp4"] == streams["disagg_tp2"]
-        == streams["disagg_pp2"])
+        streams["colocated_tp4"] == streams["disagg_tp2"])
     return out
 
 
@@ -714,8 +705,8 @@ def main() -> int:
     ap.add_argument("--skip-interference", action="store_true",
                     help="skip the colocated-vs-disagg interference legs")
     ap.add_argument("--skip-sharded", action="store_true",
-                    help="skip the per-group-sharding legs (disagg+tp / "
-                         "staged-pp vs colocated tp at matched devices)")
+                    help="skip the per-group-sharding legs (disagg+tp "
+                         "vs colocated tp at matched devices)")
     ap.add_argument("--only-interference", action="store_true",
                     help="run ONLY the interference legs (bench.py's "
                          "subprocess phase — the depth/megachunk sweep "
@@ -944,13 +935,12 @@ def _print_qos(mq: dict) -> None:
 
 def _print_sharded(msh: dict) -> None:
     print("per-group sharding under disagg (4 devices, matched count):")
-    for tag in ("colocated_tp4", "disagg_tp2", "disagg_pp2"):
+    for tag in ("colocated_tp4", "disagg_tp2"):
         pre = f"sharded_{tag}"
         fams = msh.get(f"{pre}_device_seconds", {})
         decode = ", ".join(
             f"{f} p50 {s['p50_ms']}ms (n={s['count']})"
-            for f, s in sorted(fams.items())
-            if f in ("plain", "loop", "pp_plain", "pp_loop"))
+            for f, s in sorted(fams.items()) if f in ("plain", "loop"))
         print(f"  {tag:13}: {msh[f'{pre}_tok_s']} tok/s, "
               f"{msh[f'{pre}_dispatches_per_request']:.1f} dispatches/req, "
               f"{msh[f'{pre}_handoff_bytes_per_s']} handoff B/s "

@@ -15,7 +15,6 @@ from quorum_tpu.engine.engine import InferenceEngine
 from quorum_tpu.models.model_config import MODEL_PRESETS, resolve_spec
 from quorum_tpu.ops.sampling import SamplerConfig
 
-pytestmark = pytest.mark.slow
 
 TINY = MODEL_PRESETS["llama-tiny"]
 GREEDY = SamplerConfig(temperature=0.0)
@@ -36,6 +35,7 @@ def test_greedy_token_for_token():
     assert e4.n_overrun == 0  # budget finish is detected on device
 
 
+@pytest.mark.slow
 def test_sampled_token_for_token():
     e1, e4 = _pair()
     s = SamplerConfig(temperature=0.9, top_p=0.95)
@@ -60,6 +60,7 @@ def test_eos_mid_chunk_token_for_token():
     assert e4.n_overrun == 0
 
 
+@pytest.mark.slow
 def test_stop_sequence_parity_via_backend():
     """Host-side stop-string hits cancel the row by masking it out of
     not-yet-dispatched chunks; the delivered text must match K=1 exactly
@@ -96,6 +97,7 @@ def test_stop_sequence_parity_via_backend():
     assert c4["finish_reason"] == c1["finish_reason"]
 
 
+@pytest.mark.slow
 def test_cancel_does_not_corrupt_later_requests():
     """Abandoning a stream mid-generation (cancel at a chunk boundary with
     chunks in flight) must leave the engine producing exactly the K=1
@@ -129,6 +131,7 @@ def test_admission_pressure_drains_and_matches():
     assert run_all(e4) == run_all(e1)
 
 
+@pytest.mark.slow
 def test_spec_verify_turns_drain_the_ring():
     """Speculative verification (host-synchronous turns) interleaved with
     pipelined chunks: output parity holds, and the repetitive prompt still
@@ -143,6 +146,7 @@ def test_spec_verify_turns_drain_the_ring():
     assert a.token_ids == b.token_ids
 
 
+@pytest.mark.slow
 def test_dispatch_accounting_counters():
     """The acceptance counters: a >=8-chunk generation at K=4 must block
     the host on strictly fewer dispatches than K=1 (n_decode_chunks -
@@ -177,6 +181,7 @@ def _loop_pair(k: int, **kw):
                             decode_loop=4, **kw))
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("k", [1, 4])
 def test_loop_greedy_and_sampled_token_for_token(k):
     e1, e4 = _loop_pair(k)
@@ -191,6 +196,7 @@ def test_loop_greedy_and_sampled_token_for_token(k):
     assert e4.n_overrun == 0  # budget finishes stay on device under fusion
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("k", [1, 4])
 def test_loop_eos_mid_chunk_token_for_token(k):
     """EOS landing mid-chunk inside a megachunk: the on-device early exit
@@ -206,6 +212,7 @@ def test_loop_eos_mid_chunk_token_for_token(k):
     assert e4.n_overrun == 0
 
 
+@pytest.mark.slow
 def test_loop_stop_sequence_parity_via_backend():
     """Host-side stop-string finishes under megachunks: the delivered text
     must match decode_loop=1 exactly; the already-dispatched fused tail is
@@ -240,6 +247,7 @@ def test_loop_stop_sequence_parity_via_backend():
     assert c4["finish_reason"] == c1["finish_reason"]
 
 
+@pytest.mark.slow
 def test_loop_cancel_does_not_corrupt_later_requests():
     """Abandoning a stream mid-megachunk: the wasted fused tail is
     bounded (counted as overrun), and the engine must produce exactly the
@@ -256,6 +264,7 @@ def test_loop_cancel_does_not_corrupt_later_requests():
     assert after4.token_ids == after1.token_ids
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("k", [1, 4])
 def test_loop_constrained_token_for_token(k):
     """A schema-constrained stream under megachunks: the DFA state rides
@@ -287,6 +296,7 @@ def test_loop_constrained_token_for_token(k):
     assert e4.n_overrun == 0
 
 
+@pytest.mark.slow
 def test_loop_members_token_for_token():
     """Stacked members under megachunks: every member's stream equals its
     decode_loop=1 self (the fused loop advances all members per chunk
@@ -303,6 +313,7 @@ def test_loop_members_token_for_token():
         assert a.token_ids == b.token_ids, f"member {m} diverged"
 
 
+@pytest.mark.slow
 def test_loop_dispatch_counter_acceptance():
     """The ISSUE acceptance: dispatches per 64-token request drop ~C× at
     decode_loop=C (64 tokens / chunk 4 = 16 chunks → ≤ 5 dispatches at

@@ -26,6 +26,7 @@ from quorum_tpu.ops.sampling import SamplerConfig
 from quorum_tpu.parallel.mesh import (
     MeshConfig,
     disagg_meshes,
+    group_mesh_configs,
     make_mesh,
     parse_disagg,
 )
@@ -62,6 +63,78 @@ def test_disagg_mesh_and_engine_validation():
     # disagg rides chunked prefill; an engine without it must reject
     with pytest.raises(ValueError, match="chunked prefill"):
         InferenceEngine(TINY, dm, prefill_mesh=pm, prefill_chunk=0)
+
+
+def test_group_mesh_config_rejections():
+    """Every invalid disagg-side factorization fails in
+    group_mesh_configs with the arithmetic, at config time."""
+    for kw, frag in [
+        (dict(tp=3), "does not factor"),        # non-divisible tp vs group
+        (dict(sp=3), "does not factor"),        # sp must divide prefill
+        (dict(tp=0), ">= 1"),
+        (dict(sp=0), ">= 1"),
+    ]:
+        with pytest.raises(ValueError, match=frag):
+            group_mesh_configs(4, 4, **kw)
+    # the decode group is sharded by tp alone (prefill factors: 4 = 2x2)
+    with pytest.raises(ValueError, match="decode group .* does not factor"):
+        group_mesh_configs(4, 4, sp=2, tp=2)
+    # the factoring identities that must pass
+    pre, dec = group_mesh_configs(4, 4)
+    assert (pre.tp, dec.tp) == (4, 4)  # no knobs = whole-group tp
+    pre, dec = group_mesh_configs(4, 4, tp=4)
+    assert (pre.sp, pre.tp, dec.pp, dec.tp) == (1, 4, 1, 4)
+    pre, dec = group_mesh_configs(4, 2, sp=2)
+    assert (pre.sp, pre.tp, dec.pp, dec.tp) == (2, 2, 1, 2)
+
+
+def test_engine_disagg_sharding_rejections():
+    """disagg-side engine rejections: sp in the DECODE group, and a
+    prefill-group sp that does not divide max_seq."""
+    import jax
+
+    sp_decode = make_mesh(MeshConfig(sp=2), jax.devices()[1:3])
+    with pytest.raises(ValueError, match="PREFILL group"):
+        InferenceEngine(TINY, sp_decode,
+                        prefill_mesh=make_mesh(MeshConfig(tp=1),
+                                               jax.devices()[:1]),
+                        prefill_chunk=16)
+    # sp=3 cannot shard a 128-position staging cache evenly
+    pm2, dm2 = disagg_meshes(3, 1, sp=3)
+    with pytest.raises(ValueError, match="does not divide max_seq"):
+        InferenceEngine(TINY, dm2, prefill_mesh=pm2, prefill_chunk=16)
+
+
+@pytest.mark.parametrize("how", ["url-ensemble", "url-pp", "mesh-pp"])
+def test_removed_decode_forms_raise(how):
+    """``ensemble=M`` and ``pp=K`` left the URL grammar in PR 32: a URL that
+    still sets one fails at config time naming the removal (never a quiet
+    single unsharded model), and a decode mesh with a pp axis over 1 fails
+    at engine construction naming tp."""
+    from quorum_tpu.backends.tpu_backend import TpuBackend
+    from quorum_tpu.config import BackendSpec
+
+    if how == "mesh-pp":
+        import jax
+
+        with pytest.raises(ValueError, match="removed in PR 32.*tp="):
+            InferenceEngine(TINY, make_mesh(MeshConfig(pp=2),
+                                            jax.devices()[:2]))
+        return
+    opt, instead = {"url-ensemble": ("ensemble", "members=M"),
+                    "url-pp": ("pp", "tp=")}[how]
+    with pytest.raises(ValueError,
+                       match=f"{opt}=2.*removed in PR 32.*{instead}"):
+        TpuBackend.from_spec(BackendSpec(
+            name="t", url=f"tpu://llama-tiny?{opt}=2", model="m"))
+    # the default value is what every engine is: accepted
+    TpuBackend.from_spec(BackendSpec(
+        name="t", url=f"tpu://llama-tiny?{opt}=1&slots=2", model="m"))
+
+
+def test_pp_tagged_decode_key_is_unknown():
+    with pytest.raises(budget.UnbudgetedProgramKey, match="no compile_budget"):
+        budget.classify_decode_key(("pp", 4, False, 128))
 
 
 def test_disagg_url_knob_validation():
@@ -180,11 +253,10 @@ def test_kv_handoff_fault_dooms_only_its_request(smoke_engines):
 def test_disagg_no_knob_cache_keys_unchanged(smoke_engines):
     """The no-sharding-knob disagg path keeps its exact pre-existing
     program cache keys, byte for byte (ISSUE 14 acceptance): plain
-    3-tuple decode keys — never a "pp"-tagged staged variant — and only
-    the pre-existing admit-cache tags."""
+    3-tuple decode keys and only the pre-existing admit-cache tags."""
     eng_c, eng_d = smoke_engines
     _gen(eng_d, [3, 4, 5], seed=1)
-    assert eng_d.decode_pp == 1 and eng_d.prefill_sp == 1
+    assert eng_d.prefill_sp == 1
     for k in eng_d._decode_cache:
         assert isinstance(k, tuple) and len(k) == 3, k
         assert (isinstance(k[0], int) and isinstance(k[1], bool)

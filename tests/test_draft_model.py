@@ -10,7 +10,7 @@ content NEVER depends on the draft model. These tests pin:
     oracle) and for a useless one (different seed);
   - the oracle actually accelerates: near-full acceptance, strictly fewer
     verify turns than tokens emitted;
-  - composition guards (members/ensemble, vocab/window mismatches) fail at
+  - composition guards (members, vocab/window mismatches) fail at
     construction, not per-request.
 """
 

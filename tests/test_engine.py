@@ -4,7 +4,6 @@ Runs tiny models on the CPU backend — same compiled code paths as TPU
 (SURVEY.md §4's TPU-free test strategy)."""
 
 import jax.numpy as jnp
-import pytest
 
 from quorum_tpu.engine.engine import InferenceEngine, get_engine, prefill_bucket
 from quorum_tpu.engine.tokenizer import ByteTokenizer, render_chat
@@ -12,11 +11,6 @@ from quorum_tpu.models.model_config import MODEL_PRESETS, resolve_spec
 from quorum_tpu.models.transformer import forward_logits, init_cache, prefill
 from quorum_tpu.models.init import init_params
 from quorum_tpu.ops.sampling import SamplerConfig
-
-# Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
-# make test-all and CI run everything — VERDICT r3 item 6).
-pytestmark = pytest.mark.slow
-
 
 TINY = MODEL_PRESETS["llama-tiny"]
 

@@ -21,9 +21,6 @@ from quorum_tpu.models.model_config import MODEL_PRESETS
 from quorum_tpu.ops.sampling import SamplerConfig, sample_token, sample_token_rows
 
 import pytest
-# Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
-# make test-all and CI run everything — VERDICT r3 item 6).
-pytestmark = pytest.mark.slow
 
 TINY = MODEL_PRESETS["llama-tiny"]
 
@@ -68,6 +65,7 @@ def test_abandoned_stream_releases_slot():
     assert len(res.token_ids) == 5
 
 
+@pytest.mark.slow  # compares wall-clock times
 def test_concurrency_is_faster_than_serial():
     """Two co-batched generations should take well under 2x one generation —
     batched decode is the whole point of continuous batching. Generous
@@ -86,6 +84,7 @@ def test_concurrency_is_faster_than_serial():
     assert two < 1.8 * one, f"2 concurrent took {two:.3f}s vs 1 serial {one:.3f}s"
 
 
+@pytest.mark.slow  # counts the tokens a consumer thread sees before its cancel lands
 def test_cancel_event_stops_generation():
     eng = InferenceEngine(TINY, decode_chunk=2, n_slots=2)
     cancel = threading.Event()
@@ -109,13 +108,13 @@ def test_engine_survives_failed_device_call():
     real_decode_fn = eng._decode_fn
     calls = {"n": 0}
 
-    def exploding_decode_fn(n_steps, want_lp=False, history=0):
+    def exploding_decode_fn(*args, **kwargs):
         calls["n"] += 1
         if calls["n"] == 1:
             def boom(*a, **k):
                 raise RuntimeError("injected device failure")
             return boom
-        return real_decode_fn(n_steps, want_lp, history)
+        return real_decode_fn(*args, **kwargs)
 
     eng._decode_fn = exploding_decode_fn
     try:

@@ -318,9 +318,6 @@ async def test_live_metrics_exposition_validates():
     assert "# TYPE quorum_tpu_kv_handoff_bytes_total counter" in text
     assert "# TYPE quorum_tpu_prefill_group_active gauge" in text
     assert "# TYPE quorum_tpu_decode_group_active gauge" in text
-    # per-stage decode occupancy (pipeline-staged decode, ISSUE 14): the
-    # gauge family is registered with its bare sample on unstaged engines
-    assert "# TYPE quorum_tpu_decode_stage_occupancy gauge" in text
     assert "# TYPE quorum_tpu_engine_disagg gauge" in text
     assert "# TYPE quorum_tpu_engine_prefill_group_devices gauge" in text
     assert "# TYPE quorum_tpu_engine_decode_group_devices gauge" in text

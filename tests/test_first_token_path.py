@@ -342,7 +342,10 @@ def test_profile_holds_engine_phases_and_returns_soon(tmp_path):
         stop.set()
         worker.join(timeout=120)
         eng.shutdown()
-    assert 0.5 <= took < 0.5 + 5.0
+    # The fault this guards (a stop that waits on the Python tracer) cost
+    # 48-109 s; the bound sits well under it and well over what five
+    # neighbouring test workers' load adds to a 0.5 s profile (5-8 s seen).
+    assert 0.5 <= took < 20.0
     (path,) = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
     data = jax.profiler.ProfileData.from_file(path)
     host = [p for p in data.planes if p.name.startswith("/host:")]

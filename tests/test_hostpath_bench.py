@@ -100,24 +100,17 @@ def test_interference_bench_smoke():
 
 
 def test_sharded_bench_smoke():
-    """The per-group-sharding legs (ISSUE 14): all three arms stream
-    token-for-token identical output at matched device count, the
-    disagg arms move KV across the group boundary (the tp arm via the
-    on-the-fly reshard route), and the staged arm's decode time is
-    attributed under its own pp_* program families (tok/s ORDERING is
-    the bench's printed number — wall-clock on a shared CI core flakes)."""
+    """The per-group-sharding legs (ISSUE 14): both arms stream
+    token-for-token identical output at matched device count, and the
+    disagg arm moves KV across the group boundary via the on-the-fly
+    reshard route (tok/s ORDERING is the bench's printed number —
+    wall-clock on a shared CI core flakes)."""
     m = sharded(tokens=16, chunk=4, depth=2, loop=2, repeats=1)
     assert m["sharded_tokens_match"] is True
-    for tag in ("disagg_tp2", "disagg_pp2"):
-        assert m[f"sharded_{tag}_handoff_bytes"] > 0, (tag, m)
-        assert m[f"sharded_{tag}_handoff_bytes_per_s"] > 0, (tag, m)
+    assert m["sharded_disagg_tp2_handoff_bytes"] > 0, m
+    assert m["sharded_disagg_tp2_handoff_bytes_per_s"] > 0, m
     assert m["sharded_colocated_tp4_handoff_bytes"] == 0
-    assert m["sharded_disagg_pp2_decode_pp"] == 2
-    fams = m["sharded_disagg_pp2_device_seconds"]
-    assert any(f.startswith("pp_") for f in fams), fams
-    assert not any(f.startswith("pp_")
-                   for f in m["sharded_colocated_tp4_device_seconds"])
-    for tag in ("colocated_tp4", "disagg_tp2", "disagg_pp2"):
+    for tag in ("colocated_tp4", "disagg_tp2"):
         assert m[f"sharded_{tag}_tok_s"] > 0
         assert m[f"sharded_{tag}_dispatches_per_request"] > 0
 

@@ -21,9 +21,6 @@ from quorum_tpu.models.init import param_count
 from quorum_tpu.models.transformer import init_cache
 from quorum_tpu.ops.sampling import SamplerConfig, sample_token
 
-# Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
-# make test-all and CI run everything — VERDICT r3 item 6).
-pytestmark = pytest.mark.slow
 
 TINY = ["gpt2-tiny", "llama-tiny", "mixtral-tiny", "gemma-tiny"]
 

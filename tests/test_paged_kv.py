@@ -161,7 +161,7 @@ def test_validate_page_config_rejects_bad_sizes():
 def _keyer(**over):
     """Call the real _decode_key with a minimal stand-in self — pins the
     dense tuples without paying an engine construction."""
-    ns = types.SimpleNamespace(decode_pp=1, kv_pages=False, _g_bucket=256)
+    ns = types.SimpleNamespace(kv_pages=False, _g_bucket=256)
     for k, v in over.items():
         setattr(ns, k, v)
     return lambda *a, **kw: InferenceEngine._decode_key(ns, *a, **kw)
@@ -254,8 +254,6 @@ def test_int8_wire_roundtrip():
 
 @slow
 def test_kv_pages_rejects_unsupported_knobs():
-    with pytest.raises(ValueError, match="ensemble"):
-        InferenceEngine(SPEC, kv_pages=True, ensemble=2)
     with pytest.raises(ValueError, match="draft model"):
         InferenceEngine(SPEC, kv_pages=True,
                         draft_spec=MODEL_PRESETS["llama-tiny"])

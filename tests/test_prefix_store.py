@@ -293,9 +293,6 @@ def test_invalid_store_knobs_rejected():
             name="X",
             url="tpu://llama-tiny?prefix_store=host&prefix_store_bytes=lots",
             model="m"))
-    with pytest.raises(ValueError, match="ensemble"):
-        InferenceEngine(SPEC, prefill_chunk=CHUNK, ensemble=2,
-                        prefix_store="host")
 
 
 @slow

@@ -555,8 +555,6 @@ def test_quorum_dedup_config_rejections():
     tiny = MODEL_PRESETS["llama-tiny"]
     with pytest.raises(ValueError, match="unknown member_seeds"):
         InferenceEngine(tiny, members=2, member_seeds="same")
-    with pytest.raises(ValueError, match="ensemble"):
-        InferenceEngine(tiny, ensemble=2, member_seeds="shared")
     with pytest.raises(ValueError, match="requires members>1"):
         InferenceEngine(tiny, quorum_dedup=True)
     with pytest.raises(ValueError, match="member_seeds=shared"):

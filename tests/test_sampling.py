@@ -10,11 +10,6 @@ import jax.numpy as jnp
 
 from quorum_tpu.ops.sampling import SamplerConfig, sample_token, sample_token_rows
 
-import pytest
-# Engine-scale / compile-heavy / multi-process: slow tier (make test skips,
-# make test-all and CI run everything — VERDICT r3 item 6).
-pytestmark = pytest.mark.slow
-
 
 def _logits(seed, shape=(4, 64)):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)

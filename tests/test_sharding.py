@@ -36,8 +36,8 @@ def test_param_partition_specs_follow_megatron_rules():
     specs = param_partition_specs(params)
     blocks = specs["blocks"]
     # The leading scanned-layer dim stage-shards over pp (a no-op placement
-    # on every mesh whose pp axis is 1; the pipeline-staged decode group's
-    # stages each hold L/pp layers — docs/scaling.md).
+    # on every mesh whose pp axis is 1; the pipelined training mesh's
+    # stages each hold L/pp layers — parallel/pipeline.py).
     assert blocks["wq"] == P("pp", None, "tp")     # project-in: shard output
     assert blocks["wo"] == P("pp", "tp", None)     # project-out: shard input
     assert blocks["router"] == P("pp", None, "tp")  # router over experts axis

@@ -193,9 +193,14 @@ def test_drain_based_engine_accumulates_admission_stall():
         # distinct churn prompt per admission — a repeat would tier-0
         # reuse its resident prefix and shrink the clamp window
         churn2 = [(11 + 5 * i) % 500 for i in range(48)]
-        pre = eng.submit(churn2, max_new_tokens=2, sampler=GREEDY)
+        # the stream is live before the admission is submitted: submitted
+        # the other way round, a loaded machine could finish the admission
+        # before the stream's first chunk and book no stall
         stream = eng.submit([9, 8, 7], max_new_tokens=256, sampler=GREEDY)
-        list(eng.stream_results(stream))
+        tokens = eng.stream_results(stream)
+        next(tokens)
+        pre = eng.submit(churn2, max_new_tokens=2, sampler=GREEDY)
+        list(tokens)
         list(eng.stream_results(pre))
         assert eng.admission_stall_s > 0.0
         assert eng.metrics()["admission_stall_seconds_total"] > 0.0
