@@ -15,12 +15,17 @@ same text for the layer part of each::
         mistral-7b 'quant=int8&max_seq=1024&slots=12'
     JAX_PLATFORMS=cpu python -m quorum_tpu.analysis.decode_static \
         mistral-7b 'n_layers=5&max_seq=1024&slots=8&members=3'
+    JAX_PLATFORMS=cpu python -m quorum_tpu.analysis.decode_static \
+        k-exaone-236b-a23b \
+        'n_layers=8&experts_held=16&vocab_size=19200&max_seq=4096&slots=32'
 
 prints ``temp``, then one line per operation of a loop body whose result is
-a cache side or one layer's slab of it: computation, operation, opcode,
-shape, ``op_name``, and ``WHOLE-CACHE MOVE`` on those :func:`whole_cache_moves`
-lists. Nothing runs, so this gives no time; the scan's program does not
-depend on depth, and the full-depth int8 member compiles in some ten seconds.
+a cache side or one layer's slab of it (for a spec with a ``layer_pattern``:
+a full-attention layer's side or a ring, :func:`cache_sizes`): computation,
+operation, opcode, shape, ``op_name``, and ``WHOLE-CACHE MOVE`` on those
+:func:`whole_cache_moves` lists. Nothing runs, so this gives no time; the
+scan's program does not depend on depth, and the full-depth int8 member
+compiles in some ten seconds.
 """
 
 from __future__ import annotations
@@ -177,6 +182,19 @@ def whole_cache_moves(text: str, cache_elements: int) -> "list[tuple]":
             if row[2] in _MOVES or (row[2] == "fusion" and "copy" in row[1])]
 
 
+def cache_sizes(spec, rows: int, members: int = 1) -> "tuple[tuple, tuple]":
+    """``(carried, slabs)``: the element counts of the arrays a decode chunk
+    carries its cache in, and of the smaller pieces worth listing beside
+    them. One stacked side and a layer's slab of it; for a spec with a
+    ``layer_pattern`` (a cache per layer kind, models/patterned.py) one
+    full-attention layer's side and one window layer's ring, no slab."""
+    row = rows * spec.n_kv_heads * spec.head_dim
+    if spec.layer_pattern:
+        return (row * spec.max_seq, row * spec.ring), ()
+    side = members * spec.n_layers * row * spec.max_seq
+    return (side,), (side // spec.n_layers,)
+
+
 def main(argv: "list[str]") -> int:
     if not argv:
         print(__doc__, file=sys.stderr)
@@ -191,11 +209,10 @@ def main(argv: "list[str]") -> int:
         spec, v5e_device(), rows=rows, members=members,
         history=int(options.get("history", 512)), quant=options.get("quant"))
     text = compiled.as_text()
-    side = (members * spec.n_layers * rows * spec.n_kv_heads * spec.max_seq
-            * spec.head_dim)
+    carried, slabs = cache_sizes(spec, rows, members)
     print(f"temp\t{compiled.memory_analysis().temp_size_in_bytes / 1e9:.4f} GB")
-    moves = whole_cache_moves(text, side)
-    for row in loop_body_ops(text, {side, side // spec.n_layers}):
+    moves = [row for size in carried for row in whole_cache_moves(text, size)]
+    for row in loop_body_ops(text, set(carried + slabs)):
         print(*row, "WHOLE-CACHE MOVE" if row in moves else "", sep="\t")
     return 0
 

@@ -15,6 +15,18 @@ spec over to the functions of the same name here:
     a mask made from the absolute position each ring entry holds. The K side
     also carries the expert layers' counters, so that they ride the cache
     through every program and cost no output of their own.
+    The decode step writes a row's new K and V in a loop over the rows, a
+    ``dynamic_slice`` of what the row holds, a select by the row's mask and a
+    ``dynamic_update_slice``, all three at scalar starts, K and V of a layer
+    in one loop. A masked row keeps what it holds: a row in chunked prefill
+    is masked and its positions are live data. Not ``jax.vmap`` of the same
+    three: a per-row start under ``vmap`` turns the slice into a gather, the
+    v5e compiler wants a gather's operand positions-major, and so every step
+    copied both sides of both full layers (268 MB each) and all twelve rings
+    to fetch 64 KB; attention reads the carried K-major buffer in place and
+    never wanted the copy. Nor an unrolled loop: with no ``while`` around the
+    updates the compiler flips the carried buffers positions-major and
+    re-lays the history window K-major each step (PERF.md section 6, PR 33).
   - **Expert layers over a held share.** The router scores all ``n_experts``
     in float32 and picks the top k; only the picks that fall on the experts
     held here are computed, beside the shared expert, and no pick is dropped.
@@ -466,12 +478,20 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
     hist = (history if history is not None and history < spec.max_seq
             else spec.max_seq)
 
-    def write_row(cache_row, new_row, idx, ok):
-        old = lax.dynamic_slice(cache_row, (0, idx, 0), new_row.shape)
-        return lax.dynamic_update_slice(
-            cache_row, jnp.where(ok, new_row, old), (0, idx, 0))
+    def write(ck, cv, k, v, at):
+        # row by row through scalar starts, K and V in one loop (the module
+        # docstring says why; a loop a side is 0.47 ms a step slower)
+        def put(cache, new, r):
+            start = (r, 0, at[r], 0)
+            held = lax.dynamic_slice(cache, start, (1,) + new.shape[1:])
+            mine = lax.dynamic_slice_in_dim(new, r, 1, axis=0)
+            return lax.dynamic_update_slice(
+                cache, jnp.where(allow[r], mine, held), start)
 
-    write = jax.vmap(write_row)
+        return lax.fori_loop(
+            0, b, lambda r, kv: (put(kv[0], k, r), put(kv[1], v, r)),
+            (ck, cv))
+
     held = ring_positions(lengths, spec.ring)                  # [B, R]
     ring_keep = ((held >= 0) & (held > pos - spec.sliding_window)
                  )[:, None, None, None, :]
@@ -480,8 +500,7 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         at = lengths if kind == "G" else lengths % spec.ring
         with jax.named_scope("attn.cache_write"):
-            ck = write(ck, k.astype(ck.dtype), at, allow)
-            cv = write(cv, v.astype(cv.dtype), at, allow)
+            ck, cv = write(ck, cv, k.astype(ck.dtype), v.astype(cv.dtype), at)
         with jax.named_scope("attn.core"), _scope(kind):
             if kind == "G":
                 out = decode_attention(

@@ -293,8 +293,8 @@ def test_no_whole_cache_copy_or_allocation_in_the_step_loop(
     spec = dataclasses.replace(
         MODEL_PRESETS["mistral-7b"], n_layers=2, max_seq=1024).validate()
     monkeypatch.setenv("QUORUM_TPU_QEINSUM_INT8", "1")  # the chip's products
-    side = (shape["members"] * spec.n_layers * shape["rows"]
-            * spec.n_kv_heads * spec.max_seq * spec.head_dim)
+    (side,), _ = decode_static.cache_sizes(spec, shape["rows"],
+                                           shape["members"])
 
     def program(step_blocks):
         monkeypatch.setattr(tr, "decode_step_blocks", step_blocks)
