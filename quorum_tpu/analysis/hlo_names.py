@@ -35,8 +35,13 @@ import sys
 # kind, inside attn.core, and an expert layer's three parts
 PATTERNED = ("attn.window", "attn.full", "moe.router", "moe.experts",
              "moe.shared")
+# a latent spec (models/latent.py) adds: the two latents' projections, the
+# indexer's products and scores, the selection and its gather, attention in
+# the latent space, the gate per head
+LATENT = ("attn.latent_q", "attn.latent_kv", "attn.index", "attn.select",
+          "attn.sparse", "attn.gate")
 PARTS = ("embed", "norm", "attn.qkv", "attn.cache_write", "attn.core",
-         "attn.out", "mlp", "lm_head", "sample") + PATTERNED
+         "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(

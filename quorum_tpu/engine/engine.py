@@ -141,6 +141,7 @@ from quorum_tpu.models.init import init_params, init_params_sharded
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.patterned import STATS as MOE_STATS
 from quorum_tpu.models.patterned import KindKV
+from quorum_tpu.models.patterned import stats_of as moe_stats_of
 from quorum_tpu.models.transformer import (
     decode_chunk,
     decode_loop,
@@ -1923,12 +1924,11 @@ class InferenceEngine:
                     sh, is_leaf=lambda x: isinstance(x, NamedSharding))
             return sh
         if self.spec.layer_pattern:
-            # one [slots, K, T, hd] leaf per layer, by kind; the counters
-            # replicated (tp, sp and members are refused above)
+            # a leaf per layer, by kind, and the counters: all replicated
+            # (tp, sp and members are refused above)
             leaf = NamedSharding(mesh, P())
-            full = (leaf,) * len(self.spec.layers_of("G"))
-            window = (leaf,) * len(self.spec.layers_of("L"))
-            return KindKV(full, window, leaf), KindKV(full, window, None)
+            return jax.tree.map(lambda _: leaf, jax.eval_shape(
+                lambda: init_cache(self.spec, batch=1)))
         sh = kv_cache_sharding(mesh, self.spec.n_kv_heads,
                                batch=self.n_slots, seq_shard=seq_shard)
         if self.kv_quant:
@@ -4376,12 +4376,17 @@ class InferenceEngine:
 
     def _moe_metrics(self) -> dict:
         held = self.spec.held if self.spec.layer_pattern else 0
+        names = moe_stats_of(self.spec)
         total = self._moe_total
         if total is None:
-            total = np.zeros((0, held + len(MOE_STATS)), np.int64)
+            total = np.zeros((0, held + len(names)), np.int64)
         first = self.spec.first_dense
         per_expert, rest = total[:, :held], total[:, held:]
         return {
+            # where full layers select what they attend: the positions their
+            # queries attended and the positions their histories held
+            **{f"dsa_{name}_total": int(rest[:, names.index(name)].sum())
+               for name in names[len(MOE_STATS):]},
             "moe_picks_total": int(rest[:, MOE_STATS.index("picks")].sum()),
             "moe_picks_held_total": int(per_expert.sum()),
             # picks on a held expert that no product computed: held picks
@@ -4404,18 +4409,34 @@ class InferenceEngine:
                for kind, n in self._kv_cache_bytes().items()},
         }
 
+    def _keys_kept(self, n_prompt: int) -> dict:
+        """A ``prefill`` span's ``keys_kept_share``, where full layers select
+        what they attend: of the (query, earlier position) pairs of a prompt
+        of ``n_prompt``, the percentage a full layer attends, by the spec's
+        ``index_topk`` (the device's own counts are ``dsa_keys_*_total``)."""
+        k = self.spec.index_topk
+        if not k or n_prompt <= 0:
+            return {}
+        whole = min(n_prompt, k)
+        kept = whole * (whole + 1) // 2 + (n_prompt - whole) * k
+        return {"keys_kept_share": round(
+            100.0 * kept / (n_prompt * (n_prompt + 1) // 2), 2)}
+
     def _kv_cache_bytes(self) -> dict:
         """Bytes of the slot cache by layer kind, from the arrays the engine
         holds (a donated array still says its shape): a spec without a
-        pattern has full layers only."""
+        pattern has full layers only; ``index`` is the index keys a full
+        layer keeps beside its latent rows where it selects what it attends
+        (models/latent.py)."""
         def nbytes(tree) -> int:
             return sum(a.nbytes for a in jax.tree.leaves(tree))
 
         ck, cv = getattr(self, "_ck", None), getattr(self, "_cv", None)
         if isinstance(ck, KindKV):
             return {"full": nbytes(ck.full) + nbytes(cv.full),
-                    "window": nbytes(ck.window) + nbytes(cv.window)}
-        return {"full": nbytes(ck) + nbytes(cv), "window": 0}
+                    "window": nbytes(ck.window) + nbytes(cv.window),
+                    "index": nbytes(ck.index)}
+        return {"full": nbytes(ck) + nbytes(cv), "window": 0, "index": 0}
 
     def metrics(self) -> dict:
         """Scheduler/capacity snapshot for the server's /metrics endpoint."""
@@ -5496,7 +5517,8 @@ class InferenceEngine:
                  slot=adm.slot, chunked=True, reused=adm.offset0,
                  restored=adm.restored, segments=adm.segments,
                  turns=self.n_turns - adm.turn0 + 1 if adm.segments else 0,
-                 decode_wait_ms=round(wait_s * 1000, 3))
+                 decode_wait_ms=round(wait_s * 1000, 3),
+                 **self._keys_kept(len(prompt)))
         with self._cond:
             self._slots[adm.slot] = req
         self._release_admission(adm)
@@ -5688,7 +5710,7 @@ class InferenceEngine:
         # (reuse routes through a chunked admission); recorded anyway so
         # every admission span carries the cache-effectiveness attrs.
         req.span("prefill", t0, t1, tokens=n_prompt, bucket=bucket, slot=slot,
-                 reused=0, restored=0, **picks)
+                 reused=0, restored=0, **picks, **self._keys_kept(n_prompt))
         if req.want_lp >= 0:
             req.lp.append((float(s_lp),
                            np.asarray(top_ix), np.asarray(top_lp)))

@@ -16,6 +16,18 @@ from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.transformer import Params
 
 
+# Seeded latent attention (:func:`init_patterned_from_key`). ``SCORE_DEV`` is
+# the deviation of a layer's attention scores, how peaked seeded attention is:
+# at 1 a query spreads over thousands of positions and attention adds next to
+# nothing to the stream; at 6 it attends one or two positions of 4,096.
+# ``VALUE_GAIN`` is the values' size as a share of the rescale's
+# ``sqrt(D / rank)``: what attention writes into the stream grows with it.
+# Why 4 and 0.7: the served configuration's ``assumed.weights``
+# (benchmarks/configs/dots3-ep8.json).
+SCORE_DEV = 4.0
+VALUE_GAIN = 0.7
+
+
 def init_params(spec: ModelSpec, seed: int = 0) -> Params:
     return init_params_from_key(spec, jax.random.PRNGKey(seed))
 
@@ -30,28 +42,31 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
     ``1/sqrt(2 * init_depth)`` (the GPT-2 / Megatron scaled initialisation),
     so that one expert's output is a few percent of the stream and a
     near-tie in the router's pick moves a served log-probability by little.
-    The blocks are post-norm, so that scale is the gain of the two norms (an
-    RMSNorm after ``wo`` or ``w_down`` undoes any scale on the matrix:
+    Where the blocks are post-norm that scale is the gain of the two norms
+    (an RMSNorm after ``wo`` or ``w_down`` undoes any scale on the matrix:
     seeded at 1 each sub-layer would add a unit-rms vector, and one flipped
-    pick moved a log-probability by 0.1-0.3 on the chip, PERF.md section 6).
-    The router's selection bias is small and non-zero, so that the score and
-    the score-plus-bias differ."""
+    pick moved a log-probability by 0.1-0.3 on the chip, PERF.md section 6);
+    in pre-norm blocks it is on ``wo``, ``w_down`` and the experts'
+    down-projections, and the norms' gains are 1.
+    The router's selection bias (``spec.init_bias_dev`` times a normal) is
+    small and non-zero, so that the score and the score-plus-bias differ."""
     dt = jnp.dtype(spec.dtype)
     D, V = spec.d_model, spec.vocab_size
     H = spec.n_heads * spec.head_dim
     K = spec.n_kv_heads * spec.head_dim
     F, Fe, E, held = spec.d_ff, spec.d_ff_expert, spec.n_experts, spec.held
     gain = (2.0 * (spec.init_depth or spec.n_layers)) ** -0.5
+    latent = spec.kv_lora_rank > 0
+    # pre-norm blocks have no norm after a sub-layer to carry the scale: it
+    # is on the matrix that writes into the stream
+    norm_gain, out = (gain, 1.0) if spec.post_norm else (1.0, gain)
 
-    def w(k, *shape, fan_in):
+    def w(k, *shape, fan_in, scale=1.0):
         return (jax.random.normal(k, shape, jnp.float32)
-                * fan_in ** -0.5).astype(dt)
+                * (scale * fan_in ** -0.5)).astype(dt)
 
-    def layer(i: int, key) -> dict:
-        ks = iter(jax.random.split(key, 16))
-        out = {
-            "attn_norm_w": jnp.full((1, D), gain, dt),
-            "mlp_norm_w": jnp.full((1, D), gain, dt),
+    def kv_heads(ks) -> dict:
+        return {
             "wq": w(next(ks), 1, D, H, fan_in=D),
             "wk": w(next(ks), 1, D, K, fan_in=D),
             "wv": w(next(ks), 1, D, K, fan_in=D),
@@ -59,26 +74,76 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
             "q_norm_w": jnp.ones((1, spec.head_dim), dt),
             "k_norm_w": jnp.ones((1, spec.head_dim), dt),
         }
+
+    def latent_heads(i: int, key) -> dict:
+        """models/latent.py's leaves; ``w_qb``'s columns are a head's
+        unrotated then rotated dims, head after head; ``w_kva``'s the latent
+        then the rotated key. The key/value up-projection is kept as its two
+        halves, ``w_kb`` and ``w_vb`` (a decode step multiplies the query by
+        the one and the output by the other)."""
+        kind = spec.attn_kind(i)
+        g = spec.latent(kind)
+        ks = iter(jax.random.split(key, 12))
+        # the latents come out of their norms multiplied by sqrt(D / rank):
+        # what makes queries, keys and index queries of them counts that into
+        # its fan-in (D, not the rank), so that they are of unit size as
+        # everywhere else, the queries SCORE_DEV times that. The values keep
+        # VALUE_GAIN of the rescale's gain (fan-in the rank), which makes a
+        # full layer's output as large a part of the stream as an expert
+        # layer's
+        out_w = {
+            "w_qa": w(next(ks), 1, D, g.q_rank, fan_in=D),
+            "q_a_norm_w": jnp.ones((1, g.q_rank), dt),
+            "w_qb": w(next(ks), 1, g.q_rank, g.heads * (g.nope + g.rope),
+                      fan_in=D, scale=SCORE_DEV),
+            "w_kva": w(next(ks), 1, D, g.kv_rank + g.rope, fan_in=D),
+            "kv_a_norm_w": jnp.ones((1, g.kv_rank), dt),
+            "w_kb": w(next(ks), 1, g.kv_rank, g.heads * g.nope,
+                      fan_in=D),
+            "w_vb": w(next(ks), 1, g.kv_rank, g.heads * g.v,
+                      fan_in=g.kv_rank, scale=VALUE_GAIN),
+            "w_head_gate": w(next(ks), 1, D, g.heads, fan_in=D),
+            "wo": w(next(ks), 1, g.heads * g.v, D, fan_in=g.heads * g.v,
+                    scale=out),
+        }
+        if kind == "G":
+            ih, idim = spec.index_n_heads, spec.index_head_dim
+            out_w.update(
+                w_iq=w(next(ks), 1, g.q_rank, ih * idim, fan_in=D),
+                w_ik=w(next(ks), 1, D, idim, fan_in=D),
+                ik_norm_w=jnp.ones((1, idim), dt),
+                ik_norm_b=jnp.zeros((1, idim), dt),
+                w_iw=w(next(ks), 1, D, ih, fan_in=D))
+        return out_w
+
+    def layer(i: int, key) -> dict:
+        ks = iter(jax.random.split(key, 16))
+        out_w = {
+            "attn_norm_w": jnp.full((1, D), norm_gain, dt),
+            "mlp_norm_w": jnp.full((1, D), norm_gain, dt),
+            **(latent_heads(i, next(ks)) if latent else kv_heads(ks)),
+        }
         if i < spec.first_dense:
-            out.update(
+            out_w.update(
                 w_gate=w(next(ks), 1, D, F, fan_in=D),
                 w_up=w(next(ks), 1, D, F, fan_in=D),
-                w_down=w(next(ks), 1, F, D, fan_in=F))
-            return out
-        out.update(
+                w_down=w(next(ks), 1, F, D, fan_in=F, scale=out))
+            return out_w
+        out_w.update(
             router=w(next(ks), 1, D, E, fan_in=D),
-            router_bias=(0.05 * jax.random.normal(next(ks), (1, E))
+            router_bias=(spec.init_bias_dev
+                         * jax.random.normal(next(ks), (1, E))
                          ).astype(jnp.float32),
             moe_w_gate=w(next(ks), 1, held, D, Fe, fan_in=D),
             moe_w_up=w(next(ks), 1, held, D, Fe, fan_in=D),
-            moe_w_down=w(next(ks), 1, held, Fe, D, fan_in=Fe))
+            moe_w_down=w(next(ks), 1, held, Fe, D, fan_in=Fe, scale=out))
         if spec.n_shared_experts:
             Fs = Fe * spec.n_shared_experts
-            out["shared"] = {
+            out_w["shared"] = {
                 "w_gate": w(next(ks), 1, D, Fs, fan_in=D),
                 "w_up": w(next(ks), 1, D, Fs, fan_in=D),
-                "w_down": w(next(ks), 1, Fs, D, fan_in=Fs)}
-        return out
+                "w_down": w(next(ks), 1, Fs, D, fan_in=Fs, scale=out)}
+        return out_w
 
     k_emb, k_head, k_layers = jax.random.split(key, 3)
     return {

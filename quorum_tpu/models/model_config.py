@@ -19,11 +19,25 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
+from typing import NamedTuple
+
+
+class Latent(NamedTuple):
+    """One layer kind's latent attention: heads, the two latents' ranks, a
+    head's unrotated, rotated and value sizes, the rotary base."""
+
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
 
 
 @dataclass(frozen=True)
 class ModelSpec:
-    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe"
+    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe" | "dots3_note"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -34,14 +48,48 @@ class ModelSpec:
     max_seq: int = 4096
     sliding_window: int = 0        # >0: attend only the last W positions (mistral)
     # A layer pattern ("" = every layer alike, which is every spec above the
-    # patterned family): one letter per layer, repeated over the depth, "L"
-    # a sliding-window layer (the last ``sliding_window`` positions, kept in
-    # a ring of ``ring`` positions per row) and "G" a full-attention
-    # layer (every position, no rotary embedding). A patterned spec runs
-    # models/patterned.py, which hard-codes the family's conventions:
-    # per-layer weights, a cache per layer kind, post-norm blocks, RMSNorm
-    # over each q and k head.
+    # patterned families): one letter per layer, repeated over the depth
+    # (or written out for every layer, where the model's list is no repeated
+    # period), "L" a sliding-window layer (the last ``sliding_window``
+    # positions, kept in a ring of ``ring`` positions per row) and "G" a
+    # full-attention layer (every position). A patterned spec runs
+    # models/patterned.py: per-layer weights, a cache per layer kind, one
+    # depth loop and one expert layer for its two families, whose
+    # attention conventions are written in and not chosen by the spec: K/V
+    # heads, RMSNorm over each q and k head and no rotary embedding on full
+    # layers; or, with ``kv_lora_rank`` set, latent attention
+    # (models/latent.py). ``post_norm``: a patterned spec's blocks normalise
+    # what a sub-layer gives before the add (``x + norm(f(x))``, exaone_moe)
+    # and not what it takes (``x + f(norm(x))``).
     layer_pattern: str = ""
+    post_norm: bool = False
+    # Latent attention (``kv_lora_rank`` > 0; family "dots3_note"): a query
+    # latent of ``q_lora_rank`` and a key/value latent of ``kv_lora_rank``
+    # per position, both normalised; ``n_heads`` heads of ``qk_nope_head_dim``
+    # from the latents plus ``qk_rope_head_dim`` rotated dims (one rotated
+    # key for all heads), values of ``v_head_dim``; a sigmoid gate per head
+    # on the attention output. The cache keeps the latent and the rotated
+    # key, no K or V. A window layer has a geometry of its own (``swa_*``,
+    # with its own ``swa_rope_theta``). A full layer also scores every
+    # earlier position with ``index_n_heads`` light heads of
+    # ``index_head_dim`` against a cached index key and attends only the
+    # ``index_topk`` positions of largest score (all of them while there
+    # are no more).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    swa_n_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     norm: str = "rmsnorm"          # "rmsnorm" | "layernorm"
     norm_eps: float = 1e-5
     norm_offset: float = 0.0       # weight used as (offset + w); gemma: 1.0
@@ -82,10 +130,12 @@ class ModelSpec:
     router_scale: float = 1.0
     experts_held: int = 0
     expert_first: int = 0
-    # Seeded init of the patterned family: the post-norms' gains are
-    # 1/sqrt(2 * init_depth), the published depth, whatever n_layers is cut
-    # to (models/init.py).
+    # Seeded init of the patterned families: what a sub-layer adds to the
+    # stream is scaled by 1/sqrt(2 * init_depth), the published depth,
+    # whatever n_layers is cut to; the router's selection bias is
+    # ``init_bias_dev`` times a normal (models/init.py).
     init_depth: int = 0
+    init_bias_dev: float = 0.05
     dtype: str = "bfloat16"
 
     @property
@@ -102,6 +152,17 @@ class ModelSpec:
         """Positions a window layer keeps per row: the power of two at or
         above ``sliding_window``."""
         return 1 << max(self.sliding_window - 1, 0).bit_length()
+
+    def latent(self, kind: str) -> "Latent":
+        """The latent attention's sizes in a layer of ``kind``."""
+        if kind == "L":
+            return Latent(self.swa_n_heads, self.swa_q_lora_rank,
+                          self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                          self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                          self.swa_rope_theta)
+        return Latent(self.n_heads, self.q_lora_rank, self.kv_lora_rank,
+                      self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim, self.rope_theta)
 
     def attn_kind(self, layer: int) -> str:
         return self.layer_pattern[layer % len(self.layer_pattern)]
@@ -131,6 +192,12 @@ class ModelSpec:
             assert self.pos == "rope" and self.norm == "rmsnorm"
             assert self.gated_mlp and not self.use_bias
             assert 0 <= self.first_dense <= self.n_layers
+            if self.kv_lora_rank:
+                for kind in set(self.layer_pattern[:self.n_layers]):
+                    g = self.latent(kind)
+                    assert min(g) > 0 and g.rope % 2 == 0, (kind, g)
+                assert self.index_topk > 0 and self.index_n_heads > 0
+                assert self.qk_rope_head_dim <= self.index_head_dim
             if self.first_dense < self.n_layers:
                 assert self.n_experts > 0 and self.d_ff_expert > 0
                 assert self.experts_per_token <= self.n_experts
@@ -227,6 +294,33 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         rope_theta=1000000.0, sliding_window=128, layer_pattern="LLLG",
         tied_lm_head=False, n_experts=128, experts_per_token=8, first_dense=1,
         d_ff_expert=2048, n_shared_experts=1, router_scale=2.5, init_depth=48,
+        post_norm=True,
+    ),
+    # dots3-note-prev (dots-studio, model_type dots3_note, 288B-A17B): 46
+    # layers "GG" + "LLLG" x 11. Latent attention of two geometries: full
+    # layers of 128 heads (latents 1024 / 512, 128 + 64 dims, values 128,
+    # theta 8e7) that attend the 2,048 positions a 64-head indexer scores
+    # highest; window-513 layers of 64 heads (latents 1024 / 1024, 192 + 64,
+    # values 128, theta 5e4); a sigmoid gate per head; both latents rescaled
+    # by sqrt(hidden / rank) after their norms; pre-norm blocks. A dense
+    # first layer, then 256 sigmoid-routed experts (8 picked, scaled 1)
+    # beside one shared expert. 576 GB in bf16: served as one chip's share,
+    # ``?n_layers=6&experts_held=32&vocab_size=19008``
+    # (docs/tpu_backends.md). The vision and audio towers and the
+    # multi-token-prediction layer are not loaded.
+    "dots3-note-prev": ModelSpec(
+        family="dots3_note", vocab_size=152064, d_model=5120, n_layers=46,
+        n_heads=128, n_kv_heads=128, head_dim=192, d_ff=13824, max_seq=16384,
+        rope_theta=80000000.0, sliding_window=513,
+        layer_pattern="GG" + "LLLG" * 11, tied_lm_head=False, n_experts=256,
+        experts_per_token=8, first_dense=1, d_ff_expert=1536,
+        n_shared_experts=1, router_scale=1.0, init_depth=46,
+        q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, swa_n_heads=64,
+        swa_q_lora_rank=1024, swa_kv_lora_rank=1024,
+        swa_qk_nope_head_dim=192, swa_qk_rope_head_dim=64, swa_v_head_dim=128,
+        swa_rope_theta=50000.0, index_n_heads=64, index_head_dim=128,
+        index_topk=2048, init_bias_dev=0.01,
     ),
     # Scaled-down test/dev presets (CPU-fast, same code paths)
     "gpt2-tiny": _gpt2(vocab_size=512, d_model=64, n_layers=2, n_heads=4,
@@ -248,6 +342,24 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         sliding_window=8, layer_pattern="LLLG", tied_lm_head=False,
         n_experts=16, experts_per_token=4, first_dense=1, d_ff_expert=32,
         n_shared_experts=1, router_scale=2.5, experts_held=4, init_depth=48,
+        post_norm=True,
+    ),
+    # the dots3_note family at a size the CPU runs: "GGLLLG", both latent
+    # geometries, 16 experts of which 4 are held, top-4; the indexer keeps
+    # 16 positions and the window 9 (ring 16), both far under max_seq
+    "dots3-tiny": ModelSpec(
+        family="dots3_note", vocab_size=512, d_model=64, n_layers=6,
+        n_heads=4, n_kv_heads=4, head_dim=24, d_ff=192, max_seq=128,
+        rope_theta=80000000.0, sliding_window=9,
+        layer_pattern="GG" + "LLLG" * 11, tied_lm_head=False, n_experts=16,
+        experts_per_token=4, first_dense=1, d_ff_expert=32,
+        n_shared_experts=1, router_scale=1.0, experts_held=4, init_depth=46,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, swa_n_heads=2,
+        swa_q_lora_rank=32, swa_kv_lora_rank=32, swa_qk_nope_head_dim=24,
+        swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=50000.0,
+        index_n_heads=4, index_head_dim=16, index_topk=16,
+        init_bias_dev=0.01,
     ),
     "gemma-tiny": ModelSpec(
         family="gemma", vocab_size=512, d_model=64, n_layers=2, n_heads=4,

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from quorum_tpu.models import latent
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.ops.attention import attention, decode_attention
 from quorum_tpu.ops.flash_attention import flash_prefill_attention
@@ -61,10 +62,23 @@ from quorum_tpu.ops.rotary import rope_cos_sin_for
 
 # Rows up to which the held experts run densely over every row (a decode
 # step: the step is bound by the experts' bytes, and at 32 rows 87 % of 16
-# held experts are picked anyway); above it picks are grouped by expert,
-# which is what a prefill's rows need: 512 tokens put one expert's worth of
-# picks on 16 held experts, a sixteenth of the dense form's products.
+# held experts of 128 are picked anyway); above it picks are grouped by
+# expert, which is what a prefill's rows need: 512 tokens put one expert's
+# worth of picks on 16 held experts, a sixteenth of the dense form's products.
+# Grouped below it too where the rows are expected to pick under half of the
+# held experts (:func:`dense_experts`): 16 rows pick 8 of 256 each and reach
+# 40 % of 32 held ones, so the dense form read 2.5 times the bytes the step
+# needs, 2.67 ms a layer against 1.1 (my chip run, PR 34).
 DENSE_ROWS = 64
+
+
+def dense_experts(spec: ModelSpec, rows: int) -> bool:
+    """Whether a program of ``rows`` rows runs every held expert over every
+    row: few rows, that under even routing pick at least half of them."""
+    share = 1.0 - (1.0 - spec.experts_per_token / spec.n_experts) ** rows
+    return rows <= DENSE_ROWS and share >= 0.5
+
+
 # Rows of a grouped tile: the picks on held experts are sorted by expert into
 # tiles that each belong to one expert, and as many tiles are multiplied as
 # the picks fill, so the work follows the load whatever its skew and no
@@ -76,6 +90,14 @@ TILE = 128
 # token), and picks on a held expert that no product computed: held picks
 # less the rows the products say they took.
 STATS = ("picks", "dropped")
+# Two more where the full layers select what they attend (models/latent.py),
+# summed over those layers into the first row: the positions their queries
+# attended, and the positions their histories held.
+DSA_STATS = ("keys_attended", "keys_in_history")
+
+
+def stats_of(spec: ModelSpec) -> tuple:
+    return STATS + (DSA_STATS if spec.index_topk else ())
 
 
 @jax.tree_util.register_dataclass
@@ -85,19 +107,38 @@ class KindKV:
 
     ``full``: one ``[slots, K, max_seq, hd]`` array per full-attention layer.
     ``window``: one ``[slots, K, ring, hd]`` ring per window layer.
-    ``stats``: on the K side, int32 ``[expert layers, held + len(STATS)]``,
+    ``stats``: on the K side, int32 ``[expert layers, held + len(stats_of)]``,
     counted up by every program since the cache was made: picks per held
-    expert, then :data:`STATS`. None on the V side."""
+    expert, then :func:`stats_of`. None on the V side.
+    ``index``: a latent spec's index keys, one ``[slots, max_seq,
+    index_head_dim]`` per full layer. Such a spec (models/latent.py) has no
+    K and V: its ``full`` and ``window`` are the cached latent rows,
+    ``[slots, T, latent.row_width]``, all on the first side, and its second
+    side is empty."""
 
     full: tuple
     window: tuple
     stats: Any = None
+    index: tuple = ()
 
 
 def init_cache(spec: ModelSpec, batch: int, dtype=None):
     dt = jnp.dtype(dtype or spec.dtype)
     rows = (batch, spec.n_kv_heads)
     n_sparse = spec.n_layers - spec.first_dense
+    stats = jnp.zeros((n_sparse, spec.held + len(stats_of(spec))), jnp.int32)
+    if spec.kv_lora_rank:
+        def rows_of(kind, t):
+            width = latent.row_width(spec.latent(kind))
+            return tuple(jnp.zeros((batch, t, width), dt)
+                         for _ in spec.layers_of(kind))
+
+        return (KindKV(rows_of("G", spec.max_seq), rows_of("L", spec.ring),
+                       stats,
+                       tuple(jnp.zeros((batch, spec.max_seq,
+                                        spec.index_head_dim), dt)
+                             for _ in spec.layers_of("G"))),
+                KindKV((), ()))
 
     def side(stats):
         return KindKV(
@@ -107,8 +148,7 @@ def init_cache(spec: ModelSpec, batch: int, dtype=None):
                   for _ in spec.layers_of("L")),
             stats)
 
-    return (side(jnp.zeros((n_sparse, spec.held + len(STATS)), jnp.int32)),
-            side(None))
+    return side(stats), side(None)
 
 
 def layer_of(params, i: int):
@@ -190,13 +230,16 @@ def _qkv(h, lyr, spec: ModelSpec, kind: str, cos, sin, pos):
 
 
 def _sub(x, w, fn, spec: ModelSpec):
-    """One residual sub-layer on the float32 stream ``x``, its output
-    normalised before the add; ``fn`` takes the stream and casts what it
-    feeds to a matrix product."""
+    """One residual sub-layer on the float32 stream ``x``: its output
+    normalised before the add (``spec.post_norm``), or its input; ``fn``
+    takes float32 and casts what it feeds to a matrix product."""
     from quorum_tpu.models.transformer import _norm
 
-    return x + _norm(fn(x).astype(jnp.float32), w.astype(jnp.float32), None,
-                     spec)
+    if spec.post_norm:
+        return x + _norm(fn(x).astype(jnp.float32), w.astype(jnp.float32),
+                         None, spec)
+    return x + fn(_norm(x, w.astype(jnp.float32), None, spec)).astype(
+        jnp.float32)
 
 
 def _head(params, spec: ModelSpec, x):
@@ -313,7 +356,7 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
         per_expert = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)
 
         computed = held_picks = jnp.sum(per_expert)
-        if dense if dense is not None else n <= DENSE_ROWS:
+        if dense if dense is not None else dense_experts(spec, n):
             w_held = jnp.einsum("nk,nke->ne", w_pick, one_hot)
             routed = _experts_dense(xf, lyr, w_held)
         else:
@@ -342,25 +385,32 @@ def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list,
 
 
 def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
-            attend, token_ok, dense: bool | None = None):
-    """The depth loop, written out, shared by the four served paths:
-    ``attend(h, lyr, kind, ck, cv) -> (attention output, ck, cv)`` is what
-    differs between them. ``x`` is the float32 stream; returns it with the
-    two caches, the K side's counters counted up."""
+            attend, token_ok, dense: bool | None = None, keys=()):
+    """The depth loop, written out, shared by the four served paths and the
+    two families: ``attend(h, lyr, kind, leaves) -> (attention output,
+    leaves)`` is what differs between them, ``leaves`` the layer's own of the
+    cache: ``(K, V)``, or a latent spec's ``(rows, index keys)`` and
+    ``(ring,)``. ``x`` is the float32 stream; returns it with the two
+    caches, the first side's counters counted up (``keys``: what a latent
+    spec's ``attend`` leaves there of :data:`DSA_STATS`, a pair a full
+    layer)."""
     from quorum_tpu.models import transformer as tr
 
-    caches = {"G": [list(cache_k.full), list(cache_v.full)],
-              "L": [list(cache_k.window), list(cache_v.window)]}
+    latent = bool(spec.kv_lora_rank)
+    caches = ({"G": list(zip(cache_k.full, cache_k.index)),
+               "L": [(ring,) for ring in cache_k.window]} if latent else
+              {"G": list(zip(cache_k.full, cache_v.full)),
+               "L": list(zip(cache_k.window, cache_v.window))})
     seen = {"G": 0, "L": 0}
     counts: list = []
     for i in range(spec.n_layers):
         lyr = layer_of(params, i)
         kind = spec.attn_kind(i)
-        j, (ks, vs) = seen[kind], caches[kind]
+        j, of_kind = seen[kind], caches[kind]
         seen[kind] += 1
 
         def attn(h):
-            out, ks[j], vs[j] = attend(h, lyr, kind, ks[j], vs[j])
+            out, of_kind[j] = attend(h, lyr, kind, of_kind[j])
             return tr._attn_out(out, lyr, jnp.dtype(spec.dtype))
 
         x = _sub(x, lyr["attn_norm_w"], attn, spec)
@@ -369,9 +419,20 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
                  spec)
     stats = cache_k.stats
     if counts and stats is not None:
-        stats = stats + jnp.stack(counts)
-    return (x, KindKV(tuple(caches["G"][0]), tuple(caches["L"][0]), stats),
-            KindKV(tuple(caches["G"][1]), tuple(caches["L"][1]), None))
+        moe = jnp.stack(counts)
+        if keys:
+            moe = jnp.pad(moe, ((0, 0), (0, len(DSA_STATS)))).at[
+                0, -len(DSA_STATS):].add(sum(keys).astype(jnp.int32))
+        stats = stats + moe
+
+    def of(kind: str, n: int):
+        return tuple(leaves[n] for leaves in caches[kind])
+
+    if latent:
+        return (x, KindKV(of("G", 0), of("L", 0), stats, of("G", 1)),
+                KindKV((), ()))
+    return (x, KindKV(of("G", 0), of("L", 0), stats),
+            KindKV(of("G", 1), of("L", 1), None))
 
 
 def _scope(kind: str):
@@ -395,8 +456,10 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
     cos, sin = rope_cos_sin_for(spec)
     token_ok = pos < lengths[:, None]
     zero = jnp.zeros((b,), jnp.int32)
+    keys: list = []
 
-    def attend(h, lyr, kind, ck, cv):
+    def attend(h, lyr, kind, leaves):
+        ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         with jax.named_scope("attn.core"), _scope(kind):
             out = flash_prefill_attention(
@@ -404,16 +467,18 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
                 window=spec.sliding_window if kind == "L" else 0)
         with jax.named_scope("attn.cache_write"):
             if kind == "G":
-                return (out, tr._prefill_write(ck, k, row, None),
-                        tr._prefill_write(cv, v, row, None))
-            return (out,) + tuple(
+                return out, (tr._prefill_write(ck, k, row, None),
+                             tr._prefill_write(cv, v, row, None))
+            return out, tuple(
                 lax.dynamic_update_slice_in_dim(
                     c, ring_write(_rows_of(c, row, b), new, zero, lengths),
                     row, axis=0)
                 for c, new in ((ck, k), (cv, v)))
 
+    if spec.kv_lora_rank:
+        attend = latent.prefill_attend(spec, pos, lengths, row, keys)
     x, cache_k, cache_v = _layers(params, spec, x, cache_k, cache_v, attend,
-                                  token_ok)
+                                  token_ok, keys=keys)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return _head(params, spec, last), cache_k, cache_v
 
@@ -436,8 +501,10 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
     causal = (jnp.arange(hist)[None, :] <= pos[0][:, None])[None, None, None]
     token_ok = (jnp.arange(t) < n_valid)[None, :]
     off1, valid1 = offset[None], n_valid[None]
+    keys: list = []
 
-    def attend(h, lyr, kind, ck, cv):
+    def attend(h, lyr, kind, leaves):
+        ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         if kind == "G":
             with jax.named_scope("attn.cache_write"):
@@ -450,19 +517,23 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
                 out = attention(
                     q, lax.dynamic_slice(ck, (slot, 0, 0, 0), size),
                     lax.dynamic_slice(cv, (slot, 0, 0, 0), size), causal)
-            return out, ck, cv
+            return out, (ck, cv)
         rk, rv = _rows_of(ck, slot, 1), _rows_of(cv, slot, 1)
         with jax.named_scope("attn.core"), _scope(kind):
             out = _block_window_attn(q, k, v, rk, rv, pos,
                                      spec.sliding_window)
         with jax.named_scope("attn.cache_write"):
-            return (out,
-                    lax.dynamic_update_slice_in_dim(
-                        ck, ring_write(rk, k, off1, valid1), slot, axis=0),
-                    lax.dynamic_update_slice_in_dim(
-                        cv, ring_write(rv, v, off1, valid1), slot, axis=0))
+            return out, (
+                lax.dynamic_update_slice_in_dim(
+                    ck, ring_write(rk, k, off1, valid1), slot, axis=0),
+                lax.dynamic_update_slice_in_dim(
+                    cv, ring_write(rv, v, off1, valid1), slot, axis=0))
 
-    return _layers(params, spec, x, cache_k, cache_v, attend, token_ok)[1:]
+    if spec.kv_lora_rank:
+        attend = latent.segment_attend(spec, pos, offset, n_valid, slot,
+                                       hist, keys)
+    return _layers(params, spec, x, cache_k, cache_v, attend, token_ok,
+                   keys=keys)[1:]
 
 
 def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
@@ -478,9 +549,9 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
     hist = (history if history is not None and history < spec.max_seq
             else spec.max_seq)
 
-    def write(ck, cv, k, v, at):
-        # row by row through scalar starts, K and V in one loop (the module
-        # docstring says why; a loop a side is 0.47 ms a step slower)
+    def write(caches: tuple, news: tuple, at):
+        # row by row through scalar starts, a layer's leaves in one loop (the
+        # module docstring says why; a loop a side is 0.47 ms a step slower)
         def put(cache, new, r):
             start = (r, 0, at[r], 0)
             held = lax.dynamic_slice(cache, start, (1,) + new.shape[1:])
@@ -489,18 +560,22 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
                 cache, jnp.where(allow[r], mine, held), start)
 
         return lax.fori_loop(
-            0, b, lambda r, kv: (put(kv[0], k, r), put(kv[1], v, r)),
-            (ck, cv))
+            0, b, lambda r, kv: tuple(
+                put(c, new, r) for c, new in zip(kv, news)), caches)
 
     held = ring_positions(lengths, spec.ring)                  # [B, R]
     ring_keep = ((held >= 0) & (held > pos - spec.sliding_window)
                  )[:, None, None, None, :]
 
-    def attend(h, lyr, kind, ck, cv):
+    keys: list = []
+
+    def attend(h, lyr, kind, leaves):
+        ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         at = lengths if kind == "G" else lengths % spec.ring
         with jax.named_scope("attn.cache_write"):
-            ck, cv = write(ck, cv, k.astype(ck.dtype), v.astype(cv.dtype), at)
+            ck, cv = write((ck, cv),
+                           (k.astype(ck.dtype), v.astype(cv.dtype)), at)
         with jax.named_scope("attn.core"), _scope(kind):
             if kind == "G":
                 out = decode_attention(
@@ -508,10 +583,12 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
                     lax.slice_in_dim(cv, 0, hist, axis=2), lengths + 1)
             else:
                 out = attention(q, ck, cv, ring_keep)
-        return out, ck, cv
+        return out, (ck, cv)
 
+    if spec.kv_lora_rank:
+        attend = latent.decode_attend(spec, lengths, allow, hist, write, keys)
     return _layers(params, spec, x, cache_k, cache_v, attend,
-                   allow[:, None])
+                   allow[:, None], keys=keys)
 
 
 def decode_step(params, spec: ModelSpec, token, lengths, cache_k, cache_v,
@@ -530,8 +607,8 @@ def decode_multi(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
     """T positions per row in one forward (speculative verification). A
     window layer attends over its ring as it stood and the T new positions,
     then writes them; positions past ``max_seq`` are dropped. The experts
-    run densely, as in the one-position step whose tokens this has to
-    reproduce. As transformer.decode_multi."""
+    run in the form the one-position step of as many rows takes, whose
+    tokens this has to reproduce. As transformer.decode_multi."""
     from quorum_tpu.models import transformer as tr
 
     b, t = tokens.shape
@@ -559,8 +636,11 @@ def decode_multi(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
             (0, idx - delta, 0))
 
     write = jax.vmap(write_full)
+    ok = jnp.broadcast_to(allow[:, None], (b, t))
+    keys: list = []
 
-    def attend(h, lyr, kind, ck, cv):
+    def attend(h, lyr, kind, leaves):
+        ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, rope_pos)
         if kind == "G":
             with jax.named_scope("attn.cache_write"):
@@ -570,15 +650,18 @@ def decode_multi(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
                 out = attention(
                     q, lax.slice_in_dim(ck, 0, hist, axis=2),
                     lax.slice_in_dim(cv, 0, hist, axis=2), keep_full)
-            return out, ck, cv
+            return out, (ck, cv)
         with jax.named_scope("attn.core"), _scope(kind):
             out = _block_window_attn(q, k, v, ck, cv, pos,
                                      spec.sliding_window)
         with jax.named_scope("attn.cache_write"):
-            return (out, ring_write(ck, k, lengths, n_write),
-                    ring_write(cv, v, lengths, n_write))
+            return out, (ring_write(ck, k, lengths, n_write),
+                         ring_write(cv, v, lengths, n_write))
 
+    if spec.kv_lora_rank:
+        attend = latent.multi_attend(spec, pos, rope_pos, lengths, n_write,
+                                     ok, hist, write, keys)
     x, cache_k, cache_v = _layers(
-        params, spec, x, cache_k, cache_v, attend,
-        jnp.broadcast_to(allow[:, None], (b, t)), dense=True)
+        params, spec, x, cache_k, cache_v, attend, ok,
+        dense=dense_experts(spec, b), keys=keys)
     return _head(params, spec, x), cache_k, cache_v

@@ -402,7 +402,8 @@ def create_app(
                   "kv_pages", "kv_page_size",
                   "kv_pages_allocated", "kv_pages_free",
                   "qos", "draining", "moe_experts_held",
-                  "kv_cache_full_bytes", "kv_cache_window_bytes")
+                  "kv_cache_full_bytes", "kv_cache_window_bytes",
+                  "kv_cache_index_bytes")
         # One snapshot per distinct engine (_distinct_engines). Each
         # family's TYPE line appears exactly once, with all its samples
         # grouped — the Prometheus text format rejects repeated TYPE lines.

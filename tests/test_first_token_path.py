@@ -357,7 +357,8 @@ def test_profile_holds_engine_phases_and_returns_soon(tmp_path):
 
 # the scopes of a spec without a layer pattern (tests/test_patterned.py has
 # the patterned family's)
-SCOPES = tuple(p for p in hlo_names.PARTS if p not in hlo_names.PATTERNED)
+SCOPES = tuple(p for p in hlo_names.PARTS
+               if p not in hlo_names.PATTERNED + hlo_names.LATENT)
 
 
 def _lowered(program):

@@ -189,23 +189,24 @@ def test_decode_multi_is_the_decode_step_over_a_wrapped_ring(model32, tokens):
     assert all((np.asarray(a)[0] == 0).all() for a in ck2.window)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("preset", ["k-exaone-tiny", "dots3-tiny"])
+def test_the_shares_add_up_to_the_uncut_layer(preset):
     """The routed parts that the four shares of four experts give, plus the
-    shared expert once, are the layer with every expert held."""
-    whole = resolve_spec("k-exaone-tiny", {
-        "dtype": "float32", "experts_held": "0"})
+    shared expert once, are the layer with every expert held: in both
+    families, whose expert layer is one body."""
+    whole = resolve_spec(preset, {"dtype": "float32", "experts_held": "0"})
     lyr = patterned.layer_of(init_params(whole, 5), 2)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, whole.d_model))
     ok = jnp.ones((2, 9), bool)
     uncut, counts = patterned.moe_layer(x, lyr, whole, ok)
     picks = int(counts[whole.held + STAT["picks"]])
     assert int(counts[:whole.held].sum()) == picks  # every pick is held
-    no_shared = resolve_spec("k-exaone-tiny", {
+    no_shared = resolve_spec(preset, {
         "dtype": "float32", "n_shared_experts": "0"})
     total = tr._dense_mlp_core(x, lyr["shared"], whole)
     held_picks = 0
     for share in range(4):
-        spec = resolve_spec("k-exaone-tiny", {
+        spec = resolve_spec(preset, {
             "dtype": "float32", "n_shared_experts": "0",
             "expert_first": str(4 * share)})
         mine = dict(lyr, **{k: lyr[k][4 * share:4 * share + 4] for k in (
@@ -298,13 +299,19 @@ REFUSED = {
 }
 
 
+@pytest.mark.parametrize("preset", ["k-exaone-tiny", "dots3-tiny"])
 @pytest.mark.parametrize("option", sorted(REFUSED))
-def test_a_patterned_spec_refuses_what_does_not_compose(option):
+def test_a_patterned_spec_refuses_what_does_not_compose(option, preset):
+    """What reads the cache as a K/V rectangle stays refused: for K and V by
+    layer kind and for latent rows, index keys and rings alike."""
     from quorum_tpu.engine.engine import InferenceEngine
 
-    spec = resolve_spec("k-exaone-tiny")
+    spec = resolve_spec(preset)
+    asked = dict(REFUSED[option])
+    if "spec_decode" in asked:  # the fewest drafts the ring cannot hold
+        asked["spec_decode"] = max(4, spec.ring - spec.sliding_window)
     with pytest.raises(ValueError, match="layer_pattern spec"):
-        InferenceEngine(spec, n_slots=2, **REFUSED[option])
+        InferenceEngine(spec, n_slots=2, **asked)
 
 
 def test_the_benchmarks_comparison_runs_over_this_reference_by_name(tmp_path):
@@ -468,9 +475,10 @@ def test_the_engine_serves_it_and_counts_its_picks():
         assert 'layer="1",expert="0"' in per_expert
         kinds = eng.health()["kv_cache_bytes"]
         assert kinds == {"full": m["kv_cache_full_bytes"],
-                         "window": m["kv_cache_window_bytes"]}
+                         "window": m["kv_cache_window_bytes"],
+                         "index": m["kv_cache_index_bytes"]}
         row = 2 * 4 * spec.n_kv_heads * spec.head_dim * 2
         assert kinds == {"full": 2 * spec.max_seq * row,
-                         "window": 6 * spec.ring * row}
+                         "window": 6 * spec.ring * row, "index": 0}
     finally:
         eng.shutdown()

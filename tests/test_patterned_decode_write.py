@@ -52,7 +52,8 @@ def oracle_step_blocks(params, spec, x, lengths, cache_k, cache_v,
     ring_keep = ((held >= 0) & (held > pos - spec.sliding_window)
                  )[:, None, None, None, :]
 
-    def attend(h, lyr, kind, ck, cv):
+    def attend(h, lyr, kind, leaves):
+        ck, cv = leaves
         q, k, v = patterned._qkv(h, lyr, spec, kind, cos, sin, pos)
         at = lengths if kind == "G" else lengths % spec.ring
         with jax.named_scope("attn.cache_write"):
@@ -65,7 +66,7 @@ def oracle_step_blocks(params, spec, x, lengths, cache_k, cache_v,
                     lax.slice_in_dim(cv, 0, hist, axis=2), lengths + 1)
             else:
                 out = patterned.attention(q, ck, cv, ring_keep)
-        return out, ck, cv
+        return out, (ck, cv)
 
     return patterned._layers(params, spec, x, cache_k, cache_v, attend,
                              allow[:, None])
