@@ -539,7 +539,11 @@ def kernel_child(mode: str) -> None:
     from quorum_tpu.models.model_config import resolve_spec
     from quorum_tpu.ops.attention import decode_attention, prefill_attention
     from quorum_tpu.ops.flash_attention import DEFAULT_BLOCK_Q, _flash_call
-    from quorum_tpu.ops.flash_decode import DEFAULT_BLOCK_K, _decode_call
+    from quorum_tpu.ops.flash_decode import (
+        _decode_call,
+        cache_decode_attention,
+        decode_tile,
+    )
 
     rehearsal = mode == "rehearsal"
     dev = jax.devices()[0]
@@ -595,24 +599,38 @@ def kernel_child(mode: str) -> None:
                 valid=length)
 
     def decode_case(case, h, kv, hd, t, window, slots=4, members=0):
+        # the carried leaves as the engine holds them: two layers of
+        # [slots, max_seq, K*hd], the second one read
         lead = (members,) if members else ()
         q = rand(4, lead + (slots, h, 1, hd))
-        k = rand(5, lead + (slots, kv, t, hd))
-        v = rand(6, lead + (slots, kv, t, hd))
+        k = rand(5, lead + (2, slots, t, kv * hd))
+        v = rand(6, lead + (2, slots, t, kv * hd))
         # skewed rows: near-empty, mid, full, short
         lengths = jnp.asarray([1, t // 2 - 3, t, 7][:slots], jnp.int32)
-        block = min(DEFAULT_BLOCK_K, t)
+        live = jnp.ones((slots,), bool)
 
         def kernel(q, k, v):
-            return _decode_call(q, k, v, lengths, block_k=block,
-                                interpret=interpret, window=window)
+            return _decode_call(q, k, v, jnp.int32(1), lengths, history=t,
+                                tile=decode_tile(t), window=window,
+                                interpret=interpret)
+
+        def k_major(leaf):
+            return leaf[1].reshape(slots, t, kv, hd).transpose(0, 2, 1, 3)
 
         def ref(q, k, v):
-            return decode_attention(q, k, v, lengths, window=window)
+            return decode_attention(q, k_major(k), k_major(v), lengths,
+                                    window=window)
 
         if members:
+            # the stacked quorum vmaps the step: the call's own batching
+            # rule reads the stacked store through XLA's einsums
+            def kernel(q, k, v):
+                return cache_decode_attention(
+                    q, k, v, jnp.int32(1), lengths, live, history=t,
+                    window=window)
+
             kernel, ref = jax.vmap(kernel), jax.vmap(ref)
-        compare(case, kernel(q, k, v), jax.jit(ref)(q, k, v))
+        compare(case, jax.jit(kernel)(q, k, v), jax.jit(ref)(q, k, v))
 
     for leg, members in (("quorum", QUORUM_MEMBERS), ("full_width", 0)):
         url = BackendSpec(name=leg, url="tpu://" + MODELS[mode][leg])

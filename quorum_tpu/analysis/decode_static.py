@@ -19,13 +19,19 @@ same text for the layer part of each::
         k-exaone-236b-a23b \
         'n_layers=8&experts_held=16&vocab_size=19200&max_seq=4096&slots=32'
 
-prints ``temp``, then one line per operation of a loop body whose result is
+prints ``temp``, then one line per operation of the program whose result is
 a cache side or one layer's slab of it (for a spec with a ``layer_pattern``:
-a full-attention layer's side or a ring, :func:`cache_sizes`): computation,
-operation, opcode, shape, ``op_name``, and ``WHOLE-CACHE MOVE`` on those
-:func:`whole_cache_moves` lists. Nothing runs, so this gives no time; the
-scan's program does not depend on depth, and the full-depth int8 member
-compiles in some ten seconds.
+a full-attention layer's side or a ring, :func:`cache_sizes`) and per Pallas
+call (``tpu_custom_call``) of a loop body: computation, operation, opcode,
+shape, ``op_name``, then ``WHOLE-CACHE MOVE`` on those
+:func:`whole_cache_moves` lists (a copy or allocation of a whole side, in the
+step loop or at the chunk's entry and exit) and ``SLAB MOVE`` on a ``copy``,
+``reshape`` or ``dynamic-slice`` of one layer's slab (:func:`slab_moves`: what
+a read that re-lays its history window leaves, and a kernel handed a layout
+it cannot take: that one's ``op_name`` was ``attn.cache_write/scatter``). An
+in-place update of the carry has the cache's shape and no mark. Nothing runs,
+so this gives no time; the scan's program does not depend on depth, and the
+full-depth int8 member compiles in some ten seconds.
 """
 
 from __future__ import annotations
@@ -43,6 +49,8 @@ _CALLED = re.compile(
     r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
 _MOVES = ("copy", "copy-done", "AllocateBuffer")
+_SLAB_MOVES = ("copy", "copy-done", "reshape", "dynamic-slice")
+_KERNEL = "tpu_custom_call"
 _NO_DEVICE_OP = ("get-tuple-element", "bitcast", "parameter", "tuple")
 
 
@@ -134,14 +142,10 @@ def _computations(text: str) -> "dict[str, list[str]]":
     return out
 
 
-def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
-    """``(computation, operation, opcode, shape, op_name)`` for every
-    instruction whose result has one of ``sizes`` elements in a ``while``
-    body of an optimized module's text, or in a computation such a body
-    calls (a nested loop, a branch): what runs once a decode step, or once a
-    layer of a step. Fused computations are left out: their instructions are
-    no device operations of their own."""
-    comps = _computations(text)
+def _loop_computations(comps: "dict[str, list[str]]") -> "set[str]":
+    """The ``while`` bodies of a module and the computations they call (a
+    nested loop, a branch): what runs once a decode step, or once a layer
+    of a step."""
     bodies = {m.group(1) for lines in comps.values() for line in lines
               for m in re.finditer(r"body=%?([\w.\-]+)", line)}
     reached, todo = set(), sorted(bodies)
@@ -153,18 +157,33 @@ def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
         for line in comps[name]:
             for one, many in _CALLED.findall(line):
                 todo += [one] if one else re.findall(r"[\w.\-]+", many)
+    return reached
+
+
+def program_ops(text: str, sizes: "set[int]", *,
+                loops_only: bool = False) -> "list[tuple]":
+    """``(computation, operation, opcode, shape, op_name)`` for every
+    instruction of an optimized module's text whose result has one of
+    ``sizes`` elements, and for every Pallas call (``tpu_custom_call``)
+    whatever its size; ``loops_only`` keeps the ``while`` bodies and what
+    they call. Fused computations are left out: their instructions are no
+    device operations of their own."""
+    comps = _computations(text)
+    names = _loop_computations(comps) if loops_only else {
+        name for name in comps if "fused_computation" not in name}
     out = []
-    for name in sorted(reached):
+    for name in sorted(names):
         for line in comps[name]:
             found, shape = _INSTRUCTION.match(line), _SHAPE.search(line)
             if not (found and shape and shape.group(2)):
                 continue
             dims = [int(d) for d in shape.group(2).split(",")]
             opcode = found.group(2)
-            if math.prod(dims) in sizes and opcode not in _NO_DEVICE_OP:
-                if opcode == "custom-call":
-                    target = re.search(r'custom_call_target="([^"]*)"', line)
-                    opcode = target.group(1) if target else opcode
+            if opcode == "custom-call":
+                target = re.search(r'custom_call_target="([^"]*)"', line)
+                opcode = target.group(1) if target else opcode
+            if opcode == _KERNEL or (math.prod(dims) in sizes
+                                     and opcode not in _NO_DEVICE_OP):
                 op_name = _OP_NAME.search(line)
                 out.append((name, found.group(1), opcode,
                             f"{shape.group(1)}[{shape.group(2)}]",
@@ -172,14 +191,41 @@ def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
     return out
 
 
-def whole_cache_moves(text: str, cache_elements: int) -> "list[tuple]":
-    """The rows of :func:`loop_body_ops` that allocate or copy an array as
-    large as a whole cache side inside the step loop: a ``copy``, a fusion
-    the compiler named for its copy, an ``AllocateBuffer``. An in-place
+def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
+    """:func:`program_ops` of the loop bodies alone."""
+    return program_ops(text, sizes, loops_only=True)
+
+
+def _moves(rows: "list[tuple]", opcodes: tuple) -> "list[tuple]":
+    # by opcode, or a fusion the compiler named for what it does
+    return [row for row in rows if row[2] in opcodes or (
+        row[2] == "fusion" and any(op in row[1] for op in opcodes))]
+
+
+def whole_cache_moves(text: str, cache_elements: int, *,
+                      loops_only: bool = True) -> "list[tuple]":
+    """The rows of :func:`program_ops` that allocate or copy an array as
+    large as a whole cache side: a ``copy``, a fusion the compiler named for
+    its copy, an ``AllocateBuffer``; in the step loop, or with
+    ``loops_only=False`` at the chunk's entry and exit too. An in-place
     update of the carried cache (a scatter, a dynamic-update-slice fusion)
     has the cache's shape too and moves only what it writes: not listed."""
-    return [row for row in loop_body_ops(text, {cache_elements})
-            if row[2] in _MOVES or (row[2] == "fusion" and "copy" in row[1])]
+    return _moves(program_ops(text, {cache_elements}, loops_only=loops_only),
+                  _MOVES)
+
+
+def slab_moves(text: str, slab_elements: int) -> "list[tuple]":
+    """The rows of :func:`program_ops` that copy, reshape or slice out an
+    array as large as one layer's slab of a cache side, anywhere in the
+    program: a read that moves its history window before it contracts it."""
+    return _moves(program_ops(text, {slab_elements}),
+                  _SLAB_MOVES + ("AllocateBuffer",))
+
+
+def kernel_calls(text: str) -> "list[tuple]":
+    """The Pallas calls (``tpu_custom_call``) of the loop bodies."""
+    return [row for row in program_ops(text, set(), loops_only=True)
+            if row[2] == _KERNEL]
 
 
 def cache_sizes(spec, rows: int, members: int = 1) -> "tuple[tuple, tuple]":
@@ -211,9 +257,12 @@ def main(argv: "list[str]") -> int:
     text = compiled.as_text()
     carried, slabs = cache_sizes(spec, rows, members)
     print(f"temp\t{compiled.memory_analysis().temp_size_in_bytes / 1e9:.4f} GB")
-    moves = [row for size in carried for row in whole_cache_moves(text, size)]
-    for row in loop_body_ops(text, set(carried + slabs)):
-        print(*row, "WHOLE-CACHE MOVE" if row in moves else "", sep="\t")
+    whole = [row for size in carried
+             for row in whole_cache_moves(text, size, loops_only=False)]
+    slab = [row for size in slabs for row in slab_moves(text, size)]
+    for row in program_ops(text, set(carried + slabs)):
+        print(*row, "WHOLE-CACHE MOVE" if row in whole
+              else "SLAB MOVE" if row in slab else "", sep="\t")
     return 0
 
 

@@ -97,15 +97,6 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    Cancel/stop-string finishes may waste up to C-1 chunks
                    (counted in overrun_tokens_total). Structural like
                    decode_pipeline
-  flash_decode=    per-backend Pallas flash-decode gate: 1 enables the
-                   per-row-exact decode-attention kernel on TPU, 0 (the
-                   default) keeps the masked-dense path, "interpret" runs
-                   the kernel under the Pallas interpreter (CPU tests
-                   only). Validated at config time; the process-wide
-                   QUORUM_TPU_FLASH_DECODE env var stays as an override
-                   (the on-chip A/B scripts flip it without editing
-                   config). Part of the engine cache key, so two backends
-                   can A/B the kernel inside one process (PERF.md §5)
   slots=           concurrent batch width of the engine's KV cache (default 4;
                    applies when this backend constructs the engine — backends
                    sharing an engine share its slot count)
@@ -262,7 +253,6 @@ from quorum_tpu.engine.tokenizer import get_tokenizer
 from quorum_tpu.models.model_config import resolve_spec
 from quorum_tpu.observability import current_trace, trace_span
 from quorum_tpu.telemetry.recorder import RECORDER
-from quorum_tpu.ops.flash_decode import parse_flash_decode
 from quorum_tpu.ops.sampling import SamplerConfig
 from quorum_tpu.parallel.mesh import MeshConfig, make_mesh, single_device_mesh
 
@@ -591,11 +581,6 @@ class TpuBackend:
             decode_pipeline=int(
                 opts.get("decode_pipeline", DEFAULT_DECODE_PIPELINE)),
             decode_loop=int(opts.get("decode_loop", DEFAULT_DECODE_LOOP)),
-            # Validated at config time (a typo must fail the URL, not
-            # silently run masked-dense); the engine re-resolves against
-            # the QUORUM_TPU_FLASH_DECODE env override.
-            flash_decode=parse_flash_decode(opts["flash_decode"])
-            if "flash_decode" in opts else None,
             prefill_chunk=int(opts.get("prefill_chunk", DEFAULT_PREFILL_CHUNK)),
             max_pending=int(opts.get("queue", DEFAULT_MAX_PENDING)),
             # spec_model implies speculation: default g=4 when the knob

@@ -20,11 +20,13 @@ the generalization both paths share:
     fallback when the direct put is rejected, recording bytes and seconds
     on the ``quorum_tpu_kv_handoff_*`` families either way.
 
-Layout convention (matches the engine's slot cache): non-stacked leaves are
-``[L, S, K, T, …]`` (slot axis 1, position axis 3); stacked leaves carry a
-leading member axis ``[M, L, S, K, T, …]``. Sliced chunks drop the slot (and
-member) axis: ``[L, K, n, …]`` — the one wire format snapshot, restore, and
-handoff all speak.
+Layout convention (matches the engine's dense slot cache, models/transformer.py
+``init_cache``): non-stacked leaves are ``[L, S, T, K·hd]`` values and, for an
+int8 side, ``[L, S, T, K]`` scales (slot axis 1, position axis 2, a position's
+heads one line); stacked leaves carry a leading member axis ``[M, L, S, T, …]``.
+Sliced chunks drop the slot (and member) axis and are K-major: ``[L, K, n, hd]``
+values, ``[L, K, n]`` scales — the one wire format snapshot, restore, and
+handoff all speak (host stores and peers hold it), transposed at the wire.
 """
 
 from __future__ import annotations
@@ -33,6 +35,7 @@ import logging
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
@@ -52,10 +55,12 @@ def _any_paged(cache) -> bool:
                 and any(kv_is_paged(c) for c in cache)))
 
 
-def slice_rows(cache, row, start, n: int, *, stacked: bool, n_slots: int):
+def slice_rows(cache, row, start, n: int, *, stacked: bool, n_slots: int,
+               n_kv_heads: int):
     """Slice ``n`` cache positions of flat row ``row`` starting at ``start``
     out of a cache pytree (pure; call under jit). Returns the chunk pytree
-    in the ``[L, K, n, …]`` wire layout. Non-donating by design — snapshot
+    in the ``[L, K, n, …]`` wire layout (``n_kv_heads`` splits a dense
+    side's lines into their heads). Non-donating by design — snapshot
     and handoff both READ a live cache. Paged caches (``PagedKV`` sides)
     gather through the page table into the SAME wire layout, so every
     consumer — snapshot, restore, handoff — is layout-blind."""
@@ -70,12 +75,17 @@ def slice_rows(cache, row, start, n: int, *, stacked: bool, n_slots: int):
     def take(a):
         if stacked:
             m, s = row // n_slots, row % n_slots
-            starts = (m, 0, s, 0, start) + (0,) * (a.ndim - 5)
-            sizes = ((1, a.shape[1], 1, a.shape[3], n) + tuple(a.shape[5:]))
-            return lax.dynamic_slice(a, starts, sizes)[0][:, 0]
-        starts = (0, row, 0, start) + (0,) * (a.ndim - 4)
-        sizes = (a.shape[0], 1, a.shape[2], n) + tuple(a.shape[4:])
-        return lax.dynamic_slice(a, starts, sizes)[:, 0]
+            lines = lax.dynamic_slice(
+                a, (m, 0, s, start, 0), (1, a.shape[1], 1, n, a.shape[4])
+            )[0][:, 0]
+        else:
+            lines = lax.dynamic_slice(
+                a, (0, row, start, 0), (a.shape[0], 1, n, a.shape[3]))[:, 0]
+        if lines.shape[2] == n_kv_heads:  # an int8 side's scales [L, n, K]
+            return lines.transpose(0, 2, 1)
+        # [L, n, K·hd] → [L, K, n, hd]
+        return lines.reshape(lines.shape[:2] + (n_kv_heads, -1)).transpose(
+            0, 2, 1, 3)
 
     return jax.tree.map(take, cache)
 
@@ -95,13 +105,14 @@ def write_rows(cache, chunk, row, start, *, stacked: bool, n_slots: int):
         return tuple(put_paged(c, h) for c, h in zip(cache, chunk))
 
     def put(a, h):
+        # [L, K, n, hd] → [L, n, K·hd]; scales [L, K, n] → [L, n, K]
+        lines = jnp.moveaxis(h, 1, 2).reshape(
+            h.shape[0], h.shape[2], -1).astype(a.dtype)
         if stacked:
             m, s = row // n_slots, row % n_slots
-            starts = (m, 0, s, 0, start) + (0,) * (a.ndim - 5)
             return lax.dynamic_update_slice(
-                a, h[None, :, None].astype(a.dtype), starts)
-        starts = (0, row, 0, start) + (0,) * (a.ndim - 4)
-        return lax.dynamic_update_slice(a, h[:, None].astype(a.dtype), starts)
+                a, lines[None, :, None], (m, 0, s, start, 0))
+        return lax.dynamic_update_slice(a, lines[:, None], (0, row, start, 0))
 
     return jax.tree.map(put, cache, chunk)
 

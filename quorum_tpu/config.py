@@ -15,8 +15,7 @@ quorum_tpu extends ``primary_backends[].url`` with a ``tpu://`` scheme:
 
 Query parameters configure the model (see :mod:`quorum_tpu.models.registry`)
 and the serving engine (``decode_chunk=``, ``decode_pipeline=``,
-``decode_loop=`` for megachunk decode, ``flash_decode=`` for the Pallas
-decode kernel, ``slots=``,
+``decode_loop=`` for megachunk decode, ``slots=``,
 ``quant=``, ``prefix_store=host``/``prefix_store_bytes=``/
 ``prefix_store_chunk=`` for the tiered host KV prefix store,
 ``disagg=P+D`` for disaggregated prefill/decode device groups with

@@ -5,7 +5,7 @@ VERDICT.md weakness 4 — the round-1 engine allocated a fresh KV cache on the
 host per request and held a lock for the whole generation, fully serializing
 concurrent requests):
 
-  - **Slot-batched KV cache, allocated once**: ``[L, n_slots, K, max_seq, hd]``
+  - **Slot-batched KV cache, allocated once**: ``[L, n_slots, max_seq, K·hd]``
     × 2 lives on device for the engine's lifetime and is donated through every
     compiled call — no per-request host zeros, no 1 GB device_put per request.
   - **Continuous batching**: a scheduler thread admits requests into free
@@ -152,7 +152,11 @@ from quorum_tpu.models.transformer import (
     prefill_segment,
 )
 from quorum_tpu.ops.flash_attention import tracing_program
-from quorum_tpu.ops.flash_decode import resolve_flash_decode
+from quorum_tpu.ops.flash_decode import (
+    decode_tile,
+    kernel_refusal,
+    live_tiles,
+)
 from quorum_tpu.ops.sampling import (
     SamplerConfig,
     apply_token_mask,
@@ -755,12 +759,11 @@ class _DraftRuntime:
     BITE = 16  # max tokens per advance program (T buckets: powers of two ≤ 16)
 
     def __init__(self, spec: ModelSpec, target_spec: ModelSpec, rows: int,
-                 seed: int = 0, params=None, flash: str | None = None):
-        # The owning engine's resolved flash-decode gate: the draft's own
-        # decode steps must run the same attention kernel as the target's
-        # (a flash_decode=1 backend with speculation on would otherwise
-        # silently measure a mixed-kernel arm in the PERF.md §5 A/B).
-        self.flash = flash
+                 seed: int = 0, params=None, sharded: bool = False):
+        # Whether the owning engine's programs are partitioned over devices
+        # (the fused spec loop runs the draft's decode steps inside one):
+        # decode_step's ``sharded``.
+        self.sharded = sharded
         if spec.vocab_size != target_spec.vocab_size:
             raise ValueError(
                 f"draft model vocab {spec.vocab_size} != target vocab "
@@ -820,7 +823,7 @@ class _DraftRuntime:
                     logits, ck, cv = decode_step(
                         params, self.spec, tok, lens, ck, cv,
                         write_mask=wmask, history=history,
-                        flash=self.flash)
+                        sharded=self.sharded)
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     return (nxt, lens + 1, ck, cv), nxt
 
@@ -1076,7 +1079,6 @@ class InferenceEngine:
         decode_chunk: int = 8,
         decode_pipeline: int = DEFAULT_DECODE_PIPELINE,
         decode_loop: int = DEFAULT_DECODE_LOOP,
-        flash_decode: str | None = None,
         params=None,
         n_slots: int = DEFAULT_SLOTS,
         prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
@@ -1163,10 +1165,6 @@ class InferenceEngine:
         # (48, 24, 12, 6, 3 beside the budget cap's 2..32), each a full
         # XLA compile at 7B scale.
         self.decode_loop = 1 << (int(decode_loop).bit_length() - 1)
-        # Per-backend flash-decode gate, resolved ONCE (programs are cached
-        # per engine; QUORUM_TPU_FLASH_DECODE stays a process override —
-        # ops/flash_decode.resolve_flash_decode). "" = masked-dense.
-        self._flash = resolve_flash_decode(flash_decode)
         # Runtime sync sentinel (docs/static_analysis.md): when set, the
         # decode loop (_run_chunk — dispatch, reap, spec-verify) runs under
         # jax.transfer_guard(mode), so an implicit host<->device transfer
@@ -1187,8 +1185,7 @@ class InferenceEngine:
             tg = transfer_guard
         else:
             # Env knob: an unparseable value is a LOGGED loud off, never a
-            # construction crash (the QUORUM_TPU_FLASH_DECODE convention —
-            # an env typo must not take serving down).
+            # construction crash (an env typo must not take serving down).
             tg = os.environ.get("QUORUM_TPU_TRANSFER_GUARD", "")
             if tg and tg not in levels:
                 logger.error(
@@ -1237,16 +1234,11 @@ class InferenceEngine:
         # the Pallas kernel runs per tp shard (ops/flash_attention.py).
         self._tp_mesh = (
             self.mesh if dict(self.mesh.shape).get(AXIS_TP, 1) > 1 else None)
-        if self._flash == "tpu" and self._tp_mesh is not None:
-            # The opt-in decode kernel has no such wrapper: under tp>1 XLA
-            # refuses the unpartitionable Mosaic call, so the engine serves
-            # masked-dense decode and says so.
-            logger.info(
-                "attention-path program=decode kernel=flash_decode path=xla "
-                "reason=flash_decode=1 does not run under tp=%d (Mosaic "
-                "kernels cannot be auto-partitioned)",
-                self._tp_mesh.shape[AXIS_TP])
-            self._flash = ""
+        # The decode step's Pallas read has no such wrapper: in a program
+        # GSPMD partitions over devices XLA refuses the unpartitionable
+        # Mosaic call, so decode_step is told and reads the cache through
+        # XLA's einsums (ops/flash_decode.py says so per traced program).
+        self._sharded = self.mesh.size > 1
         # Prefill-group sequence parallelism (disagg=P+D&sp=S): the STAGING
         # cache shards its position axis over the prefill mesh's sp axis —
         # a 100k-token admission's staged KV occupies O(max_seq/sp) HBM per
@@ -1393,7 +1385,7 @@ class InferenceEngine:
         self.quorum_dedup_tokens = 0
         self.quorum_dedup_prefills = 0
         # Paged KV slot memory (tpu://…&kv_pages=1, docs/tpu_backends.md):
-        # the dense [L, n_slots, K, max_seq, hd] rectangle becomes a page
+        # the dense [L, n_slots, max_seq, K·hd] rectangle becomes a page
         # pool [L, P, K, page_size, hd] plus a per-row on-device page table
         # — rows allocate pages only as they grow, so slot count is no
         # longer pinned by the worst-case sequence, and tier-0 prefix reuse
@@ -1473,7 +1465,7 @@ class InferenceEngine:
         if self.spec.layer_pattern:
             # A spec with a layer pattern keeps a cache per layer kind
             # (models/patterned.py): what reads or writes the cache as one
-            # [L, slots, K, max_seq, hd] rectangle, or runs the layers as one
+            # [L, slots, max_seq, K·hd] rectangle, or runs the layers as one
             # stack, does not compose with it yet (ROADMAP.md).
             mesh_shape = dict(self.mesh.shape)
             refused = [
@@ -1757,6 +1749,13 @@ class InferenceEngine:
         # ring-resident verify made both first-class ring entries, so this
         # is dispatches/request's denominator across spec on/off arms).
         self.n_decode_chunks = 0
+        # How far the dense decode step's read of the cache follows the
+        # rows: tiles of ops/flash_decode.DECODE_TILE positions a decode
+        # chunk's steps fetch of the history window (each live row to its own
+        # length where the Pallas read runs, every row to the bucket where
+        # XLA's einsums do), and what reading every row to the bucket counts.
+        self.n_kv_tiles_read = 0
+        self.n_kv_tiles_bucket = 0
         self._moe_total = None  # a patterned spec's expert counters
         # The backends' producer threads, where the default pool is too
         # small for a backend's slots (tpu_backend._stream_pool).
@@ -1823,7 +1822,7 @@ class InferenceEngine:
                     "off — drop the draft knob instead)")
             self._draft_rt = _DraftRuntime(
                 draft_spec, self.spec, self._rows, seed=draft_seed,
-                params=draft_params, flash=self._flash)
+                params=draft_params, sharded=self._sharded)
         else:
             self._draft_rt = None
         self._stop = False
@@ -1932,10 +1931,10 @@ class InferenceEngine:
         sh = kv_cache_sharding(mesh, self.spec.n_kv_heads,
                                batch=self.n_slots, seq_shard=seq_shard)
         if self.kv_quant:
-            # (values, scales): the scale array drops the head_dim axis.
-            sh = (sh, NamedSharding(mesh, P(*tuple(sh.spec)[:4])))
+            # (values [.., K·hd], scales [.., K]): the same axes
+            sh = (sh, sh)
         if self.members > 1:
-            # member-stacked cache [M, L, S, K, T, hd]: member axis
+            # member-stacked cache [M, L, S, T, K·hd]: member axis
             # vmapped, never sharded
             sh = jax.tree.map(
                 lambda s: NamedSharding(mesh, P(*((None,) + tuple(s.spec)))),
@@ -2459,7 +2458,7 @@ class InferenceEngine:
         docs/quorum.md): a full quorum group carries the SAME prompt and
         (``member_seeds=shared``) the same weights, so member 0's K/V IS
         every member's K/V. The prompt prefills ONCE — unvmapped, into a
-        ``[L, 1, K, bucket, hd]`` scratch mini-cache; prefill's attention
+        ``[L, 1, bucket, K·hd]`` scratch mini-cache; prefill's attention
         runs on the in-flight q/k/v and only *writes* the cache, so the
         scratch costs one bucket of HBM, not a slot copy — and the result
         broadcasts into all M stacked rows of the shared slot: one
@@ -2491,7 +2490,7 @@ class InferenceEngine:
             # dedup route only fires on full live groups) — unused.
             del enables
             p0 = jax.tree.map(lambda x: x[0], params)
-            mini = jnp.zeros((ell, 1, kv, bucket, hd), dt)
+            mini = jnp.zeros((ell, 1, bucket, kv * hd), dt)
             with tracing_program(f"admit_dedup/{bucket}"):
                 logits, mini_k, mini_v = prefill(
                     p0, spec, tokens[0], lengths[0], mini, mini,
@@ -2502,11 +2501,11 @@ class InferenceEngine:
                 pad = hp * ps - bucket
 
                 def bcast(pkv, mini_c):
-                    r = mini_c[:, 0]                   # [L, K, bucket, hd]
+                    r = mini_c[:, 0]                   # [L, bucket, K·hd]
                     if pad:
-                        r = jnp.pad(r, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                    r = r.reshape(ell, kv, hp, ps, hd).transpose(
-                        0, 2, 1, 3, 4)                 # [L, hp, K, ps, hd]
+                        r = jnp.pad(r, ((0, 0), (0, pad), (0, 0)))
+                    r = r.reshape(ell, hp, ps, kv, hd).transpose(
+                        0, 1, 3, 2, 4)                 # [L, hp, K, ps, hd]
                     # Chain ids live in every (member, layer) table copy
                     # identically; entries past the claimed chain are the
                     # zero sink, which collects the bucket's padded tail
@@ -2522,9 +2521,9 @@ class InferenceEngine:
                 def bcast(cache, mini_c):
                     upd = jnp.broadcast_to(
                         mini_c[None].astype(cache.dtype),
-                        (mem, ell, 1, kv, bucket, hd))
+                        (mem,) + mini_c.shape)
                     return lax.dynamic_update_slice(
-                        cache, upd, (0, 0, slot, 0, 0, 0))
+                        cache, upd, (0, 0, slot, 0, 0))
 
             ck = bcast(ck, mini_k)
             cv = bcast(cv, mini_v)
@@ -2666,8 +2665,8 @@ class InferenceEngine:
         fn = self._admit_cache.get(("snap", n))
         if fn is None:
             fn = jax.jit(lambda ck, cv, slot, offset: kv_transfer.slice_rows(
-                (ck, cv), slot, offset, n,
-                stacked=False, n_slots=self.n_slots))
+                (ck, cv), slot, offset, n, stacked=False,
+                n_slots=self.n_slots, n_kv_heads=self.spec.n_kv_heads))
             self._admit_cache[("snap", n)] = fn
         return fn
 
@@ -2965,7 +2964,8 @@ class InferenceEngine:
             n_s = self.n_slots
 
             fn = jax.jit(lambda ck, cv, row, start: kv_transfer.slice_rows(
-                (ck, cv), row, start, n, stacked=stacked, n_slots=n_s))
+                (ck, cv), row, start, n, stacked=stacked, n_slots=n_s,
+                n_kv_heads=self.spec.n_kv_heads))
             self._admit_cache[("hslice", n)] = fn
         return fn
 
@@ -3456,7 +3456,7 @@ class InferenceEngine:
         if fn is not None:
             return fn
         spec = self.spec
-        flash = self._flash
+        sharded = self._sharded
 
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
         n_rows = self._rows
@@ -3484,13 +3484,13 @@ class InferenceEngine:
                         mem, n_s,
                         lambda p, k, v, t, ps, w: decode_step(
                             p, spec, t, ps, k, v, write_mask=w,
-                            history=history, flash=flash),
+                            history=history, sharded=sharded),
                         params, ck, cv, tok, pos, wm)
             else:
                 def model_call(ck, cv, tok, pos, wm):
                     return decode_step(
                         params, spec, tok, pos, ck, cv, write_mask=wm,
-                        history=history, flash=flash)
+                        history=history, sharded=sharded)
 
             def sample_fn(logits, live, carry):
                 if constrained:
@@ -3925,7 +3925,7 @@ class InferenceEngine:
         if fn is not None:
             return fn
         dspec = self._draft_rt.spec
-        dflash = self._draft_rt.flash
+        sharded = self._sharded
         vocab = self.spec.vocab_size
         n_rows = self._rows
         core = self._verify_core(g, history, want_lp, constrained)
@@ -3991,7 +3991,8 @@ class InferenceEngine:
                         tok, dlen, dck, dcv, st = carry2
                         lgs, dck, dcv = decode_step(
                             dparams, dspec, tok, dlen, dck, dcv,
-                            write_mask=live, history=history, flash=dflash)
+                            write_mask=live, history=history,
+                            sharded=sharded)
                         st = dfa_adv(st, tok) if constrained else st
                         nxt = pick(lgs, st)
                         return (nxt, dlen + 1, dck, dcv, st), nxt
@@ -4459,6 +4460,8 @@ class InferenceEngine:
                 "spec_overlapped_total": self.n_spec_overlapped,
                 "decode_chunks_total": self.n_decode_chunks,
                 "decode_busy_rows_total": self.n_decode_rows,
+                "decode_kv_tiles_read_total": self.n_kv_tiles_read,
+                "decode_kv_tiles_bucket_total": self.n_kv_tiles_bucket,
                 "prefix_hits_total": self.prefix_hits,
                 "prefix_tokens_saved_total": self.prefix_tokens_saved,
                 "prefix_store_hits_total": self.prefix_store_hits,
@@ -6414,6 +6417,7 @@ class InferenceEngine:
             t0 = time.perf_counter()
             payload = self._dispatch_chunk(mask, n_steps, want_lp, history,
                                            constrained, n_chunks)
+            self._count_kv_tiles(active, ahead, history, n_steps * n_chunks)
             fam = self._family_of(key)
             seq = self._next_seq()
             self._inflight.append(
@@ -6431,6 +6435,41 @@ class InferenceEngine:
             if depth > 0:
                 self.n_overlapped += 1
             obs.PIPELINE_DEPTH.set(len(self._inflight))
+
+    def _count_kv_tiles(self, active, ahead: int, history: int,
+                        steps: int) -> None:
+        """Count what a dispatched decode chunk's ``steps`` steps read of
+        the dense cache's history window, in tiles, from the lengths and the
+        bucket the host holds (planned lengths: rows that finish on the
+        device stop short of them)."""
+        if self.spec.layer_pattern:
+            return
+        tile = decode_tile(history)
+        bucket = self._rows * (history // tile) * steps
+        self.n_kv_tiles_bucket += bucket
+        if not self._kernel_reads(history):
+            self.n_kv_tiles_read += bucket
+            return
+        # entries at the chunk's first step, its own token included (an
+        # admitted row's first token sits at position len(prompt)), then one
+        # more a step, up to the bucket
+        first_step = np.fromiter(
+            (len(r.prompt_ids) + r.emitted + ahead for _, r in active),
+            np.int64, len(active))
+        entries = np.minimum(first_step[:, None] + np.arange(steps), history)
+        self.n_kv_tiles_read += int(
+            live_tiles(entries, tile, self.spec.sliding_window).sum())
+
+    def _kernel_reads(self, history: int) -> bool:
+        """Whether this engine's decode chunk at ``history`` reads the cache
+        through the Pallas call: ops/flash_decode's own rule, from what the
+        engine holds (the device's platform, the members, the leaf)."""
+        if (self.members > 1 or self.kv_pages
+                or self.mesh.devices.flat[0].platform != "tpu"):
+            return False
+        spec = self.spec
+        return not kernel_refusal((self._rows, spec.n_heads, 1, spec.head_dim),
+                                  self._ck, history, sharded=self._sharded)
 
     def _try_spec_dispatch(self, active, g: int, ahead: int,
                            depth: int) -> str:
@@ -7139,7 +7178,6 @@ def get_engine(
     seed: int = 0,
     decode_pipeline: int = DEFAULT_DECODE_PIPELINE,
     decode_loop: int = DEFAULT_DECODE_LOOP,
-    flash_decode: str | None = None,
     n_slots: int = DEFAULT_SLOTS,
     prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
     max_pending: int = DEFAULT_MAX_PENDING,
@@ -7165,11 +7203,8 @@ def get_engine(
     quorum_dedup: bool = False,
 ) -> InferenceEngine:
     """Engines are keyed by weight identity (spec, seed, mesh, quant,
-    members, draft model) plus the cache representation (kv_quant)
-    and the flash-decode gate (flash_decode — it selects which attention
-    programs compile, and the PERF.md §5 A/B needs two backends in one
-    process to genuinely run different kernels) — dispatch knobs like
-    decode_chunk are per-call, so two backends that differ
+    members, draft model) plus the cache representation (kv_quant) —
+    dispatch knobs like decode_chunk are per-call, so two backends that differ
     only in chunking share one set of weights on device. ``n_slots``/
     ``prefill_chunk``/``max_pending``/``decode_pipeline``/``decode_loop``/
     ``prefix_store*``
@@ -7199,7 +7234,6 @@ def get_engine(
     key = (spec, seed, quant or None,
            max(1, int(members)), kv_quant or None,
            draft_spec, draft_seed, draft_ckpt, sp_key,
-           resolve_flash_decode(flash_decode),
            tuple(sorted(mesh.shape.items())),
            tuple(map(str, mesh.devices.flat)),
            # disagg is structural: the prefill group carries a second
@@ -7236,7 +7270,7 @@ def get_engine(
             eng = InferenceEngine(
                 spec, mesh, seed=seed, n_slots=n_slots,
                 decode_pipeline=decode_pipeline,
-                decode_loop=decode_loop, flash_decode=flash_decode,
+                decode_loop=decode_loop,
                 prefill_chunk=prefill_chunk, max_pending=max_pending,
                 spec_decode=spec_decode, quant=quant,
                 prefix_cache=prefix_cache, prefix_store=prefix_store,
@@ -7266,7 +7300,6 @@ def get_engine_from_ckpt(
     dtype: str | None = None,
     decode_pipeline: int = DEFAULT_DECODE_PIPELINE,
     decode_loop: int = DEFAULT_DECODE_LOOP,
-    flash_decode: str | None = None,
     n_slots: int = DEFAULT_SLOTS,
     prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
     max_pending: int = DEFAULT_MAX_PENDING,
@@ -7305,7 +7338,7 @@ def get_engine_from_ckpt(
 
     sp_key = sp_impl if dict(mesh.shape).get(_SP, 1) > 1 else None
     key = ("ckpt", resolved, eff_dtype, quant or None, kv_quant or None,
-           draft_resolved, sp_key, resolve_flash_decode(flash_decode),
+           draft_resolved, sp_key,
            tuple(sorted(mesh.shape.items())),
            tuple(map(str, mesh.devices.flat)),
            tuple(map(str, prefill_mesh.devices.flat))
@@ -7327,7 +7360,7 @@ def get_engine_from_ckpt(
             eng = InferenceEngine(
                 spec, mesh, params=params, n_slots=n_slots,
                 decode_pipeline=decode_pipeline,
-                decode_loop=decode_loop, flash_decode=flash_decode,
+                decode_loop=decode_loop,
                 prefill_chunk=prefill_chunk, max_pending=max_pending,
                 spec_decode=spec_decode, quant=quant,
                 prefix_cache=prefix_cache, prefix_store=prefix_store,

@@ -353,7 +353,6 @@ def _window_block(q_n, q_r, rows, ring, pos, first, lyr, spec: ModelSpec):
 
 def prefill_attend(spec: ModelSpec, pos, lengths, row, keys: list):
     from quorum_tpu.models import patterned
-    from quorum_tpu.models import transformer as tr
 
     b, t = pos.shape
     rope = tables(spec)
@@ -372,8 +371,7 @@ def prefill_attend(spec: ModelSpec, pos, lengths, row, keys: list):
                                      pos, token_ok, lyr, spec, keys)
             with jax.named_scope("attn.cache_write"):
                 leaves = tuple(
-                    tr._prefill_write(c, new[:, None].astype(c.dtype), row,
-                                      None)
+                    patterned.write_from_start(c, new[:, None], row)
                     for c, new in zip(leaves, (rows, k_i)))
             return gate(out, h, lyr), leaves
         (ring,) = leaves

@@ -167,6 +167,14 @@ def ring_positions(last, ring: int):
     return last - ((last - jnp.arange(ring)) % ring)
 
 
+@jax.named_scope("attn.cache_write")
+def write_from_start(cache, value, row):
+    """A prompt block's K or V (``value`` [B, K, T, ..]) into a full layer's
+    K-major side from position 0 of row ``row`` (B = 1 with a slot)."""
+    return lax.dynamic_update_slice(cache, value.astype(cache.dtype),
+                                    (row, 0, 0, 0))
+
+
 def ring_write(ring_kv, value, offset, n_valid):
     """Write positions ``offset .. offset + n_valid - 1`` of ``value``
     ``[B, K, T, hd]`` into ``ring_kv`` ``[B, K, R, hd]``: entry ``j`` takes
@@ -467,8 +475,8 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
                 window=spec.sliding_window if kind == "L" else 0)
         with jax.named_scope("attn.cache_write"):
             if kind == "G":
-                return out, (tr._prefill_write(ck, k, row, None),
-                             tr._prefill_write(cv, v, row, None))
+                return out, (write_from_start(ck, k, row),
+                             write_from_start(cv, v, row))
             return out, tuple(
                 lax.dynamic_update_slice_in_dim(
                     c, ring_write(_rows_of(c, row, b), new, zero, lengths),

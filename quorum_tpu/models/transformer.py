@@ -6,8 +6,9 @@ TPU-first design choices (SURVEY.md §7):
     ``n_layers`` dim and the depth loop is one ``lax.scan`` — compile time and
     HLO size are O(1) in depth, and XLA pipelines the layers. The decode
     step's scan carries the hidden states AND the KV cache, which each layer
-    updates in place (``decode_step_blocks``); the prefill-side scans still
-    take the cache as ``xs`` and stack it back as ``ys``.
+    updates in place and reads where it lies (``decode_step_blocks``; on a
+    TPU one Pallas call a layer, ops/flash_decode.py); the prefill-side
+    scans still take the cache as ``xs`` and stack it back as ``ys``.
   - **Static shapes everywhere**: prompts are right-padded to a bucket length
     and masked by ``lengths``; the KV cache is a preallocated ``max_seq``
     buffer indexed by position *data*. One compiled program per (batch,
@@ -62,10 +63,7 @@ from quorum_tpu.ops.attention import (
     quantize_rows,
 )
 from quorum_tpu.ops.flash_attention import flash_prefill_attention
-from quorum_tpu.ops.flash_decode import (
-    flash_decode_attention,
-    flash_decode_mode,
-)
+from quorum_tpu.ops.flash_decode import cache_decode_attention
 from quorum_tpu.parallel.ring_attention import ring_prefill_attention
 from quorum_tpu.parallel.ulysses import ulysses_prefill_attention
 from quorum_tpu.ops.norms import layernorm, rmsnorm
@@ -73,18 +71,24 @@ from quorum_tpu.ops.rotary import apply_rope, rope_cos_sin_for
 
 Params = dict[str, Any]
 
-# ---- int8 KV cache representation -----------------------------------------
+# ---- the dense KV cache's representation ------------------------------------
 #
-# A cache side (k or v) is EITHER a bf16 array [L, B, K, max_seq, hd] (the
-# default) OR, with ``kv_quant="int8"``, a tuple ``(q8, scale)`` of
-# [L, B, K, max_seq, hd] int8 and [L, B, K, max_seq] f32 with
-# ``value ≈ q8 * scale[..., None]`` (per-token-per-head symmetric amax/127,
-# the same formulation as the int8 weight quantizer in models/quant.py).
+# A cache side (k or v) is stored positions-major with the heads flattened:
+# EITHER a bf16 array [L, B, max_seq, K·hd] (the default) OR, with
+# ``kv_quant="int8"``, a tuple ``(q8, scale)`` of [L, B, max_seq, K·hd] int8
+# and [L, B, max_seq, K] f32 with ``value ≈ q8 * scale`` per token and head
+# (symmetric amax/127, the same formulation as the int8 weight quantizer in
+# models/quant.py). A position's K (or V) of every head is one contiguous
+# line: a decode step writes a row's line with one scatter, a prefill segment
+# writes T lines in one block, and attention contracts a [B, T, K, hd] view
+# of the store as it lies (``rows_major`` in ops/attention.py), so nothing
+# re-lays the cache between its write and its read (PERF.md §5 item 1).
 # Every cache op below dispatches on the representation; jax pytree
 # machinery (lax.scan carries, jit donation, vmap) handles the tuple leaves
-# transparently. Decode — the bandwidth-bound path — contracts NATIVELY in
-# int8 (ops.attention.decode_attention_q8); the cold prefill-segment /
-# verify paths dequantize their bounded history window instead.
+# transparently. Decode — the bandwidth-bound path — contracts an int8 side
+# NATIVELY in int8 (ops.attention.decode_attention_q8); the cold
+# prefill-segment / verify paths dequantize their bounded history window
+# instead. A paged pool (cache/paging.py) keeps its own K-major pages.
 
 
 # ``jax.named_scope`` names the parts of a step — embed, norm, attn.qkv,
@@ -111,6 +115,33 @@ def _kv_quantize(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 def _kv_dequant(q8: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return (q8.astype(jnp.float32) * scale[..., None]).astype(dtype)
+
+
+def _kv_lines(cache, value: jnp.ndarray):
+    """A block's K or V, [B, K, T, hd], as the lines a dense cache side
+    stores: [B, T, K·hd] in the side's dtype, or for an int8 side the pair
+    (int8 [B, T, K·hd], scale [B, T, K])."""
+    b, k, t, hd = value.shape
+
+    def lines(x):
+        return x.transpose(0, 2, 1, 3).reshape(b, t, k * hd)
+
+    if kv_is_q8(cache):
+        q8, s = _kv_quantize(value)
+        return lines(q8), s.transpose(0, 2, 1).astype(cache[1].dtype)
+    return lines(value.astype(cache.dtype))
+
+
+def _kv_rows(window, n_kv: int, dtype):
+    """The [B, T, K, hd] view of a window of a dense cache side's lines
+    ([B, T, K·hd]); an int8 side (the pair with scales [B, T, K]) is
+    dequantized to ``dtype`` (the cold paths)."""
+    def view(x):
+        return x.reshape(x.shape[:-1] + (n_kv, x.shape[-1] // n_kv))
+
+    if isinstance(window, tuple):
+        return _kv_dequant(view(window[0]), window[1], dtype)
+    return view(window)
 
 
 def _emb_rows(leaf, tokens, dtype):
@@ -338,24 +369,27 @@ def _final_norm(params, spec: ModelSpec, x):
 def _prefill_write(cache, value, cache_row, write_gate):
     """Write a prompt block's K or V into one cache row, handling both
     representations. ``value`` [B, K, T, hd] (B = 1 in slot mode) lands at
-    position ``(cache_row, 0, 0, 0)``; ``write_gate`` (scalar bool) writes
-    the touched region back unchanged when False (one extra region read —
-    never a full-cache select)."""
-    def gated(arr, new, idx):
+    position 0 of row ``cache_row`` (:func:`_block_write`)."""
+    if kv_is_paged(cache):
+        max_seq = cache.page_size * cache.table.shape[-1]
+        return page_write_prefill(cache, value, cache_row, write_gate, max_seq)
+    return _block_write(cache, value, cache_row, 0, write_gate)
+
+
+def _block_write(cache, value, row, offset, write_gate):
+    """Write ``value`` [B, K, T, hd] as T contiguous lines of one layer's
+    dense cache side ([B or S, max_seq, K·hd], or the int8 pair) from
+    ``(row, offset)``. ``write_gate`` (scalar bool) writes the touched region
+    back unchanged when False (one extra region read — never a full-cache
+    select)."""
+    def gated(arr, new):
+        idx = (row, offset, 0)
         if write_gate is not None:
             old = lax.dynamic_slice(arr, idx, new.shape)
             new = jnp.where(write_gate, new, old)
         return lax.dynamic_update_slice(arr, new, idx)
 
-    if kv_is_paged(cache):
-        max_seq = cache.page_size * cache.table.shape[-1]
-        return page_write_prefill(cache, value, cache_row, write_gate, max_seq)
-    if kv_is_q8(cache):
-        c8, cs = cache
-        q8, s = _kv_quantize(value)
-        return (gated(c8, q8, (cache_row, 0, 0, 0)),
-                gated(cs, s.astype(cs.dtype), (cache_row, 0, 0)))
-    return gated(cache, value.astype(cache.dtype), (cache_row, 0, 0, 0))
+    return jax.tree.map(gated, cache, _kv_lines(cache, value))
 
 
 def prefill(
@@ -363,7 +397,7 @@ def prefill(
     spec: ModelSpec,
     tokens: jnp.ndarray,   # [B, T] right-padded
     lengths: jnp.ndarray,  # [B] true prompt lengths
-    cache_k: jnp.ndarray,  # [L, B, K, max_seq, hd]; [L, S, K, max_seq, hd] with slot
+    cache_k: jnp.ndarray,  # [L, B, max_seq, K·hd]; [L, S, max_seq, K·hd] with slot
     cache_v: jnp.ndarray,
     remat: bool = False,
     slot: jnp.ndarray | None = None,
@@ -412,7 +446,7 @@ def prefill(
     moe_mask = jnp.arange(t)[None, :] < lengths[:, None]  # [B,T] real tokens
 
     def body(carry_x, per_layer):
-        block, ck, cv = per_layer  # ck/cv: [B or S, K, max_seq, hd]
+        block, ck, cv = per_layer  # ck/cv: [B or S, max_seq, K·hd]
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)
         if spec.pos == "rope":
@@ -459,7 +493,7 @@ def prefill_segment(
     tokens: jnp.ndarray,   # [1, T] one segment of one slot's prompt, right-padded
     offset: jnp.ndarray,   # scalar int32: absolute position of tokens[:, 0]
     n_valid: jnp.ndarray,  # scalar int32: real (unpadded) tokens in this segment
-    cache_k: jnp.ndarray,  # [L, S, K, max_seq, hd] slot-batched cache
+    cache_k: jnp.ndarray,  # [L, S, max_seq, K·hd] slot-batched cache
     cache_v: jnp.ndarray,
     slot: jnp.ndarray,     # scalar int32
     history: int | None = None,  # static: attend over cache[:history] only
@@ -508,43 +542,32 @@ def prefill_segment(
     mask = keep[None, None, None, :, :]  # [1,1,1,T,hist]
     moe_mask = (jnp.arange(t) < n_valid)[None, :]  # [1,T]
 
+    paged = kv_is_paged(cache_k)
+
     @jax.named_scope("attn.cache_write")
     def seg_write(cache, value):
         # value [1, K, t, hd] at absolute position offset of row `slot`;
         # write_gate (stacked-members segment coalescing) writes the touched
         # region back unchanged when False — region-sized extra read only.
-        def gated(arr, new, idx):
-            if write_gate is not None:
-                old = lax.dynamic_slice(arr, idx, new.shape)
-                new = jnp.where(write_gate, new, old)
-            return lax.dynamic_update_slice(arr, new, idx)
-
-        if kv_is_paged(cache):
+        if paged:
             return page_write_seg(cache, value, slot, offset, write_gate,
                                   spec.max_seq)
-        if kv_is_q8(cache):
-            c8, cs = cache
-            q8, s = _kv_quantize(value)
-            return (gated(c8, q8, (slot, 0, offset, 0)),
-                    gated(cs, s.astype(cs.dtype), (slot, 0, offset)))
-        return gated(cache, value.astype(cache.dtype), (slot, 0, offset, 0))
+        return _block_write(cache, value, slot, offset, write_gate)
 
     def seg_read(cache, dtype):
-        # the slot's history window [1, K, hist, hd]; int8 caches dequantize
-        # the bounded window (cold path — decode uses the native-int8 dot)
-        if kv_is_paged(cache):
+        # the slot's history window: [1, hist, K, hd] of the dense store as
+        # it lies ([1, K, hist, hd] gathered from a paged pool); int8 caches
+        # dequantize the bounded window (cold path — decode uses the
+        # native-int8 dot)
+        if paged:
             return page_read_row(cache, slot, hist, dtype)
-        if kv_is_q8(cache):
-            c8, cs = cache
-            row8 = lax.dynamic_slice(
-                c8, (slot, 0, 0, 0), (1, spec.n_kv_heads, hist, spec.head_dim))
-            rs = lax.dynamic_slice(cs, (slot, 0, 0), (1, spec.n_kv_heads, hist))
-            return _kv_dequant(row8, rs, dtype)
-        return lax.dynamic_slice(
-            cache, (slot, 0, 0, 0), (1, spec.n_kv_heads, hist, spec.head_dim))
+        window = jax.tree.map(
+            lambda leaf: lax.dynamic_slice(
+                leaf, (slot, 0, 0), (1, hist, leaf.shape[-1])), cache)
+        return _kv_rows(window, spec.n_kv_heads, dtype)
 
     def body(carry_x, per_layer):
-        block, ck, cv = per_layer  # ck/cv: [S, K, max_seq, hd] (or (q8, scale))
+        block, ck, cv = per_layer  # ck/cv: [S, max_seq, K·hd] (or (q8, scale))
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)
         if spec.pos == "rope":
@@ -555,7 +578,7 @@ def prefill_segment(
         with jax.named_scope("attn.core"):
             row_k = seg_read(new_ck, q.dtype)
             row_v = seg_read(new_cv, q.dtype)
-            attn = attention(q, row_k, row_v, mask)
+            attn = attention(q, row_k, row_v, mask, rows_major=not paged)
         carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = (_moe_mlp(h2, block, spec, token_mask=moe_mask)
@@ -572,11 +595,11 @@ def decode_step(
     spec: ModelSpec,
     token: jnp.ndarray,    # [B] current token ids
     lengths: jnp.ndarray,  # [B] #tokens already in cache (current token's position)
-    cache_k: jnp.ndarray,  # [L, B, K, max_seq, hd] (donated by the engine's jit
+    cache_k: jnp.ndarray,  # [L, B, max_seq, K·hd] (donated by the engine's jit
     cache_v: jnp.ndarray,  #   and updated in place inside the program)
     write_mask: jnp.ndarray | None = None,  # [B] bool: rows allowed to write
     history: int | None = None,  # static: attend over cache[:history] only
-    flash: str | None = None,  # "" off / "tpu" / "interpret"; None = env gate
+    sharded: bool = False,  # the caller's program is partitioned over devices
 ):
     """One autoregressive step. Returns (logits [B,V], cache_k, cache_v).
 
@@ -593,10 +616,10 @@ def decode_step(
     the needed bytes for a 512-token conversation. The engine picks a
     power-of-two bucket per chunk, so log-many programs cover every length.
 
-    ``flash`` selects the Pallas flash-decode kernel per CALL (the engine
-    resolves its backend's ``flash_decode=`` knob once and threads it
-    through every decode program); ``None`` keeps the process-env gate
-    (``flash_decode_mode()``) for direct callers and tests."""
+    ``sharded`` says what the model code cannot observe from inside a
+    trace: that GSPMD partitions the caller's program over more than one
+    device. A Mosaic kernel has no partitioning rule, so attention then
+    reads the store through XLA's einsums (ops/flash_decode.py)."""
     if spec.layer_pattern:
         return patterned.decode_step(params, spec, token, lengths, cache_k,
                                      cache_v, write_mask=write_mask,
@@ -604,7 +627,7 @@ def decode_step(
     x = decode_token_embed(params, spec, token, lengths)
     x, cache_k, cache_v = decode_step_blocks(
         params["blocks"], spec, x, lengths, cache_k, cache_v,
-        write_mask=write_mask, history=history, flash=flash)
+        write_mask=write_mask, history=history, sharded=sharded)
     x = _final_norm(params, spec, x)
     return _unembed(params, spec, x[:, 0, :]), cache_k, cache_v
 
@@ -628,11 +651,11 @@ def decode_step_blocks(
     spec: ModelSpec,
     x: jnp.ndarray,        # [B, 1, D] embedded hidden states
     lengths: jnp.ndarray,  # [B] current token's position per row
-    cache_k: jnp.ndarray,  # [L', B, K, max_seq, hd] (L' = the layers given)
+    cache_k: jnp.ndarray,  # [L', B, max_seq, K·hd] (L' = the layers given)
     cache_v: jnp.ndarray,
     write_mask: jnp.ndarray | None = None,
     history: int | None = None,
-    flash: str | None = None,
+    sharded: bool = False,
 ):
     """The layer-scan core of :func:`decode_step` on pre-embedded hidden
     states: per-row K/V write at ``lengths``, history-bounded read,
@@ -641,60 +664,44 @@ def decode_step_blocks(
     stack. Returns ``(x, cache_k, cache_v)`` with ``x`` still pre-final-norm.
 
     A dense cache (bf16 array or int8 tuple) rides the scan's **carry**, the
-    layer index counting within the slice given: each layer writes its
-    ``[B, K, hd]`` rows into the carried buffer with one scatter a leaf and
-    reads its history window back out of it, so nothing of the cache's size
-    is sliced out as ``xs``, stacked back as ``ys`` or copied into the
-    caller's step loop (four cache-sized copies a step on a v5e: PERF.md §5;
-    ``analysis/decode_static.py`` shows them in the compiler's text). The
-    scatter is one operation for all rows because a member ``vmap`` turns
-    per-row ``dynamic_update_slice``s into scatters the compiler will not
-    chain in place. A paged pool (cache/paging.py) writes and gathers through
-    its own page table, and keeps the ``xs``/``ys`` form."""
+    layer index counting within the slice given: each layer writes its rows'
+    ``[B, K·hd]`` lines into the carried buffer with one scatter a leaf and
+    attends over the carried buffer where it lies
+    (``ops.flash_decode.cache_decode_attention``: on a TPU one Pallas call a
+    layer that reads each live row to its own length, elsewhere and for an
+    int8 side XLA's einsums over a view of the layer's history window), so
+    nothing of the cache's size is sliced out as ``xs``, stacked back as
+    ``ys``, copied into the caller's step loop or re-laid between the write
+    and the read (PERF.md §5 item 1; ``analysis/decode_static.py`` shows
+    what a program moves in the compiler's text). The scatter is one
+    operation for all rows because a member ``vmap`` turns per-row
+    ``dynamic_update_slice``s into scatters the compiler will not chain in
+    place. A paged pool (cache/paging.py) writes and gathers through its own
+    page table, K-major, and keeps the ``xs``/``ys`` form."""
     b = x.shape[0]
-    flash_mode = flash_decode_mode() if flash is None else flash
     cos, sin = rope_cos_sin_for(spec)
     allow = (jnp.ones((b,), bool) if write_mask is None else write_mask)
     hist = spec.max_seq if history is None else min(history, spec.max_seq)
     rows = jnp.arange(b)
 
-    def write_leaf(leaf, new, layer):
-        # leaf [L', B, K, max_seq, hd] (or [L', B, K, max_seq] scale), new
-        # [B, K, hd] (or [B, K]). A masked row writes back what it holds;
-        # ``clip`` is the start-clamping of a dynamic_update_slice.
-        at = leaf.at[layer, rows, :, lengths]
-        keep = allow.reshape((b,) + (1,) * (new.ndim - 1))
-        return at.set(jnp.where(keep, new, at.get(mode="clip")), mode="clip",
+    paged = kv_is_paged(cache_k)
+
+    def write_leaf(leaf, line, layer):
+        # leaf [L', B, max_seq, K·hd] (or [L', B, max_seq, K] scale), line
+        # [B, 1, K·hd] (or [B, 1, K]). A masked row writes back what it
+        # holds; ``clip`` is the start-clamping of a dynamic_update_slice.
+        at = leaf.at[layer, rows, lengths]
+        return at.set(jnp.where(allow[:, None], line[:, 0],
+                                at.get(mode="clip")), mode="clip",
                       indices_are_sorted=True, unique_indices=True)
 
     @jax.named_scope("attn.cache_write")
     def step_write(cache, value, layer):
         # value [B, K, 1, hd] at each row's own position
-        if kv_is_paged(cache):
+        if paged:
             return page_write_step(cache, value, lengths, allow, spec.max_seq)
-        if kv_is_q8(cache):
-            c8, cs = cache
-            q8, s = _kv_quantize(value)
-            return (write_leaf(c8, q8[:, :, 0], layer),
-                    write_leaf(cs, s[:, :, 0].astype(cs.dtype), layer))
-        return write_leaf(cache, value[:, :, 0].astype(cache.dtype), layer)
-
-    def read_leaf(leaf, layer):
-        # The prefix that can hold valid entries (the write above landed at
-        # lengths < hist), of the carried and already written buffer. The
-        # mask ki < lengths+1 already excludes the tail; the slice stops it
-        # being READ.
-        sizes = (1,) + leaf.shape[1:3] + (hist,) + leaf.shape[4:]
-        return lax.dynamic_slice(
-            leaf, (layer,) + (0,) * (leaf.ndim - 1), sizes)[0]
-
-    def step_read(cache, layer):
-        if kv_is_paged(cache):
-            # Gather the history window's pages into the dense [B, K, hist,
-            # hd] layout — attention (int8 / flash / XLA) runs unchanged on
-            # the gathered window.
-            return page_read(cache, hist)
-        return jax.tree.map(lambda leaf: read_leaf(leaf, layer), cache)
+        return jax.tree.map(lambda leaf, line: write_leaf(leaf, line, layer),
+                            cache, _kv_lines(cache, value))
 
     def layer_step(carry_x, block, ck, cv, layer):
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
@@ -707,34 +714,28 @@ def decode_step_blocks(
         ck = step_write(ck, k, layer)
         cv = step_write(cv, v, layer)
         with jax.named_scope("attn.core"):
-            read_k = step_read(ck, layer)
-            read_v = step_read(cv, layer)
-            if kv_is_q8(ck):
-                # Native int8 q·K / p·V over the quantized cache: HALF the
-                # cache bytes per step, no dequantized HBM copy.
-                attn = decode_attention_q8(
-                    q, read_k[0], read_k[1], read_v[0], read_v[1],
-                    lengths + 1, window=spec.sliding_window)
-            elif flash_mode:
-                # Opt-in Pallas kernel (flash_decode=1 /
-                # QUORUM_TPU_FLASH_DECODE): per-ROW exact cache reads — a
-                # short row co-batched with a long one stops streaming K/V
-                # near its own length, not at the shared history bucket.
-                # The wrapper re-checks shape support and falls back to
-                # decode_attention itself (ops/flash_decode.py).
-                attn = flash_decode_attention(
-                    q, read_k, read_v, lengths + 1,
-                    interpret=flash_mode == "interpret",
-                    window=spec.sliding_window)
+            if not paged:
+                # The carried and already written buffer, where it lies
+                # (the write above landed at lengths < hist). An int8 side
+                # contracts natively in int8: HALF the cache bytes per step,
+                # no dequantized HBM copy.
+                attn = cache_decode_attention(
+                    q, ck, cv, layer, lengths + 1, allow, history=hist,
+                    window=spec.sliding_window, sharded=sharded)
             else:
-                attn = decode_attention(q, read_k, read_v, lengths + 1,
-                                        window=spec.sliding_window)
+                # Gather the history window's pages into a dense [B, K,
+                # hist, hd] window — attention runs unchanged on it.
+                read_k, read_v = page_read(ck, hist), page_read(cv, hist)
+                attend = (decode_attention_q8 if kv_is_q8(ck)
+                          else decode_attention)
+                attn = attend(q, *jax.tree.leaves((read_k, read_v)),
+                              lengths + 1, window=spec.sliding_window)
         carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = _moe_mlp(h2, block, spec) if spec.is_moe else _dense_mlp(h2, block, spec)
         return carry_x + mlp, ck, cv
 
-    if kv_is_paged(cache_k):
+    if paged:
         def paged_body(carry_x, per_layer):
             carry_x, ck, cv = layer_step(carry_x, *per_layer, None)
             return carry_x, (ck, cv)
@@ -768,7 +769,6 @@ def decode_chunk(
     sample_carry,
     history: int | None = None,
     model_call=None,
-    flash: str | None = None,
 ):
     """``n_steps`` decode steps with **on-device finish accounting**.
 
@@ -798,7 +798,7 @@ def decode_chunk(
     if model_call is None:
         def model_call(ck, cv, tok, pos, wm):
             return decode_step(params, spec, tok, pos, ck, cv,
-                               write_mask=wm, history=history, flash=flash)
+                               write_mask=wm, history=history)
 
     def step(carry, _):
         tok, lens, lv, bud, ck, cv, s_carry = carry
@@ -841,7 +841,6 @@ def decode_loop(
     sample_carry,
     history: int | None = None,
     model_call=None,
-    flash: str | None = None,
 ):
     """Megachunk decode: up to ``n_chunks`` :func:`decode_chunk` bodies in
     ONE device-resident program ("Kernel Looping", PAPERS.md — after
@@ -875,7 +874,7 @@ def decode_loop(
         (toks, _valid, n_valid, lv, bud, ck, cv, lens, s_carry, aux) = \
             decode_chunk(params, spec, n_steps, tok, lens, lv, bud, eos,
                          ck, cv, sample_fn, s_carry, history=history,
-                         model_call=model_call, flash=flash)
+                         model_call=model_call)
         # toks[:, -1] IS the carried token (dead rows freeze theirs).
         return (toks[:, -1], lens, lv, bud, ck, cv, s_carry), \
             (toks, n_valid, aux)
@@ -906,7 +905,7 @@ def decode_multi(
     spec: ModelSpec,
     tokens: jnp.ndarray,   # [B, T] current token + T-1 proposed continuations
     lengths: jnp.ndarray,  # [B] position of tokens[:, 0] per row
-    cache_k: jnp.ndarray,  # [L, B, K, max_seq, hd]
+    cache_k: jnp.ndarray,  # [L, B, max_seq, K·hd]
     cache_v: jnp.ndarray,
     write_mask: jnp.ndarray | None = None,  # [B] bool
     history: int | None = None,
@@ -953,8 +952,10 @@ def decode_multi(
     hist = spec.max_seq if history is None else min(history, spec.max_seq)
     allow = (jnp.ones((b,), bool) if write_mask is None else write_mask)
 
+    paged = kv_is_paged(cache_k)
+
     def write_row(cache_row, new_row, idx, w):
-        # cache_row [K, max_seq, hd] (or [K, max_seq] scale), new_row likewise
+        # cache_row [max_seq, K·hd] (or [max_seq, K] scale), new_row [T, ..]
         if clamp_writes:
             # Shift the window start back so the slice stays in bounds, and
             # roll the values right by the same amount so each kept value
@@ -962,14 +963,13 @@ def decode_multi(
             # shift write the OLD contents back (those intended positions
             # are >= max_seq — dropped).
             delta = jnp.maximum(idx + t - spec.max_seq, 0)
-            start = (0, idx - delta, 0)[: cache_row.ndim]
+            start = (idx - delta, 0)
             old = lax.dynamic_slice(cache_row, start, new_row.shape)
-            rolled = jnp.roll(new_row, delta, axis=1)
-            keep = (jnp.arange(t) >= delta).reshape(
-                (1, t) + (1,) * (new_row.ndim - 2))
+            rolled = jnp.roll(new_row, delta, axis=0)
+            keep = (jnp.arange(t) >= delta)[:, None]
             return lax.dynamic_update_slice(
                 cache_row, jnp.where(keep & w, rolled, old), start)
-        start = (0, idx, 0)[: cache_row.ndim]
+        start = (idx, 0)
         old = lax.dynamic_slice(cache_row, start, new_row.shape)
         return lax.dynamic_update_slice(
             cache_row, jnp.where(w, new_row, old), start)
@@ -978,27 +978,21 @@ def decode_multi(
 
     @jax.named_scope("attn.cache_write")
     def multi_write(cache, value):
-        if kv_is_paged(cache):
+        if paged:
             # OOB positions drop exactly — subsumes clamp_writes (the dense
             # path's roll trick exists only because dynamic_update_slice
             # clamps its start backwards; a page scatter has no start).
             return page_write_multi(cache, value, lengths, allow, spec.max_seq)
-        if kv_is_q8(cache):
-            c8, cs = cache
-            q8, s = _kv_quantize(value)
-            return (write(c8, q8, lengths, allow),
-                    write(cs, s.astype(cs.dtype), lengths, allow))
-        return write(cache, value.astype(cache.dtype), lengths, allow)
+        return jax.tree.map(lambda leaf, new: write(leaf, new, lengths, allow),
+                            cache, _kv_lines(cache, value))
 
     def multi_read(cache, dtype):
-        if kv_is_paged(cache):
+        if paged:
             r = page_read(cache, hist)
             return _kv_dequant(r[0], r[1], dtype) if kv_is_q8(cache) else r
-        if kv_is_q8(cache):
-            return _kv_dequant(
-                lax.slice_in_dim(cache[0], 0, hist, axis=2),
-                lax.slice_in_dim(cache[1], 0, hist, axis=2), dtype)
-        return lax.slice_in_dim(cache, 0, hist, axis=2)
+        window = jax.tree.map(
+            lambda leaf: lax.slice_in_dim(leaf, 0, hist, axis=1), cache)
+        return _kv_rows(window, spec.n_kv_heads, dtype)
 
     # per-row causal mask over the cache prefix: key j visible to query i of
     # row r iff j <= lengths[r] + i
@@ -1022,7 +1016,7 @@ def decode_multi(
         with jax.named_scope("attn.core"):
             read_k = multi_read(new_ck, q.dtype)
             read_v = multi_read(new_cv, q.dtype)
-            attn = attention(q, read_k, read_v, mask)
+            attn = attention(q, read_k, read_v, mask, rows_major=not paged)
         carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         # dense MoE (not grouped): verification logits must be numerically
@@ -1166,7 +1160,8 @@ def forward_logits_sp(
 
 
 def init_cache(spec: ModelSpec, batch: int, dtype=None, kv_quant: str | None = None):
-    """Preallocated KV cache: [L, B, K, max_seq, hd] × 2.
+    """Preallocated KV cache: [L, B, max_seq, K·hd] × 2 (positions-major,
+    the heads flattened: the representation's note at the top of this file).
 
     ``kv_quant="int8"`` stores each side as ``(int8 values, f32 per-token
     scales)`` — HALF the cache HBM capacity and half the bytes every decode
@@ -1180,9 +1175,11 @@ def init_cache(spec: ModelSpec, batch: int, dtype=None, kv_quant: str | None = N
         assert kv_quant is None, "a patterned spec's cache is not quantized"
         return patterned.init_cache(spec, batch, dtype)
     dt = jnp.dtype(dtype or spec.dtype)
-    shape = (spec.n_layers, batch, spec.n_kv_heads, spec.max_seq, spec.head_dim)
+    shape = (spec.n_layers, batch, spec.max_seq,
+             spec.n_kv_heads * spec.head_dim)
     if kv_quant == "int8":
         side = lambda: (jnp.zeros(shape, jnp.int8),  # noqa: E731
-                        jnp.zeros(shape[:-1], jnp.float32))
+                        jnp.zeros(shape[:-1] + (spec.n_kv_heads,),
+                                  jnp.float32))
         return side(), side()
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)

@@ -4,6 +4,9 @@ Layout: q [B, H, S, hd]; k/v [B, K, S_kv, hd] with H = K * G query groups.
 GQA is expressed by reshaping q to [B, K, G, S, hd] and contracting against
 the shared K/V heads — no materialized repeat_kv copies (which would burn HBM
 bandwidth); the grouping lives in the einsum and XLA tiles it onto the MXU.
+``rows_major=True`` takes k/v as [B, S_kv, K, hd] instead: a view of the dense
+cache's positions-major store (models/transformer.py ``init_cache``),
+contracted as it lies — the same sums, the axes named in another order.
 
 Softmax runs in float32 regardless of activation dtype. The Pallas
 flash-attention kernel (quorum_tpu.ops.flash_attention) replaces the prefill
@@ -28,19 +31,21 @@ def attention(
     k: jnp.ndarray,  # [B, K, S_kv, hd]
     v: jnp.ndarray,  # [B, K, S_kv, hd]
     mask: jnp.ndarray | None = None,  # broadcastable to [B, 1, 1, S, S_kv], bool (True=keep)
+    rows_major: bool = False,  # k/v are [B, S_kv, K, hd]
 ) -> jnp.ndarray:
     """Full attention over the given K/V. Returns [B, H, S, hd]."""
-    n_kv = k.shape[1]
+    kv = "btkd" if rows_major else "bktd"
+    n_kv = k.shape[2 if rows_major else 1]
     qg = _group_heads(q, n_kv)  # [B, K, G, S, hd]
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum(
-        "bkgsd,bktd->bkgst", qg, k, preferred_element_type=jnp.float32
+        f"bkgsd,{kv}->bkgst", qg, k, preferred_element_type=jnp.float32
     ) * scale
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgst,bktd->bkgsd", probs.astype(v.dtype), v)
+    out = jnp.einsum(f"bkgst,{kv}->bkgsd", probs.astype(v.dtype), v)
     b, k_, g, s, d = out.shape
     return out.reshape(b, k_ * g, s, d)
 
@@ -75,22 +80,29 @@ def prefill_attention(q, k, v, lengths: jnp.ndarray | None = None,
 
 def decode_attention(
     q: jnp.ndarray,  # [B, H, 1, hd]
-    k_cache: jnp.ndarray,  # [B, K, max_seq, hd]
+    k_cache: jnp.ndarray,  # [B, K, max_seq, hd]; rows_major [B, max_seq, K, hd]
     v_cache: jnp.ndarray,
     length: jnp.ndarray,  # [B] or scalar: #valid cache entries (incl. current token)
     window: int = 0,
+    rows_major: bool = False,
 ) -> jnp.ndarray:
     """One decode step against the KV cache (static max_seq, masked by
     length; ``window`` > 0 restricts to the last ``window`` positions)."""
+    mask = _decode_keep(length, k_cache.shape[1 if rows_major else 2], window)
+    return attention(q, k_cache, v_cache, mask, rows_major=rows_major)
+
+
+def _decode_keep(length, n_keys: int, window: int) -> jnp.ndarray:
+    """[B, 1, 1, 1, n_keys] bool: the cache entries a decode query at
+    position ``length - 1`` sees."""
     length = jnp.asarray(length)
     if length.ndim == 0:
         length = length[None]
-    ki = jnp.arange(k_cache.shape[2])[None, :]
-    valid = ki < length[:, None]  # [B, max_seq]
+    ki = jnp.arange(n_keys)[None, :]
+    valid = ki < length[:, None]  # [B, n_keys]
     if window and window > 0:
         valid = valid & (ki >= length[:, None] - window)
-    mask = valid[:, None, None, None, :]
-    return attention(q, k_cache, v_cache, mask)
+    return valid[:, None, None, None, :]
 
 
 def quantize_rows(x: jnp.ndarray, axis: int = -1) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -109,12 +121,13 @@ def quantize_rows(x: jnp.ndarray, axis: int = -1) -> tuple[jnp.ndarray, jnp.ndar
 
 def decode_attention_q8(
     q: jnp.ndarray,        # [B, H, 1, hd] bf16/f32
-    k8: jnp.ndarray,       # [B, K, T, hd] int8 cache
+    k8: jnp.ndarray,       # [B, K, T, hd] int8 cache; rows_major [B, T, K, hd]
     k_scale: jnp.ndarray,  # [B, K, T] f32: k ≈ k8 * k_scale[..., None]
     v8: jnp.ndarray,       # [B, K, T, hd] int8 cache
-    v_scale: jnp.ndarray,  # [B, K, T] f32
+    v_scale: jnp.ndarray,  # [B, K, T] f32; rows_major [B, T, K]
     length: jnp.ndarray,   # [B] or scalar
     window: int = 0,
+    rows_major: bool = False,
 ) -> jnp.ndarray:
     """One decode step against an int8-quantized KV cache, with the
     contractions run NATIVELY in int8 (int8×int8→int32 on the MXU) — never
@@ -129,27 +142,23 @@ def decode_attention_q8(
     q (one row per head) and p (one row per query) are dynamically
     quantized amax/127, like activations in models/quant.qeinsum."""
     b, h, s, d = q.shape
-    n_kv = k8.shape[1]
+    kv = "btkd" if rows_major else "bktd"
+    if rows_major:
+        k_scale, v_scale = (x.transpose(0, 2, 1) for x in (k_scale, v_scale))
+    n_kv, n_keys = k_scale.shape[1:]
     qg = _group_heads(q, n_kv)                        # [B, K, G, 1, hd]
     q8, qs = quantize_rows(qg, axis=-1)               # qs [B, K, G, 1, 1]
     logits_i = jnp.einsum(
-        "bkgsd,bktd->bkgst", q8, k8, preferred_element_type=jnp.int32)
+        f"bkgsd,{kv}->bkgst", q8, k8, preferred_element_type=jnp.int32)
     scale = d ** -0.5
     logits = (logits_i.astype(jnp.float32) * qs
               * k_scale[:, :, None, None, :]) * scale  # [B, K, G, 1, T]
-    length = jnp.asarray(length)
-    if length.ndim == 0:
-        length = length[None]
-    ki = jnp.arange(k8.shape[2])[None, :]
-    valid = ki < length[:, None]  # [B, T]
-    if window and window > 0:
-        valid = valid & (ki >= length[:, None] - window)
-    logits = jnp.where(valid[:, None, None, None, :], logits, NEG_INF)
+    logits = jnp.where(_decode_keep(length, n_keys, window), logits, NEG_INF)
     probs = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
     pv = probs * v_scale[:, :, None, None, :]          # fold v's scale in
     p8, ps = quantize_rows(pv, axis=-1)                # ps [B, K, G, 1, 1]
     out_i = jnp.einsum(
-        "bkgst,bktd->bkgsd", p8, v8, preferred_element_type=jnp.int32)
+        f"bkgst,{kv}->bkgsd", p8, v8, preferred_element_type=jnp.int32)
     out = out_i.astype(jnp.float32) * ps               # [B, K, G, 1, hd]
     return out.reshape(b, h, s, d).astype(q.dtype)

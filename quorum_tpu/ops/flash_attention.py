@@ -68,7 +68,8 @@ def tracing_program(label: str):
 
 def log_attention_path(kernel: str, refusal: str, *, interpret: bool,
                        q_shape: tuple, kv_shape: tuple, block: int,
-                       window: int) -> None:
+                       window: int,
+                       accepted: str = "tpu backend, shapes tile") -> None:
     """The trace-time line that makes the kernel choice visible: ``pallas``
     or ``xla`` and why, per traced program. chip_smoke.py asserts from these
     lines that every single-shot prefill bucket ran the Mosaic-compiled
@@ -79,7 +80,7 @@ def log_attention_path(kernel: str, refusal: str, *, interpret: bool,
         _PROGRAM.get(), kernel, "xla" if refusal else "pallas", interpret,
         "x".join(map(str, q_shape)), "x".join(map(str, kv_shape)), block,
         window, refusal or ("interpret mode asked for" if interpret
-                            else "tpu backend, shapes tile"))
+                            else accepted))
 
 # 512-tiles measured ~22% faster than XLA's fused attention at 16k tokens on
 # v5e (84.8 vs 108.8 ms; 128-tiles were on par) — grid overhead amortizes and

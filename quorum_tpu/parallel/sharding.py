@@ -82,8 +82,11 @@ PARAM_LOGICAL_AXES: dict[str, tuple[str | None, ...]] = {
     "moe_w_down": ("layers", "experts", None, "model"),
 }
 
-# KV cache: [layers, batch, kv_heads, max_seq, head_dim]
-KV_CACHE_AXES: tuple[str | None, ...] = ("layers", "batch", "kv_heads", "seq", "head_dim")
+# KV cache: [layers, batch, max_seq, kv_heads · head_dim] (a position's heads
+# flattened into one line, models/transformer.py ``init_cache``: whole heads
+# shard over tp as blocks of the line); an int8 side's scales
+# [layers, batch, max_seq, kv_heads] take the same axes
+KV_CACHE_AXES: tuple[str | None, ...] = ("layers", "batch", "seq", "kv_heads")
 
 
 def kv_cache_sharding(mesh: Mesh, n_kv_heads: int, batch: int | None = None,
@@ -102,11 +105,11 @@ def kv_cache_sharding(mesh: Mesh, n_kv_heads: int, batch: int | None = None,
     handoff reshards on the fly)."""
     axes = list(KV_CACHE_AXES)
     if n_kv_heads % mesh.shape[AXIS_TP] != 0:
-        axes[2] = None
+        axes[3] = None
     if batch is not None and batch % mesh.shape[AXIS_DP] != 0:
         axes[1] = None
     if seq_shard and mesh.shape[AXIS_SP] > 1:
-        axes[3] = "seq_shard"
+        axes[2] = "seq_shard"
     return logical_to_sharding(mesh, tuple(axes))
 
 

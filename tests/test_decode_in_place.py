@@ -1,15 +1,20 @@
-"""The dense decode step carries its cache through the layer scan and writes
-it in place (models/transformer.decode_step_blocks).
+"""The dense decode step carries its cache through the layer scan, writes it
+in place and reads it where it lies (models/transformer.decode_step_blocks):
+positions-major, the heads flattened, ``[L, B, max_seq, K·hd]``.
 
-The oracle is the body that was there before: the cache as the scan's ``xs``,
-written per row by a vmapped ``dynamic_update_slice`` and stacked back as
-``ys``. It is kept here as the plain reference. No arithmetic moved, so logits
-and both caches must be equal bit for bit, after one step and after an 8-step
-``decode_chunk``.
+The oracle is the body that was there before either: the cache K-major
+(``[L, B, K, max_seq, hd]``) as the scan's ``xs``, written per row by a
+vmapped ``dynamic_update_slice``, read by the K-major einsums and stacked back
+as ``ys``. It is kept here as the plain reference, handed the same values
+through a transpose at its entry and exit. No arithmetic moved (the same
+einsums, the axes named in another order), so on the CPU logits and both
+caches must be equal bit for bit, after one step and after an 8-step
+``decode_chunk``, for a bf16 cache and the int8 tuple.
 
-The static test reads the v5e compiler's text (analysis/decode_static.py):
-only that text shows whether a cache-sized copy or allocation sits inside the
-step loop; the CPU's text does not (its scatter copies).
+The static tests read the v5e compiler's text (analysis/decode_static.py):
+only that text shows whether a copy, a reshape, a slice or an allocation of a
+cache side or of a layer's slab sits in the program, and whether the Pallas
+call is there; the CPU's text does not (its scatter copies).
 """
 
 import dataclasses
@@ -32,10 +37,36 @@ TINY = dataclasses.replace(MODEL_PRESETS["llama-tiny"], max_seq=64)
 N_STEPS = 8
 
 
+def k_major(side, n_kv):
+    """A positions-major cache side as the oracle's store held it:
+    ``[L, B, S, K·hd]`` to ``[L, B, K, S, hd]``, scales ``[L, B, S, K]`` to
+    ``[L, B, K, S]``."""
+    def values(a):
+        return a.reshape(a.shape[:3] + (n_kv, -1)).transpose(0, 1, 3, 2, 4)
+
+    if isinstance(side, tuple):
+        return values(side[0]), side[1].transpose(0, 1, 3, 2)
+    return values(side)
+
+
+def lines(side):
+    """:func:`k_major` undone."""
+    def values(a):
+        return a.transpose(0, 1, 3, 2, 4).reshape(
+            a.shape[:2] + (a.shape[3], -1))
+
+    if isinstance(side, tuple):
+        return values(side[0]), side[1].transpose(0, 1, 3, 2)
+    return values(side)
+
+
 def oracle_step_blocks(blocks, spec, x, lengths, cache_k, cache_v,
-                       write_mask=None, history=None, flash=None):
-    """``decode_step_blocks`` as it was: cache as ``xs``, stacked as ``ys``."""
-    del flash
+                       write_mask=None, history=None, sharded=False):
+    """``decode_step_blocks`` as it was before PR 31 and PR 35: the cache
+    K-major as ``xs``, stacked as ``ys``."""
+    del sharded
+    cache_k, cache_v = (k_major(c, spec.n_kv_heads)
+                        for c in (cache_k, cache_v))
     b = x.shape[0]
     cos, sin = tr.rope_cos_sin_for(spec)
 
@@ -91,7 +122,7 @@ def oracle_step_blocks(blocks, spec, x, lengths, cache_k, cache_v,
         return carry_x + mlp, (new_ck, new_cv)
 
     x, (cache_k, cache_v) = lax.scan(body, x, (blocks, cache_k, cache_v))
-    return x, cache_k, cache_v
+    return x, lines(cache_k), lines(cache_v)
 
 
 def filled_cache(spec, rows, seed, kv_quant=None, members=1):
@@ -242,7 +273,7 @@ def test_the_cases_move_what_they_say():
     case = CASES["dead_row_over_a_live_prompts_position_0"]
     before = filled_cache(case.spec, 3, 7)[0]
     (_, after, _), chunk = run_case(case)
-    assert not np.array_equal(after[:, 0, :, 9], before[:, 0, :, 9])
+    assert not np.array_equal(after[:, 0, 9], before[:, 0, 9])
     np.testing.assert_array_equal(after[:, 1], before[:, 1])
     np.testing.assert_array_equal(np.asarray(chunk[5])[:, 1], before[:, 1])
     assert chunk[2].tolist() == [N_STEPS, 0, N_STEPS]
@@ -281,28 +312,86 @@ def v5e():
 
 # the cells' shapes at a cut depth: the scan's program does not depend on it
 STATIC = {
-    "chat_12_rows_of_1024_int8": dict(quant="int8", rows=12, members=1),
-    "quorum_3_members_of_8_rows_bf16": dict(quant=None, rows=8, members=3),
+    "chat_12_rows_of_1024_int8": dict(
+        quant="int8", rows=12, members=1, max_seq=1024),
+    "longprompt_6_rows_of_2048_int8": dict(
+        quant="int8", rows=6, members=1, max_seq=2048),
+    "quorum_3_members_of_8_rows_bf16": dict(
+        quant=None, rows=8, members=3, max_seq=1024),
 }
 
 
+@pytest.fixture(scope="module")
+def programs(v5e):
+    """The decode chunk of each shape at its largest history bucket,
+    compiled once for the file: ``(text, temp bytes, side, slab)``."""
+    done = {}
+
+    def program(name, monkeypatch, step_blocks=None):
+        key = (name, step_blocks is None)
+        if key not in done:
+            shape = dict(STATIC[name])
+            max_seq = shape.pop("max_seq")
+            spec = dataclasses.replace(
+                MODEL_PRESETS["mistral-7b"], n_layers=2,
+                max_seq=max_seq).validate()
+            with monkeypatch.context() as patch:
+                patch.setenv("QUORUM_TPU_QEINSUM_INT8", "1")  # the chip's
+                if step_blocks is not None:
+                    patch.setattr(tr, "decode_step_blocks", step_blocks)
+                compiled = within(
+                    COMPILE_LIMIT_S, decode_static.compile_decode_chunk,
+                    spec, v5e, history=max_seq, **shape)
+            (side,), (slab,) = decode_static.cache_sizes(
+                spec, shape["rows"], shape["members"])
+            done[key] = (compiled.as_text(),
+                         compiled.memory_analysis().temp_size_in_bytes,
+                         side, slab)
+        return done[key]
+
+    return program
+
+
 @pytest.mark.parametrize("name", sorted(STATIC))
-def test_no_whole_cache_copy_or_allocation_in_the_step_loop(
-        name, v5e, monkeypatch):
-    shape = STATIC[name]
-    spec = dataclasses.replace(
-        MODEL_PRESETS["mistral-7b"], n_layers=2, max_seq=1024).validate()
-    monkeypatch.setenv("QUORUM_TPU_QEINSUM_INT8", "1")  # the chip's products
-    (side,), _ = decode_static.cache_sizes(spec, shape["rows"],
-                                           shape["members"])
+def test_no_whole_cache_copy_or_allocation_anywhere_in_the_chunk(
+        name, programs, monkeypatch):
+    """Neither in the step loop nor at the chunk's entry and exit (the
+    K-major store was re-laid whole there, PERF.md section 5 item 1); the
+    reader is shown to find the oracle's copies first."""
+    if name.startswith("chat"):
+        text, _, side, _ = programs(name, monkeypatch, oracle_step_blocks)
+        assert decode_static.whole_cache_moves(text, side), \
+            "the reader finds nothing in the body it was written against"
+    text, _, side, _ = programs(name, monkeypatch)
+    assert not decode_static.whole_cache_moves(text, side, loops_only=False)
 
-    def program(step_blocks):
-        monkeypatch.setattr(tr, "decode_step_blocks", step_blocks)
-        compiled = within(COMPILE_LIMIT_S, decode_static.compile_decode_chunk,
-                          spec, v5e, history=512, **shape)
-        return decode_static.whole_cache_moves(compiled.as_text(), side)
 
-    new = tr.decode_step_blocks
-    assert program(oracle_step_blocks), \
-        "the reader finds nothing in the body it was written against"
-    assert not program(new)
+@pytest.mark.parametrize("name", [n for n in sorted(STATIC)
+                                  if STATIC[n]["members"] == 1])
+def test_one_member_reads_the_carried_cache_through_one_pallas_call_a_layer(
+        name, programs, monkeypatch):
+    """No copy, reshape, dynamic-slice or allocation of a layer's slab
+    anywhere, under 0.05 GB of temporaries, and exactly one Mosaic call in
+    the layer loop: it reads both sides."""
+    text, temp, side, slab = programs(name, monkeypatch)
+    assert temp < 0.05e9
+    assert not decode_static.slab_moves(text, slab)
+    (call,) = decode_static.kernel_calls(text)
+    assert "attn.core" in call[4] and "decode_attention_in_place" in call[4]
+    # what is left with the cache's shape: the two in-place scatters
+    updates = [row for row in decode_static.program_ops(text, {side})
+               if row != call]
+    assert len(updates) == 2
+    assert all("attn.cache_write/scatter" in row[4] for row in updates)
+
+
+def test_the_stacked_members_keep_xlas_einsums_over_the_same_store(
+        programs, monkeypatch):
+    """Under the member vmap no Pallas call is lowered (its batching rule
+    would slice each member's whole side out), and what moves is one
+    layer's history window a side, never the stacked cache."""
+    text, _, side, slab = programs("quorum_3_members_of_8_rows_bf16",
+                                   monkeypatch)
+    assert not decode_static.kernel_calls(text)
+    assert decode_static.slab_moves(text, slab)
+    assert not decode_static.whole_cache_moves(text, side, loops_only=False)

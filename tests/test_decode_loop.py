@@ -85,14 +85,13 @@ def test_decode_loop_floored_to_power_of_two():
 
 
 def test_url_knobs_validated_at_config_time():
-    """A typo in decode_loop=/flash_decode= must fail the URL before any
-    multi-GB engine construction, not per-request."""
+    """A decode_loop= out of range must fail the URL before any multi-GB
+    engine construction, not per-request."""
     from quorum_tpu.backends.tpu_backend import TpuBackend
     from quorum_tpu.config import BackendSpec
 
     for url in ("tpu://llama-tiny?decode_loop=0",
-                "tpu://llama-tiny?decode_loop=9999",
-                "tpu://llama-tiny?flash_decode=maybe"):
+                "tpu://llama-tiny?decode_loop=9999"):
         with pytest.raises(ValueError):
             TpuBackend.from_spec(BackendSpec(name="bad", url=url, model="m"))
 

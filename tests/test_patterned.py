@@ -391,15 +391,16 @@ def test_a_patterned_spec_has_no_cache_free_forward(model32):
 
 
 # What an accepted cell's spec lowers to, as the parent of PR 30 lowered it
-# (``decode``: as PR 31 left it, the cache carried through the layer scan;
+# (as PR 35 left all three: the dense cache stored positions-major with the
+# heads flattened, ``[L, B, max_seq, K·hd]``, and read where it lies;
 # sha256 of ``jit(...).lower(...).as_text()``, jax 0.9.0): a spec without a
 # layer_pattern compiles the programs it compiled before the patterned family
 # was added. A change of transformer.py that is meant to change them, or a
 # new jax, writes the new values here.
 UNPATTERNED = {
-    "decode": "e1f51ba0dc68f9e73d867bf023ee70a54f8a1fcba853d3c7e0ebacb3a16dc8f5",
-    "admit": "fba4fe9ce93033eb386932464e46bc46f51acf7d1868422abaeb053e6b1445c3",
-    "segment": "57ac7031e558bf596c3fd37a43a6c580b42c31413d8e3bdef18275dd7e1b7b2c",
+    "decode": "451cdb28f603831cecc0bbdc4177bfb6083f4dc3d702a706b42682574ee4c11a",
+    "admit": "8da61f804ade4d48fd674f4f8c83ff4e5bff705860cdd08c26676a349c061535",
+    "segment": "89484f518a8d2e3e418e2595b9e258c672f5787484a8ff19c12c3fddf05d93a5",
 }
 
 
@@ -415,7 +416,7 @@ def test_a_spec_without_a_pattern_lowers_to_what_it_lowered_to(program):
     lowered = {
         "decode": lambda: jax.jit(
             lambda p, t, l, k, v: tr.decode_step(
-                p, spec, t, l, k, v, history=64, flash="")).lower(
+                p, spec, t, l, k, v, history=64)).lower(
             params, i32(4), i32(4), ck, cv),
         "admit": lambda: jax.jit(
             lambda p, t, l, s, k, v: tr.prefill(

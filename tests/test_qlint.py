@@ -359,8 +359,7 @@ def test_transfer_guard_knob_validated():
 
 
 def test_transfer_guard_env_typo_is_loud_off_not_a_crash(monkeypatch):
-    """The env-knob convention (QUORUM_TPU_FLASH_DECODE precedent): a typo
-    in the serving environment must not take engine construction down —
+    """The env-knob convention: a typo in the serving environment must not take engine construction down —
     it logs loudly and runs with the guard OFF."""
     monkeypatch.setenv("QUORUM_TPU_TRANSFER_GUARD", "Disallow")  # bad case
     eng = _tiny_engine()

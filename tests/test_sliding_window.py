@@ -114,7 +114,7 @@ def test_flash_kernels_match_reference_with_window():
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     b, h, n_kv, t, hd = 2, 8, 4, 256, 64
     from quorum_tpu.ops.flash_attention import flash_prefill_attention
-    from quorum_tpu.ops.flash_decode import flash_decode_attention
+    from quorum_tpu.ops.flash_decode import cache_decode_attention
 
     # prefill kernel
     q = jax.random.normal(ks[0], (b, h, t, hd), jnp.float32)
@@ -131,8 +131,11 @@ def test_flash_kernels_match_reference_with_window():
     qd = jax.random.normal(ks[0], (b, h, 1, hd), jnp.float32)
     dlen = jnp.array([200, 7], jnp.int32)
     refd = decode_attention(qd, k, v, dlen, window=32)
-    gotd = flash_decode_attention(qd, k, v, dlen, block_k=128,
-                                  interpret=True, window=32)
+    # the kernel reads the cache's own store: one layer of [B, T, K*hd] lines
+    lines = lambda x: x.transpose(0, 2, 1, 3).reshape(1, b, t, n_kv * hd)  # noqa: E731
+    gotd = cache_decode_attention(
+        qd, lines(k), lines(v), jnp.int32(0), dlen, jnp.ones((b,), bool),
+        history=t, interpret=True, window=32)
     np.testing.assert_allclose(np.asarray(gotd), np.asarray(refd),
                                rtol=2e-5, atol=2e-5)
 
