@@ -221,9 +221,9 @@ def _params_per_model() -> int:
     from quorum_tpu.engine.engine import _ENGINES
 
     for eng in _ENGINES.values():
-        # a stacked engine's leaves are [members, …]: count one member
+        # a stacked engine's leaves hold every member: count one
         return sum(x.size for x in jax.tree_util.tree_leaves(
-            eng.params)) // eng.members
+            eng.weights)) // eng.members
     return 0
 
 

@@ -32,6 +32,18 @@ it cannot take: that one's ``op_name`` was ``attn.cache_write/scatter``). An
 in-place update of the carry has the cache's shape and no mark. Nothing runs,
 so this gives no time; the scan's program does not depend on depth, and the
 full-depth int8 member compiles in some ten seconds.
+
+With ``members`` > 1 (the second example) the member-vmapped programs are all
+compiled, over the stacked weight tree as the engine holds it
+(``parallel.sharding.member_axes``: block leaves layers-major): the decode
+chunk, the member admit at buckets 32 and 128 and the member segment
+(:func:`compile_member_admit`, :func:`compile_member_segment`), each under a
+``== <program>`` line with its ``temp``; and an operation that copies or
+transposes an array with a stacked block matrix's dimensions is listed and
+marked ``WEIGHT MOVE`` (:func:`weight_moves`: what a member ``vmap`` leaves
+at a program's head when the layer scan's ``xs`` are not batched at axis 1;
+6.5 GB a program at the second example's shape with the blocks held
+``[M, L, …]``, none as they are held).
 """
 
 from __future__ import annotations
@@ -50,6 +62,7 @@ _CALLED = re.compile(
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
 _MOVES = ("copy", "copy-done", "AllocateBuffer")
 _SLAB_MOVES = ("copy", "copy-done", "reshape", "dynamic-slice")
+_WEIGHT_MOVES = ("copy", "copy-start", "copy-done", "transpose")
 _KERNEL = "tpu_custom_call"
 _NO_DEVICE_OP = ("get-tuple-element", "bitcast", "parameter", "tuple")
 
@@ -62,6 +75,46 @@ def v5e_device():
 
     return topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0]
+
+
+def _stacked_weights(spec, members: int, quant: "str | None"):
+    """The shapes of the weight tree an engine of ``members`` holds: a
+    single member's, or the stacked tree in the engine's own layout."""
+    import jax
+
+    from quorum_tpu.models.init import init_params_from_key
+    from quorum_tpu.models.quant import quantize_params
+    from quorum_tpu.parallel.sharding import stack_members
+
+    def weights():
+        params = init_params_from_key(spec, jax.random.PRNGKey(0))
+        params = quantize_params(params) if quant == "int8" else params
+        return stack_members([params] * members) if members > 1 else params
+
+    return jax.eval_shape(weights)
+
+
+def _stacked_cache(spec, rows: int, members: int):
+    """The shapes of the two cache sides, the member axis first."""
+    import jax
+
+    from quorum_tpu.models.transformer import init_cache
+
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            ((members,) if members > 1 else ()) + s.shape, s.dtype),
+        jax.eval_shape(lambda: init_cache(spec, rows)))
+
+
+def _compile(fn, device, args, donate: tuple):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(device)
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args)
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
 
 def compile_decode_chunk(spec, device, *, rows: int, members: int = 1,
@@ -78,25 +131,9 @@ def compile_decode_chunk(spec, device, *, rows: int, members: int = 1,
     for the default backend, which is the CPU here)."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
     from quorum_tpu.engine.engine import _stacked_rows_call
-    from quorum_tpu.models.init import init_params_from_key
-    from quorum_tpu.models.quant import quantize_params
-    from quorum_tpu.models.transformer import (
-        decode_chunk,
-        decode_step,
-        init_cache,
-    )
-
-    def weights():
-        params = init_params_from_key(spec, jax.random.PRNGKey(0))
-        return quantize_params(params) if quant == "int8" else params
-
-    def stacked(shape):
-        return jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(
-                ((members,) if members > 1 else ()) + s.shape, s.dtype), shape)
+    from quorum_tpu.models.transformer import decode_chunk, decode_step
 
     def greedy(logits, live, carry):
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), carry, ()
@@ -115,19 +152,75 @@ def compile_decode_chunk(spec, device, *, rows: int, members: int = 1,
                             model_call=model_call)
 
     n = rows * members
-    ck, cv = jax.eval_shape(lambda: init_cache(spec, rows))
-    args = (stacked(jax.eval_shape(weights)),
+    ck, cv = _stacked_cache(spec, rows, members)
+    args = (_stacked_weights(spec, members, quant),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.bool_),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            stacked(ck), stacked(cv))
-    one_chip = SingleDeviceSharding(device)
-    args = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        args)
-    return jax.jit(chunk, donate_argnums=(6, 7)).lower(*args).compile()
+            ck, cv)
+    return _compile(chunk, device, args, donate=(6, 7))
+
+
+def compile_member_admit(spec, device, *, rows: int, members: int,
+                         bucket: int, quant: str | None = None):
+    """Compile a stacked engine's coalesced admit for ``device`` on shapes
+    alone: ``transformer.prefill`` of one prompt of ``bucket`` tokens a
+    member into a shared slot row, under the member vmap and with a write
+    gate a member, the cache donated, as ``engine._admit_fn_members`` writes
+    it (less the first token's sampling, which touches no weight)."""
+    import jax
+    import jax.numpy as jnp
+
+    from quorum_tpu.engine.engine import _member_vmap
+    from quorum_tpu.models.transformer import prefill
+
+    def admit(params, tokens, lengths, slot, enables, ck, cv):
+        def one(p, tok, lens, k, v, gate):
+            return prefill(p, spec, tok, lens, k, v, slot=slot,
+                           write_gate=gate)
+
+        return _member_vmap(one, params, tokens, lengths, ck, cv, enables)
+
+    ck, cv = _stacked_cache(spec, rows, members)
+    args = (_stacked_weights(spec, members, quant),
+            jax.ShapeDtypeStruct((members, 1, bucket), jnp.int32),
+            jax.ShapeDtypeStruct((members, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((members,), jnp.bool_),
+            ck, cv)
+    return _compile(admit, device, args, donate=(5, 6))
+
+
+def compile_member_segment(spec, device, *, rows: int, members: int,
+                           bucket: int, history: int,
+                           quant: str | None = None):
+    """Compile a stacked engine's member-coalesced prompt segment
+    (``engine._seg_fn_members``: ``transformer.prefill_segment`` under the
+    member vmap, the cache donated) for ``device`` on shapes alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from quorum_tpu.engine.engine import _member_vmap
+    from quorum_tpu.models.transformer import prefill_segment
+
+    def seg(params, tokens, offsets, n_valids, slots, enables, ck, cv):
+        def one(p, tok, off, nv, slot, en, k, v):
+            return prefill_segment(p, spec, tok, off, nv, k, v, slot,
+                                   history=history, write_gate=en)
+
+        return _member_vmap(
+            one, params, tokens, offsets, n_valids, slots, enables, ck, cv)
+
+    ck, cv = _stacked_cache(spec, rows, members)
+    ints = jax.ShapeDtypeStruct((members,), jnp.int32)
+    args = (_stacked_weights(spec, members, quant),
+            jax.ShapeDtypeStruct((members, 1, bucket), jnp.int32),
+            ints, ints, ints,
+            jax.ShapeDtypeStruct((members,), jnp.bool_),
+            ck, cv)
+    return _compile(seg, device, args, donate=(6, 7))
 
 
 def _computations(text: str) -> "dict[str, list[str]]":
@@ -196,6 +289,11 @@ def loop_body_ops(text: str, sizes: "set[int]") -> "list[tuple]":
     return program_ops(text, sizes, loops_only=True)
 
 
+def _dims(shape: str) -> "list[int]":
+    """``bf16[3,5,4096,1024]`` (a row's shape column) to its dimensions."""
+    return [int(d) for d in shape[shape.index("[") + 1:-1].split(",")]
+
+
 def _moves(rows: "list[tuple]", opcodes: tuple) -> "list[tuple]":
     # by opcode, or a fusion the compiler named for what it does
     return [row for row in rows if row[2] in opcodes or (
@@ -220,6 +318,33 @@ def slab_moves(text: str, slab_elements: int) -> "list[tuple]":
     program: a read that moves its history window before it contracts it."""
     return _moves(program_ops(text, {slab_elements}),
                   _SLAB_MOVES + ("AllocateBuffer",))
+
+
+def weight_matrices(spec, members: int,
+                    quant: "str | None" = None) -> "list[tuple]":
+    """The dimensions of every stacked block matrix of a ``members`` engine
+    (the leaves ``quant.QUANT_REDUCE_AXIS`` names under ``blocks``; an int8
+    leaf's values, not its scales), as the engine holds them."""
+    from quorum_tpu.models.quant import QUANT_REDUCE_AXIS, is_quantized
+
+    blocks = _stacked_weights(spec, members, quant)["blocks"]
+    leaves = [blocks[name] for name in QUANT_REDUCE_AXIS
+              if blocks.get(name) is not None]
+    return [tuple((leaf["q8"] if is_quantized(leaf) else leaf).shape)
+            for leaf in leaves]
+
+
+def weight_moves(text: str, matrices: "list[tuple]") -> "list[tuple]":
+    """The rows of :func:`program_ops`, anywhere in the program, that copy or
+    transpose an array with the dimensions of one of ``matrices`` in any
+    order (:func:`weight_matrices`): a ``copy``, ``copy-start``,
+    ``copy-done`` or ``transpose``, or a fusion the compiler named for one.
+    A member ``vmap`` whose layer scan's ``xs`` are not batched at axis 1
+    leaves one per block matrix at the head of the program."""
+    wanted = {tuple(sorted(m)) for m in matrices}
+    rows = program_ops(text, {math.prod(m) for m in matrices})
+    return [row for row in _moves(rows, _WEIGHT_MOVES)
+            if tuple(sorted(_dims(row[3]))) in wanted]
 
 
 def kernel_calls(text: str) -> "list[tuple]":
@@ -251,18 +376,35 @@ def main(argv: "list[str]") -> int:
     options = dict(parse_qsl("".join(argv[1:2])))
     spec = resolve_spec(argv[0], options)
     rows, members = int(options.get("slots", 8)), int(options.get("members", 1))
-    compiled = compile_decode_chunk(
-        spec, v5e_device(), rows=rows, members=members,
-        history=int(options.get("history", 512)), quant=options.get("quant"))
-    text = compiled.as_text()
+    shape = dict(rows=rows, members=members, quant=options.get("quant"))
+    device = v5e_device()
+    programs = {"decode chunk": compile_decode_chunk(
+        spec, device, history=int(options.get("history", 512)), **shape)}
+    if members > 1:
+        for bucket in (32, 128):
+            programs[f"member admit, bucket {bucket}"] = compile_member_admit(
+                spec, device, bucket=bucket, **shape)
+        programs["member segment, bucket 512"] = compile_member_segment(
+            spec, device, bucket=min(512, spec.max_seq),
+            history=spec.max_seq, **shape)
     carried, slabs = cache_sizes(spec, rows, members)
-    print(f"temp\t{compiled.memory_analysis().temp_size_in_bytes / 1e9:.4f} GB")
-    whole = [row for size in carried
-             for row in whole_cache_moves(text, size, loops_only=False)]
-    slab = [row for size in slabs for row in slab_moves(text, size)]
-    for row in program_ops(text, set(carried + slabs)):
-        print(*row, "WHOLE-CACHE MOVE" if row in whole
-              else "SLAB MOVE" if row in slab else "", sep="\t")
+    matrices = weight_matrices(spec, members, shape["quant"]) \
+        if members > 1 else []
+    for name, compiled in programs.items():
+        text = compiled.as_text()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        if members > 1:
+            print(f"== {name}")
+        print(f"temp\t{temp / 1e9:.4f} GB")
+        whole = [row for size in carried
+                 for row in whole_cache_moves(text, size, loops_only=False)]
+        slab = [row for size in slabs for row in slab_moves(text, size)]
+        weight = weight_moves(text, matrices)
+        for row in program_ops(text, set(carried + slabs)) + [
+                row for row in weight if row not in whole + slab]:
+            print(*row, "WHOLE-CACHE MOVE" if row in whole
+                  else "SLAB MOVE" if row in slab
+                  else "WEIGHT MOVE" if row in weight else "", sep="\t")
     return 0
 
 

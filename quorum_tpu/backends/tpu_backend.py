@@ -161,7 +161,8 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
   members=M        stacked fan-out (default 1 = off): backends whose URLs
   member=i         agree on ``members=M`` (and the base seed/spec) share ONE
                    engine holding M independently-seeded weight sets
-                   (seed..seed+M-1) stacked [M, …] on device; ``member=i``
+                   (seed..seed+M-1) stacked on device (block leaves
+                   [L, M, …], the rest [M, …]); ``member=i``
                    selects which weight set serves THIS backend. Each member
                    keeps its own slots/sampler state and produces its own
                    stream, but every decode chunk —

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from quorum_tpu.models.transformer import forward_hidden
+from quorum_tpu.parallel.sharding import member_params
 
 # Requests above this many inputs are rejected at the API layer; buckets
 # stop here.
@@ -62,7 +63,7 @@ def _embed_fn(engine, b_bucket: int, t_bucket: int):
 
     def run(params, tokens, lengths, member):
         if stacked:
-            params = jax.tree.map(lambda x: x[member], params)
+            params = member_params(params, member)
         h = forward_hidden(params, spec, tokens, lengths)  # [B, T, D]
         mask = (jnp.arange(t_bucket)[None, :] < lengths[:, None]).astype(
             jnp.float32)
@@ -100,7 +101,7 @@ def embed_token_batch(
         tokens[i, : len(t)] = t
         lengths[i] = max(len(t), 1)  # empty input → one pad-id token
     out = _embed_fn(engine, b_bucket, t_bucket)(
-        engine.params, tokens, lengths, np.int32(member))
+        engine.weights, tokens, lengths, np.int32(member))
     from quorum_tpu.engine.engine import _host_fetch
 
     return np.asarray(_host_fetch(out))[:n]

@@ -37,7 +37,9 @@ concurrent requests):
     mesh (tests), a single TPU chip (bench), or a tp×dp slice (GSPMD inserts
     the collectives).
   - **Stacked fan-out members** (``members=M``): the N-model quorum's weight
-    sets live ``[M, …]`` on ONE engine; every decode chunk, coalesced
+    sets live stacked on ONE engine (block leaves layers-major
+    ``[L, M, …]``, the rest ``[M, …]``: ``sharding.member_axes``, so that
+    no program re-lays them); every decode chunk, coalesced
     admission (single-shot or chunked segment), and speculative-verify step
     advances ALL members in a single member-vmapped program — N models'
     streams for one host turnaround per dispatch.
@@ -164,7 +166,10 @@ from quorum_tpu.ops.sampling import (
 )
 from quorum_tpu.parallel.mesh import single_device_mesh
 from quorum_tpu.parallel.sharding import (
+    BLOCKS,
     kv_cache_sharding,
+    member_axes,
+    member_params,
     paged_kv_sharding,
     shard_pytree,
 )
@@ -335,6 +340,18 @@ def _host_fetch(*arrays):
     return tuple(out) if len(arrays) > 1 else out[0]
 
 
+def _member_vmap(fn, params, *args):
+    """``fn`` vmapped over the members of a stacked engine: the stacked
+    weight tree mapped where ``member_axes`` says its member axis is (block
+    leaves are held layers-major, so the layer scan inside ``fn`` reads
+    them where they lie), every other argument at axis 0. The one call
+    every member-vmapped program family makes (decode chunk and speculative
+    verify through :func:`_stacked_rows_call`, member admit, member
+    segment)."""
+    in_axes = (member_axes(params),) + (0,) * len(args)
+    return jax.vmap(fn, in_axes=in_axes)(params, *args)
+
+
 def _stacked_rows_call(mem: int, n_s: int, fn, params, ck, cv, *rows):
     """Member-vmapped model call over flat member-major row arrays.
 
@@ -344,8 +361,25 @@ def _stacked_rows_call(mem: int, n_s: int, fn, params, ck, cv, *rows):
     the fold/unfold convention shared by the stacked decode chunk and the
     stacked speculative-verify step."""
     folded = tuple(r.reshape((mem, n_s) + r.shape[1:]) for r in rows)
-    logits, ck, cv = jax.vmap(fn)(params, ck, cv, *folded)
+    logits, ck, cv = _member_vmap(fn, params, ck, cv, *folded)
     return logits.reshape((mem * n_s,) + logits.shape[2:]), ck, cv
+
+
+class _MemberFirst:
+    """A layers-major block leaf ``[L, M, …]`` of a stacked engine, indexed
+    member first: ``leaf[member, layer, …]``. What ``InferenceEngine.params``
+    hands a host reader that takes one member's layer by index (the
+    benchmark's reference check); the slice runs on the device, the leaf is
+    never re-laid."""
+
+    __slots__ = ("leaf",)
+
+    def __init__(self, leaf):
+        self.leaf = leaf
+
+    def __getitem__(self, idx):
+        member, layer, *rest = idx
+        return self.leaf[(layer, member, *rest)]
 
 
 def prefill_bucket(n: int, max_seq: int) -> int:
@@ -1551,7 +1585,7 @@ class InferenceEngine:
         self._resident: list[list[int]] = [[] for _ in range(self._rows)]
         self.prefix_hits = 0
         self.prefix_tokens_saved = 0
-        self.params = self._build_params(self.mesh, params, seed)
+        self.weights = self._build_params(self.mesh, params, seed)
         # Disaggregated serving: the prefill group needs its own weight copy
         # (its programs cannot read across the group boundary — GSPMD never
         # spans both meshes) and a staging KV cache the admission segments
@@ -1562,7 +1596,7 @@ class InferenceEngine:
         # alias, not a second allocation).
         self.prefill_params = (
             self._build_params(self.prefill_mesh, params, seed)
-            if self.disagg else (self.params if self.zero_drain else None))
+            if self.disagg else (self.weights if self.zero_drain else None))
         self._cache_sh = self._cache_sharding(self.mesh)
         self._rep = NamedSharding(self.mesh, P())
         # Host-side wire-format contract (prefix-store snapshot/restore and
@@ -1851,6 +1885,20 @@ class InferenceEngine:
             self.n_slots, self.spec.max_seq, rep["platform"],
             rep["device_kind"], rep["device_count"], rep["mesh"])
 
+    @property
+    def params(self):
+        """The weights for a reader that takes arrays by index (the
+        benchmark's reference check, tests): ``weights``, the tree the
+        programs run, except that a stacked engine's layers-major block
+        leaves index the member first like every other leaf of it
+        (``leaf[member, layer]``, :class:`_MemberFirst`). Programs take
+        ``weights``."""
+        if self.members <= 1 or self.weights is None:
+            return self.weights
+        view = dict(self.weights)
+        view[BLOCKS] = jax.tree.map(_MemberFirst, view[BLOCKS])
+        return view
+
     def _build_params(self, mesh: Mesh, params, seed: int):
         """One device group's weight tree: shared by the decode mesh and
         (under disagg) the prefill mesh — both groups must hold identical
@@ -1859,8 +1907,9 @@ class InferenceEngine:
         if self.members > 1:
             from quorum_tpu.models.init import init_params_ensemble_sharded
 
-            # The stacked-init program: [M, …] leaves, one seed per member,
-            # quant applied per member inside the init.
+            # The stacked-init program: one seed per member, quant applied
+            # per member inside the init; block leaves layers-major
+            # [L, M, …], the rest [M, …] (sharding.member_axes).
             # member_seeds=shared repeats ONE seed: every member holds
             # identical weights (one model, M sampling streams) — the
             # quorum_dedup precondition (docs/quorum.md).
@@ -2401,8 +2450,8 @@ class InferenceEngine:
                                write_gate=gate, tp_mesh=tp_mesh)
 
             with tracing_program(f"admit_members/{bucket}"):
-                logits, ck, cv = jax.vmap(one)(
-                    params, tokens, lengths, ck, cv, enables)
+                logits, ck, cv = _member_vmap(
+                    one, params, tokens, lengths, ck, cv, enables)
             adj = logits[:, 0].astype(jnp.float32) + bias_rows  # [M, V]
             # Same PRNG stream as the single-model admit: sample the first
             # token with split row 1, carry row 0 — a member's stream is
@@ -2489,12 +2538,20 @@ class InferenceEngine:
             # one fn swap. ``enables`` is all-True by construction (the
             # dedup route only fires on full live groups) — unused.
             del enables
-            p0 = jax.tree.map(lambda x: x[0], params)
+            # Member 0's weights. The leaves outside the blocks are sliced
+            # here; the blocks stay stacked and the layer scan picks the
+            # member out of each layer's [M, …] slice (block_member=0),
+            # which it reads anyway: ``x[:, 0]`` of a layers-major leaf
+            # out here is a strided slice the compiler would materialize,
+            # a copy of one member's whole block weights per admit.
+            p0 = member_params(
+                {k: v for k, v in params.items() if k != BLOCKS}, 0)
+            p0[BLOCKS] = params[BLOCKS]
             mini = jnp.zeros((ell, 1, bucket, kv * hd), dt)
             with tracing_program(f"admit_dedup/{bucket}"):
                 logits, mini_k, mini_v = prefill(
                     p0, spec, tokens[0], lengths[0], mini, mini,
-                    tp_mesh=self._tp_mesh)
+                    tp_mesh=self._tp_mesh, block_member=0)
 
             if paged:
                 hp = -(-bucket // ps)
@@ -4631,7 +4688,7 @@ class InferenceEngine:
             # NOT null the state under it — the thread exits at its next
             # scheduler-loop boundary and the GC reclaims everything then.
             return
-        self.params = None
+        self.weights = None
         self._ck = self._cv = None
         if self.staged:
             self.prefill_params = None
@@ -5291,7 +5348,7 @@ class InferenceEngine:
              self._live, self._budget, self._eos,
              ) = (self._dedup_admit_fn(bucket) if use_dedup
                   else self._admit_fn_members(bucket))(
-                self.params, tokens, lengths, np.int32(row), enables, seeds,
+                self.weights, tokens, lengths, np.int32(row), enables, seeds,
                 temps, topps, topks, pps, fps, bias_rows, budgets, eoss,
                 self._ck, self._cv, self._token, self._lengths, self._keys,
                 self._temp, self._topp, self._topk,
@@ -5351,8 +5408,8 @@ class InferenceEngine:
                 return prefill_segment(p, spec, tok, off, nv, k, v, slot,
                                        history=history, write_gate=en)
 
-            return jax.vmap(one)(
-                params, tokens, offsets, n_valids, slots, enables, ck, cv)
+            return _member_vmap(
+                one, params, tokens, offsets, n_valids, slots, enables, ck, cv)
 
         fn = jax.jit(seg, donate_argnames=("ck", "cv"))
         self._admit_cache[("mseg", bucket, history)] = fn
@@ -5454,7 +5511,7 @@ class InferenceEngine:
         with self._attr_time("mseg"), self._segment_dispatch(
                 batch.values(), "mseg", bucket, int(n_valids.sum()), mem):
             self._ck, self._cv = self._seg_fn_members(bucket, history)(
-                self.params, tokens, offsets, n_valids, slots, enables,
+                self.weights, tokens, offsets, n_valids, slots, enables,
                 self._ck, self._cv,
             )
         for m, adm in batch.items():
@@ -5629,7 +5686,7 @@ class InferenceEngine:
                 with self._attr_time("seg"), self._segment_dispatch(
                         [adm], "seg", bucket, len(seg)):
                     self._ck, self._cv = self._seg_fn(bucket, history)(
-                        self.params, tokens, np.int32(adm.offset),
+                        self.weights, tokens, np.int32(adm.offset),
                         np.int32(len(seg)),
                         np.int32(adm.slot), self._ck, self._cv,
                     )
@@ -5679,7 +5736,7 @@ class InferenceEngine:
              self._temp, self._topp, self._topk,
              self._pp, self._fp, self._counts, self._bias,
              self._live, self._budget, self._eos) = self._admit_fn(bucket)(
-                self.params,
+                self.weights,
                 tokens,
                 np.asarray([n_prompt], np.int32),
                 np.int32(slot),
@@ -6591,7 +6648,7 @@ class InferenceEngine:
             spec_ok = jax.device_put(spec_ok, self._rep)
             out = self._spec_loop_fn(g, n_turns, history, want_lp,
                                      tstates=tstates)(
-                self.params, rt.params, mask, spec_ok, self._eos,
+                self.weights, rt.params, mask, spec_ok, self._eos,
                 self._g_trans, self._g_accept, self._ck, self._cv,
                 rt._ck, rt._cv, rt._chain, rt._chain_n, self._token,
                 self._lengths, self._keys, self._temp, self._topp,
@@ -6611,7 +6668,7 @@ class InferenceEngine:
         draft = jax.device_put(draft, self._rep)
         if constrained:
             out = self._verify_fn(g, history, want_lp, tstates=tstates)(
-                self.params, mask, self._eos, draft, self._g_trans,
+                self.weights, mask, self._eos, draft, self._g_trans,
                 self._g_accept, self._ck, self._cv, self._token,
                 self._lengths, self._keys, self._temp, self._topp,
                 self._topk, self._pp, self._fp, self._counts, self._bias,
@@ -6622,7 +6679,7 @@ class InferenceEngine:
              self._counts, self._live, self._budget, self._dfa) = tail
             return tuple(payload), drafted
         out = self._verify_fn(g, history, want_lp)(
-            self.params, mask, self._eos, draft, self._ck, self._cv,
+            self.weights, mask, self._eos, draft, self._ck, self._cv,
             self._token, self._lengths, self._keys, self._temp, self._topp,
             self._topk, self._pp, self._fp, self._counts, self._bias,
             self._live, self._budget)
@@ -6843,7 +6900,7 @@ class InferenceEngine:
             out = self._decode_fn(n_steps, want_lp, history,
                                   tstates=self._g_bucket,
                                   n_chunks=n_chunks)(
-                self.params, mask, self._eos, self._g_trans, self._g_accept,
+                self.weights, mask, self._eos, self._g_trans, self._g_accept,
                 self._ck, self._cv, self._token,
                 self._lengths, self._keys, self._temp, self._topp, self._topk,
                 self._pp, self._fp, self._counts, self._bias,
@@ -6859,7 +6916,7 @@ class InferenceEngine:
              self._budget, self._dfa) = out
             return (toks, n_valid, masked)
         out = self._decode_fn(n_steps, want_lp, history, n_chunks=n_chunks)(
-            self.params, mask, self._eos, self._ck, self._cv, self._token,
+            self.weights, mask, self._eos, self._ck, self._cv, self._token,
             self._lengths, self._keys, self._temp, self._topp, self._topk,
             self._pp, self._fp, self._counts, self._bias,
             self._live, self._budget,

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from quorum_tpu.engine.embed import MAX_BATCH, _batch_bucket, _seq_bucket
 from quorum_tpu.models.transformer import forward_logits
+from quorum_tpu.parallel.sharding import member_params
 
 
 def _score_fn(engine, b_bucket: int, t_bucket: int, top_k: int):
@@ -38,7 +39,7 @@ def _score_fn(engine, b_bucket: int, t_bucket: int, top_k: int):
 
     def run(params, tokens, lengths, member):
         if stacked:
-            params = jax.tree.map(lambda x: x[member], params)
+            params = member_params(params, member)
         # lengths gates MoE expert capacity: without it, an earlier row's
         # pad tokens would evict a later row's REAL tokens from the fixed
         # capacity buffers, making logprobs batch-composition-dependent.
@@ -86,7 +87,7 @@ def score_token_batch(
         tokens[i, : len(t)] = t
         lengths[i] = len(t)
     out = _score_fn(engine, b_bucket, t_bucket, top_k)(
-        engine.params, tokens, lengths, np.int32(member))
+        engine.weights, tokens, lengths, np.int32(member))
     from quorum_tpu.engine.engine import _host_fetch
 
     fetched = [np.asarray(x) for x in _host_fetch(*out)] if len(out) > 1 \

@@ -158,7 +158,8 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
 
 def init_params_from_key(spec: ModelSpec, key) -> Params:
     """Init from a PRNG key (traced-friendly: vmappable over stacked keys —
-    how stacked members materialize directly into their [M, …] slices)."""
+    how stacked members materialize directly into their slices,
+    :func:`init_params_ensemble_sharded`)."""
     spec.validate()
     if spec.layer_pattern:
         return init_patterned_from_key(spec, key)
@@ -239,23 +240,31 @@ def init_params_sharded(spec: ModelSpec, mesh, seed: int = 0) -> Params:
 def init_params_ensemble_sharded(
     spec: ModelSpec, mesh, seeds: list[int], quant: str | None = None
 ) -> Params:
-    """Member-stacked parameters ``[M, …]`` for a stacked engine
-    (``members=M``): each member is an independent seeded
-    init, vmapped over stacked PRNG keys so every leaf materializes directly
-    into its ``[M, …]`` slice — no per-member temporaries + stack copy
-    (which would transiently need ~2× the stack's weight HBM). The
-    member axis is replicated (vmapped, never communicated).
+    """Member-stacked parameters for a stacked engine (``members=M``), in
+    the layout ``parallel.sharding.member_axes`` names: block leaves
+    layers-major ``[L, M, …]`` (the member-vmapped layer scan reads a
+    layer's ``[M, …]`` slice where it lies), the rest ``[M, …]``. Each
+    member is an independent seeded init, vmapped over stacked PRNG keys
+    with the layout as the ``vmap``'s ``out_axes``, so every leaf
+    materializes directly into its place: no per-member temporaries and
+    stack copy, no transposition of a finished tree (either would
+    transiently need ~2× the stack's weight HBM). The member axis is
+    replicated (vmapped, never communicated).
 
     ``quant="int8"`` fuses per-member quantization into the same program
     (scales reduce over the contraction axis, so the stacked tree's scales
-    are exactly each member's own) — two int8 7B members fit one 16 GB
-    chip."""
-    from quorum_tpu.parallel.sharding import param_shardings
+    are exactly each member's own, laid as their leaf is) — two int8 7B
+    members fit one 16 GB chip."""
+    from quorum_tpu.parallel.sharding import member_axes, param_shardings
 
     keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
 
+    def one(key) -> Params:
+        return init_params_from_key(spec, key)
+
     def build(ks) -> Params:
-        params = jax.vmap(lambda k: init_params_from_key(spec, k))(ks)
+        params = jax.vmap(one, out_axes=member_axes(
+            jax.eval_shape(one, ks[0])))(ks)
         if quant == "int8":
             from quorum_tpu.models.quant import quantize_params
 
@@ -263,7 +272,7 @@ def init_params_ensemble_sharded(
         return params
 
     shapes = jax.eval_shape(build, keys)
-    shardings = param_shardings(mesh, shapes, lead_axes=1,
+    shardings = param_shardings(mesh, shapes, stacked=True,
                                 n_kv_heads=spec.n_kv_heads)
     return jax.jit(build, out_shardings=shardings)(keys)
 

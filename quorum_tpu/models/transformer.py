@@ -405,6 +405,7 @@ def prefill(
     write_gate: jnp.ndarray | None = None,  # scalar bool: False → cache unchanged
     sp_impl: str = "ring",  # "ring" | "ulysses" — SP attention strategy
     tp_mesh=None,  # the caller's mesh when heads are sharded over tp > 1
+    block_member: int | None = None,  # blocks are stacked [L, M, …]: take m
 ):
     """Process the full prompt; returns (last-token logits [B,V], cache_k, cache_v).
 
@@ -428,6 +429,12 @@ def prefill(
     memory is O(T/sp), KV blocks ride the ICI ring at KV-head width, and
     the K/V written to the cache is unchanged (the cache's seq axis stays
     replicated, so decode is sp-agnostic).
+
+    ``block_member`` (a static int): ``params["blocks"]`` is a stacked
+    engine's, layers-major ``[L, M, …]``, and the layer scan takes that
+    member's weights out of each layer's slice as it reads it: one member's
+    prefill outside the member vmap (the dedup admit) without slicing the
+    member out of every block leaf first.
     """
     if spec.layer_pattern:
         return patterned.prefill(params, spec, tokens, lengths, cache_k,
@@ -447,6 +454,8 @@ def prefill(
 
     def body(carry_x, per_layer):
         block, ck, cv = per_layer  # ck/cv: [B or S, max_seq, K·hd]
+        if block_member is not None:
+            block = jax.tree.map(lambda w: w[block_member], block)
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)
         if spec.pos == "rope":

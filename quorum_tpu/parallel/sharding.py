@@ -15,6 +15,8 @@ from __future__ import annotations
 from typing import Any, Mapping
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from quorum_tpu.parallel.mesh import AXIS_DP, AXIS_PP, AXIS_SP, AXIS_TP
@@ -132,15 +134,59 @@ ACT_AXES: tuple[str | None, ...] = ("batch", "seq", "model")
 TOKEN_AXES: tuple[str | None, ...] = ("batch", "seq")
 
 
+# ---- member-stacked trees (a ``members=M`` engine) ---------------------------
+#
+# The M members' weights are one tree of stacked leaves, and every model call
+# is a ``jax.vmap`` over the member axis. The block leaves are the layer
+# scan's ``xs``, and ``vmap`` of a ``lax.scan`` wants a batched ``xs`` at axis
+# 1 (it scans over axis 0): handed ``[M, L, …]`` it transposes every leaf
+# first, which the TPU compiler carries out as a copy of all the block
+# weights at the head of every program (PERF.md section 5 item 2). So the
+# blocks are held layers-major, ``[L, M, …]``: a layer's ``[M, …]`` slice is
+# contiguous and the scan reads it where it lies. The leaves outside the
+# blocks are no scan's ``xs`` and stay ``[M, …]``.
+
+BLOCKS = "blocks"
+
+
+def member_axes(params: Mapping[str, Any]) -> dict[str, int]:
+    """Where the member axis of a member-stacked parameter tree is, as a
+    tree prefix for ``jax.vmap``'s ``in_axes`` / ``out_axes``: 1 on every
+    leaf under ``blocks``, 0 elsewhere. THE one statement of the rule: the
+    stacked init, its shardings, every member-vmapped program and the
+    by-member readers take the axis from here."""
+    return {k: 1 if k == BLOCKS else 0 for k in params}
+
+
+def member_params(params: Mapping[str, Any], member) -> dict[str, Any]:
+    """One member's tree out of a member-stacked one (``member`` a Python
+    int or a traced scalar). Inside a program a block leaf's ``[:, m]`` is a
+    strided slice the compiler materializes, a member's share of the block
+    weights: for the by-member forwards (scoring, embeddings), never for a
+    program of the serving loop."""
+    return {k: jax.tree.map(
+        lambda x: lax.dynamic_index_in_dim(x, member, axis, keepdims=False),
+        params[k]) for k, axis in member_axes(params).items()}
+
+
+def stack_members(members: list) -> dict[str, Any]:
+    """Single-member trees stacked into the member-stacked layout (what the
+    stacked init program yields without the stack: models/init.py)."""
+    first = members[0]
+    return {k: jax.tree.map(lambda *leaves: jnp.stack(leaves, axis=axis),
+                            *(m[k] for m in members))
+            for k, axis in member_axes(first).items()}
+
+
 def param_partition_specs(
-    params: Mapping[str, Any], lead_axes: int = 0,
+    params: Mapping[str, Any], stacked: bool = False,
     *, replicate_kv_heads: bool = False
 ) -> dict[str, Any]:
     """PartitionSpec pytree matching a parameter pytree (same nesting).
 
-    ``lead_axes`` prepends that many replicated dims to every leaf's spec —
-    used for member-stacked params ``[M, …]`` (the member axis is
-    vmapped, never sharded).
+    ``stacked`` says the tree is member-stacked (``members=M``): every
+    leaf's spec gets one more replicated dim where :func:`member_axes` puts
+    the member axis (it is vmapped, never sharded).
 
     ``replicate_kv_heads`` replicates every leaf whose logical axes include
     ``kv_heads`` (wk/wv/bk/bv). The kv projection's output dim is the *flat*
@@ -154,15 +200,18 @@ def param_partition_specs(
     degrade rule: when kv heads don't divide tp, whole-head sharding is
     impossible and sharding half a head buys nothing."""
 
-    def spec_for(name: str) -> P:
+    def spec_for(name: str, member_axis: int | None) -> P:
         axes = PARAM_LOGICAL_AXES.get(name)
         if axes is None:
             return P()  # unknown leaf → replicate
         if replicate_kv_heads and "kv_heads" in axes:
             axes = tuple(None if a == "kv_heads" else a for a in axes)
-        return P(*((None,) * lead_axes + tuple(logical_to_spec(axes))))
+        spec = list(logical_to_spec(axes))
+        if member_axis is not None:
+            spec.insert(member_axis, None)
+        return P(*spec)
 
-    def walk(tree: Mapping[str, Any]) -> dict[str, Any]:
+    def walk(tree: Mapping[str, Any], member_axis: int | None) -> dict[str, Any]:
         out: dict[str, Any] = {}
         for k, v in tree.items():
             if isinstance(v, Mapping):
@@ -171,16 +220,18 @@ def param_partition_specs(
                     # leaf's shape → parent spec; the scale keeps the same
                     # logical axes with reduced dims at size 1, which
                     # _fit_spec auto-replicates (1 % mesh_size != 0).
-                    out[k] = {"q8": spec_for(k), "qs": spec_for(k)}
+                    spec = spec_for(k, member_axis)
+                    out[k] = {"q8": spec, "qs": spec}
                 else:
-                    out[k] = walk(v)
+                    out[k] = walk(v, member_axis)
             elif v is None:
                 out[k] = None
             else:
-                out[k] = spec_for(k)
+                out[k] = spec_for(k, member_axis)
         return out
 
-    return walk(params)
+    axes = member_axes(params) if stacked else dict.fromkeys(params)
+    return {k: walk({k: v}, axes[k])[k] for k, v in params.items()}
 
 
 def _fit_spec(spec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
@@ -197,15 +248,16 @@ def _fit_spec(spec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
 
 
 def param_shardings(
-    mesh: Mesh, params: Mapping[str, Any], lead_axes: int = 0,
+    mesh: Mesh, params: Mapping[str, Any], stacked: bool = False,
     n_kv_heads: int | None = None,
 ) -> dict[str, Any]:
-    """Shardings for a param pytree; pass ``n_kv_heads`` so GQA kv
-    projections degrade to replicated (whole leaf) when the head count
-    doesn't divide tp — see :func:`param_partition_specs`."""
+    """Shardings for a param pytree (``stacked``: a member-stacked one);
+    pass ``n_kv_heads`` so GQA kv projections degrade to replicated (whole
+    leaf) when the head count doesn't divide tp — see
+    :func:`param_partition_specs`."""
     replicate_kv = (n_kv_heads is not None
                     and n_kv_heads % mesh.shape[AXIS_TP] != 0)
-    specs = param_partition_specs(params, lead_axes,
+    specs = param_partition_specs(params, stacked,
                                   replicate_kv_heads=replicate_kv)
     return jax.tree.map(
         lambda x, s: None if x is None else NamedSharding(mesh, _fit_spec(s, x.shape, mesh)),

@@ -14,10 +14,15 @@ caches must be equal bit for bit, after one step and after an 8-step
 The static tests read the v5e compiler's text (analysis/decode_static.py):
 only that text shows whether a copy, a reshape, a slice or an allocation of a
 cache side or of a layer's slab sits in the program, and whether the Pallas
-call is there; the CPU's text does not (its scatter copies).
+call is there; the CPU's text does not (its scatter copies). The last of
+them hold the stacked quorum's member-vmapped programs to their weights'
+store (parallel/sharding.py ``member_axes``: block leaves layers-major): no
+program copies or transposes a stacked block matrix (tests/
+test_stacked_weights.py has the values' side of that store, on the CPU).
 """
 
 import dataclasses
+import math
 import threading
 
 import jax
@@ -32,6 +37,8 @@ from quorum_tpu.models import transformer as tr
 from quorum_tpu.models.init import init_params
 from quorum_tpu.models.model_config import MODEL_PRESETS
 from quorum_tpu.models.quant import quantize_params
+from quorum_tpu.parallel import sharding
+from quorum_tpu.parallel.sharding import stack_members
 
 TINY = dataclasses.replace(MODEL_PRESETS["llama-tiny"], max_seq=64)
 N_STEPS = 8
@@ -204,8 +211,7 @@ def run_case(case: Case):
     one = [init_params(spec, seed=s) for s in range(mem)]
     if case.quant:
         one = [quantize_params(p) for p in one]
-    params = one[0] if mem == 1 else jax.tree.map(
-        lambda *leaves: jnp.stack(leaves), *one)
+    params = one[0] if mem == 1 else stack_members(one)
     # a pipeline stage's slab holds more rows than the tick's group
     ck, cv = filled_cache(spec, case.shard[3] + 1 if case.shard else rows, 7,
                           case.kv_quant, mem)
@@ -395,3 +401,63 @@ def test_the_stacked_members_keep_xlas_einsums_over_the_same_store(
     assert not decode_static.kernel_calls(text)
     assert decode_static.slab_moves(text, slab)
     assert not decode_static.whole_cache_moves(text, side, loops_only=False)
+
+
+# ---- the stacked members' weights are read where they lie --------------------
+
+# quorum's shape, depth included: at 2 layers a stacked ``wk`` has as many
+# elements as a layer's cache slab
+QUORUM = dict(rows=8, members=3, quant=None)
+MEMBER_PROGRAMS = {
+    # name: (compile function, its shape, temp limit in GB)
+    "decode_chunk": ("compile_decode_chunk", dict(history=512), 0.05),
+    "member_admit_32": ("compile_member_admit", dict(bucket=32), 0.6),
+    "member_admit_128": ("compile_member_admit", dict(bucket=128), 0.6),
+    # its temp is the cache's two copies (0.50 GB: PERF.md section 7, not
+    # the weights') and a 128-token segment's activations
+    "member_segment_128": ("compile_member_segment",
+                           dict(bucket=128, history=1024), 0.7),
+}
+
+
+@pytest.fixture(scope="module")
+def quorum_spec():
+    return dataclasses.replace(MODEL_PRESETS["mistral-7b"], n_layers=5,
+                               max_seq=1024).validate()
+
+
+@pytest.mark.parametrize("name", sorted(MEMBER_PROGRAMS))
+def test_no_member_program_moves_a_stacked_block_matrix(
+        name, v5e, quorum_spec):
+    """No copy or transposition with a stacked block matrix's dimensions
+    (the smallest is ``wk``: 3 x 5 x 4096 x 1024) and no weight-sized
+    temporary: 6.54 GB of them a program when the blocks were ``[M, L, …]``
+    (PERF.md section 5 item 2)."""
+    fn, shape, temp_gb = MEMBER_PROGRAMS[name]
+    compiled = within(COMPILE_LIMIT_S, getattr(decode_static, fn),
+                      quorum_spec, v5e, **QUORUM, **shape)
+    matrices = decode_static.weight_matrices(quorum_spec, 3)
+    assert (3 * 5 * 4096 * 1024) == min(math.prod(m) for m in matrices)
+    assert not decode_static.weight_moves(compiled.as_text(), matrices)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+
+
+def test_the_reader_finds_the_copies_of_a_members_major_store(
+        v5e, quorum_spec, monkeypatch):
+    """The same chunk over the tree as it was held, ``[M, L, …]``: the
+    member vmap moves the layer scan's ``xs`` to axis 1, seven copies and
+    6.54 GB of temporaries."""
+    from quorum_tpu.engine import engine
+
+    def members_major(params):
+        return dict.fromkeys(params, 0)
+
+    monkeypatch.setattr(sharding, "member_axes", members_major)
+    monkeypatch.setattr(engine, "member_axes", members_major)
+    compiled = within(COMPILE_LIMIT_S, decode_static.compile_decode_chunk,
+                      quorum_spec, v5e, history=512, **QUORUM)
+    matrices = decode_static.weight_matrices(quorum_spec, 3)
+    assert sorted(matrices)[0] == (3, 5, 4096, 1024)
+    moves = decode_static.weight_moves(compiled.as_text(), matrices)
+    assert len(moves) == len(matrices) == 7
+    assert compiled.memory_analysis().temp_size_in_bytes > 6.5e9
