@@ -103,7 +103,7 @@ SHAPE_KNOBS = {"decode_chunk", "prefill_chunk", "decode_loop",
                "decode_pipeline", "spec_decode"}
 
 # Names whose call RESULT is a host (numpy/python) value.
-HOST_FETCHERS = {"_host_fetch", "fetch_to_host"}
+HOST_FETCHERS = {"_host_fetch", "fetch_to_host", "_fetch_landing"}
 HOST_BUILTINS = {"len", "min", "max", "sum", "sorted", "list", "tuple",
                  "dict", "set", "range", "enumerate", "zip", "abs", "round",
                  "str", "repr", "any", "all", "int", "float", "bool", "id",

@@ -99,7 +99,6 @@ import contextlib
 import logging
 import os
 import queue
-import statistics
 import threading
 import time
 import weakref
@@ -123,6 +122,9 @@ from quorum_tpu.breaker import (  # noqa: F401  (constants re-exported)
     BREAKER_WINDOW_S,
     Breaker,
 )
+from quorum_tpu.telemetry.device_ledger import (DECODE, OTHER, PREFILL,
+                                                DeviceLedger,
+                                                device_families)
 from quorum_tpu.telemetry.latency import LatencyModel
 from quorum_tpu.telemetry.recorder import RECORDER as FLIGHT
 from quorum_tpu.cache import kv_transfer
@@ -211,9 +213,12 @@ MAX_DECODE_LOOP = 64
 # EWMA weight for the per-chunk device-latency estimate feeding the
 # deadline clamp on the effective megachunk length.
 CHUNK_EWMA_ALPHA = 0.3
-# Chunks with prefill segments ahead of them whose readings of a segment
-# token's pace are kept; the segment rule uses their median (_segment_room).
-SEG_PACE_SAMPLES = 5
+# The stall witness (_note_stall): one blocking wait on a landing, or one
+# stretch the device stood dry while the loop was neither idle nor
+# compiling, longer than this and than this many times the family's booked
+# median, is counted, logged and dumped.
+STALL_MIN_S = 2.0
+STALL_MEDIANS = 10.0
 # Concurrent scoring/embedding device forwards per engine (see
 # ``score_gate`` in InferenceEngine.__init__); excess requests 503.
 SCORE_GATE_SLOTS = 2
@@ -338,6 +343,12 @@ def _host_fetch(*arrays):
     out = jax.device_get(  # qlint: allow-sync(the one blocking read per dispatch)
         tuple(gather(x) for x in arrays))
     return tuple(out) if len(arrays) > 1 else out[0]
+
+
+def _block(witness) -> None:
+    """Wait for a program's witness ahead of the turn's blocking fetch."""
+    # qlint: allow-sync(inside the turn's one blocking wait: a landing between the programs queued ahead of the fetched one)
+    jax.block_until_ready(witness)
 
 
 def _member_vmap(fn, params, *args):
@@ -630,11 +641,11 @@ class _InflightChunk:
 
     __slots__ = ("payload", "active", "n_steps", "t0", "history", "depth",
                  "constrained", "n_chunks", "spec_turn", "drafted",
-                 "stacked", "family", "seq", "t_ready", "seg_tokens", "moe")
+                 "stacked", "family", "seq", "prog", "moe")
 
     def __init__(self, payload, active, n_steps, t0, history, depth,
                  constrained=False, n_chunks=1, spec_turn=False, drafted=0,
-                 stacked=None, family="", seq=0, seg_tokens=0, moe=None):
+                 stacked=None, family="", seq=0, prog=None, moe=None):
         self.payload = payload
         # A patterned spec's expert counters as they stood after this
         # dispatch (_moe_snapshot): a device future the reap fetches.
@@ -663,20 +674,15 @@ class _InflightChunk:
         # (the fused draft→verify scan emits it even at one turn; plain
         # chunk/verify payloads gain it in the reap's normalization).
         self.stacked = n_chunks > 1 if stacked is None else stacked
-        # Tokens (as padded) of the segment programs queued on the device
-        # ahead of this dispatch, since the dispatch before it
-        # (_book_segment_time).
-        self.seg_tokens = seg_tokens
-        # Device-time attribution (telemetry/latency.py): the program-key
-        # family this dispatch compiled under (compile_budget.json), its
-        # flight-recorder sequence number, and the first stamp at which the
-        # payload was observed landed — the ready() probe's success, else
-        # the blocking fetch's completion. dispatch→t_ready is the
-        # per-family device-seconds observation; neither stamp adds a
-        # blocking sync.
+        # Device-time attribution: the program-key family this dispatch
+        # compiled under (compile_budget.json), its flight-recorder
+        # sequence number, and its entry in the device ledger
+        # (telemetry/device_ledger.py), which the first observation of the
+        # payload landed — the ready() probe's success, else the blocking
+        # fetch's completion — books landing to landing.
         self.family = family
         self.seq = seq
-        self.t_ready: "float | None" = None
+        self.prog = prog
 
     @property
     def tokens_ahead(self) -> int:
@@ -692,8 +698,8 @@ class _InflightChunk:
                          if isinstance(x, jax.Array))
         except Exception:
             return False
-        if landed and self.t_ready is None:
-            self.t_ready = time.perf_counter()
+        if landed:
+            self.prog.land(time.perf_counter(), exact=False)
         return landed
 
 
@@ -711,7 +717,7 @@ class _Admission:
     admission span can attribute cache effectiveness per tier."""
 
     __slots__ = ("req", "slot", "offset", "offset0", "restored", "t_start",
-                 "handed", "final_sent", "dead", "segments", "turn0", "wait0")
+                 "handed", "final_sent", "dead", "segments", "turn0", "acct")
 
     def __init__(self, req: _Request, slot: int, offset: int = 0,
                  restored: int = 0):
@@ -731,11 +737,12 @@ class _Admission:
         self.final_sent = False
         self.dead = False
         # What the admission's ``prefill`` span waited for: segment programs
-        # dispatched so far, and the loop's turn count and running decode
-        # time as they stood at the first of them (_segment_dispatch).
+        # dispatched so far, the loop's turn count at the first of them
+        # (_segment_dispatch), and the span's account in the device ledger
+        # (_open_admission).
         self.segments = 0
         self.turn0 = 0
-        self.wait0 = 0.0
+        self.acct = None
 
 
 class _SegmentRoom:
@@ -1676,25 +1683,22 @@ class InferenceEngine:
         self._turn_lock = threading.Lock()
         self._turn_s = dict.fromkeys(TURN_PHASES, 0.0)
         self._phase_open: dict[int, dict] = {}
-        # What a chunked admission's ``prefill`` span snapshots to say how
-        # much of it was waiting behind decode chunks: the decode loop's
-        # turn count, its running time inside _run_chunk, and of that the
-        # device's time on segment programs (_book_segment_time: segments
-        # tokens, as padded, queued since the last chunk dispatch, the
-        # landing stamp of the last chunk reaped, and a decode step's time
-        # in the last chunk that had no segment ahead of it). That pace and
-        # a segment token's, the median of what the last few chunks with
-        # segments ahead of them gave (one that met a compile, or noise
-        # around nothing, moves no median), also say how many segments a
-        # turn may dispatch ahead of its chunk (_segment_room).
+        # The one account of device time (telemetry/device_ledger.py):
+        # every program a loop dispatches is appended (_sent), every
+        # landing it sees books the interval since the one before to the
+        # programs in between, and a dry device's time goes to the phase
+        # the loop had open (_phase_switch). It feeds the device_* and
+        # prefill_{own,peer,decode_wait} families of metrics(), the
+        # per-family latency model and histogram (_booked), the parts of a
+        # ``prefill`` span, and the two paces that say how many segments a
+        # turn may dispatch ahead of its chunk (_segment_room). A disagg
+        # engine's prefill loop books its own device group.
+        self._ledger = DeviceLedger(TURN_PHASES, self._booked)
+        self._prefill_ledger = (DeviceLedger(TURN_PHASES, self._booked)
+                                if self.disagg else None)
+        self._prefill_thread = None  # set where the loops start
         self.n_turns = 0
-        self._chunk_s = 0.0
-        self._seg_s = 0.0
-        self._seg_queued = 0
-        self._ready_prev = 0.0
-        self._step_alone_s = 0.0
-        self._seg_tok_s = 0.0
-        self._seg_tok_samples: deque = deque(maxlen=SEG_PACE_SAMPLES)
+        self.n_stalls = 0
         # Prefill programs dispatched (admit, member-admit, segment): prompt
         # tokens they were asked to compute against tokens as padded to the
         # program's rows x bucket; segment programs, and the turns that
@@ -1706,6 +1710,8 @@ class InferenceEngine:
         self.n_prefill_segment_turns = 0
         self.prefill_span_s = 0.0
         self.prefill_decode_wait_s = 0.0
+        self.prefill_own_s = 0.0
+        self.prefill_peer_s = 0.0
 
         self._admit_cache: dict[int, object] = {}   # bucket → compiled admit
         self._decode_cache: dict[int, object] = {}  # n_steps → compiled chunk
@@ -2341,11 +2347,9 @@ class InferenceEngine:
         cache write. Data flow orders everything: the admission program
         consumes both the copied pool and the new table arrays."""
         for dst, src in cow:
-            t0 = time.perf_counter()
             self._ck, self._cv = self._page_copy_fn()(
                 self._ck, self._cv, np.int32(dst), np.int32(src))
-            self._observe_device_time("page_copy",
-                                      time.perf_counter() - t0)
+            self._sent(OTHER, "page_copy")
             self.kv_page_cow_copies += 1
             obs.KV_PAGE_COW_COPIES.inc()
         self._paged_sync_table()
@@ -2792,9 +2796,9 @@ class InferenceEngine:
                 have = self.prefix_store.covered(tokens)
                 if have >= len(tokens):
                     continue
-                with self._attr_time("snap"):
-                    payload = self._snapshot_fn(len(tokens) - have)(
-                        self._ck, self._cv, np.int32(slot), np.int32(have))
+                payload = self._snapshot_fn(len(tokens) - have)(
+                    self._ck, self._cv, np.int32(slot), np.int32(have))
+                self._sent(OTHER, "snap")
                 self._snap_queue.put((tokens, have, payload))
             except Exception:
                 # Snapshots are opportunistic: a failed slice (first-use
@@ -2989,16 +2993,18 @@ class InferenceEngine:
         if stage:
             self._sck, self._scv = self._restore_fn(n)(
                 self._sck, self._scv, np.int32(slot), np.int32(start), host)
+            prog = self._sent(OTHER, "restore")
             # qlint: allow-sync(admission path; blocking here is the honest restore latency the histogram reports)
             jax.block_until_ready((self._sck, self._scv))
         else:
             self._ck, self._cv = self._restore_fn(n)(
                 self._ck, self._cv, np.int32(slot), np.int32(start), host)
+            prog = self._sent(OTHER, "restore")
             # qlint: allow-sync(admission path; blocking here is the honest restore latency the histogram reports)
             jax.block_until_ready((self._ck, self._cv))
         t1 = time.perf_counter()
+        prog.land(t1)
         obs.PREFIX_STORE_RESTORE.observe(t1 - t0)
-        self._observe_device_time("restore", t1 - t0)
         obs.PREFIX_STORE_HITS.inc()
         obs.PREFIX_STORE_RESTORED_TOKENS.inc(n)
         self.prefix_store_hits += 1
@@ -3056,10 +3062,9 @@ class InferenceEngine:
         b = 1 << (upto - adm.handed - 1).bit_length()
         b = min(b, self.spec.max_seq)
         start = max(0, upto - b)
-        with self._attr_time("hslice"):
-            payload = self._handoff_slice_fn(b)(
-                self._sck, self._scv, np.int32(adm.slot), np.int32(start))
-        return (payload, start, b, upto)
+        payload = self._handoff_slice_fn(b)(
+            self._sck, self._scv, np.int32(adm.slot), np.int32(start))
+        return (payload, start, b, upto, self._sent(OTHER, "hslice"))
 
     def _handoff_commit(self, adm: _Admission, disp, final: bool = False):
         """Transfer a dispatched slice device→device onto the decode mesh
@@ -3069,7 +3074,7 @@ class InferenceEngine:
         dispatched before segment i+1, so this transfer proceeds while the
         prefill group computes the next segment."""
         if disp is not None:
-            payload, start, b, upto = disp
+            payload, start, b, upto, prog = disp
             faults.fire("engine.kv_handoff")
             t0 = time.perf_counter()
             if self.zero_drain:
@@ -3081,6 +3086,9 @@ class InferenceEngine:
             else:
                 moved, n_bytes, dt, route = kv_transfer.transfer(
                     payload, self._rep)
+                # the transfer waited for the slice: the prefill group's
+                # programs up to it have landed
+                prog.land(time.perf_counter())
                 self.n_kv_handoffs += 1
                 self.kv_handoff_bytes += n_bytes
                 self.kv_handoff_s += dt
@@ -3120,10 +3128,10 @@ class InferenceEngine:
                     # (no-op when clean, and always on THIS loop — the
                     # decode-cache owner).
                     self._paged_sync_table()
-                    with self._attr_time("hput"):
-                        self._ck, self._cv = self._handoff_write_fn(n)(
-                            self._ck, self._cv, chunk,
-                            np.int32(adm.slot), np.int32(start))
+                    self._ck, self._cv = self._handoff_write_fn(n)(
+                        self._ck, self._cv, chunk,
+                        np.int32(adm.slot), np.int32(start))
+                    self._sent(OTHER, "hput")
                     FLIGHT.record("inject", rid=adm.req.rid,
                                   engine=self._tag, loop="decode",
                                   slot=adm.slot, tokens=n)
@@ -3195,7 +3203,7 @@ class InferenceEngine:
             return
         if restore is not None:
             offset = restore[0]
-        adm = _Admission(req, slot, offset=offset, restored=offset)
+        adm = self._open_admission(req, slot, offset=offset, restored=offset)
         FLIGHT.record("stage-admit", rid=req.rid, engine=self._tag,
                       loop="prefill" if self.disagg else "decode",
                       slot=slot, restored=offset)
@@ -3440,8 +3448,8 @@ class InferenceEngine:
         with self._cond:
             rows, self._pending_dfa_resets = self._pending_dfa_resets, []
         for r in rows:
-            with self._attr_time("dfa_reset"):
-                self._dfa = self._dfa_reset_fn()(self._dfa, np.int32(r))
+            self._dfa = self._dfa_reset_fn()(self._dfa, np.int32(r))
+            self._sent(OTHER, "dfa_reset")
 
     def _decode_key(self, n_steps: int, want_lp: bool, history: int,
                     constrained: bool, n_chunks: int = 1):
@@ -4412,7 +4420,8 @@ class InferenceEngine:
         if fn is None:
             fn = self._util_fns["moe_snapshot"] = jax.jit(lambda a: a + 0)
         self._moe_seq += 1
-        return self._moe_seq, fn(self._ck.stats)
+        counts = fn(self._ck.stats)
+        return self._moe_seq, counts, self._sent(OTHER, "snapshot")
 
     def _moe_note(self, snapshot) -> "int | None":
         """Add a fetched snapshot to the totals; returns the picks that
@@ -4420,7 +4429,7 @@ class InferenceEngine:
         counts wrap: the difference is taken modulo 2**32). A single-shot
         admit reads its snapshot at once, ahead of the chunks dispatched
         before it: theirs, older and already counted, give None."""
-        seq, counts = snapshot
+        seq, counts, _ = snapshot
         if seq < self._moe_noted:
             return None
         self._moe_noted = seq
@@ -4608,6 +4617,10 @@ class InferenceEngine:
                 "prefill_span_seconds_total": round(self.prefill_span_s, 6),
                 "prefill_decode_wait_seconds_total": round(
                     self.prefill_decode_wait_s, 6),
+                "prefill_own_seconds_total": round(self.prefill_own_s, 6),
+                "prefill_peer_seconds_total": round(self.prefill_peer_s, 6),
+                "stalls_total": self.n_stalls,
+                **device_families(self._ledger, self._prefill_ledger),
                 # The scheduler turn by phase (_phase), open phases counted
                 # up to now: between two scrapes the phases of a colocated
                 # engine add up to the wall time between them.
@@ -4760,9 +4773,7 @@ class InferenceEngine:
                             # joins the batch at the very next ring fill.
                             self._drain_handoffs()
                 if any(self._slots) or self._inflight:
-                    t_chunk = time.perf_counter()
                     self._run_chunk()
-                    self._chunk_s += time.perf_counter() - t_chunk
                 else:
                     # No decode work this turn (the clamped stream finished
                     # and/or the admission retired without activating):
@@ -4814,6 +4825,7 @@ class InferenceEngine:
                 st["open"].pop()
             else:
                 st["open"].append(push)
+            self._led().switch(st["open"][-1] if st["open"] else None, now)
 
     def _turn_seconds(self) -> dict:
         """The phase table with every open phase counted up to now."""
@@ -4829,53 +4841,142 @@ class InferenceEngine:
                           rows: int = 1):
         """Count one prefill program execution: the prompt ``tokens`` it was
         asked to compute, and tokens as the program computes them (``rows`` x
-        ``bucket``, the padding and absent members included). Returns the
-        ``engine.dispatch`` annotation that names the call in a running
-        profile; use as ``with``."""
+        ``bucket``, the padding and absent members included: its weight in
+        the device ledger too). Returns the ``engine.dispatch`` annotation
+        that names the call in a running profile; use as ``with``."""
         self.n_prefill_tokens += tokens
         self.n_prefill_padded += rows * bucket
         return jax.profiler.TraceAnnotation("engine.dispatch", family=family,
                                             bucket=bucket)
 
+    @contextlib.contextmanager
     def _segment_dispatch(self, adms, family: str, bucket: int, tokens: int,
                           rows: int = 1):
         """:meth:`_prefill_dispatch` for a segment program advancing the
-        chunked admissions ``adms``; at an admission's first segment the
-        loop's turn count and decode-only time are snapshotted for its
-        span. A ``disagg`` engine's segments run on the prefill group, not
-        ahead of a decode chunk."""
+        chunked admissions ``adms``, entered in the ledger where the call
+        has returned; at an admission's first segment the loop's turn count
+        is noted for its span."""
         self.n_prefill_segments += 1
-        if not self.disagg:
-            self._seg_queued += rows * bucket
         for adm in adms:
             if adm.segments == 0:
                 adm.turn0 = self.n_turns
-                adm.wait0 = self._chunk_s - self._seg_s
             adm.segments += 1
-        return self._prefill_dispatch(family, bucket, tokens, rows)
+        with self._prefill_dispatch(family, bucket, tokens, rows):
+            yield
+        self._sent(PREFILL, family, bucket, rows * bucket,
+                   [adm.acct for adm in adms])
 
-    def _book_segment_time(self, c: "_InflightChunk", t_ready: float,
-                           probed: bool) -> None:
-        """The device's time on segment programs, as the reaps see it. The
-        device runs what it is given in order, so a chunk lands ``own``
-        seconds after the chunk before it (or after its own dispatch, if
-        that was later); with segments queued ahead of it
-        (``c.seg_tokens``), what ``own`` holds beyond its steps at the pace
-        of the last chunk that ran alone is the segments', and gives a
-        segment token's pace. A chunk the drain found landed (``probed``)
-        has no landing stamp of its own and books nothing."""
-        own = t_ready - max(self._ready_prev, c.t0)
-        self._ready_prev = t_ready
-        if probed:
+    # ---- the device ledger (telemetry/device_ledger.py) ----------------------
+
+    def _led(self) -> DeviceLedger:
+        """The calling loop's ledger: a disagg engine's prefill loop hands
+        its programs to a device group of its own."""
+        if (self._prefill_ledger is not None
+                and threading.current_thread() is self._prefill_thread):
+            return self._prefill_ledger
+        return self._ledger
+
+    def _sent(self, cls: str, family: str, bucket: int = 0, weight: int = 0,
+              accts=(), witness=None):
+        """Enter a program the calling loop has just dispatched in its
+        ledger. The device standing dry before it, with the loop neither
+        idle nor compiling, for longer than a stall is the witness's."""
+        prog = self._led().dispatch(cls, family, bucket, weight,
+                                    [a for a in accts if a is not None],
+                                    witness)
+        if prog.starved_before > STALL_MIN_S:
+            self._note_stall("starved", prog.starved_before, family, bucket)
+        return prog
+
+    def _fetch_landing(self, prog, *arrays):
+        """The blocking fetch of ``arrays``, outputs of the programs up to
+        ``prog``, as a landing: what had a witness queued ahead is waited
+        on first and lands on its own (DeviceLedger.wait_before); then the
+        arrays are waited on until ready, which is ``prog``'s landing (one
+        that finds them already in is a late one), and fetched: the host's
+        copy and wake-up after the program's end are the device's dry
+        time, not the program's. A wait past the stall limits is the
+        witness's."""
+        t_wait = time.perf_counter()
+        prog.ledger.wait_before(prog, _block)
+        late = all(x.is_ready() for x in arrays if isinstance(x, jax.Array))
+        if not late:
+            for x in arrays:  # the copies start behind the program, as the
+                if isinstance(x, jax.Array) and x.is_fully_addressable:
+                    x.copy_to_host_async()  # fetch alone would start them
+            _block(arrays)
+        prog.land(time.perf_counter(), exact=not late)
+        out = _host_fetch(*arrays)
+        waited = time.perf_counter() - t_wait
+        if waited > STALL_MIN_S:
+            self._note_stall("wait", waited, prog.family, prog.bucket)
+        return out
+
+    def _mark(self) -> None:
+        """Ahead of a decode dispatch with prefill programs queued since
+        the last landing: a program of a few bytes that reads the cache
+        they wrote, never donated, so that the blocking reap can wait on it
+        first and give prefill and decode an interval each
+        (DeviceLedger.wait_before). A staged engine's segments write
+        another cache: none there."""
+        if self.staged:
             return
-        steps = c.n_steps * c.n_chunks
-        if not c.seg_tokens:
-            self._step_alone_s = own / steps
-        elif self._step_alone_s:
-            seg_s = max(0.0, own - self._step_alone_s * steps)
-            self._seg_s += seg_s
-            self._seg_tok_samples.append(seg_s / c.seg_tokens)
-            self._seg_tok_s = statistics.median(self._seg_tok_samples)
+        for prog in reversed(self._led().pending):
+            if prog.cls == PREFILL:
+                break
+            if prog.cls == DECODE or prog.family == "mark":
+                return
+        else:
+            return
+        leaf = jax.tree.leaves(self._ck)[0]
+        fn = self._util_fns.get("ledger_mark")
+        if fn is None:
+            fn = self._util_fns["ledger_mark"] = jax.jit(
+                lambda a: a[(0,) * a.ndim] + 0)
+        self._sent(OTHER, "mark", witness=fn(leaf))
+
+    def _booked(self, prog) -> None:
+        """A landing booked ``prog`` its seconds: the per-family latency
+        model and histogram, and the recorder's ``reap`` event for a prefill
+        program, or one that is neither and had an interval to itself (a
+        ring entry has _deliver_chunk's)."""
+        self.latency.observe(prog.family, prog.seconds)
+        obs.DISPATCH_DEVICE_SECONDS.observe(prog.seconds, family=prog.family)
+        if prog.cls == PREFILL or (prog.cls == OTHER and prog.seconds):
+            FLIGHT.record("reap", engine=self._tag,
+                          loop=("prefill" if prog.ledger
+                                is self._prefill_ledger else "decode"),
+                          family=prog.family, bucket=prog.bucket,
+                          t_issue=round(prog.t, 6), t_start=round(prog.t0, 6),
+                          t_ready=round(prog.t1, 6),
+                          booked_s=round(prog.seconds, 6))
+
+    def _note_stall(self, what: str, seconds: float, family: str,
+                    bucket: int) -> None:
+        """The stall witness: ``seconds`` of one blocking wait on a landing
+        (``what`` "wait"), or of the device standing dry with the loop
+        neither idle nor compiling ("starved"), past STALL_MIN_S and
+        STALL_MEDIANS times the family's booked median."""
+        median_ms = self.latency.snapshot().get(family, {}).get("p50_ms", 0.0)
+        if seconds <= max(STALL_MIN_S, STALL_MEDIANS * median_ms / 1e3):
+            return
+        self.n_stalls += 1
+        led = self._led()
+        logger.warning(
+            "engine stall: %s %.3f s (phase %s, family %s, bucket %s, "
+            "%d rows live, ring depth %d)", what, seconds, led.phase, family,
+            bucket, len(self._active_rows()), len(self._inflight))
+        FLIGHT.record("stall", engine=self._tag, what=what,
+                      seconds=round(seconds, 6), phase=led.phase,
+                      family=family, bucket=bucket)
+        FLIGHT.dump("stall")
+
+    def _open_admission(self, req: _Request, slot: int, **kw) -> _Admission:
+        """A chunked admission, its ``prefill`` span's account opened in
+        the calling loop's ledger at the slot claim."""
+        adm = _Admission(req, slot, **kw)
+        adm.acct = self._led().open(adm.t_start)
+        return adm
 
     # Individual scheduler-turn spans recorded per request per kind before
     # coalescing kicks in: a multi-thousand-token generation must not fill
@@ -5073,7 +5174,7 @@ class InferenceEngine:
                     # Rows [0, n_restore) hold the restored prefix once the
                     # dispatch below lands; beyond it the slot is in flux.
                     self._resident[slot] = req.prompt_ids[:n_restore]
-                    self._admitting.append(_Admission(
+                    self._admitting.append(self._open_admission(
                         req, slot, offset=n_restore,
                         restored=n_restore - reuse))
                 self._restore_into(slot, reuse, n_restore - reuse, host, req)
@@ -5088,7 +5189,8 @@ class InferenceEngine:
                     # During the admission the rows beyond the reused prefix
                     # are in flux; advertise only what is already valid.
                     self._resident[slot] = req.prompt_ids[:reuse]
-                    self._admitting.append(_Admission(req, slot, offset=reuse))
+                    self._admitting.append(
+                        self._open_admission(req, slot, offset=reuse))
             else:
                 with self._cond:
                     self._resident[slot] = []
@@ -5186,7 +5288,8 @@ class InferenceEngine:
                         self._note_admitted(r)
                         self._claimed.add(slot)
                         self._resident[slot] = r.prompt_ids[:reuse]
-                        admit_chunked = _Admission(r, slot, offset=reuse)
+                        admit_chunked = self._open_admission(
+                            r, slot, offset=reuse)
                         self._admitting.append(admit_chunked)
                         break
                 if admit_chunked is None:
@@ -5338,9 +5441,10 @@ class InferenceEngine:
         # member-vmapped one computes a bucket per member, absent ones too.
         n_asked = (len(next(iter(live.values())).prompt_ids) if use_dedup
                    else sum(len(r.prompt_ids) for r in live.values()))
-        with self._prefill_dispatch("dedup" if use_dedup else "single_shot",
-                                    bucket, n_asked,
-                                    rows=1 if use_dedup else mem):
+        family, rows = ("dedup", 1) if use_dedup else ("single_shot", mem)
+        acct = self._led().open(t0)  # one span for all: same programs
+        with contextlib.closing(acct), self._prefill_dispatch(
+                family, bucket, n_asked, rows):
             (firsts, s_lp, top_ix, top_lp,
              self._ck, self._cv, self._token, self._lengths, self._keys,
              self._temp, self._topp, self._topk,
@@ -5355,13 +5459,13 @@ class InferenceEngine:
                 self._pp, self._fp, self._counts, self._bias,
                 self._live, self._budget, self._eos,
             )
+            prog = self._sent(PREFILL, family, bucket, rows * bucket, [acct])
             with self._phase("reap_block"):  # the admit blocks on its token
-                firsts, s_lp, top_ix, top_lp = _host_fetch(
-                    firsts, s_lp, top_ix, top_lp)
-        t1 = time.perf_counter()
+                firsts, s_lp, top_ix, top_lp = self._fetch_landing(
+                    prog, firsts, s_lp, top_ix, top_lp)
+                t1 = time.perf_counter()
+                acct.close(t1)  # whole once the phase's end passes it
         obs.PREFILL.observe(t1 - t0)
-        self._observe_device_time("dedup" if use_dedup else "single_shot",
-                                  t1 - t0)
         if use_dedup:
             saved = (mem - 1) * len(next(iter(live.values())).prompt_ids)
             self.quorum_dedup_tokens += saved
@@ -5375,7 +5479,8 @@ class InferenceEngine:
             # admission span carries the cache-effectiveness attrs.
             req.span("prefill", t0, t1, tokens=len(req.prompt_ids),
                      bucket=bucket, slot=row, coalesced=len(live),
-                     reused=0, restored=0, dedup=int(use_dedup))
+                     reused=0, restored=0, dedup=int(use_dedup),
+                     **acct.parts_ms())
         for m, req in live.items():
             flat = m * n_s + row
             self._resident[flat] = list(req.prompt_ids)
@@ -5494,7 +5599,7 @@ class InferenceEngine:
             # the prefill group computes the next segment.
             disps = {m: self._handoff_dispatch(adm, adm.offset)
                      for m, adm in batch.items()}
-            with self._attr_time("mseg"), self._segment_dispatch(
+            with self._segment_dispatch(
                     batch.values(), "mseg", bucket, int(n_valids.sum()), mem):
                 self._sck, self._scv = self._seg_fn_members(bucket, history)(
                     self.prefill_params, tokens, offsets, n_valids, slots,
@@ -5508,7 +5613,7 @@ class InferenceEngine:
                         adm, self._handoff_dispatch(adm, adm.offset),
                         final=True)
             return
-        with self._attr_time("mseg"), self._segment_dispatch(
+        with self._segment_dispatch(
                 batch.values(), "mseg", bucket, int(n_valids.sum()), mem):
             self._ck, self._cv = self._seg_fn_members(bucket, history)(
                 self.weights, tokens, offsets, n_valids, slots, enables,
@@ -5526,7 +5631,6 @@ class InferenceEngine:
         req = adm.req
         prompt = req.prompt_ids
         bias = req.bias_row if req.bias_row is not None else self._zero_bias
-        t_reg = time.perf_counter()
         (self._token, self._lengths, self._keys, self._temp,
          self._topp, self._topk, self._pp, self._fp,
          self._counts, self._bias,
@@ -5550,8 +5654,7 @@ class InferenceEngine:
             self._pp, self._fp, self._counts, self._bias,
             self._live, self._budget, self._eos, self._dfa,
         )
-        t1 = time.perf_counter()
-        self._observe_device_time("register", t1 - t_reg)
+        t1 = self._sent(OTHER, "register").t
         FLIGHT.record("register", rid=req.rid, engine=self._tag,
                       loop="decode", slot=adm.slot, tokens=len(prompt),
                       reused=adm.offset0, restored=adm.restored)
@@ -5559,26 +5662,30 @@ class InferenceEngine:
         # include the decode turns interleaved between segments — that IS
         # the latency the admitted request experienced.
         obs.PREFILL.observe(t1 - adm.t_start)
-        # What the span waited for: the part the loop spent inside
-        # _run_chunk since the first segment went out, less the device's
-        # time on segment programs there: other rows' decode chunks. The
-        # rest is segments, register and sweeps. A ``disagg`` admission
-        # waits for no decode chunk.
-        span_s = t1 - adm.t_start
-        wait_s = (self._chunk_s - self._seg_s - adm.wait0
-                  if adm.segments and not self.disagg else 0.0)
-        self.prefill_span_s += span_s
-        self.prefill_decode_wait_s += wait_s
+        self.prefill_span_s += t1 - adm.t_start
         # Per-request cache effectiveness on the admission span:
         # ``reused`` is the total prefix the admission skipped
         # (offset0), ``restored`` the portion that came host→device
-        # from the prefix store rather than sitting slot-resident.
-        req.span("prefill", adm.t_start, t1, tokens=len(prompt),
-                 slot=adm.slot, chunked=True, reused=adm.offset0,
-                 restored=adm.restored, segments=adm.segments,
-                 turns=self.n_turns - adm.turn0 + 1 if adm.segments else 0,
-                 decode_wait_ms=round(wait_s * 1000, 3),
-                 **self._keys_kept(len(prompt)))
+        # from the prefix store rather than sitting slot-resident. What
+        # the span waited for (OpenSpan.parts_ms) the ledger fills in once
+        # the programs the span saw dispatched have landed: with the
+        # turn's decode chunk.
+        span = req.span(
+            "prefill", adm.t_start, t1, tokens=len(prompt),
+            slot=adm.slot, chunked=True, reused=adm.offset0,
+            restored=adm.restored, segments=adm.segments,
+            turns=self.n_turns - adm.turn0 + 1 if adm.segments else 0,
+            **self._keys_kept(len(prompt)))
+
+        def settled(acct):
+            self.prefill_own_s += acct.own
+            self.prefill_peer_s += acct.peer
+            self.prefill_decode_wait_s += acct.decode
+            if span is not None:
+                span.meta.update(acct.parts_ms())
+
+        if adm.acct is not None:
+            adm.acct.close(t1, settled)
         with self._cond:
             self._slots[adm.slot] = req
         self._release_admission(adm)
@@ -5608,16 +5715,18 @@ class InferenceEngine:
 
     def _segment_room(self) -> _SegmentRoom:
         """The room this turn's segments have: the decode chunk the turn
-        will dispatch after them, at the pace of the last chunk that ran
-        alone, against a segment token's pace in the last few chunks that
-        had segments ahead of them (:meth:`_book_segment_time`). With no
+        will dispatch after them, at the pace of the last chunk that had
+        an interval of the device to itself, against a segment token's
+        pace in the last few intervals that held segments (the device
+        ledger times both on its landings: ``_time_paces``). With no
         live row there is no chunk to protect and the turn does not block:
         no room, one segment a turn."""
         rows = self._active_rows()
         if not rows:
             return _SegmentRoom(0.0, 0.0)
         steps = max(1, min(r.chunk_hint or self.decode_chunk for _, r in rows))
-        return _SegmentRoom(self._step_alone_s * steps, self._seg_tok_s)
+        led = self._led()
+        return _SegmentRoom(led.step_alone_s * steps, led.seg_tok_s)
 
     def _segment_round(self, room: _SegmentRoom, floor: bool) -> bool:
         """One round of segment dispatches. The ``floor`` round advances
@@ -5663,7 +5772,7 @@ class InferenceEngine:
                     # is already resident and the overlap is with the
                     # decode ring's own megachunks instead.)
                     disp = self._handoff_dispatch(adm, adm.offset)
-                    with self._attr_time("seg"), self._segment_dispatch(
+                    with self._segment_dispatch(
                             [adm], "seg", bucket, len(seg)):
                         self._sck, self._scv = self._seg_fn(bucket, history)(
                             self.prefill_params, tokens,
@@ -5683,7 +5792,7 @@ class InferenceEngine:
                 continue
             try:
                 faults.fire("engine.prefill_segment")
-                with self._attr_time("seg"), self._segment_dispatch(
+                with self._segment_dispatch(
                         [adm], "seg", bucket, len(seg)):
                     self._ck, self._cv = self._seg_fn(bucket, history)(
                         self.weights, tokens, np.int32(adm.offset),
@@ -5705,6 +5814,8 @@ class InferenceEngine:
         return floor
 
     def _release_admission(self, adm: _Admission) -> None:
+        if adm.acct is not None:  # a span that never ended: no account
+            adm.acct.close()
         with self._cond:
             if adm in self._admitting:
                 self._admitting.remove(adm)
@@ -5730,7 +5841,9 @@ class InferenceEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :n_prompt] = req.prompt_ids
         bias = req.bias_row if req.bias_row is not None else self._zero_bias
-        with self._prefill_dispatch("single_shot", bucket, n_prompt):
+        acct = self._led().open(t0)
+        with contextlib.closing(acct), self._prefill_dispatch(
+                "single_shot", bucket, n_prompt):
             (first, s_lp, top_ix, top_lp,
              self._ck, self._cv, self._token, self._lengths, self._keys,
              self._temp, self._topp, self._topk,
@@ -5754,23 +5867,24 @@ class InferenceEngine:
                 self._pp, self._fp, self._counts, self._bias,
                 self._live, self._budget, self._eos,
             )
+            prog = self._sent(PREFILL, "single_shot", bucket, bucket, [acct])
             moe = self._moe_snapshot()
             with self._phase("reap_block"):  # the admit blocks on its token
-                first, s_lp, top_ix, top_lp = _host_fetch(
+                first, s_lp, top_ix, top_lp = self._fetch_landing(
+                    prog if moe is None else moe[2],
                     first, s_lp, top_ix, top_lp)
                 picks = ({} if moe is None else
                          {"picks_held": self._moe_note(moe)})
-        t1 = time.perf_counter()
+                t1 = time.perf_counter()
+                acct.close(t1)  # whole once the phase's end passes it
         obs.PREFILL.observe(t1 - t0)
-        # Honest device time: the single-shot admit blocks on its own
-        # first-token fetch, so dispatch→fetch IS the program's span.
-        self._observe_device_time("single_shot", t1 - t0)
         self.breaker.record_success()  # a half-open probe admitted cleanly
         # reused/restored are structurally 0 on the single-shot path
         # (reuse routes through a chunked admission); recorded anyway so
         # every admission span carries the cache-effectiveness attrs.
         req.span("prefill", t0, t1, tokens=n_prompt, bucket=bucket, slot=slot,
-                 reused=0, restored=0, **picks, **self._keys_kept(n_prompt))
+                 reused=0, restored=0, **acct.parts_ms(), **picks,
+                 **self._keys_kept(n_prompt))
         if req.want_lp >= 0:
             req.lp.append((float(s_lp),
                            np.asarray(top_ix), np.asarray(top_lp)))
@@ -6109,13 +6223,6 @@ class InferenceEngine:
             self._family_cache[key] = fam
         return fam
 
-    def _observe_device_time(self, family: str, seconds: float) -> None:
-        """One per-family device-time observation: the engine's latency
-        model (EWMA + percentiles) and the process-global
-        quorum_tpu_dispatch_device_seconds{family=...} histogram."""
-        self.latency.observe(family, seconds)
-        obs.DISPATCH_DEVICE_SECONDS.observe(max(0.0, seconds), family=family)
-
     def _record_breaker_failure(self) -> None:
         """Feed the failure breaker and, on the CLOSED/HALF-OPEN → OPEN
         transition only, record the breaker event + post-mortem dump — a
@@ -6126,22 +6233,6 @@ class InferenceEngine:
         if not was_open and self.breaker.state == "open":
             FLIGHT.record("breaker", engine=self._tag, state="open")
             FLIGHT.dump("breaker-open")
-
-    @contextlib.contextmanager
-    def _attr_time(self, family: str):
-        """Attribute the wall time of an admission-path program call site
-        to its admit-cache family. For call sites that block (single-shot
-        admit's first-token fetch, the prefix restore) this is honest
-        device time; for chained async dispatches (staged segments) it is
-        the enqueue cost — a lower bound, labeled by the same family either
-        way so the family APPEARS in the attribution with its call rate.
-        The decode ring's families use dispatch→ready instead
-        (_reap_oldest)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._observe_device_time(family, time.perf_counter() - t0)
 
     def _run_chunk(self) -> None:
         # The guard covers everything the token critical path does on this
@@ -6471,18 +6562,19 @@ class InferenceEngine:
             mask = np.zeros((self._rows,), np.int32)
             for i, _ in active:
                 mask[i] = 1
+            self._mark()
             t0 = time.perf_counter()
             payload = self._dispatch_chunk(mask, n_steps, want_lp, history,
                                            constrained, n_chunks)
-            self._count_kv_tiles(active, ahead, history, n_steps * n_chunks)
             fam = self._family_of(key)
+            prog = self._sent(DECODE, fam, history, n_steps * n_chunks,
+                              witness=payload)
+            self._count_kv_tiles(active, ahead, history, n_steps * n_chunks)
             seq = self._next_seq()
             self._inflight.append(
                 _InflightChunk(payload, active, n_steps, t0, history, depth,
                                constrained, n_chunks, family=fam, seq=seq,
-                               seg_tokens=self._seg_queued,
-                               moe=self._moe_snapshot()))
-            self._seg_queued = 0
+                               prog=prog, moe=self._moe_snapshot()))
             FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                           seq=seq, family=fam, depth=depth, chunks=n_chunks,
                           steps=n_steps,
@@ -6592,6 +6684,7 @@ class InferenceEngine:
                     # the ring, so plain traffic keeps full chunk depth.
                     return "stop"
                 return "chunk"
+        self._mark()
         t0 = time.perf_counter()
         try:
             payload, drafted = self._dispatch_spec(
@@ -6600,14 +6693,15 @@ class InferenceEngine:
             self._contain_verify_failure(active, exc)
             return "stop"
         fam = self._family_of(key)
+        prog = self._sent(DECODE, fam, history, n_steps * n_turns,
+                          witness=payload)
         seq = self._next_seq()
         self._inflight.append(
             _InflightChunk(payload, active, n_steps, t0, history, depth,
                            constrained, n_turns, spec_turn=True,
                            drafted=drafted, stacked=fused,
-                           family=fam, seq=seq, seg_tokens=self._seg_queued,
+                           family=fam, seq=seq, prog=prog,
                            moe=self._moe_snapshot()))
-        self._seg_queued = 0
         FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
                       seq=seq, family=fam, depth=depth, chunks=n_turns,
                       steps=n_steps, drafted=drafted,
@@ -6736,22 +6830,20 @@ class InferenceEngine:
         accounting (histograms, recorder, spans) and the finished rows'
         release."""
         t0 = time.perf_counter()
-        probed = c.t_ready is not None
         done, n_exec, delivered = self._emit_chunk(c)
         t1 = time.perf_counter()
         obs.DECODE_CHUNK.observe(t1 - t0)
-        # Per-family device-time attribution (telemetry/latency.py):
-        # dispatch→ready, where "ready" is the first stamp the payload was
-        # observed landed — the incremental drain's is_ready probe when it
-        # fired, else the blocking fetch's completion (an upper bound by
-        # the host-fetch time; zero NEW blocking syncs either way).
-        t_ready = c.t_ready if c.t_ready is not None else t1
-        self._observe_device_time(c.family or "unknown", t_ready - c.t0)
-        self._book_segment_time(c, t_ready, probed)
+        # The ledger booked the chunk at its landing (_fetch_landing, or
+        # the drain's probe): the interval since the landing before it,
+        # with the steps dispatched; a megachunk that stopped early ran
+        # fewer (one whose rows had all finished still ran a chunk).
+        self._led().decode_steps -= c.n_steps * (c.n_chunks - max(1, n_exec))
         FLIGHT.record("reap", engine=self._tag, loop="decode",
                       seq=c.seq, family=c.family or "unknown",
                       depth=c.depth, t_issue=round(c.t0, 6),
-                      t_ready=round(t_ready, 6), chunks=n_exec,
+                      t_start=round(c.prog.t0, 6),
+                      t_ready=round(c.prog.t1, 6),
+                      booked_s=round(c.prog.seconds, 6), chunks=n_exec,
                       spec=c.spec_turn,
                       rids=[r.rid for _, r in c.active])
         obs.PIPELINE_DEPTH.set(len(self._inflight))
@@ -6954,15 +7046,16 @@ class InferenceEngine:
         accounting (accepted = delivered − 1 per executed turn)."""
         active, payload = c.active, c.payload
         with self._phase("reap_block"):
-            fetched = _host_fetch(*payload)
+            # The landing: the first observation of the payload in (the
+            # incremental drain's ready() probe may have been earlier).
+            # The counters' copy ran right behind the chunk and is in with
+            # it.
+            c.prog.witness = None  # the fetch itself is the chunk's landing
+            fetched = self._fetch_landing(
+                c.prog if c.moe is None else c.moe[2], *payload)
             if c.moe is not None:
-                # landed with the payload: the copy ran right behind it
                 c.moe = self._moe_note(c.moe)
         t_fetch = time.perf_counter()
-        if c.t_ready is None:
-            # First observation of the payload landed (the blocking path;
-            # the incremental drain's ready() probe stamps earlier/tighter).
-            c.t_ready = t_fetch
         if c.constrained:
             # The grammar variant's trailing per-step masked-entry counts
             # ride the fetch the tokens already require — no extra sync.
@@ -7120,6 +7213,8 @@ class InferenceEngine:
                 # Disagg: queued handoff pieces reference re-issued claims
                 # after the rebuild — the drain must drop them.
                 a.dead = True
+                if a.acct is not None:
+                    a.acct.close()
             self._handoffs.clear()
             self._slots = [None] * self._rows
             self._admitting = []

@@ -451,17 +451,16 @@ TRACE_PROPAGATED = METRICS.counter(
 
 # Engine flight recorder + per-family device-time attribution + SLO
 # accounting (quorum_tpu/telemetry/, docs/observability.md — ISSUE 12).
-# Decode-ring dispatches attribute dispatch→ready time (issue stamp to the
-# payload's non-blocking is_ready probe / fetch completion — zero new
-# blocking syncs) to their compile_budget.json program family; admission-
-# path programs (seg/register/hslice/hput/...) attribute the dispatch wall
-# observed at their call sites. Buckets reach below the serving ladder:
-# one tiny-chunk dispatch is sub-millisecond on a warm TPU.
+# Every program an engine dispatches is observed with the seconds its
+# device ledger (telemetry/device_ledger.py) booked to it, landing to
+# landing, under its compile_budget.json program family (admission-path
+# programs under seg/register/hslice/hput/...). Buckets reach below the
+# serving ladder: one tiny-chunk dispatch is sub-millisecond on a warm TPU.
 DISPATCH_DEVICE_SECONDS = METRICS.histogram(
     "quorum_tpu_dispatch_device_seconds",
-    "Per-dispatch device time by compile_budget.json program family "
-    "(decode-ring families: dispatch to payload-ready; admission-path "
-    "families: dispatch wall at the call site).",
+    "Per-program device time by compile_budget.json program family, as "
+    "the engine's device ledger booked it: the interval between the "
+    "landing before the program's and its own.",
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
              0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0))
 # SLO accounting (quorum_tpu/telemetry/slo.py): requests classify by

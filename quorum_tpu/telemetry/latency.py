@@ -2,9 +2,9 @@
 
 Generalizes the PR 6 effective-C clamp EWMA (one scalar per engine — the
 per-chunk dispatch-to-reap estimate) into per-program-family statistics:
-every reaped dispatch's dispatch→ready time is attributed to its
-``compile_budget.json`` family ("plain", "loop", "verify", "dfa", ...;
-admission-path programs attribute their dispatch wall time under their
+the seconds the engine's device ledger (``device_ledger.py``) books to each
+program at its landing are attributed to its ``compile_budget.json`` family
+("plain", "loop", "verify", "dfa", ...; admission-path programs under their
 admit-cache family names), and each family keeps an EWMA, running totals,
 and a bounded sample reservoir for exact p50/p99.
 

@@ -178,7 +178,11 @@ class FlightRecorder:
             if kind in _SPAN_KINDS and "t_issue" in data:
                 t_issue = float(data["t_issue"])
                 t_ready = float(data.get("t_ready") or t)
-                tid = tid_of(pid, "ring[%d]" % int(data.get("depth", 0)))
+                # a ring entry on its depth's track; a program that is none
+                # (admit, segment, register: the device ledger's) on one of
+                # their own
+                tid = tid_of(pid, "ring[%d]" % int(data["depth"])
+                             if "depth" in data else "programs")
                 out.append({
                     "ph": "X", "name": str(data.get("family") or kind),
                     "cat": "dispatch", "pid": pid, "tid": tid,
@@ -290,7 +294,8 @@ def merged_trace_events(
                     t_ready = float(ev.get("t_ready") or ev["t"]) + offset
                 except (TypeError, ValueError):
                     continue
-                track = "ring[%d]" % int(ev.get("depth", 0) or 0)
+                track = ("ring[%d]" % int(ev["depth"] or 0)
+                         if "depth" in ev else "programs")
                 if engine:
                     track = f"{engine} {track}"
                 out.append({

@@ -119,9 +119,11 @@ def run(tokens: int = 64, chunk: int = 4, depth: int = 4,
             (eng.drain_gap_s - g0) / max(1.0, dispatches * repeats) * 1e3, 3)
         out[f"{tag}_wall_s"] = round(statistics.median(walls), 4)
         out[f"{tag}_tok_s"] = round(tokens / statistics.median(walls), 1)
-        # Per-family device-seconds (ISSUE 12): the leg's dispatch time
-        # attributed by compile-budget program family (p50/p99 from the
-        # engine's LatencyModel reservoir) — an A/B arm's win is
+        # Per-family device-seconds: the leg's device time as the engine's
+        # device ledger booked it, landing to landing, by compile-budget
+        # program family (p50/p99 from the engine's LatencyModel
+        # reservoir; a ring K deep no longer multiplies a chunk's
+        # reading by its depth) — an A/B arm's win is
         # attributable to the family that moved (the loop leg's time
         # lives under "loop", the unfused legs' under "plain").
         out[f"{tag}_device_seconds"] = eng.latency.snapshot()

@@ -51,10 +51,12 @@ def test_chunked_matches_single_shot_prefill():
 
 def _freeze_paces(eng, step_s, tok_s):
     """Pin the two paces the segment rule reads (``_segment_room``) as if
-    the reaps had timed them so: a decode step's seconds in the last chunk
-    that ran alone, a segment token's in the last that had segments ahead."""
-    eng._step_alone_s, eng._seg_tok_s = step_s, tok_s
-    eng._book_segment_time = lambda c, t_ready, probed: None
+    the device ledger's landings had timed them so: a decode step's seconds
+    in the last chunk that ran alone, a segment token's in the last
+    intervals that held segments."""
+    led = eng._ledger
+    led.step_alone_s, led.seg_tok_s = step_s, tok_s
+    led._time_paces = lambda *interval: None
 
 
 def _admit_under_decode(eng, prompts, resident_tokens=60):
@@ -152,6 +154,13 @@ def test_segments_per_turn_follow_the_timed_paces(members, paces, turns):
     assert [s.meta["turns"] for s in spans] == [turns] * members
     if turns == 1:  # the span ends in the turn that opened it
         assert [s.meta["decode_wait_ms"] for s in spans] == [0] * members
+    else:  # the chunks of the turns it waited out
+        assert all(s.meta["decode_wait_ms"] > 0 for s in spans)
+    for s in spans:  # what the span waited for adds up to its length
+        parts = sum(s.meta[k] for k in (
+            "own_ms", "peer_ms", "decode_wait_ms", "decode_ahead_ms",
+            "starved_ms"))
+        assert parts == pytest.approx((s.end - s.start) * 1e3, abs=0.01)
     assert rose == {"prefill_segments_total": 4,
                     "prefill_segment_turns_total": turns}
 
@@ -199,7 +208,7 @@ def test_the_engine_times_both_paces_on_itself():
     try:
         eng.generate(_prompt(100, 1), max_new_tokens=2)  # compile
         _admit_under_decode(eng, [_prompt(100, 2)], resident_tokens=100)
-        step_s, tok_s = eng._step_alone_s, eng._seg_tok_s
+        step_s, tok_s = eng._ledger.step_alone_s, eng._ledger.seg_tok_s
         assert step_s > 0 and tok_s >= 0
         room = eng._segment_room()
         assert (room.left_s, room.tok_s) == (0.0, 0.0)  # idle: no live row

@@ -230,14 +230,25 @@ def test_megachunk_run_records_family_tagged_overlapped_dispatches():
     assert len(res.token_ids) == 32
     events = RECORDER.snapshot()
     mine = [e for e in events if e.get("engine") == eng._tag]
-    reaps = [e for e in mine if e["kind"] == "reap"]
+    # a ring entry's reap carries its seq; the admit's, booked by the same
+    # device ledger, carries none and renders on a track of its own
+    landed = [e for e in mine if e["kind"] == "reap"]
+    reaps = [e for e in landed if "seq" in e]
     assert reaps, mine
     assert all(e["family"] == "loop" for e in reaps), reaps
-    assert all(e["t_ready"] >= e["t_issue"] for e in reaps)
+    assert {e["family"] for e in landed if "seq" not in e} == {"single_shot"}
+    assert all(e["t_ready"] >= e["t_issue"] for e in landed)
     # dispatch/reap pair by seq
     disp = {e["seq"] for e in mine if e["kind"] == "dispatch"}
     assert {e["seq"] for e in reaps} <= disp
     assert any(e["depth"] > 0 for e in reaps) or eng.n_overlapped > 0
+    # booked landing to landing: the intervals follow one another, where
+    # dispatch→ready of overlapped entries stacked up with the ring's depth
+    landed.sort(key=lambda e: e["t_start"])
+    assert all(a["t_ready"] <= b["t_start"] + 2e-6
+               for a, b in zip(landed, landed[1:]))
+    assert all(e["booked_s"] == pytest.approx(e["t_ready"] - e["t_start"],
+                                              abs=2e-6) for e in landed)
     xs = [e for e in RECORDER.to_trace_events() if e.get("ph") == "X"]
     assert any(e["name"] == "loop" for e in xs)
     # the per-engine latency model saw the same family
