@@ -36,10 +36,11 @@ import sys
 PATTERNED = ("attn.window", "attn.full", "moe.router", "moe.experts",
              "moe.shared")
 # a latent spec (models/latent.py) adds: the two latents' projections, the
-# indexer's products and scores, the selection and its gather, attention in
-# the latent space, the gate per head
+# indexer's products and scores, the selection's mask, attention in the
+# latent space (a decode step's) or through keys and values made a tile at a
+# time (a block of selecting queries'), the gate per head
 LATENT = ("attn.latent_q", "attn.latent_kv", "attn.index", "attn.select",
-          "attn.sparse", "attn.gate")
+          "attn.sparse", "attn.tiled", "attn.gate")
 PARTS = ("embed", "norm", "attn.qkv", "attn.cache_write", "attn.core",
          "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT
 

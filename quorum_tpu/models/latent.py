@@ -17,33 +17,42 @@ One position of a layer keeps (``spec.latent(kind)`` gives the sizes):
     own, since scoring reads it for the whole history and nothing else.
 
 A query's score of a position is ``(q_n . W_kb c_kv + q_r . k_r) / sqrt(nope
-+ rope)``. Two ways to compute it, chosen by the program and never by an
-option:
++ rope)``. Two ways to compute it, chosen by the program from its static
+shapes and never by an option:
 
   - **materialised**: keys and values made from the latents (``W_kb c_kv``,
-    ``W_vb c_kv``), then ops.attention. Cheapest where many queries share
-    the positions they attend: a prefill block over a window, or over a
-    history the indexer keeps whole.
+    ``W_vb c_kv``): ``nope + rope + v`` multiply-adds a (query, position,
+    head), and ``kv_rank (nope + v)`` a head to make a position's key and
+    value, once for all the queries of a block. Cheapest where many queries
+    read the same positions: a prefill block over a window or over a history
+    the indexer keeps whole (ops.attention over all of it at once), and a
+    block of selecting queries large enough to pay for the keys
+    (:func:`tiles_pay`; :func:`tiled`: the selection is a mask the heads
+    share, so the queries still share every position they multiply).
   - **absorbed**: the query taken into the latent space (``W_kb^T q_n``,
     beside ``q_r``), scored against the cached rows as they are, the
-    attended latents summed and only that sum taken through ``W_vb``. One
-    cached row serves every head. What a decode step runs, and what every
-    query runs once the indexer selects: each query then attends positions
-    of its own, the others masked.
+    attended latents summed and only that sum taken through ``W_vb``:
+    ``row_width + kv_rank`` a (query, position, head), 3.6 times the
+    materialised form's at the published sizes, and nothing to make. One
+    cached row serves every head. What a decode step runs, and a few
+    selecting queries (speculative verification).
 
 **The selection.** ``index_scores`` gives every (query, earlier position)
 pair ``sum_j w_j relu(qI_j . kI)``: the products of bfloat16 operands
 accumulated in float32 on the matrix unit, the ReLU, the weighting and the
 sum over the index heads in float32. Of the positions at or before the query
 the ``index_topk`` of largest score are kept (:func:`selected`: a mask over
-the history the program reads, so a selecting query costs the dense product's
-operations); where that history (a static extent) is no longer than
+the history the program reads, so the products are dense over what they
+cover); where that history (a static extent) is no longer than
 ``index_topk``, no score is computed and every position is attended. Queries
-are taken in blocks so that a block's scores over 16,384 positions stay under
-300 MB.
+are scored in blocks so that a block's scores over 16,384 positions stay
+under 300 MB. The scores and the mask cover the program's whole history
+bucket; :func:`tiled`'s products stop at the last tile a counted query can
+see, the absorbed and the whole-history forms multiply the bucket.
 
 Counted beside the expert counters (patterned.DSA_STATS): the positions the
-full layers' queries attended and the positions their histories held.
+full layers' queries attended, the positions their histories held, and the
+positions their attention products covered.
 """
 
 from __future__ import annotations
@@ -55,13 +64,23 @@ import jax.numpy as jnp
 from jax import lax
 
 from quorum_tpu.models.model_config import ModelSpec
+from quorum_tpu.ops import latent_flash
 from quorum_tpu.ops.attention import NEG_INF, attention
+from quorum_tpu.ops.flash_attention import log_attention_path
 from quorum_tpu.ops.norms import layernorm, rmsnorm
 from quorum_tpu.ops.rotary import rope_cos_sin
 
 # query block x history positions whose index products are held at once
 # (float32, times ``index_n_heads``: 64 heads x 2**20 x 4 B = 268 MB)
 SCORE_BLOCK = 1 << 20
+# positions whose keys and values :func:`tiled` makes at once. The kernel
+# holds a tile's rows and one head's keys, values and logits in fast memory;
+# a full layer's attention of 512 queries at 8,192 live positions of a 16,384
+# bucket took 6.7 / 8.0 / 7.3 / 11.4 ms in tiles of 1,024 / 512 / 2,048 / 256
+# (my chip run, PR 41: a shorter tile pays more grid steps, a longer one
+# rounds the live history up further). XLA's loop makes them for all heads:
+# 84 MB of keys and values and 268 MB of float32 logits a tile of 1,024.
+KEY_TILE = 1024
 
 
 def row_width(g) -> int:
@@ -220,9 +239,10 @@ def absorbed(q_n, q_r, rows, keep, lyr, g):
                           _heads(lyr["w_vb"], g.heads))
 
 
-def materialised(q_n, q_r, rows, keep, lyr, g):
-    """Attention over keys and values made from ``rows`` ``[B, S, C]``;
-    ``keep`` broadcasts to ``[B, 1, 1, T, S]``. Returns ``[B, H, T, v]``."""
+def _made(rows, lyr, g):
+    """Keys ``[B, H, S, nope + rope]`` and values ``[B, H, S, v]`` made from
+    ``rows`` ``[B, S, C]``: the latent through ``W_kb`` and ``W_vb``, the
+    rotated key beside every head's."""
     c_kv = rows[..., :g.kv_rank]
     k_r = rows[..., g.kv_rank:g.kv_rank + g.rope]
     k_n = jnp.einsum("bsc,chn->bhsn", c_kv, _heads(lyr["w_kb"], g.heads))
@@ -230,8 +250,111 @@ def materialised(q_n, q_r, rows, keep, lyr, g):
     k = jnp.concatenate(
         [k_n, jnp.broadcast_to(k_r[:, None], k_n.shape[:3] + k_r.shape[-1:])],
         axis=-1)
+    return k, v
+
+
+def materialised(q_n, q_r, rows, keep, lyr, g):
+    """Attention over keys and values made from ``rows`` ``[B, S, C]``;
+    ``keep`` broadcasts to ``[B, 1, 1, T, S]``. Returns ``[B, H, T, v]``."""
+    k, v = _made(rows, lyr, g)
     q = jnp.concatenate([q_n, q_r], axis=-1).transpose(0, 2, 1, 3)
     return attention(q, k.astype(q.dtype), v.astype(q.dtype), keep)
+
+
+def tiles_pay(g, t: int) -> bool:
+    """Whether a block of ``t`` selecting queries multiplies less through
+    keys and values made for it (:func:`tiled`) than in the latent space
+    (:func:`absorbed`): what the queries save a position against what the
+    position's key and value cost, a head. 158 queries at the published
+    sizes."""
+    saved = row_width(g) + g.kv_rank - (g.nope + g.rope + g.v)
+    return t * saved >= g.kv_rank * (g.nope + g.v)
+
+
+def tiled(q_n, q_r, rows, first, hist: int, keep, last, lyr, g,
+          interpret: bool = False):
+    """Attention of a block of queries through keys and values made
+    ``KEY_TILE`` positions at a time, folded into a running softmax. Row
+    ``b`` reads row ``first + b`` of ``rows`` ``[N, S, C]``, and of it the
+    tiles up to position ``last[b]``, the last a counted query is at: the
+    work stops after the furthest row's tile, and what lies behind
+    ``last[b]`` in a tile is taken as zeros. ``keep`` ``[B, T, hist]``.
+    Returns ``([B, H, T, v], the positions a row's products covered)``.
+
+    One Pallas call where the program is lowered for a TPU and the shapes
+    tile (ops/latent_flash.py: every product and exponential once), XLA's
+    loop elsewhere; ``interpret`` runs the kernel through the Pallas
+    interpreter, for tests."""
+    b, t = q_n.shape[:2]
+    dt = q_n.dtype
+    tile = min(KEY_TILE, hist)
+    n_tiles = jnp.clip((jnp.max(last) + tile) // tile, 1, -(-hist // tile))
+    scale = (g.nope + g.rope) ** -0.5
+
+    def loop(q_n, q_r, rows, first, keep, last, n_tiles, ups):
+        q = jnp.concatenate([q_n, q_r], axis=-1).transpose(0, 2, 1, 3)
+
+        def fold(i, carry):
+            top, total, acc = carry
+            # the last tile of a history that is no multiple of the tile
+            # starts early, and leaves out what the tile before it held
+            start = jnp.minimum(i * tile, hist - tile)
+            at = start + jnp.arange(tile)
+            live = (at >= i * tile) & (at <= last[:, None])     # [B, tile]
+            # the rows at their 640 lanes, the latent and the key cut out of
+            # the tile: a narrower slice of the leaf re-lays the cache
+            # (:func:`row_width`)
+            part = lax.dynamic_slice(rows, (first, start, 0),
+                                     (b, tile, rows.shape[-1]))
+            k, v = _made(jnp.where(live[..., None], part, 0).astype(dt),
+                         ups, g)
+            logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
+                                preferred_element_type=jnp.float32) * scale
+            seen = lax.dynamic_slice_in_dim(keep, start, tile, axis=2) & (
+                live[:, None])
+            logits = jnp.where(seen[:, None], logits, NEG_INF)
+            # the barriers: as in :func:`absorbed`
+            new_top = jnp.maximum(top, lax.optimization_barrier(
+                jnp.max(logits, axis=-1)))
+            p = jnp.exp(logits - new_top[..., None])
+            shrink = jnp.exp(top - new_top)
+            total = total * shrink + lax.optimization_barrier(
+                jnp.sum(p, axis=-1))
+            acc = acc * shrink[..., None] + jnp.einsum(
+                "bhts,bhsv->bhtv", p.astype(dt), v,
+                preferred_element_type=jnp.float32)
+            return new_top, total, acc
+
+        # a query that kept nothing yet has seen NEG_INF everywhere: what it
+        # summed of masked positions goes when its first kept one scales it
+        # by exp(NEG_INF - logit), which is 0
+        top, total, acc = lax.fori_loop(0, n_tiles, fold, (
+            jnp.full((b, g.heads, t), NEG_INF, jnp.float32),
+            jnp.zeros((b, g.heads, t), jnp.float32),
+            jnp.zeros((b, g.heads, t, g.v), jnp.float32)))
+        return (acc / total[..., None]).astype(dt)
+
+    def kernel(q_n, q_r, rows, first, keep, last, n_tiles, ups):
+        return latent_flash.tile_attention(
+            q_n, q_r, rows, first, hist, keep, last, n_tiles, ups["w_kb"],
+            ups["w_vb"], g, tile=tile, interpret=interpret)
+
+    refusal = latent_flash.kernel_refusal(t, hist, tile, rows, g,
+                                          interpret=interpret)
+    log_attention_path(
+        "latent_tiles", refusal, interpret=interpret, q_shape=q_n.shape,
+        kv_shape=rows.shape, block=tile, window=0,
+        accepted="pallas where lowered for a tpu, xla's loop elsewhere")
+    args = (q_n, q_r, rows, jnp.asarray(first, jnp.int32), keep, last,
+            n_tiles, {name: lyr[name] for name in ("w_kb", "w_vb")})
+    with jax.named_scope("attn.tiled"):
+        if refusal:
+            out = loop(*args)
+        elif interpret:
+            out = kernel(*args)
+        else:
+            out = lax.platform_dependent(*args, tpu=kernel, default=loop)
+    return out, jnp.minimum(n_tiles * tile, hist)
 
 
 def gate(out, h, lyr):
@@ -249,16 +372,21 @@ def full_attention(q_n, q_r, q_i, w, cached, first, hist: int, pos, ok, lyr,
     reads the first ``hist`` entries of row ``first + b`` of ``cached``, the
     layer's rows ``[N, S, C]`` and index keys ``[N, S, d]``, whose entry
     ``s`` is position ``s``. ``ok`` ``[B, T]`` marks the queries that count;
-    ``keys`` takes this layer's two counts."""
+    ``keys`` takes this layer's three counts (patterned.DSA_STATS)."""
     g = spec.latent("G")
     b, t = pos.shape
     rows, k_i = cached
+    ok = jnp.broadcast_to(ok, pos.shape)
+    counted = jnp.sum(ok)
     in_history = jnp.sum(jnp.where(ok, pos + 1, 0))
+    whole = hist * counted          # what a product over the bucket covers
 
     def mine(c):
         return lax.dynamic_slice(c, (first, 0, 0), (b, hist, c.shape[-1]))
 
-    rows = mine(rows)
+    in_tiles = hist > spec.index_topk and tiles_pay(g, t)
+    if not in_tiles:    # every other form reads the row's bucket whole
+        rows = mine(rows)
     if hist <= spec.index_topk:
         # the indexer would keep everything: no score
         causal = jnp.arange(hist) <= pos[..., None]             # [B, T, S]
@@ -266,32 +394,48 @@ def full_attention(q_n, q_r, q_i, w, cached, first, hist: int, pos, ok, lyr,
             out = materialised(q_n, q_r, rows, causal[:, None, None], lyr, g)
         else:
             out = absorbed(q_n, q_r, rows, causal[:, :, None], lyr, g)
-        keys.append(jnp.stack([in_history, in_history]))
+        keys.append(jnp.stack([in_history, in_history, whole]))
         return out
 
     k_i = mine(k_i)
-
-    def block(args):
-        q_n, q_r, q_i, w, pos = args                         # [B, tq, ...]
-        keep = selected(index_scores(q_i, w, k_i), pos, spec.index_topk)
-        return (absorbed(q_n, q_r, rows, keep[:, :, None], lyr, g),
-                jnp.sum(keep, axis=-1))
-
     tq = max(1, min(t, SCORE_BLOCK // hist))
     if t % tq:
         tq = t
-    if tq == t:
-        out, kept = block((q_n, q_r, q_i, w, pos))
-    else:
-        def blocks(x):  # [B, T, ...] -> [T / tq, B, tq, ...]
-            return jnp.moveaxis(
-                x.reshape((b, t // tq, tq) + x.shape[2:]), 1, 0)
 
-        out, kept = lax.map(block, tuple(
-            blocks(x) for x in (q_n, q_r, q_i, w, pos)))
-        out = jnp.moveaxis(out, 0, 2).reshape(b, g.heads, t, g.v)
-        kept = jnp.moveaxis(kept, 0, 1).reshape(b, t)
-    keys.append(jnp.stack([jnp.sum(jnp.where(ok, kept, 0)), in_history]))
+    def blocks(fn, t_axes: tuple):
+        """``fn`` of the queries' arrays ``[B, tq, ...]``, ``tq`` queries at
+        a time; its outputs have their queries at ``t_axes``."""
+        args = (q_n, q_r, q_i, w, pos)
+        if tq == t:
+            return fn(args)
+        outs = lax.map(fn, tuple(
+            jnp.moveaxis(x.reshape((b, t // tq, tq) + x.shape[2:]), 1, 0)
+            for x in args))
+        return tuple(
+            jnp.moveaxis(o, 0, ax).reshape(
+                o.shape[1:ax + 1] + (t,) + o.shape[ax + 2:])
+            for o, ax in zip(outs, t_axes))
+
+    def keep_of(q_i, w, pos):
+        return selected(index_scores(q_i, w, k_i), pos, spec.index_topk)
+
+    if in_tiles:
+        (keep,) = blocks(lambda a: (keep_of(*a[2:]),), (1,))
+        kept = jnp.sum(keep, axis=-1)
+        last = jnp.max(jnp.where(ok, pos, -1), axis=1)
+        out, extent = tiled(q_n, q_r, rows, first, hist, keep, last, lyr, g)
+        covered = extent * counted
+    else:
+        def block(args):
+            q_n, q_r, q_i, w, pos = args
+            keep = keep_of(q_i, w, pos)
+            return (absorbed(q_n, q_r, rows, keep[:, :, None], lyr, g),
+                    jnp.sum(keep, axis=-1))
+
+        out, kept = blocks(block, (2, 1))
+        covered = whole
+    keys.append(jnp.stack(
+        [jnp.sum(jnp.where(ok, kept, 0)), in_history, covered]))
     return out
 
 

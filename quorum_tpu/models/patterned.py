@@ -90,10 +90,12 @@ TILE = 128
 # token), and picks on a held expert that no product computed: held picks
 # less the rows the products say they took.
 STATS = ("picks", "dropped")
-# Two more where the full layers select what they attend (models/latent.py),
+# Three more where the full layers select what they attend (models/latent.py),
 # summed over those layers into the first row: the positions their queries
-# attended, and the positions their histories held.
-DSA_STATS = ("keys_attended", "keys_in_history")
+# attended, the positions their histories held, and the positions their
+# attention products covered (a program's history bucket, or the tiles of it
+# a block of queries stopped at).
+DSA_STATS = ("keys_attended", "keys_in_history", "keys_multiplied")
 
 
 def stats_of(spec: ModelSpec) -> tuple:
@@ -400,7 +402,7 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
     cache: ``(K, V)``, or a latent spec's ``(rows, index keys)`` and
     ``(ring,)``. ``x`` is the float32 stream; returns it with the two
     caches, the first side's counters counted up (``keys``: what a latent
-    spec's ``attend`` leaves there of :data:`DSA_STATS`, a pair a full
+    spec's ``attend`` leaves there of :data:`DSA_STATS`, a triple a full
     layer)."""
     from quorum_tpu.models import transformer as tr
 

@@ -461,3 +461,53 @@ def test_the_reader_finds_the_copies_of_a_members_major_store(
     moves = decode_static.weight_moves(compiled.as_text(), matrices)
     assert len(moves) == len(matrices) == 7
     assert compiled.memory_analysis().temp_size_in_bytes > 6.5e9
+
+
+# ---- the latent family's tile kernel, at the published widths -----------------
+
+
+@pytest.mark.parametrize("hist", [4096, 16384])
+def test_a_selecting_segment_reads_the_carried_rows_through_one_pallas_call(
+        v5e, hist):
+    """A full layer's attention of a 512-query segment of ``dots3-ep8`` at
+    a selecting history bucket (models/latent.full_attention): Mosaic takes
+    the kernel at these widths, it is the program's one Pallas call, and the
+    row's slab of the leaf ``[16, 16384, 640]`` is neither sliced out nor
+    copied for it (the latent-space form sliced the bucket out first)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from quorum_tpu.models import latent
+    from quorum_tpu.models.model_config import resolve_spec
+
+    spec = resolve_spec("dots3-note-prev", {"max_seq": "16384"})
+    g = spec.latent("G")
+    t, slots = 512, 16
+    assert latent.tiles_pay(g, t) and hist > spec.index_topk
+
+    def attend(q_n, q_r, q_i, w, rows, k_i, w_kb, w_vb, offset):
+        keys: list = []
+        pos = (offset + jnp.arange(t))[None]
+        return latent.full_attention(
+            q_n, q_r, q_i, w, (rows, k_i), 5, hist, pos,
+            jnp.ones((1, t), bool), {"w_kb": w_kb, "w_vb": w_vb}, spec, keys)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    width = latent.row_width(g)
+    args = (shape(1, t, g.heads, g.nope), shape(1, t, g.heads, g.rope),
+            shape(1, t, spec.index_n_heads, spec.index_head_dim),
+            shape(1, t, spec.index_n_heads, dtype=jnp.float32),
+            shape(slots, spec.max_seq, width),
+            shape(slots, spec.max_seq, spec.index_head_dim),
+            shape(g.kv_rank, g.heads * g.nope),
+            shape(g.kv_rank, g.heads * g.v), shape(dtype=jnp.int32))
+    compiled = within(COMPILE_LIMIT_S,
+                      lambda: jax.jit(attend).lower(*args).compile())
+    text = compiled.as_text()
+    calls = [row for row in decode_static.program_ops(text, set())
+             if row[2] == "tpu_custom_call"]
+    (call,) = calls
+    assert "attn.tiled" in call[4] and "latent_tile_attention" in call[4]
+    assert not decode_static.slab_moves(text, hist * width)

@@ -90,10 +90,13 @@ def _step(params, spec, tok, lens, live, ck, cv):
 
 @pytest.fixture
 def patched(monkeypatch):
-    """monkeypatch for what the two programs trace: they are traced anew
-    under the patch and again after it."""
-    _segment.clear_cache(), _step.clear_cache()
-    yield monkeypatch
+    """``setattr`` for what the two programs trace: they are traced anew
+    under each patch and again after it."""
+    def setattr(*args):
+        monkeypatch.setattr(*args)
+        _segment.clear_cache(), _step.clear_cache()
+
+    yield types.SimpleNamespace(setattr=setattr)
     monkeypatch.undo()
     _segment.clear_cache(), _step.clear_cache()
 
@@ -180,6 +183,8 @@ def test_prefill_then_decode_through_the_latent_caches(model, tokens, segment):
     assert rest[0, STAT["keys_in_history"]] == 3 * sum(p + 1 for p in at)
     assert rest[0, STAT["keys_attended"]] == 3 * sum(
         min(p + 1, spec.index_topk) for p in at)
+    # every program here reads a history of 64, in one tile
+    assert rest[0, STAT["keys_multiplied"]] == 3 * 64 * len(at)
     assert (rest[1:, len(patterned.STATS):] == 0).all()
 
 
@@ -265,6 +270,154 @@ def test_query_blocks_and_one_block_are_one_attention(
     assert (np.asarray(ck_blocked.stats) == np.asarray(ck.stats)).all()
 
 
+def test_key_tiles_and_one_tile_are_one_attention(model32, tokens, patched):
+    """At the cell's size a segment's keys and values are made 1,024
+    positions at a time and the loop stops at the row's last live tile; here
+    16 at a time over 64: the same logits, and ``keys_multiplied`` counts
+    the tiles the loop covered where one tile covers the bucket."""
+    spec, params = model32
+    whole, ck = served(spec, params, tokens, 16)
+    patched.setattr(latent, "KEY_TILE", 16)
+    in_tiles, ck_tiles = served(spec, params, tokens, 16)
+    assert np.abs(in_tiles - whole).max() < 1e-5
+    one, many = (np.asarray(c.stats)[:, spec.held:] for c in (ck, ck_tiles))
+    col = STAT["keys_multiplied"]
+    assert (np.delete(one, col, 1) == np.delete(many, col, 1)).all()
+    # segments of 16, 16 and 8 real queries end in tiles 1, 2 and 3; a
+    # decode step is in the latent space over its whole bucket
+    steps = N_NEW
+    assert many[0, col] == 3 * (16 * 16 + 16 * 32 + 8 * 48 + steps * 64)
+    assert one[0, col] == 3 * (N_PROMPT + steps) * 64
+
+
+def _block(t: int, hist: int, live: int, seed: int = 0):
+    """A block of ``t`` queries somewhere in the first ``live`` positions of
+    row SLOT of float32 leaves ``[SLOTS, hist + 8, .]``, with an arbitrary
+    causal mask: what :func:`latent.tiled` and :func:`latent.absorbed`
+    take."""
+    spec = resolve_spec("dots3-tiny", {"dtype": "float32"})
+    g = spec.latent("G")
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    lyr = {"w_kb": normal(g.kv_rank, g.heads * g.nope) * g.kv_rank ** -0.5,
+           "w_vb": normal(g.kv_rank, g.heads * g.v) * g.kv_rank ** -0.5}
+    pos = np.sort(rng.integers(0, live, size=(1, t)), axis=1)
+    pos[0, -1] = live - 1
+    keep = (rng.random((1, t, hist)) < 0.6) & (
+        np.arange(hist) <= pos[..., None])
+    keep[0, :, 0] = True
+    rows = normal(SLOTS, hist + 8, latent.row_width(g))
+    return (spec, g, lyr, normal(1, t, g.heads, g.nope),
+            normal(1, t, g.heads, g.rope), rows, jnp.asarray(pos),
+            jnp.asarray(keep))
+
+
+TINY_G = resolve_spec("dots3-tiny").latent("G")
+BREAK_EVEN = next(t for t in range(1, 64) if latent.tiles_pay(TINY_G, t))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("hist", [24, 256], ids=["over_topk", "bucket"])
+@pytest.mark.parametrize("tiles", ["one", "several", "ends_inside"])
+@pytest.mark.parametrize("t", [BREAK_EVEN, 512], ids=["break_even", "512"])
+def test_keys_made_a_tile_at_a_time_give_the_latent_space_attention(
+        monkeypatch, t, tiles, hist, kernel):
+    """The two exact forms over one mask, in float32: one tile over the
+    whole history, several (the last of a history of 24 starts early and
+    skips what the first held), and a live length that ends inside a tile,
+    after which the work stops. XLA's loop, and the Pallas kernel through
+    the interpreter (which takes the loop where the history does not
+    tile)."""
+    live = hist if tiles != "ends_inside" else hist * 5 // 8 + 3
+    tile = hist if tiles == "one" else 16
+    monkeypatch.setattr(latent, "KEY_TILE", tile)
+    spec, g, lyr, q_n, q_r, rows, pos, keep = _block(t, hist, live)
+    assert hist > spec.index_topk and latent.tiles_pay(g, t)
+    got, extent = latent.tiled(q_n, q_r, rows, SLOT, hist, keep,
+                               jnp.max(pos, axis=1), lyr, g,
+                               interpret=kernel)
+    want = latent.absorbed(q_n, q_r, rows[SLOT:SLOT + 1, :hist],
+                           keep[:, :, None], lyr, g)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    assert int(extent) == min(-(-live // tile) * tile, hist)
+
+
+@pytest.mark.parametrize("t,pays", [(1, False), (8, False), (157, False),
+                                    (158, True), (512, True)])
+def test_the_form_follows_the_operation_count_at_the_published_sizes(
+        t, pays):
+    """A position's key and value cost 512 x 256 multiply-adds a head; a
+    query saves 640 + 512 - 320 a position and head by them: a decode step
+    and a few verified positions stay in the latent space, a segment's 512
+    queries have the keys made."""
+    g = resolve_spec("dots3-note-prev").latent("G")
+    assert latent.tiles_pay(g, t) is pays
+
+
+@pytest.mark.parametrize("program,t,scope", [
+    ("step", 1, "attn.sparse"), ("segment", BREAK_EVEN - 1, "attn.sparse"),
+    ("segment", 16, "attn.tiled")])
+def test_a_full_layer_takes_the_form_its_queries_earn(
+        model32, program, t, scope):
+    """Read off the lowered text: under ``attn.full`` a decode step and a
+    segment under the break-even are in the latent space, a segment over it
+    goes through the key tiles; never both."""
+    spec, params = model32
+    ck, cv = tr.init_cache(spec, SLOTS)
+    if program == "step":
+        lowered = _step.lower(
+            params, spec, jnp.zeros((SLOTS,), jnp.int32),
+            jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), bool), ck, cv)
+    else:
+        lowered = _segment.lower(
+            params, spec, jnp.zeros((1, t), jnp.int32), jnp.int32(16),
+            jnp.int32(t), ck, cv)
+    text = lowered.as_text(debug_info=True)
+    forms = {"attn.sparse", "attn.tiled"}
+    assert {f for f in forms if f"/attn.full/{f}" in text} == {scope}
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
+def test_positions_past_the_live_history_are_never_read(monkeypatch, kernel):
+    """A segment of 16 queries of which 11 are real, at offset 32 of a
+    history bucket of 64 in tiles of 16: what the row holds behind position
+    42 (another request's rows; here NaN) does not reach a real query, and
+    no query comes out other than finite. The counts: 11 real queries, and
+    three tiles of 16 reach position 42."""
+    spec, g, lyr, q_n, q_r, rows, _, _ = _block(16, 64, 64)
+    rng = np.random.default_rng(1)
+    n_valid, offset = 11, 32
+    pos = jnp.asarray(offset + np.arange(16))[None]
+    ok = jnp.asarray(np.arange(16) < n_valid)[None]
+    q_i = jnp.asarray(rng.normal(size=(1, 16, spec.index_n_heads,
+                                       spec.index_head_dim)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(1, 16, spec.index_n_heads)), jnp.float32)
+    k_i = jnp.asarray(rng.normal(size=(SLOTS, 72, spec.index_head_dim)),
+                      jnp.float32)
+    poisoned = rows.at[:, offset + n_valid:].set(jnp.nan)
+    monkeypatch.setattr(latent, "KEY_TILE", 16)
+    monkeypatch.setattr(latent, "tiled", functools.partial(
+        latent.tiled, interpret=kernel))
+
+    def attend(rows):
+        keys: list = []
+        out = latent.full_attention(q_n, q_r, q_i, w, (rows, k_i), SLOT, 64,
+                                    pos, ok, lyr, spec, keys)
+        return np.asarray(out), np.asarray(keys[0])
+
+    clean, counts = attend(rows)
+    got, again = attend(poisoned)
+    assert np.isfinite(got).all()
+    assert (got[:, :, :n_valid] == clean[:, :, :n_valid]).all()
+    assert (again == counts).all()
+    assert list(counts) == [11 * spec.index_topk,
+                            sum(range(offset + 1, offset + n_valid + 1)),
+                            11 * 48]
+
+
 def test_the_mask_keeps_what_top_k_keeps_equal_scores_included():
     """A ReLU makes exact zeros: of equal scores the earlier position stays,
     in ``selected`` as in ``lax.top_k``; -0.0 is 0.0."""
@@ -320,9 +473,13 @@ def test_a_latent_program_carries_its_scopes(model32):
         params, jnp.zeros((SLOTS,), jnp.int32),
         jnp.zeros((SLOTS,), jnp.int32), ck, cv).as_text(debug_info=True)
     for scope in hlo_names.LATENT + hlo_names.PATTERNED + ("attn.out",):
-        assert f"/{scope}/" in text or f'{scope}"' in text, scope
+        if scope != "attn.tiled":  # a block of queries' form, not a step's
+            assert f"/{scope}/" in text or f'{scope}"' in text, scope
     assert hlo_names.part_of(
         "jit(seg)/attn.core/attn.full/attn.select/while") == "attn.select"
+    assert hlo_names.part_of(
+        "jit(seg)/attn.core/attn.full/attn.tiled/while/body/dot_general"
+    ) == "attn.tiled"
 
 
 def test_the_cache_is_latent_rows_index_keys_and_rings():
@@ -335,7 +492,7 @@ def test_the_cache_is_latent_rows_index_keys_and_rings():
         (5, spec.max_seq, spec.index_head_dim)] * 3
     assert [a.shape for a in ck.window] == [
         (5, spec.ring, latent.row_width(w))] * 3
-    assert ck.stats.shape == (5, spec.held + 4)
+    assert ck.stats.shape == (5, spec.held + 5)
     assert jax.tree.leaves(cv) == []
 
 
@@ -360,7 +517,8 @@ def test_the_engine_serves_it_and_counts_its_keys():
         assert 0 < m["moe_picks_held_total"] < m["moe_picks_total"]
         assert len(m["moe_expert_picks_total"]) == 5 * spec.held
         assert (0 < m["dsa_keys_attended_total"]
-                < m["dsa_keys_in_history_total"])
+                < m["dsa_keys_in_history_total"]
+                < m["dsa_keys_multiplied_total"])
         kinds = eng.health()["kv_cache_bytes"]
         assert kinds == {k: m[f"kv_cache_{k}_bytes"]
                          for k in ("full", "window", "index")}
