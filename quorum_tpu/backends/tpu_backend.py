@@ -232,6 +232,7 @@ import numpy as np
 
 from quorum_tpu import oai
 from quorum_tpu.backends.base import BackendError, CompletionResult, prepare_body
+from quorum_tpu.compile_cache import cache_enabled
 from quorum_tpu.config import BackendSpec
 from quorum_tpu.engine.engine import (
     DEFAULT_DECODE_LOOP,
@@ -616,6 +617,10 @@ class TpuBackend:
             member_seeds=opts.get("member_seeds", "distinct"),
             quorum_dedup=_parse_bool_opt(
                 "quorum_dedup", opts.get("quorum_dedup", "0")),
+            # The serving entry keeps the engine's compiled programs
+            # across starts and loads them ahead of any request
+            # (engine/prepare.py), where the persistent compile cache is on.
+            prepare=cache_enabled(),
         )
         store = str(opts.get("prefix_store", "")).strip().lower()
         if store in ("", "0", "none", "off"):

@@ -36,6 +36,14 @@ reassociation from the in-process compile — and a near-tie sample then
 flips between two otherwise-identical generations. Harmless for serving
 throughput, fatal for bit-exact determinism tests.
 
+**Its first reader is the program store** (``engine/prepare.py``): where
+the cache is on, a serving engine keeps every program it compiles whole in
+``programs/`` inside the cache's directory (``prepare.store_root``), and a
+later start loads what is there on a pool of threads, before it makes its
+weights, without even tracing. That preparation loads only: what the store
+lacks is compiled by its first dispatch, reading this cache as before, and
+then stored. Emptying the cache's directory empties the store with it.
+
 No reference equivalent: the reference proxy compiles nothing
 (/root/reference/src/quorum/oai_proxy.py is pure HTTP dispatch); this is
 TPU-runtime surface the reference never needed.

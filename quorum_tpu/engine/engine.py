@@ -96,6 +96,7 @@ The reference has no analog — its "backends" are HTTP calls
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import queue
@@ -103,6 +104,7 @@ import threading
 import time
 import weakref
 from collections import deque
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Iterator
 
@@ -141,6 +143,7 @@ from quorum_tpu.cache.prefix_store import (
 )
 from quorum_tpu.compile_cache import enable_persistent_compile_cache
 from quorum_tpu.devices import device_report
+from quorum_tpu.engine.prepare import Preparation
 from quorum_tpu.models.init import init_params, init_params_sharded
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.patterned import STATS as MOE_STATS
@@ -391,6 +394,26 @@ class _MemberFirst:
     def __getitem__(self, idx):
         member, layer, *rest = idx
         return self.leaf[(layer, member, *rest)]
+
+
+def _program(memo: str, key, kept=False):
+    """A builder of one jitted program, memoised: the decorated method
+    returns ``getattr(self, memo)[key(self, *args)]``, making it with the
+    method's body where the memo has none (``InferenceEngine._memo``).
+    ``kept``: whether the program store (engine/prepare.py) keeps the
+    compiled program across starts, under the builder's name and the key;
+    a predicate of the key where only some of a builder's variants are.
+    The programs every request can reach are kept; the variants behind an
+    option (constrained, speculative, megachunked, deduplicated, the prefix
+    store's and the handoff's) are built on demand in every process."""
+    def deco(make):
+        @functools.wraps(make)
+        def get(self, *args, **kw):
+            return self._memo(get, get.key(self, *args, **kw), args, kw)
+        get.memo, get.key, get.make = memo, key, make
+        get.kept = kept if callable(kept) else (lambda key: kept)
+        return get
+    return deco
 
 
 def prefill_bucket(n: int, max_seq: int) -> int:
@@ -1145,6 +1168,7 @@ class InferenceEngine:
         qos: bool = False,
         member_seeds: str = "distinct",
         quorum_dedup: bool = False,
+        prepare: bool = False,
     ):
         self.spec = spec.validate()
         self.mesh = mesh or single_device_mesh()
@@ -1592,6 +1616,27 @@ class InferenceEngine:
         self._resident: list[list[int]] = [[] for _ in range(self._rows)]
         self.prefix_hits = 0
         self.prefix_tokens_saved = 0
+        # Cached jit wrappers for the rebuild-path utility programs (the
+        # zero-fills): a fresh jax.jit per failure-containment rebuild
+        # would recompile them (qlint: recompile/jit-immediate-call); and
+        # the two few-byte programs of the ledger and the expert counters.
+        self._util_fns: dict = {}
+        # The programs (:func:`_program`): a jitted function a first
+        # dispatch built, a compiled program it kept or the store held, or
+        # the Future of one being loaded.
+        self._admit_cache: dict = {}   # bucket, or (kind, …) → admit side
+        self._decode_cache: dict = {}  # _decode_key & co. → decode side
+        self._tag = f"engine-{id(self):x}"
+        # The program store (prepare_programs): none for an engine built
+        # directly; the serving entry asks for it where the persistent
+        # compile cache is on (``prepare``), and its loads start here, so
+        # that they run while the init program compiles and fills the
+        # device. First dispatches that had to build their own program are
+        # counted beside what was loaded.
+        self._prep: "Preparation | None" = None
+        self.n_programs_on_demand = 0
+        if prepare:
+            self.prepare_programs()
         self.weights = self._build_params(self.mesh, params, seed)
         # Disaggregated serving: the prefill group needs its own weight copy
         # (its programs cannot read across the group boundary — GSPMD never
@@ -1622,10 +1667,6 @@ class InferenceEngine:
         else:
             self._wire_leaf = [((_L, _K, _hd), jnp.dtype(self.spec.dtype))] * 2
             self._wire_def = jax.tree.structure((0, 1))
-        # Cached jit wrappers for the rebuild-path utility programs (the
-        # zero-fills): a fresh jax.jit per failure-containment rebuild
-        # would recompile them (qlint: recompile/jit-immediate-call).
-        self._util_fns: dict = {}
         self._init_device_state()
         if self.staged:
             # Disagg: the staging cache lives on the prefill mesh — with
@@ -1674,7 +1715,6 @@ class InferenceEngine:
         # compile-budget-family memo, and the per-family latency model
         # (EWMAs + percentiles — the generalization of _chunk_ewma_s that
         # open item 1's preemption cost model consumes).
-        self._tag = f"engine-{id(self):x}"
         self._dispatch_seq = 0
         self._family_cache: dict = {}
         self.latency = LatencyModel(alpha=CHUNK_EWMA_ALPHA)
@@ -1712,9 +1752,6 @@ class InferenceEngine:
         self.prefill_decode_wait_s = 0.0
         self.prefill_own_s = 0.0
         self.prefill_peer_s = 0.0
-
-        self._admit_cache: dict[int, object] = {}   # bucket → compiled admit
-        self._decode_cache: dict[int, object] = {}  # n_steps → compiled chunk
 
         # Scheduler state, guarded by _cond's lock. The machine-checked
         # source of truth is the module-level _GUARDED_BY map (every field
@@ -2296,22 +2333,18 @@ class InferenceEngine:
             self._table_dirty = True
         self._paged_note_occupancy()
 
+    @_program("_admit_cache", lambda self: ("page_copy",))
     def _page_copy_fn(self):
         """Jitted physical page copy (all layers/members at once) — the
         copy-on-write program behind prefix aliasing. One admit-cache
         entry, key ``("page_copy",)`` (compile-budget family page_copy)."""
-        fn = self._admit_cache.get(("page_copy",))
-        if fn is not None:
-            return fn
         stacked = self.members > 1
 
         def cp(ck, cv, dst, src):
             return (paged_copy_page(ck, dst, src, stacked=stacked),
                     paged_copy_page(cv, dst, src, stacked=stacked))
 
-        fn = jax.jit(cp, donate_argnames=("ck", "cv"))
-        self._admit_cache[("page_copy",)] = fn
-        return fn
+        return jax.jit(cp, donate_argnames=("ck", "cv"))
 
     def _paged_sync_table(self) -> None:
         """Upload the host page-table mirror into both decode-cache sides
@@ -2356,11 +2389,77 @@ class InferenceEngine:
 
     # ---- compiled programs ------------------------------------------------
 
+    def _memo(self, builder, key, args=(), kw=None):
+        """The program ``builder`` (:func:`_program`) keeps under ``key``:
+        what the memo holds; or what the preparation is loading, waited for
+        (this one program, no other); or, where neither, the builder's
+        body's, built by this first dispatch as it always was and counted
+        as on demand. Where a preparation is open, a kept builder's program
+        is then compiled whole and stored for the next start."""
+        memo = getattr(self, builder.memo)
+        fn = memo.get(key)
+        if isinstance(fn, Future):
+            fn = fn.result()  # None: the file did not load
+        if fn is None:
+            self.n_programs_on_demand += 1
+            fn = builder.make(self, *args, **(kw or {}))
+            if self._prep is not None:
+                logger.info("program %r built on demand", key)
+                if builder.kept(key):
+                    fn = self._prep.keep(memo, builder.__name__, key, fn)
+            memo[key] = fn
+        return fn
+
+    def _program_ready(self, key) -> bool:
+        """Whether a decode dispatch under ``key`` would neither build nor
+        wait for its program."""
+        fn = self._decode_cache.get(key)
+        return fn is not None and not isinstance(fn, Future)
+
+    def _program_config(self) -> tuple:
+        """What a kept program's text follows from beside the package's
+        sources, its builder and its key: the program store's directory
+        (engine/prepare.py) is named after it."""
+        return (self.spec, self.members, self.n_slots, self._rows,
+                self.quant, self.kv_quant, self.prefill_chunk,
+                self.decode_chunk, self.sp_impl)
+
+    def prepare_programs(self) -> None:
+        """Start loading, on a pool of threads, every program an earlier
+        start of this configuration compiled and stored (engine/prepare.py;
+        once, where the persistent compile cache is on and every array has
+        one possible placement). Returns at once: ``programs_preparing`` on
+        :meth:`health` counts what is still out, and a dispatch that comes
+        early waits for its own program. What the store lacks is its first
+        dispatch's, which stores it."""
+        if self._prep is not None or self.mesh.size > 1 or self.staged \
+                or self.kv_pages:
+            # Across devices the compiler picks the shardings a program
+            # returns, so what the next one is handed (and is compiled
+            # for) is known only once the first has run; a staged or paged
+            # engine's programs are opt-in variants, on demand as before.
+            return
+        self._prep = prep = Preparation.open(
+            self._tag, self.mesh.devices.flat[0], self._program_config())
+        if prep is None:
+            return
+        for name, key in prep.stored():
+            builder = getattr(type(self), name, None)
+            if builder is not None and getattr(builder, "kept", None) \
+                    and builder.kept(key) \
+                    and key not in getattr(self, builder.memo):
+                prep.load(getattr(self, builder.memo), name, key)
+        prep.seal()
+
+    @property
+    def programs_preparing(self) -> int:
+        """Stored programs not loaded yet: /ready waits for 0, so that
+        nothing loads behind a measured window."""
+        return self._prep.pending if self._prep is not None else 0
+
+    @_program("_admit_cache", lambda self, bucket: bucket, kept=True)
     def _admit_fn(self, bucket: int):
         """Jitted: prefill one prompt into a slot + sample its first token."""
-        fn = self._admit_cache.get(bucket)
-        if fn is not None:
-            return fn
         spec = self.spec
 
         mesh = self.mesh if self._use_sp else None
@@ -2412,7 +2511,7 @@ class InferenceEngine:
                 eos_s.at[slot].set(eos1),
             )
 
-        fn = jax.jit(
+        return jax.jit(
             admit,
             donate_argnames=(
                 "ck", "cv", "token_s", "lengths_s", "keys_s",
@@ -2421,9 +2520,9 @@ class InferenceEngine:
                 "live_s", "budget_s", "eos_s",
             ),
         )
-        self._admit_cache[bucket] = fn
-        return fn
 
+    @_program("_admit_cache", lambda self, bucket: ("members", bucket),
+              kept=True)
     def _admit_fn_members(self, bucket: int):
         """Jitted coalesced admission for a stacked-members engine: up to one
         prompt PER member prefills into one shared slot row in a single
@@ -2434,9 +2533,6 @@ class InferenceEngine:
         transformer.prefill's ``write_gate``) and state update, so a
         partially-filled group (or a lone admission) runs the same compiled
         program without touching absent members' rows."""
-        fn = self._admit_cache.get(("members", bucket))
-        if fn is not None:
-            return fn
         spec = self.spec
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
         n_s = self.n_slots
@@ -2494,7 +2590,7 @@ class InferenceEngine:
                 upd(eos_s, eoss),
             )
 
-        fn = jax.jit(
+        return jax.jit(
             admit,
             donate_argnames=(
                 "ck", "cv", "token_s", "lengths_s", "keys_s",
@@ -2503,9 +2599,8 @@ class InferenceEngine:
                 "live_s", "budget_s", "eos_s",
             ),
         )
-        self._admit_cache[("members", bucket)] = fn
-        return fn
 
+    @_program("_admit_cache", lambda self, bucket: ("dedup", bucket))
     def _dedup_admit_fn(self, bucket: int):
         """Jitted shared-prefix dedup admission (``quorum_dedup=1``,
         docs/quorum.md): a full quorum group carries the SAME prompt and
@@ -2522,9 +2617,6 @@ class InferenceEngine:
         per-member and bit-identical to ``_admit_fn_members``, so each
         member's stream stays token-for-token the stream the M-prefill
         path produces."""
-        fn = self._admit_cache.get(("dedup", bucket))
-        if fn is not None:
-            return fn
         spec = self.spec
         n_top = min(TOP_LOGPROBS, spec.vocab_size)
         n_s = self.n_slots
@@ -2624,7 +2716,7 @@ class InferenceEngine:
                 upd(eos_s, eoss),
             )
 
-        fn = jax.jit(
+        return jax.jit(
             admit,
             donate_argnames=(
                 "ck", "cv", "token_s", "lengths_s", "keys_s",
@@ -2633,17 +2725,15 @@ class InferenceEngine:
                 "live_s", "budget_s", "eos_s",
             ),
         )
-        self._admit_cache[("dedup", bucket)] = fn
-        return fn
 
+    @_program("_admit_cache",
+              lambda self, bucket, history: ("seg", bucket, history),
+              kept=True)
     def _seg_fn(self, bucket: int, history: int):
         """Jitted: write one prompt segment's K/V into a slot (chunked
         prefill). ``history`` (static, power-of-two) bounds the attention
         reads to the cache prefix that actually holds history — one program
         per (segment bucket, history bucket) pair."""
-        fn = self._admit_cache.get(("seg", bucket, history))
-        if fn is not None:
-            return fn
         spec = self.spec
 
         def seg(params, tokens, offset, n_valid, slot, ck, cv):
@@ -2651,10 +2741,9 @@ class InferenceEngine:
                 params, spec, tokens, offset, n_valid, ck, cv, slot,
                 history=history)
 
-        fn = jax.jit(seg, donate_argnames=("ck", "cv"))
-        self._admit_cache[("seg", bucket, history)] = fn
-        return fn
+        return jax.jit(seg, donate_argnames=("ck", "cv"))
 
+    @_program("_admit_cache", lambda self: "register", kept=True)
     def _register_fn(self):
         """Jitted: install a finished chunked admission's per-slot state.
 
@@ -2668,10 +2757,6 @@ class InferenceEngine:
         floating-point reassociation (and by capacity drops when
         ``moe_capacity_factor < E/k``), so a near-tie sample can diverge.
         """
-        fn = self._admit_cache.get("register")
-        if fn is not None:
-            return fn
-
         vocab = self.spec.vocab_size
 
         def register(slot, last_tok, n_minus1, seed, temp1, topp1, topk1,
@@ -2703,7 +2788,7 @@ class InferenceEngine:
                 dfa_s.at[slot].set(dfa1),
             )
 
-        fn = jax.jit(
+        return jax.jit(
             register,
             donate_argnames=(
                 "token_s", "lengths_s", "keys_s", "temp_s", "topp_s", "topk_s",
@@ -2711,9 +2796,8 @@ class InferenceEngine:
                 "live_s", "budget_s", "eos_s", "dfa_s",
             ),
         )
-        self._admit_cache["register"] = fn
-        return fn
 
+    @_program("_admit_cache", lambda self, n: ("snap", n))
     def _snapshot_fn(self, n: int):
         """Jitted: slice ``n`` cache positions of one slot starting at a
         dynamic offset — the device→host snapshot's device half
@@ -2723,14 +2807,11 @@ class InferenceEngine:
         (values, scales) pairs — the host store receives the native
         representation either way). Always unstacked: the prefix store
         rejects members engines at config time."""
-        fn = self._admit_cache.get(("snap", n))
-        if fn is None:
-            fn = jax.jit(lambda ck, cv, slot, offset: kv_transfer.slice_rows(
-                (ck, cv), slot, offset, n, stacked=False,
-                n_slots=self.n_slots, n_kv_heads=self.spec.n_kv_heads))
-            self._admit_cache[("snap", n)] = fn
-        return fn
+        return jax.jit(lambda ck, cv, slot, offset: kv_transfer.slice_rows(
+            (ck, cv), slot, offset, n, stacked=False,
+            n_slots=self.n_slots, n_kv_heads=self.spec.n_kv_heads))
 
+    @_program("_admit_cache", lambda self, n: ("restore", n))
     def _restore_fn(self, n: int):
         """Jitted: write an ``n``-token host KV slice into positions
         [start, start+n) of one slot (host→device restore,
@@ -2739,16 +2820,12 @@ class InferenceEngine:
         like every other cache-writing program; ``n`` is always a
         prefill_chunk multiple, so the program count is bounded by
         max_seq/prefill_chunk."""
-        fn = self._admit_cache.get(("restore", n))
-        if fn is None:
-            def restore(ck, cv, slot, start, host):
-                return kv_transfer.write_rows(
-                    (ck, cv), host, slot, start,
-                    stacked=False, n_slots=self.n_slots)
+        def restore(ck, cv, slot, start, host):
+            return kv_transfer.write_rows(
+                (ck, cv), host, slot, start,
+                stacked=False, n_slots=self.n_slots)
 
-            fn = jax.jit(restore, donate_argnames=("ck", "cv"))
-            self._admit_cache[("restore", n)] = fn
-        return fn
+        return jax.jit(restore, donate_argnames=("ck", "cv"))
 
     # ---- host prefix store (tier behind the slot-resident cache) ----------
 
@@ -3014,6 +3091,7 @@ class InferenceEngine:
 
     # ---- disaggregated serving: prefill loop + device↔device KV handoff ----
 
+    @_program("_admit_cache", lambda self, n: ("hslice", n))
     def _handoff_slice_fn(self, n: int):
         """Jitted: slice ``n`` staging-cache positions of one flat row into
         the chunk wire layout (kv_transfer.slice_rows) — the prefill-mesh
@@ -3021,35 +3099,28 @@ class InferenceEngine:
         and is dispatched BEFORE the next segment donates those buffers
         (enqueue order is execution order, so the read completes first —
         the same discipline the decode ring's payload chains rely on)."""
-        fn = self._admit_cache.get(("hslice", n))
-        if fn is None:
-            stacked = self.members > 1
-            n_s = self.n_slots
+        stacked = self.members > 1
+        n_s = self.n_slots
 
-            fn = jax.jit(lambda ck, cv, row, start: kv_transfer.slice_rows(
-                (ck, cv), row, start, n, stacked=stacked, n_slots=n_s,
-                n_kv_heads=self.spec.n_kv_heads))
-            self._admit_cache[("hslice", n)] = fn
-        return fn
+        return jax.jit(lambda ck, cv, row, start: kv_transfer.slice_rows(
+            (ck, cv), row, start, n, stacked=stacked, n_slots=n_s,
+            n_kv_heads=self.spec.n_kv_heads))
 
+    @_program("_admit_cache", lambda self, n: ("hput", n))
     def _handoff_write_fn(self, n: int):
         """Jitted: write a transferred ``n``-position chunk into the decode
         cache's claimed slot (kv_transfer.write_rows) — the decode-mesh
         half, run by the DECODE loop only (all decode-cache mutation stays
         on one thread) and donating the cache like every other writer."""
-        fn = self._admit_cache.get(("hput", n))
-        if fn is None:
-            stacked = self.members > 1
-            n_s = self.n_slots
+        stacked = self.members > 1
+        n_s = self.n_slots
 
-            def put(ck, cv, chunk, row, start):
-                return kv_transfer.write_rows(
-                    (ck, cv), chunk, row, start,
-                    stacked=stacked, n_slots=n_s)
+        def put(ck, cv, chunk, row, start):
+            return kv_transfer.write_rows(
+                (ck, cv), chunk, row, start,
+                stacked=stacked, n_slots=n_s)
 
-            fn = jax.jit(put, donate_argnames=("ck", "cv"))
-            self._admit_cache[("hput", n)] = fn
-        return fn
+        return jax.jit(put, donate_argnames=("ck", "cv"))
 
     def _handoff_dispatch(self, adm: _Admission, upto: int):
         """Dispatch (async) the staging slice covering rows
@@ -3431,13 +3502,10 @@ class InferenceEngine:
         self._g_trans = self._g_accept = None
         self._g_bucket = 0
 
+    @_program("_admit_cache", lambda self: "dfa_reset")
     def _dfa_reset_fn(self):
-        fn = self._admit_cache.get("dfa_reset")
-        if fn is None:
-            fn = jax.jit(lambda dfa, row: dfa.at[row].set(0),
-                         donate_argnums=(0,))
-            self._admit_cache["dfa_reset"] = fn
-        return fn
+        return jax.jit(lambda dfa, row: dfa.at[row].set(0),
+                       donate_argnums=(0,))
 
     def _flush_dfa_resets(self) -> None:
         """Return released constrained rows' device DFA state to FREE
@@ -3476,6 +3544,12 @@ class InferenceEngine:
             return ("paged",) + base
         return base
 
+    @_program("_decode_cache",
+              lambda self, n_steps, want_lp, history, tstates=0, n_chunks=1:
+              self._decode_key(n_steps, want_lp, history, tstates > 0,
+                               n_chunks),
+              # the plain chunk and its logprobs twin: _decode_key's 3-tuple
+              kept=lambda key: len(key) == 3)
     def _decode_fn(self, n_steps: int, want_lp: bool, history: int,
                    tstates: int = 0, n_chunks: int = 1):
         """Jitted: ``n_steps`` batched decode+sample steps over all slots —
@@ -3515,11 +3589,6 @@ class InferenceEngine:
         so grammar completion maps to finish_reason "stop" with no new
         host logic.)"""
         constrained = tstates > 0
-        key = self._decode_key(n_steps, want_lp, history, constrained,
-                               n_chunks)
-        fn = self._decode_cache.get(key)
-        if fn is not None:
-            return fn
         spec = self.spec
         sharded = self._sharded
 
@@ -3675,7 +3744,7 @@ class InferenceEngine:
                     bias_s, live_s, budget_s,
                     trans_t=trans_t, accept_t=accept_t, dfa_s=dfa_s)
 
-            fn = jax.jit(
+            return jax.jit(
                 chunk,
                 donate_argnames=("ck", "cv", "token_s", "lengths_s",
                                  "keys_s", "counts_s", "live_s", "budget_s",
@@ -3690,13 +3759,11 @@ class InferenceEngine:
                     keys_s, temp_s, topp_s, topk_s, pp_s, fp_s, counts_s,
                     bias_s, live_s, budget_s)
 
-            fn = jax.jit(
+            return jax.jit(
                 chunk,
                 donate_argnames=("ck", "cv", "token_s", "lengths_s",
                                  "keys_s", "counts_s", "live_s", "budget_s"),
             )
-        self._decode_cache[key] = fn
-        return fn
 
     def _verify_core(self, g: int, history: int, want_lp: bool,
                      constrained: bool):
@@ -3910,6 +3977,9 @@ class InferenceEngine:
         # are structurally different HLO, dense keys stay byte-identical.
         return ("paged",) + key if self.kv_pages else key
 
+    @_program("_decode_cache",
+              lambda self, g, history, want_lp=False, tstates=0:
+              self._verify_key(g, want_lp, history, tstates > 0))
     def _verify_fn(self, g: int, history: int, want_lp: bool = False,
                    tstates: int = 0):
         """Jitted ring-resident speculative-verification step (see
@@ -3918,10 +3988,6 @@ class InferenceEngine:
         batch that contains a logprobs/constrained row pays that
         variant."""
         constrained = tstates > 0
-        key = self._verify_key(g, want_lp, history, constrained)
-        fn = self._decode_cache.get(key)
-        if fn is not None:
-            return fn
         core = self._verify_core(g, history, want_lp, constrained)
 
         if constrained:
@@ -3954,7 +4020,6 @@ class InferenceEngine:
                                  "keys_s", "counts_s", "live_s",
                                  "budget_s"),
             )
-        self._decode_cache[key] = fn
         return fn
 
     def _spec_loop_key(self, n_turns: int, g: int, want_lp: bool,
@@ -3964,6 +4029,9 @@ class InferenceEngine:
                     self._g_bucket)
         return ("spec_loop", n_turns, g, want_lp, history)
 
+    @_program("_decode_cache",
+              lambda self, g, n_turns, history, want_lp=False, tstates=0:
+              self._spec_loop_key(n_turns, g, want_lp, history, tstates > 0))
     def _spec_loop_fn(self, g: int, n_turns: int, history: int,
                       want_lp: bool = False, tstates: int = 0):
         """Jitted fused draft→verify scan for ``spec_model=`` engines: up
@@ -3985,10 +4053,6 @@ class InferenceEngine:
         device with NO host input beyond the active mask — what lets
         draft-model speculation keep the decode_pipeline ring full."""
         constrained = tstates > 0
-        key = self._spec_loop_key(n_turns, g, want_lp, history, constrained)
-        fn = self._decode_cache.get(key)
-        if fn is not None:
-            return fn
         dspec = self._draft_rt.spec
         sharded = self._sharded
         vocab = self.spec.vocab_size
@@ -4120,14 +4184,12 @@ class InferenceEngine:
                     keys_s, counts_s, live_s, budget_s, dfa_s)
             return tuple(outs) + tail
 
-        fn = jax.jit(
+        return jax.jit(
             spec_loop,
             donate_argnames=("ck", "cv", "dck", "dcv", "chain", "chain_n",
                              "token_s", "lengths_s", "keys_s", "counts_s",
                              "live_s", "budget_s", "dfa_s"),
         )
-        self._decode_cache[key] = fn
-        return fn
 
     # ---- public API -------------------------------------------------------
 
@@ -4413,14 +4475,15 @@ class InferenceEngine:
     # bytes, never donated) and reads the copy with the tokens it fetches
     # anyway; /metrics reads the host's running totals and never the device.
 
+    @_program("_util_fns", lambda self: "moe_snapshot", kept=True)
+    def _moe_snapshot_fn(self):
+        return jax.jit(lambda a: a + 0)
+
     def _moe_snapshot(self):
         if not self.spec.layer_pattern:
             return None
-        fn = self._util_fns.get("moe_snapshot")
-        if fn is None:
-            fn = self._util_fns["moe_snapshot"] = jax.jit(lambda a: a + 0)
         self._moe_seq += 1
-        counts = fn(self._ck.stats)
+        counts = self._moe_snapshot_fn()(self._ck.stats)
         return self._moe_seq, counts, self._sent(OTHER, "snapshot")
 
     def _moe_note(self, snapshot) -> "int | None":
@@ -4620,6 +4683,14 @@ class InferenceEngine:
                 "prefill_own_seconds_total": round(self.prefill_own_s, 6),
                 "prefill_peer_seconds_total": round(self.prefill_peer_s, 6),
                 "stalls_total": self.n_stalls,
+                # Programs loaded from the store ahead of any request
+                # (engine/prepare.py), first dispatches that built their
+                # own program, and the wall clock of the loads at their end.
+                "programs_prepared_total": (
+                    self._prep.loaded if self._prep else 0),
+                "programs_on_demand_total": self.n_programs_on_demand,
+                "prepare_seconds": round(
+                    self._prep.seconds if self._prep else 0.0, 3),
                 **device_families(self._ledger, self._prefill_ledger),
                 # The scheduler turn by phase (_phase), open phases counted
                 # up to now: between two scrapes the phases of a colocated
@@ -4657,6 +4728,8 @@ class InferenceEngine:
             # A draining engine still answers /health but must shed
             # /ready: the fleet rotates it out while residents finish.
             "draining": self.draining,
+            # Stored programs still being loaded: /ready waits.
+            "programs_preparing": self.programs_preparing,
         }
 
     def shutdown(self, timeout: float = 30.0) -> None:
@@ -4683,6 +4756,10 @@ class InferenceEngine:
         self._thread.join(timeout=timeout)
         if self._prefill_thread is not None:
             self._prefill_thread.join(timeout=timeout)
+        if self._prep is not None:
+            # nothing more is loaded; what the first dispatches built is
+            # written to the program store before the process may go
+            self._prep.close()
         if self.stream_pool is not None:
             # the backends' producer threads (tpu_backend._stream_pool):
             # their streams have just ended
@@ -4929,11 +5006,11 @@ class InferenceEngine:
         else:
             return
         leaf = jax.tree.leaves(self._ck)[0]
-        fn = self._util_fns.get("ledger_mark")
-        if fn is None:
-            fn = self._util_fns["ledger_mark"] = jax.jit(
-                lambda a: a[(0,) * a.ndim] + 0)
-        self._sent(OTHER, "mark", witness=fn(leaf))
+        self._sent(OTHER, "mark", witness=self._mark_fn()(leaf))
+
+    @_program("_util_fns", lambda self: "ledger_mark", kept=True)
+    def _mark_fn(self):
+        return jax.jit(lambda a: a[(0,) * a.ndim] + 0)
 
     def _booked(self, prog) -> None:
         """A landing booked ``prog`` its seconds: the per-family latency
@@ -5497,13 +5574,13 @@ class InferenceEngine:
                 with self._cond:
                     self._paged_release_row(flat)
 
+    @_program("_admit_cache",
+              lambda self, bucket, history: ("mseg", bucket, history),
+              kept=True)
     def _seg_fn_members(self, bucket: int, history: int):
         """Jitted member-coalesced prompt segment: each member advances its
         own in-flight admission (own tokens/offset/slot row) in one vmapped
         program; ``enables[m]`` gates absent members' cache writes."""
-        fn = self._admit_cache.get(("mseg", bucket, history))
-        if fn is not None:
-            return fn
         spec = self.spec
 
         def seg(params, tokens, offsets, n_valids, slots, enables, ck, cv):
@@ -5516,9 +5593,7 @@ class InferenceEngine:
             return _member_vmap(
                 one, params, tokens, offsets, n_valids, slots, enables, ck, cv)
 
-        fn = jax.jit(seg, donate_argnames=("ck", "cv"))
-        self._admit_cache[("mseg", bucket, history)] = fn
-        return fn
+        return jax.jit(seg, donate_argnames=("ck", "cv"))
 
     def _segment_round_members(self, room: _SegmentRoom,
                                floor: bool) -> bool:
@@ -6554,7 +6629,7 @@ class InferenceEngine:
                 self.spec.max_seq)
             key = self._decode_key(n_steps, want_lp, history, constrained,
                                    n_chunks)
-            if depth > 0 and key not in self._decode_cache:
+            if depth > 0 and not self._program_ready(key):
                 # Only dispatch ahead onto a warm program — a first-use
                 # history bucket would stall the already-computed older
                 # chunks behind a full XLA compile.
@@ -7353,6 +7428,7 @@ def get_engine(
     qos: bool = False,
     member_seeds: str = "distinct",
     quorum_dedup: bool = False,
+    prepare: bool = False,
 ) -> InferenceEngine:
     """Engines are keyed by weight identity (spec, seed, mesh, quant,
     members, draft model) plus the cache representation (kv_quant) —
@@ -7371,7 +7447,10 @@ def get_engine(
     program or cache layout depends on it, so it stays OUT of the key
     (qos=0 and qos=1 URLs share one engine, and pre-QoS cache keys are
     byte-identical); an explicit ``qos=1`` from any backend enables the
-    policy on the shared engine (opt-in wins, mirroring prefix_cache)."""
+    policy on the shared engine (opt-in wins, mirroring prefix_cache).
+    ``prepare`` (engine/prepare.py: the stored programs loaded on a pool
+    of threads, beside the weights' init) is not structural either, and
+    acts where the engine is built: every serving caller passes the same."""
     import os
 
     if draft_ckpt and draft_spec is not None:
@@ -7435,6 +7514,7 @@ def get_engine(
                 kv_pages=kv_pages, kv_page_size=kv_page_size,
                 kv_pool_pages=kv_pool_pages, qos=qos,
                 member_seeds=member_seeds, quorum_dedup=quorum_dedup,
+                prepare=prepare,
             )
             _ENGINES[key] = eng
         else:
@@ -7470,6 +7550,7 @@ def get_engine_from_ckpt(
     kv_page_size: int = 0,
     kv_pool_pages: int = 0,
     qos: bool = False,
+    prepare: bool = False,
 ) -> InferenceEngine:
     """Engine over a local HF checkpoint; keyed by (resolved path, mesh,
     draft checkpoint) so N backends pointing at one checkpoint with the
@@ -7523,7 +7604,7 @@ def get_engine_from_ckpt(
                 sp_impl=sp_impl, prefill_mesh=prefill_mesh,
                 zero_drain=zero_drain,
                 kv_pages=kv_pages, kv_page_size=kv_page_size,
-                kv_pool_pages=kv_pool_pages, qos=qos,
+                kv_pool_pages=kv_pool_pages, qos=qos, prepare=prepare,
             )
             _ENGINES[key] = eng
         else:

@@ -301,10 +301,15 @@ def create_app(
                 return "unhealthy", checks + failed
             if (row["breaker"] != "closed"
                     or row["pending"] >= row["queue_limit"]
-                    or row.get("draining")):
+                    or row.get("draining")
+                    or row.get("programs_preparing")):
                 # Draining: admissions are gated shut (POST /admin/drain)
                 # but residents still finish — degraded sheds /ready so
                 # the fleet rotates the replica out while they do.
+                # Preparing: the engine's stored programs are still being
+                # loaded (engine/prepare.py); a request would be served,
+                # waiting for its own program, but /ready turns 200 only
+                # once nothing loads behind the traffic.
                 status = "degraded"
         # SLO burn-rate degradation (telemetry/slo.py): opt-in via
         # QUORUM_TPU_SLO_READY_BURN — while a class burns objectives past
@@ -403,7 +408,7 @@ def create_app(
                   "kv_pages_allocated", "kv_pages_free",
                   "qos", "draining", "moe_experts_held",
                   "kv_cache_full_bytes", "kv_cache_window_bytes",
-                  "kv_cache_index_bytes")
+                  "kv_cache_index_bytes", "prepare_seconds")
         # One snapshot per distinct engine (_distinct_engines). Each
         # family's TYPE line appears exactly once, with all its samples
         # grouped — the Prometheus text format rejects repeated TYPE lines.
