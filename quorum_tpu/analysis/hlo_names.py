@@ -5,7 +5,8 @@ A profile names device operations as XLA numbered them (``fusion.277``,
 without its metadata. The metadata is in XLA's text of the optimized
 module: every instruction there has ``metadata={op_name="jit(chunk)/…/
 mlp/dot_general"}``, and the ``jax.named_scope`` parts of
-``models/transformer.py`` and ``models/patterned.py`` (:data:`PARTS`) are components of that path. This
+``models/transformer.py``, ``models/patterned.py`` and ``models/ssm.py``
+(:data:`PARTS`) are components of that path. This
 module reads that text, from ``compiled.as_text()`` or from the files an
 ``--xla_dump_to`` run leaves behind::
 
@@ -41,8 +42,14 @@ PATTERNED = ("attn.window", "attn.full", "moe.router", "moe.experts",
 # time (a block of selecting queries'), the gate per head
 LATENT = ("attn.latent_q", "attn.latent_kv", "attn.index", "attn.select",
           "attn.sparse", "attn.tiled", "attn.gate")
+# a spec with a mixer beside attention (models/ssm.py) adds: the fused input
+# projection, the depthwise convolution, the recurrence in chunks (a prefill
+# program's) or as one step (a decode step's), the gated grouped norm, the
+# output projection
+MIXER = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.step", "ssm.gate_norm",
+         "ssm.out_proj")
 PARTS = ("embed", "norm", "attn.qkv", "attn.cache_write", "attn.core",
-         "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT
+         "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT + MIXER
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(

@@ -149,6 +149,7 @@ from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.patterned import STATS as MOE_STATS
 from quorum_tpu.models.patterned import KindKV
 from quorum_tpu.models.patterned import stats_of as moe_stats_of
+from quorum_tpu.models.ssm import StateKV
 from quorum_tpu.models.transformer import (
     decode_chunk,
     decode_loop,
@@ -1559,6 +1560,46 @@ class InferenceEngine:
             # Slot-resident prefix reuse reads a row's first positions back:
             # a window layer's ring holds the row's last ones.
             self.prefix_cache = False
+        if self.spec.ssm_heads:
+            # A spec with a mixer (models/ssm.py) keeps a recurrent state a
+            # row and layer beside K and V. The state is the whole of what
+            # the row has read: it has no prefix to take up again, cannot be
+            # taken back a position, and is not copied or split by what
+            # copies or splits the K/V rectangle (ROADMAP.md).
+            mesh_shape = dict(self.mesh.shape)
+            refused = [
+                ("kv_pages=1", self.kv_pages,
+                 "a page pool has no place for"),
+                ("prefix_store", bool((prefix_store or "").strip()),
+                 "a stored prefix of positions comes without"),
+                ("disagg=P+D / zero_drain=1 (kv_transfer)", self.staged,
+                 "the hand-off of a row's positions does not carry"),
+                ("kv_quant=int8", bool(self.kv_quant),
+                 "the quantized cache is the two rectangles only, beside"),
+                ("quant=int8", bool(self.quant),
+                 "the weight quantizer does not know the projections of"),
+                ("spec_model= / spec_ckpt= (draft-model speculation)",
+                 draft_spec is not None,
+                 "a rejected draft cannot be taken back out of"),
+                (f"spec_decode={self.spec_decode}", self.spec_decode > 0,
+                 "a rejected draft cannot be taken back out of"),
+                ("sp>1 (ring or ulysses admission)", self._use_sp,
+                 "a sequence split over devices does not hand on"),
+                ("tp>1", mesh_shape.get(AXIS_TP, 1) > 1,
+                 "no sharding rule splits the heads of"),
+                ("members>1 (member stacking)", self.members > 1,
+                 "the member-stacked programs do not carry"),
+            ]
+            for what, asked, why in refused:
+                if asked:
+                    raise ValueError(
+                        f"{what} does not compose with a spec that has a "
+                        f"mixer ({self.spec.family}, ssm_heads="
+                        f"{self.spec.ssm_heads}): {why} the recurrent state "
+                        "a row keeps beside its K and V")
+            # Slot-resident prefix reuse starts a row at a position past 0:
+            # the state there is the last tenant's, at its own last position.
+            self.prefix_cache = False
         mode = (prefix_store or "").strip().lower() or None
         if mode not in (None, "host"):
             raise ValueError(
@@ -1833,6 +1874,12 @@ class InferenceEngine:
         # XLA's einsums do), and what reading every row to the bucket counts.
         self.n_kv_tiles_read = 0
         self.n_kv_tiles_bucket = 0
+        # A spec with a mixer (models/ssm.py): rows x steps x layers whose
+        # recurrent state a decode chunk's program read and wrote back (every
+        # row of the cache, each step), and those of them that belonged to a
+        # row decoding in that step by the host's plan.
+        self.n_ssm_rows_stepped = 0
+        self.n_ssm_rows_live = 0
         self._moe_total = None  # a patterned spec's expert counters
         # The backends' producer threads, where the default pool is too
         # small for a backend's slots (tpu_backend._stream_pool).
@@ -2014,8 +2061,9 @@ class InferenceEngine:
                         mesh, P(*((None,) + tuple(s.spec)))),
                     sh, is_leaf=lambda x: isinstance(x, NamedSharding))
             return sh
-        if self.spec.layer_pattern:
-            # a leaf per layer, by kind, and the counters: all replicated
+        if self.spec.layer_pattern or self.spec.ssm_heads:
+            # a leaf per layer, by kind, and the counters (or the two
+            # rectangles and the mixer's state and tail): all replicated
             # (tp, sp and members are refused above)
             leaf = NamedSharding(mesh, P())
             return jax.tree.map(lambda _: leaf, jax.eval_shape(
@@ -2044,6 +2092,11 @@ class InferenceEngine:
         materialization or transfer of the multi-GB buffer.
         """
         self._ck, self._cv = self._zero_cache(self._cache_sh)
+        # what one row holds of a mixer's state and tail, all layers: a
+        # constant of the cache's shape (a ``prefill`` span's ``state_bytes``)
+        self._state_row_bytes = (
+            self._kv_cache_bytes()["state"] // self._rows
+            if self.spec.ssm_heads else 0)
         # the expert counters ride the cache, so they start over with it;
         # snapshots of the cache that was are not to be counted
         self._moe_last = None
@@ -2137,10 +2190,12 @@ class InferenceEngine:
         fn = self._util_fns.get(key)
         if fn is None:
             # a patterned spec's sharding is the (K side, V side) pair
-            # already: only its K side carries the expert counters
+            # already (only its K side carries the expert counters), and so
+            # is a spec's with a mixer (state on one side, tail on the other)
             fn = self._util_fns[key] = jax.jit(
                 zero_cache, out_shardings=(
-                    shardings if self.spec.layer_pattern
+                    shardings
+                    if self.spec.layer_pattern or self.spec.ssm_heads
                     else (shardings, shardings)))
         return fn()
 
@@ -4552,6 +4607,17 @@ class InferenceEngine:
         return {"keys_kept_share": round(
             100.0 * kept / (n_prompt * (n_prompt + 1) // 2), 2)}
 
+    def _state_carried(self, carried: bool) -> dict:
+        """A ``prefill`` span's ``state_carried`` and ``state_bytes``, where
+        the spec has a mixer: whether the row's recurrent state went from
+        one program to the next on the way (a chunked admission's segments
+        and its register's decode step; a single-shot admit makes it in one
+        program), and the bytes of state and tail the row holds."""
+        if not self.spec.ssm_heads:
+            return {}
+        return {"state_carried": carried,
+                "state_bytes": self._state_row_bytes}
+
     def _kv_cache_bytes(self) -> dict:
         """Bytes of the slot cache by layer kind, from the arrays the engine
         holds (a donated array still says its shape): a spec without a
@@ -4566,6 +4632,12 @@ class InferenceEngine:
             return {"full": nbytes(ck.full) + nbytes(cv.full),
                     "window": nbytes(ck.window) + nbytes(cv.window),
                     "index": nbytes(ck.index)}
+        if isinstance(ck, StateKV):
+            # ``state``: the mixer's state and convolution tail, per layer
+            # and row, beside the K and V rectangles
+            return {"full": nbytes(ck.kv) + nbytes(cv.kv), "window": 0,
+                    "index": 0,
+                    "state": nbytes(ck.carry) + nbytes(cv.carry)}
         return {"full": nbytes(ck) + nbytes(cv), "window": 0, "index": 0}
 
     def metrics(self) -> dict:
@@ -4591,6 +4663,9 @@ class InferenceEngine:
                 "decode_busy_rows_total": self.n_decode_rows,
                 "decode_kv_tiles_read_total": self.n_kv_tiles_read,
                 "decode_kv_tiles_bucket_total": self.n_kv_tiles_bucket,
+                **({"ssm_state_rows_stepped_total": self.n_ssm_rows_stepped,
+                    "ssm_state_rows_live_total": self.n_ssm_rows_live}
+                   if self.spec.ssm_heads else {}),
                 "prefix_hits_total": self.prefix_hits,
                 "prefix_tokens_saved_total": self.prefix_tokens_saved,
                 "prefix_store_hits_total": self.prefix_store_hits,
@@ -5750,7 +5825,7 @@ class InferenceEngine:
             slot=adm.slot, chunked=True, reused=adm.offset0,
             restored=adm.restored, segments=adm.segments,
             turns=self.n_turns - adm.turn0 + 1 if adm.segments else 0,
-            **self._keys_kept(len(prompt)))
+            **self._keys_kept(len(prompt)), **self._state_carried(True))
 
         def settled(acct):
             self.prefill_own_s += acct.own
@@ -5829,7 +5904,13 @@ class InferenceEngine:
             if adm.final_sent:
                 continue  # fully staged; awaiting the register
             prompt = req.prompt_ids
-            seg = prompt[adm.offset : adm.offset + self.prefill_chunk]
+            # A row that holds a recurrent state admits all but its last
+            # token in segments: the register's decode step runs that token
+            # again (for K and V a second, equal write; a state would take
+            # the position twice).
+            end = len(prompt) - (1 if self.spec.ssm_heads else 0)
+            seg = prompt[adm.offset : min(adm.offset + self.prefill_chunk,
+                                          end)]
             bucket = prefill_bucket(len(seg), self.prefill_chunk)
             history = prefill_bucket(adm.offset + len(seg), self.spec.max_seq)
             tokens = np.zeros((1, bucket), np.int32)
@@ -5877,7 +5958,7 @@ class InferenceEngine:
                 adm.offset += len(seg)
                 # keep the prefix-cache view in sync with the cache rows
                 self._resident[adm.slot] = prompt[: adm.offset]
-                if adm.offset >= len(prompt):
+                if adm.offset >= end:
                     self._finish_admission(adm)
             except Exception as e:
                 # One admission's segment failed: doom it alone; active
@@ -5959,7 +6040,7 @@ class InferenceEngine:
         # every admission span carries the cache-effectiveness attrs.
         req.span("prefill", t0, t1, tokens=n_prompt, bucket=bucket, slot=slot,
                  reused=0, restored=0, **acct.parts_ms(), **picks,
-                 **self._keys_kept(n_prompt))
+                 **self._keys_kept(n_prompt), **self._state_carried(False))
         if req.want_lp >= 0:
             req.lp.append((float(s_lp),
                            np.asarray(top_ix), np.asarray(top_lp)))
@@ -6665,9 +6746,16 @@ class InferenceEngine:
         """Count what a dispatched decode chunk's ``steps`` steps read of
         the dense cache's history window, in tiles, from the lengths and the
         bucket the host holds (planned lengths: rows that finish on the
-        device stop short of them)."""
+        device stop short of them); and, where the spec has a mixer, the
+        rows' states its steps rewrote and those that were a live row's."""
         if self.spec.layer_pattern:
             return
+        if self.spec.ssm_heads:
+            layers = self.spec.n_layers
+            self.n_ssm_rows_stepped += layers * self._rows * steps
+            self.n_ssm_rows_live += layers * sum(
+                min(steps, max(r.budget - r.emitted - ahead, 0))
+                for _, r in active)
         tile = decode_tile(history)
         bucket = self._rows * (history // tile) * steps
         self.n_kv_tiles_bucket += bucket
@@ -6692,8 +6780,9 @@ class InferenceEngine:
                 or self.mesh.devices.flat[0].platform != "tpu"):
             return False
         spec = self.spec
+        side = self._ck.kv if isinstance(self._ck, StateKV) else self._ck
         return not kernel_refusal((self._rows, spec.n_heads, 1, spec.head_dim),
-                                  self._ck, history, sharded=self._sharded)
+                                  side, history, sharded=self._sharded)
 
     def _try_spec_dispatch(self, active, g: int, ahead: int,
                            depth: int) -> str:

@@ -156,6 +156,80 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
     }
 
 
+def init_mixer_from_key(spec: ModelSpec, key) -> Params:
+    """The weights of a spec with a mixer beside attention (models/ssm.py;
+    family "falcon_h1"), in the dense family's stacked layout.
+
+    The published multipliers are small (the logits' is 1/128, the keys'
+    1/90) because the trained matrices they meet are large. Unit-scale seeded
+    matrices under them would give flat logits over the vocabulary and scores
+    of zero, and every fault would pass. So each matrix is seeded at
+    ``1/sqrt(fan_in)`` DIVIDED BY the multipliers its product meets: what
+    comes out of every product is then of the size the dense family's seeded
+    init gives (a position's log-probabilities spread by a few nats), and a
+    multiplier left out or put on the wrong product moves the result by its
+    own factor. Embedding rows are of unit rms after ``emb_scale``. The
+    recurrence's own numbers are Mamba-2's: ``A`` uniform in [1, 16] a head,
+    ``softplus(dt_bias)`` log-uniform in [0.001, 0.1], ``D`` 1, convolution
+    taps uniform in +-1/sqrt(taps) with a zero bias, norm gains 1. The
+    benchmark's configuration states the same under ``assumed.weights``."""
+    dt = jnp.dtype(spec.dtype)
+    ks = iter(jax.random.split(key, 16))
+    L, D, V = spec.n_layers, spec.d_model, spec.vocab_size
+    H = spec.n_heads * spec.head_dim
+    K = spec.n_kv_heads * spec.head_dim
+    F = spec.d_ff
+    d, gn, heads = (spec.ssm_width, spec.ssm_groups * spec.ssm_state,
+                    spec.ssm_heads)
+    taps, width = spec.ssm_conv, spec.ssm_conv_width
+
+    def w(k, *shape, under=1.0):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (shape[-2] ** -0.5 / under)).astype(dt)
+
+    m_z, m_x, m_b, m_c, m_dt = spec.ssm_mults
+    # the input projection's columns: z, x, B, C, dt, each under its own
+    # multiplier and the mixer's input multiplier
+    part = jnp.concatenate([
+        jnp.full((n,), m * spec.ssm_in_mult, jnp.float32)
+        for n, m in ((d, m_z), (d, m_x), (gn, m_b), (gn, m_c),
+                     (heads, m_dt))])
+    ssm_in = (jax.random.normal(next(ks), (L, D, 2 * d + 2 * gn + heads),
+                                jnp.float32) * D ** -0.5 / part).astype(dt)
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (L, heads), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    blocks = {
+        "attn_norm_w": jnp.ones((L, D), dt),
+        "mlp_norm_w": jnp.ones((L, D), dt),
+        "wq": w(next(ks), L, D, H, under=spec.attn_in_mult),
+        "wk": w(next(ks), L, D, K, under=spec.attn_in_mult * spec.key_mult),
+        "wv": w(next(ks), L, D, K, under=spec.attn_in_mult),
+        "wo": w(next(ks), L, H, D, under=spec.attn_out_mult),
+        "w_gate": w(next(ks), L, D, F, under=spec.mlp_gate_mult),
+        "w_up": w(next(ks), L, D, F),
+        "w_down": w(next(ks), L, F, D, under=spec.mlp_down_mult),
+        "ssm_in": ssm_in,
+        "ssm_conv_w": jax.random.uniform(
+            next(ks), (L, taps, width), jnp.float32, -taps ** -0.5,
+            taps ** -0.5).astype(dt),
+        "ssm_conv_b": jnp.zeros((L, width), dt),
+        # softplus(dt_bias) = step: its inverse, step + log(1 - exp(-step))
+        "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "ssm_a_log": jnp.log(jax.random.uniform(
+            next(ks), (L, heads), jnp.float32, 1.0, 16.0)),
+        "ssm_d": jnp.ones((L, heads), jnp.float32),
+        "ssm_norm_w": jnp.ones((L, d), dt),
+        "ssm_out": w(next(ks), L, d, D, under=spec.ssm_out_mult),
+    }
+    return {
+        "tok_emb": (jax.random.normal(next(ks), (V, D), jnp.float32)
+                    / spec.emb_scale).astype(dt),
+        "final_norm_w": jnp.ones((D,), dt),
+        "lm_head": w(next(ks), D, V, under=spec.lm_head_mult),
+        "blocks": blocks,
+    }
+
+
 def init_params_from_key(spec: ModelSpec, key) -> Params:
     """Init from a PRNG key (traced-friendly: vmappable over stacked keys —
     how stacked members materialize directly into their slices,
@@ -163,6 +237,8 @@ def init_params_from_key(spec: ModelSpec, key) -> Params:
     spec.validate()
     if spec.layer_pattern:
         return init_patterned_from_key(spec, key)
+    if spec.ssm_heads:
+        return init_mixer_from_key(spec, key)
     dt = jnp.dtype(spec.dtype)
     keys = iter(jax.random.split(key, 32))
 

@@ -37,7 +37,7 @@ class Latent(NamedTuple):
 
 @dataclass(frozen=True)
 class ModelSpec:
-    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe" | "dots3_note"
+    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe" | "dots3_note" | "falcon_h1"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -136,6 +136,36 @@ class ModelSpec:
     # ``init_bias_dev`` times a normal (models/init.py).
     init_depth: int = 0
     init_bias_dev: float = 0.05
+    # A Mamba-2 mixer beside attention in every block (``ssm_heads`` > 0;
+    # family "falcon_h1"; models/ssm.py): both read the block's normed input
+    # and both add to the stream. ``ssm_heads`` heads of ``ssm_head_dim``
+    # channels, each with a recurrent state of ``ssm_head_dim`` x
+    # ``ssm_state``; the state's input and output vectors (B, C) are shared
+    # by the heads of one of ``ssm_groups`` groups; a depthwise causal
+    # convolution of ``ssm_conv`` taps before the recurrence; prefill runs
+    # the recurrence in chunks of ``ssm_chunk`` positions. The cache keeps,
+    # per row and layer, the float32 state and the convolution's last
+    # ``ssm_conv - 1`` inputs beside K and V (``StateKV``).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # muP multipliers (1.0 = none, and then no operation): on attention's
+    # input and output, on the keys after their projection, on the mixer's
+    # input and output and on the five parts of its input projection (gate
+    # z, x, B, C, dt), on the MLP's gate product and its down product, on
+    # the logits. The embedding's is ``emb_scale``.
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_gate_mult: float = 1.0
+    mlp_down_mult: float = 1.0
+    lm_head_mult: float = 1.0
     dtype: str = "bfloat16"
 
     @property
@@ -152,6 +182,16 @@ class ModelSpec:
         """Positions a window layer keeps per row: the power of two at or
         above ``sliding_window``."""
         return 1 << max(self.sliding_window - 1, 0).bit_length()
+
+    @property
+    def ssm_width(self) -> int:
+        """The mixer's channels: ``ssm_heads`` x ``ssm_head_dim``."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the mixer's convolution runs over: x, B and C."""
+        return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
 
     def latent(self, kind: str) -> "Latent":
         """The latent attention's sizes in a layer of ``kind``."""
@@ -182,6 +222,15 @@ class ModelSpec:
         assert self.pos in ("rope", "learned")
         assert self.rope_scaling in ("", "llama3"), (
             f"unsupported rope_scaling {self.rope_scaling!r}")
+        if self.ssm_heads:
+            assert not self.layer_pattern and not self.is_moe, (
+                "a mixer beside attention is the dense family's")
+            assert min(self.ssm_head_dim, self.ssm_state, self.ssm_conv - 1,
+                       self.ssm_chunk) > 0
+            assert self.ssm_heads % self.ssm_groups == 0
+            assert len(self.ssm_mults) == 5
+            assert self.pos == "rope" and self.norm == "rmsnorm"
+            assert self.gated_mlp and not self.use_bias
         if self.layer_pattern:
             assert set(self.layer_pattern) <= {"L", "G"}, (
                 f"layer_pattern {self.layer_pattern!r}: L (window) and G "
@@ -322,6 +371,27 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         swa_rope_theta=50000.0, index_n_heads=64, index_head_dim=128,
         index_topk=2048, init_bias_dev=0.01,
     ),
+    # Falcon-H1-34B-Instruct (tiiuae, model_type falcon_h1): 72 blocks, all
+    # alike, each with grouped-query attention (20 / 4 heads of 128, theta
+    # 1e11) AND a Mamba-2 mixer (32 heads of 128 with a state of 256, 2
+    # groups, convolution 4, chunks of 128) on the same normed input, then a
+    # SwiGLU of 21504; muP multipliers throughout. 67 GB in bf16: served as
+    # the first pipeline stage, ``?n_layers=6&max_seq=2048``
+    # (docs/tpu_backends.md).
+    "falcon-h1-34b": ModelSpec(
+        family="falcon_h1", vocab_size=261120, d_model=5120, n_layers=72,
+        n_heads=20, n_kv_heads=4, head_dim=128, d_ff=21504, max_seq=8192,
+        rope_theta=1e11, tied_lm_head=False, norm_eps=1e-5,
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+        ssm_conv=4, ssm_chunk=128, emb_scale=5.656854249492381,
+        attn_in_mult=1.0, attn_out_mult=0.0375,
+        key_mult=0.011048543456039804, ssm_in_mult=0.25,
+        ssm_out_mult=0.08838834764831845,
+        ssm_mults=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                   0.3535533905932738),
+        mlp_gate_mult=0.1767766952966369,
+        mlp_down_mult=0.011160714285714284, lm_head_mult=0.0078125,
+    ),
     # Scaled-down test/dev presets (CPU-fast, same code paths)
     "gpt2-tiny": _gpt2(vocab_size=512, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, head_dim=16, d_ff=128, max_seq=128),
@@ -360,6 +430,16 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=50000.0,
         index_n_heads=4, index_head_dim=16, index_topk=16,
         init_bias_dev=0.01,
+    ),
+    # the falcon_h1 family at a size the CPU runs: every multiplier off 1
+    "falcon-h1-tiny": ModelSpec(
+        family="falcon_h1", vocab_size=256, d_model=64, n_layers=3,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, max_seq=128,
+        rope_theta=1e11, tied_lm_head=False, ssm_heads=4, ssm_head_dim=16,
+        ssm_state=16, ssm_groups=2, ssm_conv=4, ssm_chunk=8, emb_scale=1.5,
+        attn_in_mult=0.8, attn_out_mult=0.6, key_mult=0.5, ssm_in_mult=0.25,
+        ssm_out_mult=0.7, ssm_mults=(0.35, 0.25, 0.18, 0.5, 0.35),
+        mlp_gate_mult=0.4, mlp_down_mult=0.3, lm_head_mult=0.125,
     ),
     "gemma-tiny": ModelSpec(
         family="gemma", vocab_size=512, d_model=64, n_layers=2, n_heads=4,

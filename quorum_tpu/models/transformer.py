@@ -33,6 +33,15 @@ and a spec without a pattern compiles what it always compiled.
           bq/bk/bv/bo? · mlp_norm_w/b [L,D]
           dense: w_gate? w_up [L,D,F] · w_down [L,F,D] · b_up/b_down?
           moe:   router [L,D,E] · moe_w_gate/up [L,E,D,F] · moe_w_down [L,E,F,D]
+          mixer: ssm_in [L,D,2d+2GN+H] · ssm_conv_w [L,taps,d+2GN] · ssm_conv_b
+                 ssm_dt_bias/ssm_a_log/ssm_d [L,H] · ssm_norm_w [L,d] · ssm_out [L,d,D]
+
+A spec with ``ssm_heads`` runs a Mamba-2 mixer (models/ssm.py) beside
+attention in every block, on the same normed input, and both add to the
+stream. Each side of its cache is a :class:`StateKV`: the K or V rectangle
+and what the mixer carries per layer and row (the state, the convolution's
+tail). A decode step carries both leaves through the layer scan, and so does
+a prefill program, which writes one row's lines, state and tail in place.
 """
 
 from __future__ import annotations
@@ -52,8 +61,9 @@ from quorum_tpu.cache.paging import (
     page_write_seg,
     page_write_step,
 )
-from quorum_tpu.models import patterned
+from quorum_tpu.models import patterned, ssm
 from quorum_tpu.models.model_config import ModelSpec
+from quorum_tpu.models.ssm import StateKV
 from quorum_tpu.models.quant import is_quantized, qeinsum
 from quorum_tpu.ops.attention import (
     attention,
@@ -172,6 +182,8 @@ def _maybe(block: Params, name: str, layer_slice):
 def _dense_mlp_core(x, block, spec: ModelSpec):
     if spec.gated_mlp:
         gate = qeinsum("btd,df->btf", x, block["w_gate"])
+        if spec.mlp_gate_mult != 1.0:
+            gate = gate * spec.mlp_gate_mult
         up = qeinsum("btd,df->btf", x, block["w_up"])
         # swiglu (llama/mistral) gates with SiLU; geglu (gemma) with
         # tanh-approximated GELU (HF act_fn "gelu_pytorch_tanh").
@@ -183,6 +195,8 @@ def _dense_mlp_core(x, block, spec: ModelSpec):
             up = up + block["b_up"]
         h = jax.nn.gelu(up, approximate=True).astype(x.dtype)
     out = qeinsum("btf,fd->btd", h, block["w_down"])
+    if spec.mlp_down_mult != 1.0:
+        out = out * spec.mlp_down_mult
     if block.get("b_down") is not None:
         out = out + block["b_down"]
     return out.astype(x.dtype)
@@ -311,8 +325,12 @@ def _moe_mlp(x, block, spec: ModelSpec, token_mask=None):
 def _qkv(x, block, spec: ModelSpec):
     """Project to q [B,H,T,hd], k/v [B,K,T,hd]."""
     b, t, _ = x.shape
+    if spec.attn_in_mult != 1.0:
+        x = x * jnp.asarray(spec.attn_in_mult, x.dtype)
     q = qeinsum("btd,dh->bth", x, block["wq"])
     k = qeinsum("btd,dh->bth", x, block["wk"])
+    if spec.key_mult != 1.0:
+        k = k * spec.key_mult
     v = qeinsum("btd,dh->bth", x, block["wv"])
     if block.get("bq") is not None:
         q, k, v = q + block["bq"], k + block["bk"], v + block["bv"]
@@ -332,10 +350,12 @@ def _qkv(x, block, spec: ModelSpec):
 
 
 @jax.named_scope("attn.out")
-def _attn_out(attn, block, x_dtype):
+def _attn_out(attn, block, x_dtype, mult: float = 1.0):
     b, h, t, d = attn.shape
     merged = attn.transpose(0, 2, 1, 3).reshape(b, t, h * d)
     out = qeinsum("bth,hd->btd", merged, block["wo"])
+    if mult != 1.0:
+        out = out * mult
     if block.get("bo") is not None:
         out = out + block["bo"]
     return out.astype(x_dtype)
@@ -355,10 +375,14 @@ def _embed(params, spec: ModelSpec, tokens, positions):
 def _unembed(params, spec: ModelSpec, x):
     w = params.get("lm_head")
     if w is not None:
-        return qeinsum("...d,dv->...v", x, w)
-    # tied head: contract against the embedding table's rows directly — the
-    # quantized table's per-row scales become per-vocab output scales.
-    return qeinsum("...d,vd->...v", x, params["tok_emb"])
+        logits = qeinsum("...d,dv->...v", x, w)
+    else:
+        # tied head: contract against the embedding table's rows directly —
+        # the quantized table's per-row scales become per-vocab output scales.
+        logits = qeinsum("...d,vd->...v", x, params["tok_emb"])
+    if spec.lm_head_mult != 1.0:
+        logits = logits * spec.lm_head_mult
+    return logits
 
 
 def _final_norm(params, spec: ModelSpec, x):
@@ -376,20 +400,51 @@ def _prefill_write(cache, value, cache_row, write_gate):
     return _block_write(cache, value, cache_row, 0, write_gate)
 
 
-def _block_write(cache, value, row, offset, write_gate):
+def _block_write(cache, value, row, offset, write_gate, layer=None):
     """Write ``value`` [B, K, T, hd] as T contiguous lines of one layer's
     dense cache side ([B or S, max_seq, K·hd], or the int8 pair) from
-    ``(row, offset)``. ``write_gate`` (scalar bool) writes the touched region
-    back unchanged when False (one extra region read — never a full-cache
-    select)."""
+    ``(row, offset)``; with ``layer``, of that layer of a whole side
+    ([L, B or S, max_seq, K·hd]). ``write_gate`` (scalar bool) writes the
+    touched region back unchanged when False (one extra region read — never
+    a full-cache select)."""
     def gated(arr, new):
         idx = (row, offset, 0)
+        if layer is not None:
+            idx, new = (layer,) + idx, new[None]
         if write_gate is not None:
             old = lax.dynamic_slice(arr, idx, new.shape)
             new = jnp.where(write_gate, new, old)
         return lax.dynamic_update_slice(arr, new, idx)
 
     return jax.tree.map(gated, cache, _kv_lines(cache, value))
+
+
+def _mixer_row(h, block, spec: ModelSpec, held, layer, row, n_valid, fresh):
+    """The mixer's branch of one prefill program's block over ``h``
+    [B, T, D], rows ``row ..`` of layer ``layer`` of the cache ``held`` (its
+    two :class:`StateKV` sides): from a zero state and tail where ``fresh``
+    (True, or a bool scalar: the row's first position is this program's),
+    else from what the rows carry. Returns the branch's output and ``held``
+    with the rows' new state and tail written."""
+    b = h.shape[0]
+    carried = (held[0].carry, held[1].carry)
+    state, tail = ssm.init_carry(spec, b, carried[1].dtype)
+    if fresh is not True:
+        state, tail = (jnp.where(fresh, zero, ssm.rows_read(leaf, layer, row, b))
+                       for zero, leaf in zip((state, tail), carried))
+    out, state, tail = ssm.mixer(h, block, spec, state, tail, n_valid)
+    with jax.named_scope("ssm.scan"):  # the rows' carry, written in place
+        return out, tuple(
+            StateKV(side.kv, ssm.rows_write(side.carry, new, layer, row))
+            for side, new in zip(held, (state, tail)))
+
+
+def _held_write(held, k, v, row, offset, layer):
+    """``held`` with a block's K and V written into layer ``layer`` of its
+    two rectangles, from ``(row, offset)``."""
+    return tuple(
+        StateKV(_block_write(side.kv, value, row, offset, None, layer),
+                side.carry) for side, value in zip(held, (k, v)))
 
 
 def prefill(
@@ -452,8 +507,15 @@ def prefill(
     cos, sin = rope_cos_sin_for(spec)
     moe_mask = jnp.arange(t)[None, :] < lengths[:, None]  # [B,T] real tokens
 
-    def body(carry_x, per_layer):
-        block, ck, cv = per_layer  # ck/cv: [B or S, max_seq, K·hd]
+    # A mixer spec's whole cache rides the scan's carry, and each layer
+    # writes its row's lines, state and tail where they lie; every other
+    # spec's K and V are the scan's xs and ys.
+    held = (cache_k, cache_v) if spec.ssm_heads else None
+    assert held is None or (write_gate is None and block_member is None)
+
+    def body(carry, per_layer):
+        carry_x, held = carry
+        block, ck, cv, layer = per_layer  # ck/cv: [B or S, max_seq, K·hd]
         if block_member is not None:
             block = jax.tree.map(lambda w: w[block_member], block)
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
@@ -478,18 +540,32 @@ def prefill(
                 attn = flash_prefill_attention(q, k, v, lengths,
                                                window=spec.sliding_window,
                                                tp_mesh=tp_mesh)
-        carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
+        added = _attn_out(attn, block, carry_x.dtype, spec.attn_out_mult)
+        if held is not None:
+            # a single-shot prefill is the row's whole prompt: from zero
+            mix_out, held = _mixer_row(h, block, spec, held, layer,
+                                       cache_row, lengths, True)
+            added = added + mix_out
+        carry_x = carry_x + added
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = (_moe_mlp(h2, block, spec, token_mask=moe_mask)
                if spec.is_moe else _dense_mlp(h2, block, spec))
         carry_x = carry_x + mlp
+        if held is not None:
+            with jax.named_scope("attn.cache_write"):
+                held = _held_write(held, k, v, cache_row, 0, layer)
+            return (carry_x, held), None
         new_ck = _prefill_write(ck, k, cache_row, write_gate)
         new_cv = _prefill_write(cv, v, cache_row, write_gate)
-        return carry_x, (new_ck, new_cv)
+        return (carry_x, None), (new_ck, new_cv)
 
     if remat:
         body = jax.checkpoint(body)
-    x, (cache_k, cache_v) = lax.scan(body, x, (params["blocks"], cache_k, cache_v))
+    per_layer = ((params["blocks"], cache_k, cache_v, None) if held is None
+                 else (params["blocks"], None, None,
+                       jnp.arange(spec.n_layers)))
+    (x, held), written = lax.scan(body, (x, held), per_layer)
+    cache_k, cache_v = written if held is None else held
     x = _final_norm(params, spec, x)
     # Only the last real token's logits matter for generation; gather per row.
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0, :]
@@ -551,6 +627,8 @@ def prefill_segment(
     mask = keep[None, None, None, :, :]  # [1,1,1,T,hist]
     moe_mask = (jnp.arange(t) < n_valid)[None, :]  # [1,T]
 
+    held = (cache_k, cache_v) if spec.ssm_heads else None  # as in prefill
+    assert held is None or write_gate is None
     paged = kv_is_paged(cache_k)
 
     @jax.named_scope("attn.cache_write")
@@ -575,28 +653,61 @@ def prefill_segment(
                 leaf, (slot, 0, 0), (1, hist, leaf.shape[-1])), cache)
         return _kv_rows(window, spec.n_kv_heads, dtype)
 
-    def body(carry_x, per_layer):
-        block, ck, cv = per_layer  # ck/cv: [S, max_seq, K·hd] (or (q8, scale))
+    def held_window(side, value, layer, dtype):
+        # A carried side's window of the slot as the earlier segments left
+        # it, with this segment's lines laid into the copy: the products
+        # read a small array of their own and the carried side is only
+        # written (read after its write, the compiler re-laid each whole
+        # side at the program's entry and exit: 0.8 GB each way at 64 rows
+        # of 2,048).
+        window = lax.dynamic_slice(side, (layer, slot, 0, 0),
+                                   (1, 1, hist, side.shape[-1]))[0]
+        return _kv_rows(_block_write(window, value, 0, offset, None),
+                        spec.n_kv_heads, dtype)
+
+    def body(carry, per_layer):
+        carry_x, held = carry
+        block, ck, cv, layer = per_layer  # ck/cv: [S, max_seq, K·hd] (or (q8, scale))
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)
         if spec.pos == "rope":
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-        new_ck = seg_write(ck, k)
-        new_cv = seg_write(cv, v)
+        if held is None:
+            new_ck = seg_write(ck, k)
+            new_cv = seg_write(cv, v)
         with jax.named_scope("attn.core"):
-            row_k = seg_read(new_ck, q.dtype)
-            row_v = seg_read(new_cv, q.dtype)
+            if held is None:
+                row_k = seg_read(new_ck, q.dtype)
+                row_v = seg_read(new_cv, q.dtype)
+            else:
+                row_k, row_v = (held_window(side.kv, value, layer, q.dtype)
+                                for side, value in zip(held, (k, v)))
             attn = attention(q, row_k, row_v, mask, rows_major=not paged)
-        carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
+        added = _attn_out(attn, block, carry_x.dtype, spec.attn_out_mult)
+        if held is not None:
+            with jax.named_scope("attn.cache_write"):
+                held = _held_write(held, k, v, slot, offset, layer)
+            # the row's state as its last segment left it; a segment at
+            # offset 0 is a new tenant's first, whatever the row held
+            mix_out, held = _mixer_row(h, block, spec, held, layer, slot,
+                                       jnp.reshape(n_valid, (1,)),
+                                       offset == 0)
+            added = added + mix_out
+        carry_x = carry_x + added
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = (_moe_mlp(h2, block, spec, token_mask=moe_mask)
                if spec.is_moe else _dense_mlp(h2, block, spec))
         carry_x = carry_x + mlp
-        return carry_x, (new_ck, new_cv)
+        if held is not None:
+            return (carry_x, held), None
+        return (carry_x, None), (new_ck, new_cv)
 
-    _, (cache_k, cache_v) = lax.scan(body, x, (params["blocks"], cache_k, cache_v))
-    return cache_k, cache_v
+    per_layer = ((params["blocks"], cache_k, cache_v, None) if held is None
+                 else (params["blocks"], None, None,
+                       jnp.arange(spec.n_layers)))
+    (_, held), written = lax.scan(body, (x, held), per_layer)
+    return written if held is None else held
 
 
 def decode_step(
@@ -713,6 +824,8 @@ def decode_step_blocks(
                             cache, _kv_lines(cache, value))
 
     def layer_step(carry_x, block, ck, cv, layer):
+        if spec.ssm_heads:  # the two StateKV sides: (state, tail) apart
+            carried, ck, cv = (ck.carry, cv.carry), ck.kv, cv.kv
         h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
         q, k, v = _qkv(h, block, spec)  # q [B,H,1,hd], k/v [B,K,1,hd]
         if spec.pos == "rope":
@@ -739,7 +852,19 @@ def decode_step_blocks(
                           else decode_attention)
                 attn = attend(q, *jax.tree.leaves((read_k, read_v)),
                               lengths + 1, window=spec.sliding_window)
-        carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
+        added = _attn_out(attn, block, carry_x.dtype, spec.attn_out_mult)
+        if spec.ssm_heads:
+            # every row's state is read and written back; a row the step
+            # may not write takes no position, which leaves it as it was
+            state, tail = (lax.dynamic_index_in_dim(leaf, layer, 0, False)
+                           for leaf in carried)
+            mix_out, state, tail = ssm.mixer(
+                h, block, spec, state, tail, allow.astype(jnp.int32))
+            added = added + mix_out
+            with jax.named_scope("ssm.step"):  # the update lands in place
+                ck = StateKV(ck, ssm.rows_write(carried[0], state, layer, 0))
+                cv = StateKV(cv, ssm.rows_write(carried[1], tail, layer, 0))
+        carry_x = carry_x + added
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
         mlp = _moe_mlp(h2, block, spec) if spec.is_moe else _dense_mlp(h2, block, spec)
         return carry_x + mlp, ck, cv
@@ -945,6 +1070,10 @@ def decode_multi(
         return patterned.decode_multi(params, spec, tokens, lengths, cache_k,
                                       cache_v, write_mask=write_mask,
                                       history=history)
+    if spec.ssm_heads:
+        raise NotImplementedError(
+            "a spec with a mixer (ssm_heads) has no multi-token decode: a "
+            "rejected draft's positions cannot be taken out of its state")
     b, t = tokens.shape
     pos = lengths[:, None] + jnp.arange(t)[None, :]              # [B,T]
     with jax.named_scope("embed"):
@@ -1057,7 +1186,15 @@ def _layer_body(carry_x, block, spec: ModelSpec, positions, cos, sin, attn_fn,
         k = apply_rope(k, cos, sin, positions)
     with jax.named_scope("attn.core"):
         attn = attn_fn(q, k, v)
-    carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
+    added = _attn_out(attn, block, carry_x.dtype, spec.attn_out_mult)
+    if spec.ssm_heads:
+        # no cache: every row from a zero state, through its real positions
+        b, t = carry_x.shape[:2]
+        state, tail = ssm.init_carry(spec, b, carry_x.dtype)
+        n_valid = (jnp.full((b,), t, jnp.int32) if token_mask is None
+                   else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
+        added = added + ssm.mixer(h, block, spec, state, tail, n_valid)[0]
+    carry_x = carry_x + added
     h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
     mlp = (_moe_mlp(h2, block, spec, token_mask=token_mask)
            if spec.is_moe else _dense_mlp(h2, block, spec))
@@ -1179,13 +1316,21 @@ def init_cache(spec: ModelSpec, batch: int, dtype=None, kv_quant: str | None = N
     8k window the bf16 cache is 1.07 GB per slot; int8 is 0.54 GB.
 
     A spec with a ``layer_pattern`` gets a cache per layer kind instead
-    (models/patterned.py: ``KindKV``), in bf16 only."""
+    (models/patterned.py: ``KindKV``), in bf16 only. A spec with a mixer
+    (``ssm_heads``) gets each side as a :class:`StateKV`: the rectangle and,
+    per layer and row, the float32 state (K side) or the convolution's tail
+    (V side), in bf16 only."""
     if spec.layer_pattern:
         assert kv_quant is None, "a patterned spec's cache is not quantized"
         return patterned.init_cache(spec, batch, dtype)
     dt = jnp.dtype(dtype or spec.dtype)
     shape = (spec.n_layers, batch, spec.max_seq,
              spec.n_kv_heads * spec.head_dim)
+    if spec.ssm_heads:
+        assert kv_quant is None, "a cache that holds a state is not quantized"
+        state, tail = ssm.init_carry(spec, batch, dt, (spec.n_layers,))
+        return (StateKV(jnp.zeros(shape, dt), state),
+                StateKV(jnp.zeros(shape, dt), tail))
     if kv_quant == "int8":
         side = lambda: (jnp.zeros(shape, jnp.int8),  # noqa: E731
                         jnp.zeros(shape[:-1] + (spec.n_kv_heads,),

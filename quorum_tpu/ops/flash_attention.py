@@ -66,6 +66,11 @@ def tracing_program(label: str):
         _PROGRAM.reset(token)
 
 
+def traced_program() -> str:
+    """The label :func:`tracing_program` gave the program being traced."""
+    return _PROGRAM.get()
+
+
 def log_attention_path(kernel: str, refusal: str, *, interpret: bool,
                        q_shape: tuple, kv_shape: tuple, block: int,
                        window: int,

@@ -408,7 +408,8 @@ def create_app(
                   "kv_pages_allocated", "kv_pages_free",
                   "qos", "draining", "moe_experts_held",
                   "kv_cache_full_bytes", "kv_cache_window_bytes",
-                  "kv_cache_index_bytes", "prepare_seconds")
+                  "kv_cache_index_bytes", "kv_cache_state_bytes",
+                  "prepare_seconds")
         # One snapshot per distinct engine (_distinct_engines). Each
         # family's TYPE line appears exactly once, with all its samples
         # grouped — the Prometheus text format rejects repeated TYPE lines.

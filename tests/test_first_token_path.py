@@ -355,10 +355,12 @@ def test_profile_holds_engine_phases_and_returns_soon(tmp_path):
 
 # ---- (h) scopes name the parts of a step and add no operation -----------------
 
-# the scopes of a spec without a layer pattern (tests/test_patterned.py has
-# the patterned family's)
+# the scopes of a spec without a layer pattern and without a mixer
+# (tests/test_patterned.py has the patterned family's, tests/test_falcon_h1.py
+# the mixer's)
 SCOPES = tuple(p for p in hlo_names.PARTS
-               if p not in hlo_names.PATTERNED + hlo_names.LATENT)
+               if p not in hlo_names.PATTERNED + hlo_names.LATENT
+               + hlo_names.MIXER)
 
 
 def _lowered(program):
