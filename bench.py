@@ -70,11 +70,7 @@ Prints ONE JSON line (each phase child prints its own, device named on each):
    / "interference_p99_ratio" / "disagg_kv_handoff_bytes": the prefill-
    interference A/B (disagg=P+D, docs/tpu_backends.md) — streaming
    inter-token gap under concurrent admission churn, colocated vs
-   disaggregated device groups (QUORUM_TPU_BENCH_DISAGG=0 skips),
-   "spec_{rep,crep}_*": the speculative-decoding A/B (ISSUE 10) — tok/s,
-   acceptance rate, dispatches/request and ring-overlap counters with
-   spec_decode on vs off, on a repetitive and a CONSTRAINED repetitive
-   leg, tokens asserted identical (QUORUM_TPU_BENCH_SPEC=0 skips)}
+   disaggregated device groups (QUORUM_TPU_BENCH_DISAGG=0 skips)}
 
 The ``*_prefix_*`` keys measure automatic prefix caching where it matters —
 7B prefill dominates TTFT there: a long shared system preamble is sent
@@ -298,7 +294,7 @@ async def _engine_counters(client) -> dict:
     out: dict = {}
     for name in ("requests_total", "decode_chunks_total",
                  "overlapped_chunks_total", "overrun_tokens_total",
-                 "spec_turns_total", "decode_pipeline", "decode_loop",
+                 "decode_pipeline", "decode_loop",
                  "decode_loop_chunks_total", "drain_gap_seconds_total"):
         m = re.search(rf"^quorum_tpu_engine_{name}\{{[^}}]*\}} (\S+)$",
                       resp.text, re.M)
@@ -319,7 +315,6 @@ def _dispatch_report(prefix: str, counters: dict) -> dict:
     if not reqs:
         return {}
     chunks = counters.get("decode_chunks_total", 0)
-    chunks += counters.get("spec_turns_total", 0)
     synced = chunks - counters.get("overlapped_chunks_total", 0)
     out = {
         f"{prefix}_dispatches_per_req": round(chunks / reqs, 2),
@@ -604,32 +599,6 @@ def run_interference_phase(budget: int = 900) -> dict:
             "disagg_kv_handoffs", "disagg_kv_handoff_bytes",
             "colocated_device_seconds", "zero_drain_device_seconds",
             "disagg_device_seconds")
-    return {k: got[k] for k in keep if k in got}
-
-
-def run_spec_phase(budget: int = 900) -> dict:
-    """Speculative-decoding A/B (ISSUE 10, docs/tpu_backends.md):
-    acceptance rate / tok-s / dispatches-per-request with spec on vs off
-    on a repetitive leg and a constrained repetitive leg, tokens asserted
-    identical — scripts/hostpath_bench.py's measurement, run in a
-    SUBPROCESS (fresh engines, no program-cache bleed from the serving
-    phases). Gate with ``QUORUM_TPU_BENCH_SPEC=0``."""
-    if os.environ.get("QUORUM_TPU_BENCH_SPEC", "1") == "0":
-        return {}
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "scripts", "hostpath_bench.py")
-    got = _run_json_subprocess(
-        [sys.executable, script, "--tokens", "48", "--only-spec"],
-        "spec", budget, env)
-    keep = tuple(
-        f"spec_{leg}_{k}" for leg in ("rep", "crep")
-        for k in ("off_tok_s", "on_tok_s", "speedup", "tokens_match",
-                  "on_acceptance", "on_spec_turns", "on_spec_overlapped",
-                  "off_dispatches_per_request",
-                  "on_dispatches_per_request",
-                  "off_device_seconds", "on_device_seconds"))
     return {k: got[k] for k in keep if k in got}
 
 
@@ -979,9 +948,8 @@ def main() -> None:
             out.update(_ab_keys(got) if prefix == "ab" else got)
     if on_cpu:
         # CPU A/B counts from scripts/hostpath_bench.py: prefill
-        # interference (disagg=P+D), speculation, paged KV, QoS.
+        # interference (disagg=P+D), paged KV, QoS.
         out.update(run_interference_phase())
-        out.update(run_spec_phase())
         out.update(run_paged_phase())
         out.update(run_qos_phase())
     print(json.dumps(out), flush=True)

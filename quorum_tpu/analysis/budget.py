@@ -48,15 +48,13 @@ def classify_decode_key(key) -> str:
         if key[0] == "paged":
             # Paged-KV decode variants (kv_pages=1): the dense key with a
             # leading "paged" tag — table-gather attention can never share
-            # a compiled program with its rectangular twin. spec_model is
-            # rejected under kv_pages, so the paged families are exactly
-            # the non-spec_loop dense set.
+            # a compiled program with its rectangular twin.
             rest = key[1:]
             if rest and rest[0] == "loop":
                 fam = ("paged_loop_dfa" if len(rest) > 2 and rest[2] == "dfa"
                        else "paged_loop")
-            elif rest and rest[0] in ("dfa", "verify", "dfa_verify"):
-                fam = "paged_" + rest[0]
+            elif rest and rest[0] == "dfa":
+                fam = "paged_dfa"
             elif rest and all(isinstance(x, (int, bool)) for x in rest):
                 fam = "paged_plain"
             else:
@@ -67,9 +65,8 @@ def classify_decode_key(key) -> str:
         if key[0] == "loop":
             fam = "loop_dfa" if len(key) > 2 and key[2] == "dfa" else "loop"
             return _check_len("decode_cache", fam, key)
-        if key[0] in ("dfa", "verify", "dfa_verify", "spec_loop",
-                      "spec_loop_dfa"):
-            return _check_len("decode_cache", key[0], key)
+        if key[0] == "dfa":
+            return _check_len("decode_cache", "dfa", key)
         if all(isinstance(x, (int, bool)) for x in key):
             return _check_len("decode_cache", "plain", key)
     raise UnbudgetedProgramKey(

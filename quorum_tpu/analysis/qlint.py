@@ -100,7 +100,7 @@ MUTATORS = {
 # per-dispatch clamps halve; a non-pow2 value doubles the program-shape
 # family count — see compile_budget.json).
 SHAPE_KNOBS = {"decode_chunk", "prefill_chunk", "decode_loop",
-               "decode_pipeline", "spec_decode"}
+               "decode_pipeline"}
 
 # Names whose call RESULT is a host (numpy/python) value.
 HOST_FETCHERS = {"_host_fetch", "fetch_to_host", "_fetch_landing"}

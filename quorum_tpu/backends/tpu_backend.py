@@ -22,8 +22,7 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    streaming inter-token gaps: the decode ring keeps full
                    depth under any admission pressure. Structural; builds
                    its own per-group tp meshes, so tp=/dp=/sp= do not
-                   compose (neither do spec_model=/spec_ckpt= — the draft
-                   runtime is not group-placed); requires chunked prefill
+                   compose; requires chunked prefill
                    (prefill_chunk >= 16). See docs/tpu_backends.md for the
                    interaction matrix
   zero_drain=0|1   zero-drain continuous batching (default 0): the disagg
@@ -51,9 +50,8 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    Admission reserves a row's full span up front: pool
                    exhaustion sheds at admission (503 + Retry-After),
                    never mid-stream. Structural (part of the engine cache
-                   key); composes with kv_quant=int8, members=M, tp= and
-                   prompt-lookup spec_decode; rejected with sp>1 and
-                   draft-model speculation. See
+                   key); composes with kv_quant=int8, members=M and tp=;
+                   rejected with sp>1. See
                    docs/tpu_backends.md for the interaction matrix
   kv_page_size=    tokens per KV page (default: prefill_chunk, else
                    min(64, max_seq)); power of two dividing max_seq
@@ -118,36 +116,6 @@ URL grammar:  ``tpu://<model-id>?<spec overrides>&<engine options>``
                    programs). NOT structural: pure host policy, outside
                    the engine cache key; qos=0/qos=1 URLs share one
                    engine with opt-in winning
-  spec_decode=G    speculative decoding (default 0 = off): speculative
-                   dispatches verify up to G draft tokens PER ROW in one
-                   multi-token forward — accepted runs advance G+1 tokens
-                   for one dispatch's weight reads (decode is HBM-bound).
-                   Composes with everything (ISSUE 10): row-wise gating
-                   (a penalties/logprobs row rides the same dispatch at
-                   draft length 0; bias and response_format rows draft at
-                   full length — constrained rows through the dfa-verify
-                   variant's per-position draft-prefix masking), and
-                   verify turns are ring-resident (they enter the
-                   decode_pipeline ring instead of draining it). Greedy
-                   OR sampled — verification samples each position with
-                   the row's own RNG chain, so tokens match the plain
-                   path bit for bit
-  spec_model=<id>  draft-MODEL speculation: the named preset (random init,
-                   seeded by spec_seed=, target's vocab/window) proposes
-                   the G-token drafts instead of prompt lookup; its own
-                   slot KV cache tracks each request, and draft+verify
-                   run FUSED in one on-device scan (up to decode_loop=C
-                   turns per dispatch — the spec_loop program family), so
-                   consecutive dispatches pipeline with no host input.
-                   Speed-only knob — acceptance still requires equality
-                   with the token the target itself emits (sampled with
-                   the request's RNG chain; greedy = argmax). Implies
-                   spec_decode=4 when unset; random-init engines only
-                   (rejected with ckpt=)
-  spec_ckpt=<dir>  draft-MODEL speculation from a REAL small checkpoint
-                   (same tokenizer/vocab as the target; window raised to
-                   the target's). Works for both ckpt= and random-init
-                   targets; implies spec_decode=4 when unset
   quant=int8       weight-only int8 with per-channel scales (models/quant.py):
                    halves weight HBM bytes/token (decode is bandwidth-bound →
                    up to 2× decode tokens/s) and weight HBM capacity
@@ -233,7 +201,7 @@ import numpy as np
 from quorum_tpu import oai
 from quorum_tpu.backends.base import BackendError, CompletionResult, prepare_body
 from quorum_tpu.compile_cache import cache_enabled
-from quorum_tpu.config import BackendSpec
+from quorum_tpu.config import CHUNKS_ONLY, BackendSpec
 from quorum_tpu.engine.engine import (
     DEFAULT_DECODE_LOOP,
     DEFAULT_DECODE_PIPELINE,
@@ -285,13 +253,20 @@ def _parse_bytes_opt(name: str, raw: str) -> int:
     return out
 
 
-# URL options that once selected a decode program and no longer exist, with
-# what serves their purpose: a URL that still sets one must fail at config
-# time, not quietly serve one unsharded model.
+# URL options that once selected a decode program and no longer exist: the
+# value that still means "off", the PR that removed the option, and what to
+# do instead. A URL that still sets one must fail at config time, not
+# quietly serve something else.
+_CHUNKS_ONLY = f"drop the option: {CHUNKS_ONLY}"
 _REMOVED_OPTIONS = (
-    ("ensemble", "members=M backends under the concatenate or aggregate "
-                 "strategy (one stacked engine, M streams)"),
-    ("pp", "tp= to shard a served model (pp remains the training axis)"),
+    ("ensemble", "1", 32, "use members=M backends under the concatenate or "
+                          "aggregate strategy (one stacked engine, M streams)"),
+    ("pp", "1", 32, "use tp= to shard a served model (pp remains the "
+                    "training axis)"),
+    ("spec_decode", "0", 51, _CHUNKS_ONLY),
+    ("spec_model", "", 51, _CHUNKS_ONLY),
+    ("spec_ckpt", "", 51, _CHUNKS_ONLY),
+    ("spec_seed", "0", 51, _CHUNKS_ONLY),
 )
 
 
@@ -526,11 +501,11 @@ class TpuBackend:
         tp = int(opts.get("tp", 1))
         dp = int(opts.get("dp", 1))
         sp = int(opts.get("sp", 1))
-        for name, instead in _REMOVED_OPTIONS:
-            if opts.get(name, "1").strip() != "1":
+        for name, off, pr, instead in _REMOVED_OPTIONS:
+            if opts.get(name, off).strip() != off:
                 raise ValueError(
                     f"{name}={opts[name]}: the {name}= option was removed "
-                    f"in PR 32 — use {instead}")
+                    f"in PR {pr} — {instead}")
         zero_drain = _parse_bool_opt(
             "zero_drain", opts.get("zero_drain", "0"))
         if zero_drain and opts.get("disagg"):
@@ -585,12 +560,6 @@ class TpuBackend:
             decode_loop=int(opts.get("decode_loop", DEFAULT_DECODE_LOOP)),
             prefill_chunk=int(opts.get("prefill_chunk", DEFAULT_PREFILL_CHUNK)),
             max_pending=int(opts.get("queue", DEFAULT_MAX_PENDING)),
-            # spec_model implies speculation: default g=4 when the knob
-            # is absent. An EXPLICIT spec_decode=0 beside spec_model= is a
-            # contradiction the engine rejects (never silently rewritten).
-            spec_decode=int(opts.get(
-                "spec_decode", "4" if (opts.get("spec_model")
-                                       or opts.get("spec_ckpt")) else "0")),
             quant=opts.get("quant") or None,
             kv_quant=opts.get("kv_quant") or None,
             prefix_cache=_parse_bool_opt(
@@ -650,27 +619,6 @@ class TpuBackend:
                 "prefix_store_bytes=/prefix_store_chunk= have no effect "
                 "without prefix_store=host — a silently ignored sizing "
                 "knob hides a misconfiguration")
-        spec_model = opts.get("spec_model", "")
-        spec_ckpt = opts.get("spec_ckpt", "")
-        if spec_model and ckpt:
-            raise ValueError(
-                "spec_model= (a random-init draft) would draft for real "
-                "ckpt= weights with ~0 acceptance — pure overhead; point "
-                "spec_ckpt= at a small same-tokenizer checkpoint instead")
-        if spec_model and spec_ckpt:
-            raise ValueError("spec_model= and spec_ckpt= are mutually "
-                             "exclusive draft sources")
-        if spec_ckpt:
-            # Config-time validation (the members= check below follows the
-            # same pattern): a typo must fail fast, not after the multi-GB
-            # target checkpoint has already loaded into HBM.
-            import os as _os
-
-            if not _os.path.exists(_os.path.join(spec_ckpt, "config.json")):
-                raise ValueError(
-                    f"spec_ckpt={spec_ckpt!r} is not a checkpoint dir "
-                    "(no config.json)")
-            eng_kw["draft_ckpt"] = spec_ckpt
         if ckpt and members > 1:
             # Checked here (not just in the engine): ckpt engines are keyed
             # without members, so a stacked URL would otherwise construct a
@@ -706,19 +654,6 @@ class TpuBackend:
                 tokenizer_path = ckpt
         else:
             spec = resolve_spec(model_id, opts)
-            if spec_model:
-                # The draft runs the TARGET's vocab and window: drafted ids
-                # must be comparable (and embeddable) in the target, the
-                # draft cache must reach every target position, and the
-                # draft's attention span must match the target's sliding
-                # window (ADVICE r3: a preset window on the draft diverged
-                # from the documented contract and lowered acceptance).
-                eng_kw["draft_spec"] = resolve_spec(spec_model, {
-                    "max_seq": str(spec.max_seq),
-                    "vocab_size": str(spec.vocab_size),
-                    "sliding_window": str(spec.sliding_window),
-                })
-                eng_kw["draft_seed"] = int(opts.get("spec_seed", 0))
             engine = get_engine(
                 spec, mesh, seed=int(opts.get("seed", 0)), members=members,
                 **eng_kw

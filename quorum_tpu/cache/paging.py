@@ -233,34 +233,6 @@ def page_write_step(pkv: PagedKV, value, lengths, allow, max_seq: int):
     return PagedKV(pool, pkv.table)
 
 
-def page_write_multi(pkv: PagedKV, value, lengths, allow, max_seq: int):
-    """T-token (speculative-verify) write: ``value [S, K, T, hd]`` at
-    positions ``lengths[s] + t``. Out-of-window positions are dropped
-    EXACTLY (no dynamic_update_slice start-clamping to work around), which
-    subsumes the dense path's ``clamp_writes`` roll trick."""
-    vals, scales = _pool_parts(pkv.pool)
-    ps = vals.shape[-2]
-    mp = pkv.table.shape[-1]
-    drop = vals.shape[0]
-    t = value.shape[2]
-    pos = lengths[:, None] + jnp.arange(t)[None, :]       # [S, T]
-    phys = jnp.take_along_axis(pkv.table, jnp.clip(pos // ps, 0, mp - 1),
-                               axis=1)
-    phys = jnp.where(allow[:, None] & (pos < max_seq), phys, drop)
-    off = pos % ps
-
-    def scat(p, new):  # new [S, T, K(, hd)]
-        return p.at[phys, :, off].set(new, mode="drop")
-
-    if scales is not None:
-        q8, s = _quantize(value)
-        pool = (scat(vals, q8.transpose(0, 2, 1, 3)),
-                scat(scales, s.transpose(0, 2, 1).astype(scales.dtype)))
-    else:
-        pool = scat(vals, value.transpose(0, 2, 1, 3).astype(vals.dtype))
-    return PagedKV(pool, pkv.table)
-
-
 def page_write_seg(pkv: PagedKV, value, slot, offset, write_gate,
                    max_seq: int):
     """Chunked-prefill segment write: ``value [1, K, T, hd]`` at absolute

@@ -21,10 +21,7 @@ and the serving engine (``decode_chunk=``, ``decode_pipeline=``,
 ``disagg=P+D`` for disaggregated prefill/decode device groups with
 device→device KV handoff, ``zero_drain=0|1`` for zero-drain continuous
 batching on colocated engines (staged in-flight row injection — admission
-bursts never clamp the decode ring),
-``spec_decode=G``/``spec_model=``/``spec_ckpt=``
-for speculative decoding — ring-resident, row-wise gated, and composing
-with ``response_format`` grammars since ISSUE 10 — … the full grammar is
+bursts never clamp the decode ring) … the full grammar is
 the docstring of
 :mod:`quorum_tpu.backends.tpu_backend`); anything absent falls back to the
 named preset for ``<model-id>`` and the engine defaults.
@@ -62,6 +59,10 @@ DEFAULT_CONFIG: dict[str, Any] = {
 # Reference config.yaml:34 lists "Thought" alongside "thought"; matching is
 # case-insensitive so it is redundant, but kept for config-file parity.
 DEFAULT_THINKING_TAGS = list(_BASE_THINKING_TAGS) + ["Thought"]
+
+# What every refusal of a removed speculation option says (PR 51).
+CHUNKS_ONLY = ("the engine decodes by chunks only (speculative decoding "
+               "left the tree; the old code is at commit 804c4df)")
 
 DEFAULT_AGGREGATE_PROMPT = (
     "You have received the following responses regarding the user's query:\n\n"
@@ -170,15 +171,11 @@ class AggregateParams:
     # In-engine aggregation hop (docs/quorum.md): the synthesis request is
     # a first-class engine request — aggregator_priority pins its QoS
     # dispatch class on qos=1 engines (interactive/batch/background; ""
-    # sends no knob), stream_aggregate relays the aggregator's tokens to
+    # sends no knob) and stream_aggregate relays the aggregator's tokens to
     # the client AS THEY DECODE on the streaming path (instead of one
-    # buffered final chunk), and speculative_aggregation asserts at boot
-    # that the aggregator's engine runs prompt-lookup speculation
-    # (spec_decode > 0) — the aggregation prompt quotes the members' tails,
-    # which is exactly what prompt-lookup drafts the aggregate from.
+    # buffered final chunk).
     aggregator_priority: str = "interactive"
     stream_aggregate: bool = False
-    speculative_aggregation: bool = False
 
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "AggregateParams":
@@ -208,8 +205,10 @@ class AggregateParams:
                 "batch, background, or \"\" to send no priority knob)")
         p.aggregator_priority = prio
         p.stream_aggregate = bool(d.get("stream_aggregate", p.stream_aggregate))
-        p.speculative_aggregation = bool(
-            d.get("speculative_aggregation", p.speculative_aggregation))
+        if d.get("speculative_aggregation"):
+            raise ValueError(
+                "speculative_aggregation: true: the key was removed in PR 51 "
+                f"— drop it: {CHUNKS_ONLY}")
         return p
 
 

@@ -39,8 +39,8 @@ concurrent requests):
   - **Stacked fan-out members** (``members=M``): the N-model quorum's weight
     sets live stacked on ONE engine (block leaves layers-major
     ``[L, M, …]``, the rest ``[M, …]``: ``sharding.member_axes``, so that
-    no program re-lays them); every decode chunk, coalesced
-    admission (single-shot or chunked segment), and speculative-verify step
+    no program re-lays them); every decode chunk and coalesced
+    admission (single-shot or chunked segment)
     advances ALL members in a single member-vmapped program — N models'
     streams for one host turnaround per dispatch.
   - **Tiered prefix caching**: each slot's resident token prefix is reusable
@@ -59,18 +59,6 @@ concurrent requests):
     with zero extra host round-trips at any ``decode_pipeline`` depth.
     Unconstrained batches compile and run the exact unconstrained program
     variant (the logprobs-gating pattern).
-  - **Composing speculative decoding** (``spec_decode=G``): speculative
-    dispatches verify up to G draft tokens PER ROW in one multi-token
-    forward, with row-wise gating (penalties/logprobs rows ride at draft
-    length 0; bias and constrained rows draft at full length — the
-    dfa-verify variant masks each position with its draft-prefix DFA
-    state), ring-resident verify turns (they enter the decode_pipeline
-    ring with on-device EOS/budget finish instead of draining it;
-    pipelined prompt-lookup drafts come from an optimistic source-
-    continuation cursor), and — with ``spec_model=`` — a fused on-device
-    draft→verify scan (``spec_loop``) that needs no host input between
-    dispatches. A draft is accepted only when it equals the token the
-    model itself samples, so speculation changes speed, never content.
   - **Quantized representations**: ``quant=int8`` stores weights int8 with
     per-channel scales (native int8 MXU matmuls); ``kv_quant=int8`` stores
     the KV cache as (int8, per-token scale) pairs with native int8 decode
@@ -144,7 +132,7 @@ from quorum_tpu.cache.prefix_store import (
 from quorum_tpu.compile_cache import enable_persistent_compile_cache
 from quorum_tpu.devices import device_report
 from quorum_tpu.engine.prepare import Preparation
-from quorum_tpu.models.init import init_params, init_params_sharded
+from quorum_tpu.models.init import init_params_sharded
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.patterned import STATS as MOE_STATS
 from quorum_tpu.models.patterned import KindKV
@@ -153,7 +141,6 @@ from quorum_tpu.models.ssm import StateKV
 from quorum_tpu.models.transformer import (
     decode_chunk,
     decode_loop,
-    decode_multi,
     decode_step,
     init_cache,
     prefill,
@@ -360,9 +347,8 @@ def _member_vmap(fn, params, *args):
     weight tree mapped where ``member_axes`` says its member axis is (block
     leaves are held layers-major, so the layer scan inside ``fn`` reads
     them where they lie), every other argument at axis 0. The one call
-    every member-vmapped program family makes (decode chunk and speculative
-    verify through :func:`_stacked_rows_call`, member admit, member
-    segment)."""
+    every member-vmapped program family makes (decode chunk through
+    :func:`_stacked_rows_call`, member admit, member segment)."""
     in_axes = (member_axes(params),) + (0,) * len(args)
     return jax.vmap(fn, in_axes=in_axes)(params, *args)
 
@@ -372,9 +358,8 @@ def _stacked_rows_call(mem: int, n_s: int, fn, params, ck, cv, *rows):
 
     Each array in ``rows`` ([M·S, …]) folds to [M, S, …] for the vmap;
     ``fn(params_m, ck_m, cv_m, *rows_m)`` returns (logits, ck, cv) for one
-    member; the stacked logits unfold back to flat rows. The one home for
-    the fold/unfold convention shared by the stacked decode chunk and the
-    stacked speculative-verify step."""
+    member; the stacked logits unfold back to flat rows. The home of the
+    stacked decode chunk's fold/unfold convention."""
     folded = tuple(r.reshape((mem, n_s) + r.shape[1:]) for r in rows)
     logits, ck, cv = _member_vmap(fn, params, ck, cv, *folded)
     return logits.reshape((mem * n_s,) + logits.shape[2:]), ck, cv
@@ -405,7 +390,7 @@ def _program(memo: str, key, kept=False):
     compiled program across starts, under the builder's name and the key;
     a predicate of the key where only some of a builder's variants are.
     The programs every request can reach are kept; the variants behind an
-    option (constrained, speculative, megachunked, deduplicated, the prefix
+    option (constrained, megachunked, deduplicated, the prefix
     store's and the handoff's) are built on demand in every process."""
     def deco(make):
         @functools.wraps(make)
@@ -446,9 +431,9 @@ class _Request:
     __slots__ = (
         "prompt_ids", "budget", "temperature", "top_p", "top_k", "seed",
         "eos_id", "cancel", "chunk_hint", "out", "emitted",
-        "pp", "fp", "bias_row", "want_lp", "lp", "hist", "ngram", "member",
+        "pp", "fp", "bias_row", "want_lp", "lp", "hist", "member",
         "trace", "t_submit", "tspans", "deadline", "expired", "grammar",
-        "g_start", "dfa_host", "n_inflight", "spec_state", "rid",
+        "g_start", "rid",
         "priority", "tenant", "sched_class", "n_preempts", "replay",
         "preempt_flag", "t_admit", "parked", "parent", "path", "t_first",
         "t_delta",
@@ -487,24 +472,6 @@ class _Request:
         # engine's device arena — assigned at admission by _ensure_grammar.
         self.grammar = grammar
         self.g_start = 0
-        # Host shadow of the row's LOCAL DFA state, advanced in _emit over
-        # every delivered token. Only a draft-quality input (the grammar-
-        # aware draft filter truncates a prompt-lookup draft at its first
-        # dead token) — correctness rides the on-device mask, which never
-        # trusts the host's view.
-        self.dfa_host = grammar.start if grammar is not None else 0
-        # Dispatches currently in flight that cover this request (decode
-        # chunks AND speculative turns) — a fresh prompt-lookup draft may
-        # only be formed when this is 0, because the host's `hist` lags the
-        # device by every in-flight dispatch's emissions.
-        self.n_inflight = 0
-        # Pipelined-draft cursor (ring-resident speculation): while verify
-        # turns are in flight, the next draft is formed from the SOURCE
-        # continuation the last fresh draft came from, optimistically
-        # assuming full acceptance — (src index, last-two optimistic
-        # tokens, optimistic local DFA state). None = no continuation; any
-        # rejection at reap resets it.
-        self.spec_state: "tuple | None" = None
         # QoS scheduler state (quorum_tpu/sched/, docs/scheduling.md): the
         # explicit priority knob + tenant id, the resolved dispatch class
         # (assigned in _submit), how many times this request has been
@@ -557,15 +524,10 @@ class _Request:
                      if self.trace is not None else None)
         self.parent = self.path["span"] if self.path is not None else None
         self.tspans: dict = {}  # span kind -> (last span, turn count)
-        # Prompt-lookup drafting state: the running token history and an
-        # incrementally-maintained 2-gram → position index ("lagged": a pair
-        # is recorded only once a token FOLLOWS it, so the index never
-        # contains the trailing pair and lookups are O(1) per draft).
+        # The running token history (prompt + delivered tokens): what the
+        # slot's cache rows hold at release (_release_slot), and what a
+        # preemption's replay expects (begin_replay).
         self.hist: list[int] = list(prompt_ids)
-        self.ngram: dict = {
-            (prompt_ids[n - 2], prompt_ids[n - 1]): n - 1
-            for n in range(2, len(prompt_ids))
-        }
 
     def span(self, name: str, t0: float, t1: float, **meta):
         """Record an engine span of this request on its trace (None when
@@ -606,52 +568,21 @@ class _Request:
         the ORDINARY admission machinery (prefix reuse, chunked segments,
         staged zero-drain injection — no preemption-specific device
         program), and because the token sequence is a pure function of
-        (prompt, seed, sampler) — one RNG split per emitted token on every
-        path, including speculative verify — the resumed row regenerates
-        the delivered tokens bit for bit; ``_emit``'s replay guard swallows
-        them (byte-comparing each against the expectation) and the stream
-        continues where it left off. Returns the parked token count."""
+        (prompt, seed, sampler) — one RNG split per emitted token — the
+        resumed row regenerates the delivered tokens bit for bit;
+        ``_emit``'s replay guard swallows them (byte-comparing each against
+        the expectation) and the stream continues where it left off.
+        Returns the parked token count."""
         generated = self.hist[len(self.prompt_ids):]
         # A second preemption mid-replay must expect the FULL delivered
         # sequence again: what was already re-swallowed plus the remainder.
         already = self.replay or []
         self.replay = generated + already
         self.hist = list(self.prompt_ids)
-        self.ngram = {
-            (self.prompt_ids[n - 2], self.prompt_ids[n - 1]): n - 1
-            for n in range(2, len(self.prompt_ids))
-        }
-        self.dfa_host = self.grammar.start if self.grammar is not None else 0
-        self.spec_state = None
         self.emitted = 0
-        self.n_inflight = 0
         self.n_preempts += 1
         self.t_admit = None
         return len(generated)
-
-    @property
-    def spec_draft_ok(self) -> bool:
-        """May carry a nonzero draft length in a speculative dispatch.
-        SAMPLED requests qualify — verification samples every position with
-        the row's own RNG chain (one key split per emitted token, exactly
-        the decode path's discipline), so the emitted tokens equal the
-        non-speculative path's bit for bit; a draft token is accepted iff
-        it equals the token the model itself SAMPLES there. logit_bias
-        qualifies too (a static per-row additive term the verify program
-        applies at every position), and CONSTRAINED requests qualify: the
-        draft tokens are known before dispatch, so the dfa-verify variant
-        advances the token-DFA over the draft prefix up front and masks
-        each position with its draft-prefix state — the accepted-prefix
-        state wherever a position can actually be emitted — without
-        serializing the g+1 samples.
-
-        Rows that return False still RIDE speculative dispatches (draft
-        length 0: a sentinel draft that never matches, so they emit exactly
-        the model's own next token): presence/frequency penalties depend on
-        the running generated-token counts position by position, and
-        logprobs requests emit one lp record per token — both exact at one
-        token per dispatch, wrong beyond it."""
-        return self.pp == 0.0 and self.fp == 0.0 and self.want_lp < 0
 
 
 class _InflightChunk:
@@ -664,12 +595,11 @@ class _InflightChunk:
     at dispatch (0 = the blocking chunk), recorded on the decode span."""
 
     __slots__ = ("payload", "active", "n_steps", "t0", "history", "depth",
-                 "constrained", "n_chunks", "spec_turn", "drafted",
-                 "stacked", "family", "seq", "prog", "moe")
+                 "constrained", "n_chunks", "family", "seq", "prog", "moe")
 
     def __init__(self, payload, active, n_steps, t0, history, depth,
-                 constrained=False, n_chunks=1, spec_turn=False, drafted=0,
-                 stacked=None, family="", seq=0, prog=None, moe=None):
+                 constrained=False, n_chunks=1, family="", seq=0, prog=None,
+                 moe=None):
         self.payload = payload
         # A patterned spec's expert counters as they stood after this
         # dispatch (_moe_snapshot): a device future the reap fetches.
@@ -688,16 +618,6 @@ class _InflightChunk:
         # fused variant whose token/valid/aux arrays carry a leading
         # per-chunk axis the reap drains segment by segment.
         self.n_chunks = n_chunks
-        # Speculative dispatch (a verify turn, or n_chunks fused draft→
-        # verify turns): the reap counts spec turns/draft/accepted tokens
-        # and records spec-verify spans instead of decode spans.
-        # ``drafted`` = real (non-sentinel) draft tokens proposed per turn.
-        self.spec_turn = spec_turn
-        self.drafted = drafted
-        # Whether the payload ALREADY carries the leading per-segment axis
-        # (the fused draft→verify scan emits it even at one turn; plain
-        # chunk/verify payloads gain it in the reap's normalization).
-        self.stacked = n_chunks > 1 if stacked is None else stacked
         # Device-time attribution: the program-key family this dispatch
         # compiled under (compile_budget.json), its flight-recorder
         # sequence number, and its entry in the device ledger
@@ -793,256 +713,6 @@ class _SegmentRoom:
         return True
 
 
-class _DraftRuntime:
-    """Draft-model state for speculative decoding (``spec_model=…``).
-
-    A small model proposes each verify turn's g-token draft instead of the
-    prompt-lookup 2-gram heuristic — a few milliseconds of draft-model
-    dispatches buy model-quality guesses, so acceptance (and therefore
-    tokens per target dispatch) is high wherever the draft model predicts
-    the target well. Correctness NEVER depends on the draft: verification
-    accepts a token iff it equals the token the target model itself emits
-    there — sampled with the request's own RNG chain, argmax for greedy
-    rows (``InferenceEngine._verify_fn``) — so any draft state — stale,
-    random, or mid-resync — affects only speed. All calls happen on the engine's
-    scheduler thread (no locking).
-
-    State: the draft model's own slot KV cache plus, per target slot, how
-    many of the request's tokens have been fed (``synced``). The serving
-    path is the FUSED draft→verify scan (``engine._spec_loop_fn``): the
-    draft cache rides the fused program's donated carry, the per-turn
-    ingest/extend happens on device, and the only host work left here is
-    :meth:`resync` — bringing a reassigned slot's draft cache up to the
-    request's history before its first fused dispatch. :meth:`draft_all`
-    (the original host-paced reference: advance in ≤``BITE``-token bites,
-    then g−1 greedy ``decode_step`` extensions) is kept as the
-    correctness oracle the draft-runtime unit tests exercise directly.
-    Drafted/pad positions sit beyond ``synced`` and are overwritten by the
-    next ingest — no rollback is ever needed.
-    """
-
-    BITE = 16  # max tokens per advance program (T buckets: powers of two ≤ 16)
-
-    def __init__(self, spec: ModelSpec, target_spec: ModelSpec, rows: int,
-                 seed: int = 0, params=None, sharded: bool = False):
-        # Whether the owning engine's programs are partitioned over devices
-        # (the fused spec loop runs the draft's decode steps inside one):
-        # decode_step's ``sharded``.
-        self.sharded = sharded
-        if spec.vocab_size != target_spec.vocab_size:
-            raise ValueError(
-                f"draft model vocab {spec.vocab_size} != target vocab "
-                f"{target_spec.vocab_size}: drafted ids would be meaningless "
-                "(and can index out of the target embedding)")
-        if spec.max_seq < target_spec.max_seq:
-            raise ValueError(
-                f"draft model max_seq {spec.max_seq} < target max_seq "
-                f"{target_spec.max_seq}: the draft cache must hold every "
-                "position the target can reach")
-        self.spec = spec.validate()
-        # Explicit device placement for provided (checkpoint) weights: the
-        # draft programs dispatch inside the engine's decode transfer
-        # guard, where a lazy numpy→device transfer on first use would be
-        # a guard violation (and a per-call risk).
-        self.params = (jax.device_put(params) if params is not None
-                       else init_params(spec, seed))
-        self.rows = rows
-        self._ck, self._cv = init_cache(spec, rows)
-        self.synced = [0] * rows
-        self.reqs: list = [None] * rows
-        self._advance_cache: dict = {}
-        self._step_cache: dict = {}
-        # Fused-loop carry (engine._spec_loop_fn): the last verify turn's
-        # emitted chain per row ([rows, g+1] tokens + counts). The next
-        # turn re-ingests it through a decode_multi of the SAME width as
-        # the verify forward, so accepted positions' draft-cache K/V
-        # reassociates like the target's — for an oracle draft the chains
-        # then agree everywhere but true near-ties. Allocated at first
-        # fused dispatch (width is g+1).
-        self._chain = None
-        self._chain_n = None
-        self._chain_w = 0  # host mirror of the chain width (g + 1)
-
-    def _advance_fn(self, t: int, history: int):
-        fn = self._advance_cache.get((t, history))
-        if fn is None:
-            def run(params, tokens, lengths, wmask, ck, cv):
-                logits, ck, cv = decode_multi(
-                    params, self.spec, tokens, lengths, ck, cv,
-                    write_mask=wmask, history=history)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
-
-            fn = jax.jit(run, donate_argnums=(4, 5))
-            self._advance_cache[(t, history)] = fn
-        return fn
-
-    def _extend_fn(self, n: int, history: int):
-        """One dispatch drafting ``n`` greedy tokens: a lax.scan carries
-        the token on device (no per-step host round trip — the engine's
-        scheduler path avoids host turnarounds everywhere else too)."""
-        fn = self._step_cache.get((n, history))
-        if fn is None:
-            def run(params, token, lengths, wmask, ck, cv):
-                def body(carry, _):
-                    tok, lens, ck, cv = carry
-                    logits, ck, cv = decode_step(
-                        params, self.spec, tok, lens, ck, cv,
-                        write_mask=wmask, history=history,
-                        sharded=self.sharded)
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return (nxt, lens + 1, ck, cv), nxt
-
-                (_, _, ck, cv), toks = lax.scan(
-                    body, (token, lengths, ck, cv), None, length=n)
-                return toks, ck, cv  # toks [n, rows]
-
-            fn = jax.jit(run, donate_argnums=(4, 5))
-            self._step_cache[(n, history)] = fn
-        return fn
-
-    def draft_all(self, active, g: int) -> dict:
-        """g-token draft per active slot: sync the unsynced history, then
-        extend greedily. Returns {slot: [t0..t_{g-1}]}."""
-        for i, r in active:
-            if self.reqs[i] is not r:   # slot reassigned → full resync
-                self.reqs[i] = r
-                self.synced[i] = 0
-        max_hist = max(len(r.hist) for _, r in active)
-        history = prefill_bucket(
-            min(max_hist + g + 1, self.spec.max_seq), self.spec.max_seq)
-        # Feed hist[pos..] (≥1 token: refeed hist[-1] when already synced —
-        # an identical rewrite, done only to recover its next-token logits).
-        rem = {i: max(1, len(r.hist) - self.synced[i]) for i, r in active}
-        pos = {i: len(r.hist) - rem[i] for i, r in active}
-        first: dict[int, int] = {}
-        while any(v > 0 for v in rem.values()):
-            t_bite = min(self.BITE, max(rem.values()))
-            # Pad writes land at pos..pos+t_bite-1 for EVERY masked row;
-            # near the window cap that span must not run past max_seq
-            # (dynamic_update_slice would clamp the start BACKWARDS and
-            # silently corrupt already-synced positions). len(hist) ≤
-            # max_seq always, so the clamp keeps t_bite ≥ 1.
-            t_bite = min(t_bite, self.spec.max_seq
-                         - max(pos[i] for i, _ in active if rem[i] > 0))
-            t_bite = 1 << (t_bite - 1).bit_length()  # pow-2 program reuse
-            if t_bite > self.spec.max_seq - max(
-                    pos[i] for i, _ in active if rem[i] > 0):
-                t_bite >>= 1  # pow-2 rounding may not exceed the cap
-            tokens = np.zeros((self.rows, t_bite), np.int32)
-            lengths = np.zeros((self.rows,), np.int32)
-            wmask = np.zeros((self.rows,), bool)
-            for i, r in active:
-                if rem[i] <= 0:
-                    continue
-                k = min(rem[i], t_bite)
-                seg = r.hist[pos[i]: pos[i] + k]
-                tokens[i, :k] = seg
-                tokens[i, k:] = seg[-1]
-                lengths[i] = pos[i]
-                wmask[i] = True
-            # Explicit uploads: draft turns run inside the engine's decode
-            # transfer guard (the verify step they feed is decode-path).
-            toks, self._ck, self._cv = self._advance_fn(t_bite, history)(
-                self.params, jax.device_put(tokens),
-                jax.device_put(lengths), jax.device_put(wmask),
-                self._ck, self._cv)
-            toks = np.asarray(_host_fetch(toks))
-            for i, r in active:
-                if rem[i] <= 0:
-                    continue
-                k = min(rem[i], t_bite)
-                pos[i] += k
-                rem[i] -= k
-                if rem[i] == 0:
-                    first[i] = int(toks[i, k - 1])
-                    self.synced[i] = len(r.hist)
-        drafts = {i: [first[i]] for i, _ in active}
-        if g > 1:
-            token = np.zeros((self.rows,), np.int32)
-            lengths = np.zeros((self.rows,), np.int32)
-            wmask = np.zeros((self.rows,), bool)
-            for i, r in active:
-                token[i] = first[i]
-                lengths[i] = len(r.hist)
-                wmask[i] = True
-            toks, self._ck, self._cv = self._extend_fn(g - 1, history)(
-                self.params, jax.device_put(token),
-                jax.device_put(lengths), jax.device_put(wmask),
-                self._ck, self._cv)
-            toks = np.asarray(_host_fetch(toks))  # [g-1, rows]
-            for i, _ in active:
-                drafts[i].extend(int(t) for t in toks[:, i])
-        return drafts
-
-    def ensure_chain(self, g: int, rep) -> None:
-        """Allocate (or re-shape) the fused-loop chain carry. A width
-        change (a shared engine's spec_decode was raised) resets every
-        row's assignment so resync rebuilds a coherent chain — draft
-        quality only, never correctness."""
-        if self._chain_w == g + 1:
-            return
-        self._chain = jax.device_put(
-            np.zeros((self.rows, g + 1), np.int32), rep)
-        self._chain_n = jax.device_put(np.ones((self.rows,), np.int32), rep)
-        self._chain_w = g + 1
-        self.reqs = [None] * self.rows
-
-    def _chain_set_fn(self):
-        fn = self._advance_cache.get("chain_set")
-        if fn is None:
-            fn = jax.jit(
-                lambda chain, n, row, tok: (chain.at[row, 0].set(tok),
-                                            n.at[row].set(1)),
-                donate_argnums=(0, 1))
-            self._advance_cache["chain_set"] = fn
-        return fn
-
-    def resync(self, i: int, r, g: int) -> None:
-        """Bring draft row ``i`` to the fused-loop invariant for a newly
-        (re)assigned request: the draft cache holds K/V for ``hist[:-1]``
-        and the chain carry holds the one token the target will anchor on
-        (``hist[-1]`` — the fused ingest then (re)writes it at position
-        ``lengths`` = ``len(hist) - 1``), so draft and target stay
-        position-aligned with no further host work. Runs on the scheduler
-        thread; its dispatches chain behind any in-flight fused program
-        still writing this row (the later write wins, and pad writes land
-        beyond the true length — the standard overwrite discipline)."""
-        self.reqs[i] = r
-        self.synced[i] = len(r.hist) - 1
-        self._chain, self._chain_n = self._chain_set_fn()(
-            self._chain, self._chain_n,
-            jax.device_put(np.int32(i)), jax.device_put(np.int32(r.hist[-1])))
-        n = len(r.hist) - 1
-        if n <= 0:
-            return
-        history = prefill_bucket(
-            min(len(r.hist) + g + 1, self.spec.max_seq), self.spec.max_seq)
-        pos = 0
-        while pos < n:
-            t_bite = min(self.BITE, n - pos)
-            # Same near-cap clamp as draft_all: the pad-write span must not
-            # run past max_seq (dynamic_update_slice would clamp the start
-            # backwards and corrupt already-synced positions).
-            t_bite = min(t_bite, self.spec.max_seq - pos)
-            t_bite = 1 << (t_bite - 1).bit_length()
-            if t_bite > self.spec.max_seq - pos:
-                t_bite >>= 1
-            k = min(n - pos, t_bite)
-            seg = r.hist[pos: pos + k]
-            tokens = np.zeros((self.rows, t_bite), np.int32)
-            tokens[i, :k] = seg
-            tokens[i, k:] = seg[-1]
-            lengths = np.zeros((self.rows,), np.int32)
-            lengths[i] = pos
-            wmask = np.zeros((self.rows,), bool)
-            wmask[i] = True
-            _, self._ck, self._cv = self._advance_fn(t_bite, history)(
-                self.params, jax.device_put(tokens),
-                jax.device_put(lengths), jax.device_put(wmask),
-                self._ck, self._cv)
-            pos += k
-
-
 # Lock-discipline contract for the engine's cross-thread state, verified by
 # static analysis (`make qlint`, quorum_tpu/analysis/qlint.py — the
 # "guarded" rule family; docs/static_analysis.md). This map is the SOURCE OF
@@ -1087,11 +757,9 @@ _GUARDED_BY = {
     "_draining_park": {"lock": "_cond"},
     "n_drain_parked": {"lock": "_cond"},
     # single-owner: the decode scheduler thread's dispatch ring (drained
-    # by _fail_all on that same thread's exception path; speculative
-    # dispatches append through _try_spec_dispatch on the same thread)
-    "_inflight": {"owner": ["_fill_inflight", "_try_spec_dispatch",
-                            "_reap_oldest", "_drain_inflight",
-                            "_fail_all"]},
+    # by _fail_all on that same thread's exception path)
+    "_inflight": {"owner": ["_fill_inflight", "_reap_oldest",
+                            "_drain_inflight", "_fail_all"]},
     # single-owner: the admission-clamp stall window (scheduler thread's
     # ring-fill turn — quorum_tpu_admission_stall_seconds_total)
     "_clamp_t0": {"owner": ["_note_admission_clamp"]},
@@ -1148,7 +816,6 @@ class InferenceEngine:
         n_slots: int = DEFAULT_SLOTS,
         prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
         max_pending: int = DEFAULT_MAX_PENDING,
-        spec_decode: int = 0,
         quant: str | None = None,
         prefix_cache: bool = True,
         prefix_store: str | None = None,
@@ -1156,9 +823,6 @@ class InferenceEngine:
         prefix_store_chunk: int = 0,
         members: int = 1,
         kv_quant: str | None = None,
-        draft_spec: ModelSpec | None = None,
-        draft_seed: int = 0,
-        draft_params=None,
         sp_impl: str = "ring",
         prefill_mesh: Mesh | None = None,
         transfer_guard: str | None = None,
@@ -1187,11 +851,6 @@ class InferenceEngine:
                 raise ValueError(
                     f"disagg device groups must be disjoint; {len(overlap)} "
                     "device(s) appear in both the prefill and decode mesh")
-            if draft_spec is not None:
-                raise ValueError(
-                    "draft-model speculation (spec_model=/spec_ckpt=) does "
-                    "not compose with disagg: the draft runtime is not "
-                    "group-placed (prompt-lookup spec_decode composes)")
         if quant not in (None, "", "int8"):
             raise ValueError(f"unsupported quant mode {quant!r} (int8 or none)")
         self.quant = quant or None
@@ -1232,7 +891,7 @@ class InferenceEngine:
         # XLA compile at 7B scale.
         self.decode_loop = 1 << (int(decode_loop).bit_length() - 1)
         # Runtime sync sentinel (docs/static_analysis.md): when set, the
-        # decode loop (_run_chunk — dispatch, reap, spec-verify) runs under
+        # decode loop (_run_chunk — dispatch, reap) runs under
         # jax.transfer_guard(mode), so an implicit host<->device transfer
         # on the token critical path RAISES instead of silently stalling
         # the dispatch ring. The designated explicit points (_host_fetch's
@@ -1273,12 +932,6 @@ class InferenceEngine:
         # whole fan-out's admissions in ONE queue, so M members must carry
         # the aggregate capacity M separate engines would have had.
         self.max_pending = max(1, max_pending) * max(1, int(members))
-        # Speculative decoding draft length (0 = off): verify dispatches
-        # score up to spec_decode draft tokens per row in one multi-token
-        # forward — ROW-WISE gated (penalties/logprobs rows ride along at
-        # one token per dispatch) and ring-resident (verify turns enter the
-        # decode_pipeline ring instead of draining it).
-        self.spec_decode = max(0, min(spec_decode, 16))
         # Chunked prefill needs segment offsets that never cross max_seq
         # (dynamic_update_slice clamps out-of-range starts, which would
         # silently corrupt cache history): round the chunk down to a
@@ -1464,13 +1117,6 @@ class InferenceEngine:
         self.kv_pool_pages = 0
         self._page_alloc: PageAllocator | None = None
         if self.kv_pages:
-            if draft_spec is not None:
-                raise ValueError(
-                    "kv_pages=1 does not compose with a draft model "
-                    "(spec_model=/spec_ckpt=): the draft runtime keeps its "
-                    "own dense cache and the fused draft→verify scan would "
-                    "mix layouts in one program — prompt-lookup "
-                    "spec_decode composes")
             if self._use_sp:
                 raise ValueError(
                     "kv_pages=1 does not compose with sp>1: ring attention "
@@ -1540,15 +1186,9 @@ class InferenceEngine:
                 ("disagg=P+D / zero_drain=1 (kv_transfer)", self.staged),
                 ("kv_quant=int8", bool(self.kv_quant)),
                 ("quant=int8", bool(self.quant)),
-                ("spec_model= / spec_ckpt= (draft-model speculation)",
-                 draft_spec is not None),
                 ("sp>1 (ring or ulysses admission)", self._use_sp),
                 ("tp>1", mesh_shape.get(AXIS_TP, 1) > 1),
                 ("members>1 (member stacking)", self.members > 1),
-                (f"spec_decode={self.spec_decode} with a ring of "
-                 f"{self.spec.ring} under window + spec_decode + 1",
-                 self.spec_decode > 0 and self.spec.ring
-                 < self.spec.sliding_window + self.spec_decode + 1),
             ]
             for what, asked in refused:
                 if asked:
@@ -1578,11 +1218,6 @@ class InferenceEngine:
                  "the quantized cache is the two rectangles only, beside"),
                 ("quant=int8", bool(self.quant),
                  "the weight quantizer does not know the projections of"),
-                ("spec_model= / spec_ckpt= (draft-model speculation)",
-                 draft_spec is not None,
-                 "a rejected draft cannot be taken back out of"),
-                (f"spec_decode={self.spec_decode}", self.spec_decode > 0,
-                 "a rejected draft cannot be taken back out of"),
                 ("sp>1 (ring or ulysses admission)", self._use_sp,
                  "a sequence split over devices does not hand on"),
                 ("tp>1", mesh_shape.get(AXIS_TP, 1) > 1,
@@ -1856,16 +1491,7 @@ class InferenceEngine:
         # first; each entry is (payload arrays, active rows at dispatch,
         # n_steps, dispatch stamp, history bucket, depth at dispatch).
         self._inflight: deque = deque()
-        self.n_spec_turns = 0      # speculative verify turns executed
-        self.n_spec_accepted = 0   # draft tokens accepted across them
-        self.n_spec_drafted = 0    # real draft tokens proposed across them
-        # Speculative dispatches issued at ring depth > 0 — the ring-
-        # resident-verify acceptance counter: verify turns that would have
-        # DRAINED the pipeline before this PR now overlap it.
-        self.n_spec_overlapped = 0
-        # Decode-path dispatches (batched chunks AND speculative turns —
-        # ring-resident verify made both first-class ring entries, so this
-        # is dispatches/request's denominator across spec on/off arms).
+        # Decode-path dispatches reaped (dispatches/request's denominator).
         self.n_decode_chunks = 0
         # How far the dense decode step's read of the cache follows the
         # rows: tiles of ops/flash_decode.DECODE_TILE positions a decode
@@ -1922,33 +1548,9 @@ class InferenceEngine:
         self.n_constrained = 0
         self.n_constrain_masked = 0
         # Occupancy accounting: active rows summed over every decode-path
-        # DISPATCH (chunks and speculative turns alike — decode_chunks_total
-        # counts both since ring-resident verify) — average batch occupancy
-        # is decode_busy_rows_total / decode_chunks_total.
+        # DISPATCH — average batch occupancy is
+        # decode_busy_rows_total / decode_chunks_total.
         self.n_decode_rows = 0
-        # Draft-MODEL speculative decoding (spec_model=…): a second, small
-        # model proposes each verify turn's draft instead of prompt lookup
-        # — fused with the verify into one on-device draft→verify scan
-        # (_spec_loop_fn), so consecutive dispatches pipeline with no host
-        # input. Subject to the same row-wise spec_draft_ok gating;
-        # excluded for stacked engines — the draft runtime is not
-        # member-vmapped.
-        if draft_spec is not None:
-            if self.members > 1:
-                raise ValueError(
-                    "draft-model decoding (spec_model=/spec_ckpt=) does "
-                    "not compose with members engines")
-            if self.spec_decode <= 0:
-                raise ValueError(
-                    "a draft model requires spec_decode > 0 (the backend "
-                    "defaults spec_decode=4 when spec_model=/spec_ckpt= is "
-                    "set and spec_decode= is absent; an explicit 0 means "
-                    "off — drop the draft knob instead)")
-            self._draft_rt = _DraftRuntime(
-                draft_spec, self.spec, self._rows, seed=draft_seed,
-                params=draft_params, sharded=self._sharded)
-        else:
-            self._draft_rt = None
         self._stop = False
         self._thread = threading.Thread(
             target=self._scheduler, name=f"engine-{id(self):x}", daemon=True
@@ -2213,7 +1815,7 @@ class InferenceEngine:
     # ---- paged KV bookkeeping (kv_pages=1) --------------------------------
     #
     # Host half of the paged layout: admission reserves a row's FULL page
-    # span up front (prompt + budget + spec-decode overshoot), so the
+    # span up front (prompt + budget + one position), so the
     # device table for a live row never changes mid-decode and pool
     # exhaustion sheds at admission instead of OOMing a running stream.
     # Allocator / mirror mutations run under _cond; the device upload and
@@ -2230,11 +1832,8 @@ class InferenceEngine:
 
     def _paged_need(self, n_prompt: int, budget: int) -> int:
         """Pages covering every position a request could ever write:
-        prompt, generation budget, plus the speculative-verify overshoot
-        (a verify turn writes up to spec_decode+1 positions past the
-        accepted length before the rollback masks them)."""
-        need_t = min(self.spec.max_seq,
-                     n_prompt + budget + self.spec_decode + 1)
+        prompt and generation budget, and one position of slack."""
+        need_t = min(self.spec.max_seq, n_prompt + budget + 1)
         return self._page_alloc.pages_for(need_t)
 
     def _paged_fits(self, row: int, req: "_Request") -> bool:
@@ -3820,432 +3419,6 @@ class InferenceEngine:
                                  "keys_s", "counts_s", "live_s", "budget_s"),
             )
 
-    def _verify_core(self, g: int, history: int, want_lp: bool,
-                     constrained: bool):
-        """The speculative-verification turn body shared by the standalone
-        verify programs (:meth:`_verify_fn`) and the fused draft→verify
-        scan (:meth:`_spec_loop_fn`): every position 0..g is SAMPLED with
-        the row's own RNG chain exactly as the normal decode path would
-        sample it (one key split per position; greedy rows reduce to
-        argmax), and the longest draft prefix matching that sampled chain
-        is accepted — 1 + n_accept tokens for ONE dispatch's worth of
-        weight reads (decode is bandwidth-bound, so the g extra positions
-        are nearly free).
-
-        Ring-ready (the dispatch never drains the pipeline), so finish
-        accounting is ON DEVICE like a decode chunk's: the emitted count
-        truncates at the chain's first EOS and at the remaining budget,
-        liveness follows ``(active) & live & (budget > 0)``, and the
-        payload is shaped exactly like a chunk payload with n_steps = g+1
-        (tokens [S, g+1] + per-row n_valid, plus the want_lp logprob
-        triple and the constrained masked-entry vector) — one reap path
-        serves both.
-
-        Row-wise draft lengths ride in the DRAFT CONTENT: a row whose
-        draft is the −1 sentinel can never match the sampled chain, so it
-        emits exactly the model's own next token — penalties/logprobs rows
-        co-batch with accepting rows at no gate. The sampler adjustment
-        applies the bias/penalty terms with the TURN-START counts at every
-        position: exact, because rows that may emit more than one token
-        have zero penalty terms and a static bias, and penalty rows emit
-        only position 0 (whose counts are the turn-start counts).
-
-        ``constrained`` threads the grammar arena: the per-position DFA
-        states are advanced over the DRAFT up front (position j's state is
-        the draft-prefix state — the accepted-prefix state wherever j can
-        actually be emitted, including the bonus token at the rejection
-        point), each position's logits are masked by its state's
-        allow-set, and the carried per-row state advances over the
-        actually-emitted chain.
-
-        Acceptance is sound regardless of where drafts come from: draft i
-        is accepted only if it EQUALS the token the model itself samples at
-        that position, so the output sequence — and the carried RNG state —
-        is identical to the non-speculative path's. (The multi-token
-        forward may reassociate float ops differently from the single-token
-        program; a near-tie flip under a sampling threshold is the same
-        caveat as any program-shape change.)"""
-        spec = self.spec
-        n_rows = self._rows  # flat rows (member-major on stacked engines)
-        n_s = self.n_slots
-        mem = self.members
-        vocab = spec.vocab_size
-        n_top = min(TOP_LOGPROBS, vocab)
-
-        def core(params, active, eos_s, draft, ck, cv, token_s, lengths_s,
-                 keys_s, temp_s, topp_s, topk_s, pp_s, fp_s, counts_s,
-                 bias_s, live_s, budget_s,
-                 trans_t=None, accept_t=None, dfa_s=None):
-            live = (active > 0) & live_s & (budget_s > 0)
-            pos = jnp.where(live, lengths_s, 0)
-            # feed row: the device-carried anchor token + the g draft
-            # tokens (−1 sentinels clamp to 0 in the embedding gather and
-            # can never be accepted — a sampled token is always >= 0).
-            tokens = jnp.concatenate(
-                [jnp.where(live, token_s, 0)[:, None],
-                 jnp.maximum(draft, 0)], axis=1)                 # [S, g+1]
-            if mem > 1:
-                # Stacked members: verify all members' drafts in one
-                # member-vmapped multi-token forward (same fold/unfold as
-                # the decode chunk — _stacked_rows_call).
-                logits, ck, cv = _stacked_rows_call(
-                    mem, n_s,
-                    lambda p, k, v, t, ps, wm: decode_multi(
-                        p, spec, t, ps, k, v, write_mask=wm,
-                        history=history, clamp_writes=True),
-                    params, ck, cv, tokens, pos, live)
-            else:
-                logits, ck, cv = decode_multi(
-                    params, spec, tokens, pos, ck, cv, write_mask=live,
-                    history=history, clamp_writes=True)  # [S, g+1, V]
-            lg_pos = jnp.moveaxis(logits, 1, 0).astype(jnp.float32)
-            if constrained:
-                # Advance the DFA over the draft up front: states[j] masks
-                # position j. A dead/sentinel draft token parks the chain
-                # in FREE — those positions can never be emitted (the
-                # chain already broke at the dead token).
-                def dfa_step(st, dtok):
-                    nxt = jnp.take_along_axis(
-                        trans_t[st], jnp.maximum(dtok, 0)[:, None],
-                        axis=1)[:, 0]
-                    return jnp.where((dtok >= 0) & (nxt >= 0), nxt, 0), st
-
-                st_end, st_pre = lax.scan(dfa_step, dfa_s, draft.T)
-                states = jnp.concatenate(
-                    [st_pre, st_end[None]], axis=0)              # [g+1, S]
-                eos_col = jnp.arange(vocab)[None, :] == eos_s[:, None]
-
-                def position_adj(lg, st):
-                    adj = (lg + bias_s - fp_s[:, None] * counts_s
-                           - pp_s[:, None] * (counts_s > 0))
-                    rowt = trans_t[st]                           # [S, V]
-                    allow = rowt >= 0
-                    allow = jnp.where(
-                        eos_col,
-                        (accept_t[st] & (eos_s >= 0))[:, None], allow)
-                    return apply_token_mask(adj, allow), allow
-
-                adj_pos, allow_pos = jax.vmap(position_adj)(lg_pos, states)
-            else:
-                adj_pos = (lg_pos + bias_s - fp_s[:, None] * counts_s
-                           - pp_s[:, None] * (counts_s > 0))
-            # The model's own token chain over positions 0..g, SAMPLED with
-            # each row's key stream — one split per position, exactly the
-            # decode path's per-token discipline, so emitted tokens (and the
-            # carried key after `emitted` splits) match the non-speculative
-            # path bit for bit. Greedy rows reduce to argmax (key-free).
-            # Keys first (a trivial scan over splits), then all g+1
-            # positions sample in PARALLEL — each position's sample depends
-            # only on its key, and serializing g+1 top-p sorts would add
-            # latency comparable to the forward itself.
-            def key_step(keys, _):
-                split = jax.vmap(jax.random.split)(keys)       # [S, 2, 2]
-                return split[:, 0], (split[:, 0], split[:, 1])
-
-            _, (key_chain, samp_keys) = lax.scan(
-                key_step, keys_s, None, length=g + 1)
-            sampled = jax.vmap(
-                lambda adj, kk: sample_token_rows(
-                    adj, kk, temp_s, topp_s, topk_s)
-            )(adj_pos, samp_keys)                               # [g+1, S]
-            sampled = jnp.swapaxes(sampled, 0, 1)               # [S, g+1]
-            # chain acceptance: draft j must equal the model's token at
-            # position j; EMISSION additionally truncates at the chain's
-            # first EOS and at the remaining budget (on-device finish — the
-            # ring may hold younger dispatches that must see true state).
-            ok = jnp.cumprod(
-                (draft == sampled[:, :-1]).astype(jnp.int32), axis=1)
-            not_eos = ((sampled[:, :-1] != eos_s[:, None])
-                       | (eos_s < 0)[:, None])
-            steps = jnp.arange(1, g + 1)[None, :]
-            cont = ok.astype(bool) & not_eos & (budget_s[:, None] > steps)
-            emit = jnp.concatenate(
-                [jnp.ones((n_rows, 1), jnp.int32),
-                 jnp.cumprod(cont.astype(jnp.int32), axis=1)], axis=1)
-            emit = emit * live[:, None].astype(jnp.int32)       # [S, g+1]
-            e = jnp.sum(emit, axis=1)                           # [S]
-            rows = jnp.arange(n_rows)
-            e1 = jnp.maximum(e, 1)
-            last = sampled[rows, e1 - 1]
-            counts_new = counts_s
-            for j in range(g + 1):
-                counts_new = counts_new.at[rows, sampled[:, j]].add(
-                    emit[:, j])
-            # Key after `e` splits per row (dead rows keep theirs).
-            key_sel = jnp.take_along_axis(
-                jnp.moveaxis(key_chain, 0, 1),                   # [S,g+1,2]
-                (e1 - 1)[:, None, None], axis=1)[:, 0]
-            keys_new = jnp.where(live[:, None], key_sel, keys_s)
-            budget_new = budget_s - e
-            lengths_new = lengths_s + e
-            fin = live & ((last == eos_s) | (budget_new <= 0))
-            live_new = jnp.where(active > 0, live & ~fin, live_s)
-            token_new = jnp.where(live, last, token_s)
-            if want_lp:
-                lp_all = jax.nn.log_softmax(adj_pos, axis=-1)    # [g+1,S,V]
-                s_lp = jnp.take_along_axis(
-                    lp_all, jnp.swapaxes(sampled, 0, 1)[:, :, None],
-                    axis=2)[:, :, 0]                             # [g+1, S]
-                top_lp, top_ix = lax.top_k(lp_all, n_top)
-                lp_out = (s_lp.T, jnp.swapaxes(top_ix, 0, 1),
-                          jnp.swapaxes(top_lp, 0, 1))
-            else:
-                lp_out = ()
-            if constrained:
-                # Masked-entry counts for live constrained rows, gated to
-                # positions that actually emitted (metric parity with the
-                # chunk variant's per-step vector).
-                con = live & (dfa_s > 0)
-                masked = jnp.sum(
-                    (~allow_pos) & con[None, :, None]
-                    & (jnp.swapaxes(emit, 0, 1)[:, :, None] > 0),
-                    axis=(1, 2), dtype=jnp.int32)                # [g+1]
-                # Carried state: the accepted-prefix state at the last
-                # emitted position, advanced on the last emitted token
-                # (stay put on EOS, exactly the chunk variant's rule).
-                st_last = jnp.take_along_axis(
-                    jnp.swapaxes(states, 0, 1), (e1 - 1)[:, None],
-                    axis=1)[:, 0]
-                nd = jnp.take_along_axis(
-                    trans_t[st_last], last[:, None], axis=1)[:, 0]
-                adv = (last != eos_s) & (nd >= 0)
-                dfa_new = jnp.where(live, jnp.where(adv, nd, st_last),
-                                    dfa_s)
-                mask_out = (masked,)
-            else:
-                mask_out = ()
-            tail = (ck, cv, token_new, lengths_new, keys_new, counts_new,
-                    live_new, budget_new)
-            if constrained:
-                tail = tail + (dfa_new,)
-            return (sampled, e) + lp_out + mask_out + tail
-
-        return core
-
-    def _verify_key(self, g: int, want_lp: bool, history: int,
-                    constrained: bool):
-        if constrained:
-            key = ("dfa_verify", g, want_lp, history, self._g_bucket)
-        else:
-            key = ("verify", g, want_lp, history)
-        # Same tagging rule as _decode_key: paged-layout verify programs
-        # are structurally different HLO, dense keys stay byte-identical.
-        return ("paged",) + key if self.kv_pages else key
-
-    @_program("_decode_cache",
-              lambda self, g, history, want_lp=False, tstates=0:
-              self._verify_key(g, want_lp, history, tstates > 0))
-    def _verify_fn(self, g: int, history: int, want_lp: bool = False,
-                   tstates: int = 0):
-        """Jitted ring-resident speculative-verification step (see
-        :meth:`_verify_core`). Variants per (g, want_lp, history[, arena
-        bucket]) — the same gating pattern as the decode chunk: only a
-        batch that contains a logprobs/constrained row pays that
-        variant."""
-        constrained = tstates > 0
-        core = self._verify_core(g, history, want_lp, constrained)
-
-        if constrained:
-            def verify(params, active, eos_s, draft, trans_t, accept_t,
-                       ck, cv, token_s, lengths_s, keys_s, temp_s, topp_s,
-                       topk_s, pp_s, fp_s, counts_s, bias_s, live_s,
-                       budget_s, dfa_s):
-                return core(params, active, eos_s, draft, ck, cv, token_s,
-                            lengths_s, keys_s, temp_s, topp_s, topk_s,
-                            pp_s, fp_s, counts_s, bias_s, live_s, budget_s,
-                            trans_t=trans_t, accept_t=accept_t, dfa_s=dfa_s)
-
-            fn = jax.jit(
-                verify,
-                donate_argnames=("ck", "cv", "token_s", "lengths_s",
-                                 "keys_s", "counts_s", "live_s",
-                                 "budget_s", "dfa_s"),
-            )
-        else:
-            def verify(params, active, eos_s, draft, ck, cv, token_s,
-                       lengths_s, keys_s, temp_s, topp_s, topk_s, pp_s,
-                       fp_s, counts_s, bias_s, live_s, budget_s):
-                return core(params, active, eos_s, draft, ck, cv, token_s,
-                            lengths_s, keys_s, temp_s, topp_s, topk_s,
-                            pp_s, fp_s, counts_s, bias_s, live_s, budget_s)
-
-            fn = jax.jit(
-                verify,
-                donate_argnames=("ck", "cv", "token_s", "lengths_s",
-                                 "keys_s", "counts_s", "live_s",
-                                 "budget_s"),
-            )
-        return fn
-
-    def _spec_loop_key(self, n_turns: int, g: int, want_lp: bool,
-                       history: int, constrained: bool):
-        if constrained:
-            return ("spec_loop_dfa", n_turns, g, want_lp, history,
-                    self._g_bucket)
-        return ("spec_loop", n_turns, g, want_lp, history)
-
-    @_program("_decode_cache",
-              lambda self, g, n_turns, history, want_lp=False, tstates=0:
-              self._spec_loop_key(n_turns, g, want_lp, history, tstates > 0))
-    def _spec_loop_fn(self, g: int, n_turns: int, history: int,
-                      want_lp: bool = False, tstates: int = 0):
-        """Jitted fused draft→verify scan for ``spec_model=`` engines: up
-        to ``n_turns`` speculative turns in ONE dispatch, borrowing the
-        decode_loop carry pattern (all-rows-finished early exit; token/
-        n_valid outputs gain a leading per-turn axis the megachunk reap
-        drains segment by segment).
-
-        Each turn: (1) ingest the target's carried token into the draft
-        model (one draft decode_step at the shared ``lengths`` position —
-        the draft cache already holds every earlier accepted token because
-        accepted drafts ARE the tokens the extension wrote; only the
-        rejection-point token ever differs, and this ingest rewrites it),
-        (2) extend g−1 greedy draft steps — with the grammar arena
-        threaded, each draft logit row is masked by its draft-prefix
-        allow-set first, so the draft never proposes a dead token, (3)
-        verify against the target (:meth:`_verify_core`). The draft cache
-        rides the donated carry, so consecutive fused dispatches chain on
-        device with NO host input beyond the active mask — what lets
-        draft-model speculation keep the decode_pipeline ring full."""
-        constrained = tstates > 0
-        dspec = self._draft_rt.spec
-        sharded = self._sharded
-        vocab = self.spec.vocab_size
-        n_rows = self._rows
-        core = self._verify_core(g, history, want_lp, constrained)
-
-        def spec_loop(params, dparams, active, spec_ok, eos_s, trans_t,
-                      accept_t, ck, cv, dck, dcv, chain, chain_n, token_s,
-                      lengths_s, keys_s, temp_s, topp_s, topk_s, pp_s,
-                      fp_s, counts_s, bias_s, live_s, budget_s, dfa_s):
-            def pick(lg, st):
-                # Greedy draft pick, grammar-filtered: mask by the draft-
-                # prefix state's allow-set (EOS allowed exactly in accept
-                # states) before the argmax, so the draft never proposes a
-                # dead token. A filtered draft can still be rejected — only
-                # the target's own sampled chain decides.
-                lg = lg.astype(jnp.float32)
-                if constrained:
-                    rowt = trans_t[st]
-                    allow = rowt >= 0
-                    eos_col = (jnp.arange(vocab)[None, :]
-                               == eos_s[:, None])
-                    allow = jnp.where(
-                        eos_col,
-                        (accept_t[st] & (eos_s >= 0))[:, None], allow)
-                    lg = apply_token_mask(lg, allow)
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            def dfa_adv(st, tok):
-                nd = jnp.take_along_axis(trans_t[st], tok[:, None],
-                                         axis=1)[:, 0]
-                return jnp.where(nd >= 0, nd, 0)
-
-            def run_turn(op):
-                (ck, cv, dck, dcv, chain, chain_n, token_s, lengths_s,
-                 keys_s, counts_s, live_s, budget_s, dfa_s) = op
-                live = (active > 0) & live_s & (budget_s > 0)
-                # (1) ingest: re-feed the last verify turn's emitted chain
-                # (ending at the target's carried token — positions
-                # lengths−n+1..lengths) through a decode_multi of the SAME
-                # width as the verify forward, so accepted positions'
-                # draft-cache K/V reassociates like the target cache's —
-                # what keeps an oracle draft's chain agreeing with the
-                # target everywhere but true near-ties. Padding repeats
-                # the last chain token; its writes land beyond the stream
-                # and the extension below overwrites them.
-                idx = jnp.minimum(jnp.arange(g + 1)[None, :],
-                                  chain_n[:, None] - 1)
-                feed = jnp.take_along_axis(chain, idx, axis=1)
-                pos0 = jnp.where(live, lengths_s - chain_n + 1, 0)
-                dlg_all, dck, dcv = decode_multi(
-                    dparams, dspec, feed, pos0, dck, dcv, write_mask=live,
-                    history=history, clamp_writes=True)
-                dlg = jnp.take_along_axis(
-                    dlg_all, (chain_n - 1)[:, None, None], axis=1)[:, 0]
-                st = dfa_s if constrained else jnp.zeros((n_rows,),
-                                                         jnp.int32)
-                d0 = pick(dlg, st)
-                if g > 1:
-                    # Extension writes can transiently run past max_seq for
-                    # near-cap rows: only DRAFT cache positions, overwritten
-                    # as the true stream reaches them — draft quality, never
-                    # correctness (the target verify clamps its own writes).
-                    def ext(carry2, _):
-                        tok, dlen, dck, dcv, st = carry2
-                        lgs, dck, dcv = decode_step(
-                            dparams, dspec, tok, dlen, dck, dcv,
-                            write_mask=live, history=history,
-                            sharded=sharded)
-                        st = dfa_adv(st, tok) if constrained else st
-                        nxt = pick(lgs, st)
-                        return (nxt, dlen + 1, dck, dcv, st), nxt
-
-                    (_, _, dck, dcv, _), rest = lax.scan(
-                        ext,
-                        (d0, jnp.where(live, lengths_s + 1, 0), dck, dcv,
-                         st),
-                        None, length=g - 1)
-                    drafted = jnp.concatenate(
-                        [d0[:, None], jnp.swapaxes(rest, 0, 1)], axis=1)
-                else:
-                    drafted = d0[:, None]
-                # Rows that may not draft (penalties/logprobs ride at one
-                # token per turn): sentinel out their drafts.
-                drafted = jnp.where(spec_ok[:, None], drafted, -1)
-                # (3) verify against the target.
-                kw = ({"trans_t": trans_t, "accept_t": accept_t,
-                       "dfa_s": dfa_s} if constrained else {})
-                out = core(params, active, eos_s, drafted, ck, cv, token_s,
-                           lengths_s, keys_s, temp_s, topp_s, topk_s, pp_s,
-                           fp_s, counts_s, bias_s, live_s, budget_s, **kw)
-                n_tail = 9 if constrained else 8
-                outs, tail = out[:-n_tail], out[-n_tail:]
-                if constrained:
-                    (ck, cv, token_s, lengths_s, keys_s, counts_s, live_s,
-                     budget_s, dfa_s) = tail
-                else:
-                    (ck, cv, token_s, lengths_s, keys_s, counts_s, live_s,
-                     budget_s) = tail
-                # Chain carry for the next turn's ingest: the emitted
-                # tokens (outs[0] first e1 valid), count clamped >= 1.
-                sampled, e = outs[0], outs[1]
-                chain = jnp.where(live[:, None], sampled, chain)
-                chain_n = jnp.where(live, jnp.maximum(e, 1), chain_n)
-                return (ck, cv, dck, dcv, chain, chain_n, token_s,
-                        lengths_s, keys_s, counts_s, live_s, budget_s,
-                        dfa_s), tuple(outs)
-
-            carry0 = (ck, cv, dck, dcv, chain, chain_n, token_s, lengths_s,
-                      keys_s, counts_s, live_s, budget_s, dfa_s)
-            # The decode_loop skip pattern: the dead branch must emit the
-            # same output pytree as a real turn; eval_shape is trace-free.
-            out_shapes = jax.eval_shape(lambda op: run_turn(op)[1], carry0)
-
-            def skip_turn(op):
-                zeros = jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), out_shapes)
-                return op, zeros
-
-            def body(carry, _):
-                alive = jnp.any((active > 0) & carry[10] & (carry[11] > 0))
-                return lax.cond(alive, run_turn, skip_turn, carry)
-
-            carry, outs = lax.scan(body, carry0, None, length=n_turns)
-            (ck, cv, dck, dcv, chain, chain_n, token_s, lengths_s, keys_s,
-             counts_s, live_s, budget_s, dfa_s) = carry
-            # outs: (sampled [C, S, g+1], e [C, S], lp?…, masked? [C, g+1])
-            tail = (ck, cv, dck, dcv, chain, chain_n, token_s, lengths_s,
-                    keys_s, counts_s, live_s, budget_s, dfa_s)
-            return tuple(outs) + tail
-
-        return jax.jit(
-            spec_loop,
-            donate_argnames=("ck", "cv", "dck", "dcv", "chain", "chain_n",
-                             "token_s", "lengths_s", "keys_s", "counts_s",
-                             "live_s", "budget_s", "dfa_s"),
-        )
-
     # ---- public API -------------------------------------------------------
 
     def generate_stream(
@@ -4655,10 +3828,6 @@ class InferenceEngine:
                 "tokens_total": self.n_tokens,
                 "failures_total": self.n_failures,
                 "cancellations_total": self.n_cancelled,
-                "spec_turns_total": self.n_spec_turns,
-                "spec_accepted_total": self.n_spec_accepted,
-                "spec_draft_tokens_total": self.n_spec_drafted,
-                "spec_overlapped_total": self.n_spec_overlapped,
                 "decode_chunks_total": self.n_decode_chunks,
                 "decode_busy_rows_total": self.n_decode_rows,
                 "decode_kv_tiles_read_total": self.n_kv_tiles_read,
@@ -4862,10 +4031,6 @@ class InferenceEngine:
             # contract is lexical: queue mutations hold the lock, period.
             with self._cond:
                 self._handoffs.clear()
-        if self._draft_rt is not None:  # draft weights + cache go with them
-            self._draft_rt.params = None
-            self._draft_rt._ck = self._draft_rt._cv = None
-            self._draft_rt = None
 
     def _scheduler(self) -> None:
         # Under disagg this loop is the DECODE group's: admissions and
@@ -5138,9 +4303,9 @@ class InferenceEngine:
 
     def _turn_span(self, req: _Request, name: str, t0: float, t1: float,
                    **meta) -> None:
-        """Record one scheduler turn (decode chunk / spec-verify) on the
+        """Record one scheduler turn (a decode chunk) on the
         request's trace; past TURN_SPAN_CAP turns of a kind, extend that
-        kind's last span (summing steps/accepted, counting the coalesced
+        kind's last span (summing steps, counting the coalesced
         turns) instead of appending."""
         trace = req.trace
         if trace is None:
@@ -5149,9 +4314,8 @@ class InferenceEngine:
         count += 1
         if span is not None and count > self.TURN_SPAN_CAP:
             span.end = trace.rel(t1)
-            for k in ("steps", "accepted"):
-                if k in meta and isinstance(span.meta.get(k), int):
-                    span.meta[k] += meta[k]
+            if "steps" in meta and isinstance(span.meta.get("steps"), int):
+                span.meta["steps"] += meta["steps"]
             if "occupancy" in meta:
                 span.meta["occupancy"] = max(
                     span.meta.get("occupancy", 0), meta["occupancy"])
@@ -6392,9 +5556,9 @@ class InferenceEngine:
 
     def _run_chunk(self) -> None:
         # The guard covers everything the token critical path does on this
-        # thread: ring fill (dispatch), blocking reap, and speculative
-        # verify turns. Admission/prefill stays outside — uploading the
-        # prompt is a legitimate per-request transfer.
+        # thread: ring fill (dispatch) and blocking reap. Admission/prefill
+        # stays outside — uploading the prompt is a legitimate per-request
+        # transfer.
         with self._decode_guard():
             self._run_chunk_steps()
 
@@ -6407,11 +5571,9 @@ class InferenceEngine:
             self._note_admission_clamp(False)
             self._drain_inflight()
             return
-        # Depth-K pipelined decode: top the ring up (speculative verify
-        # turns enter the ring like any chunk — they no longer drain it),
-        # then block on (only) the oldest dispatch. The device rolls
-        # dispatch-to-dispatch while the host detokenizes, SSE-emits, and
-        # schedules the next iteration.
+        # Depth-K pipelined decode: top the ring up, then block on (only)
+        # the oldest dispatch. The device rolls dispatch-to-dispatch while
+        # the host detokenizes, SSE-emits, and schedules the next iteration.
         with self._phase("fill"):
             self._fill_inflight()
         if self._inflight:
@@ -6551,123 +5713,6 @@ class InferenceEngine:
                 c //= 2
         return max(1, c)
 
-    def _form_draft(self, req: _Request, g: int) -> "list[int] | None":
-        """Per-row prompt-lookup draft for the NEXT verify dispatch.
-
-        Fresh (nothing in flight for this row): delegate to :meth:`_draft`
-        on the true history, and — when the draft is the n-gram index's own
-        continuation — remember its source so pipelined turns can keep
-        drafting. Pipelined (dispatches in flight): continue from the
-        remembered source, optimistically assuming the in-flight turns
-        accept in full; a full-accept turn emits exactly its g drafts plus
-        ONE undrafted position (the bonus token), and the next turn's
-        first draft proposes that turn's own first sample — so the cursor
-        skips 1 between drafts. When the cursor runs off the real history
-        it re-anchors through the n-gram index on the last two optimistic
-        tokens — periodic text keeps drafting at any ring depth. A wrong
-        assumption only costs acceptance: the stale draft fails
-        verification and the reap resets the cursor."""
-        if req.n_inflight == 0:
-            d = self._draft(req, g)
-            req.spec_state = None
-            if d is None:
-                return None
-            if req.grammar is not None:
-                d = self._filter_draft(req, req.dfa_host, d)
-            if d is not None and all(t >= 0 for t in d) and len(
-                    req.hist) >= 4:
-                pos = req.ngram.get((req.hist[-2], req.hist[-1]))
-                if pos is not None:
-                    cont = req.hist[pos + 1: pos + 1 + g]
-                    if d == cont + [cont[-1]] * (g - len(cont)):
-                        opt = (req.hist + d)[-2:]
-                        odfa = self._advance_local(req, req.dfa_host, d)
-                        req.spec_state = (pos + 1 + g, opt[0], opt[1],
-                                          odfa)
-            return d
-        state = req.spec_state
-        if state is None:
-            return None
-        cont: list[int] = []
-        truncated = False
-        for k in range(g + 1):
-            step = self._spec_take(req, state)
-            if step is None:
-                state = None
-                break
-            state, tok = step
-            if req.grammar is not None:
-                src, t1, t2, odfa = state
-                odfa = (int(req.grammar.trans[odfa, tok])
-                        if odfa >= 0 else -1)
-                if odfa < 0:
-                    # The optimistic stream leaves the grammar here: the
-                    # full-accept assumption cannot extend past it.
-                    state = None
-                    truncated = True
-                    break
-                state = (src, t1, t2, odfa)
-            if k >= 1:       # the first taken token is the undrafted bonus
-                cont.append(tok)
-        req.spec_state = state
-        if not cont:
-            return None
-        if len(cont) < g:
-            pad = -1 if truncated else cont[-1]
-            cont = cont + [pad] * (g - len(cont))
-        return cont
-
-    @staticmethod
-    def _spec_take(req: _Request, state):
-        """Advance the optimistic-draft cursor one source token; returns
-        ``(new state, token)`` or None when the cursor dies. Re-anchors
-        through the n-gram index when it runs off the real history (the
-        optimistic stream's trailing pair rides in the state), so periodic
-        text keeps drafting at any ring depth."""
-        src, t1, t2, odfa = state
-        if src >= len(req.hist):
-            pos = req.ngram.get((t1, t2))
-            if pos is None or pos + 1 >= len(req.hist):
-                return None
-            src = pos + 1
-        tok = req.hist[src]
-        return (src + 1, t2, tok, odfa), tok
-
-    @staticmethod
-    def _advance_local(req: _Request, state: int, d: "list[int]") -> int:
-        """Walk a host-side LOCAL DFA state over draft tokens (−1 =
-        unknown, stays unknown). Draft quality only — the device mask is
-        the correctness backstop."""
-        if req.grammar is None:
-            return -1
-        for t in d:
-            if state < 0 or t < 0:
-                return -1
-            state = int(req.grammar.trans[state, t])
-        return state
-
-    @staticmethod
-    def _filter_draft(req: _Request, state: int, d: "list[int]"):
-        """Grammar-aware draft filter: truncate a prompt-lookup draft at
-        its first dead token (walking the request's compiled table from
-        the LOCAL ``state``; −1 = unknown, no filtering), padding with the
-        −1 sentinel — the draft never proposes a token the device mask
-        would −inf anyway. A stale state only costs acceptance."""
-        if state < 0:
-            return d  # unknown state: let the device mask decide
-        out: list[int] = []
-        for t in d:
-            if t < 0:
-                break
-            nxt = int(req.grammar.trans[state, t])
-            if nxt < 0:
-                break
-            out.append(t)
-            state = nxt
-        if not out:
-            return None
-        return out + [-1] * (len(d) - len(out))
-
     def _fill_inflight(self) -> None:
         target = self._target_depth()
         while len(self._inflight) < target:
@@ -6686,14 +5731,6 @@ class InferenceEngine:
                 # row can still be decoding in this dispatch (the device
                 # budget would otherwise mask the whole window off).
                 return
-            g = self.spec_decode
-            if g > 0 and any(r.spec_draft_ok for _, r in active):
-                disp = self._try_spec_dispatch(active, g, ahead, depth)
-                if disp == "dispatched":
-                    continue
-                if disp == "stop":
-                    return
-                # disp == "chunk": no draft anywhere — fall through.
             n_steps = max(
                 1, min(r.chunk_hint or self.decode_chunk for _, r in active))
             want_lp = any(r.want_lp >= 0 for _, r in active)
@@ -6735,8 +5772,6 @@ class InferenceEngine:
                           seq=seq, family=fam, depth=depth, chunks=n_chunks,
                           steps=n_steps,
                           rids=[r.rid for _, r in active])
-            for _, r in active:
-                r.n_inflight += 1
             if depth > 0:
                 self.n_overlapped += 1
             obs.PIPELINE_DEPTH.set(len(self._inflight))
@@ -6784,195 +5819,6 @@ class InferenceEngine:
         return not kernel_refusal((self._rows, spec.n_heads, 1, spec.head_dim),
                                   side, history, sharded=self._sharded)
 
-    def _try_spec_dispatch(self, active, g: int, ahead: int,
-                           depth: int) -> str:
-        """Try to make the next ring entry a speculative dispatch. Returns
-        ``"dispatched"`` (an entry was appended), ``"chunk"`` (no draft
-        available anywhere and none in flight — the plain chunked path
-        should dispatch instead), or ``"stop"`` (leave the ring as is: a
-        verify turn is in flight and no pipelined draft exists, so a chunk
-        dispatched now would advance rows past the host's view and poison
-        every future draft — or the spec program is cold and compiling it
-        would stall the in-flight entries)."""
-        want_lp = any(r.want_lp >= 0 for _, r in active)
-        constrained = any(r.grammar is not None for _, r in active)
-        n_steps = g + 1
-        fused = self._draft_rt is not None
-        n_turns = (self._effective_loop(active, n_steps, ahead)
-                   if fused else 1)
-        planned = max(len(r.prompt_ids) + r.emitted for _, r in active)
-        planned += ahead
-        history = prefill_bucket(
-            min(planned + n_steps * n_turns, self.spec.max_seq),
-            self.spec.max_seq)
-        tstates = self._g_bucket if constrained else 0
-        if fused:
-            key = self._spec_loop_key(n_turns, g, want_lp, history,
-                                      constrained)
-        else:
-            key = self._verify_key(g, want_lp, history, constrained)
-        if depth > 0 and key not in self._decode_cache:
-            return "stop"
-        if fused and depth > 0 and any(
-                self._draft_rt.reqs[i] is not r for i, r in active):
-            # A reassigned slot needs a draft resync whose advance/chain
-            # programs may be first-use XLA compiles — never pay those
-            # behind K−1 already-computed dispatches (the same stall the
-            # warm-program guard above prevents); the ring drains to the
-            # blocking entry and the resync runs at depth 0.
-            return "stop"
-        drafts: dict[int, list[int]] = {}
-        if not fused:
-            for i, r in active:
-                if not r.spec_draft_ok:
-                    continue
-                d = self._form_draft(r, g)
-                if d is not None:
-                    drafts[i] = d
-            if not drafts:
-                # A draftless verify turn would emit 1 token per dispatch
-                # and forfeit decode_chunk amortization for nothing.
-                if any(c.spec_turn for c in self._inflight):
-                    return "stop"
-                if any(r.spec_draft_ok and r.n_inflight > 0
-                       and (r.spec_state is not None
-                            or (len(r.hist) >= 4
-                                and r.ngram.get(
-                                    (r.hist[-2], r.hist[-1])) is not None))
-                       for _, r in active):
-                    # A repetitive-looking row is only STALE (dispatches in
-                    # flight hide its true tail): hold the ring instead of
-                    # piling chunks on — it drains within <= K reaps, the
-                    # history catches up, and a fresh draft re-engages
-                    # speculation. Rows with no n-gram signal never hold
-                    # the ring, so plain traffic keeps full chunk depth.
-                    return "stop"
-                return "chunk"
-        self._mark()
-        t0 = time.perf_counter()
-        try:
-            payload, drafted = self._dispatch_spec(
-                active, g, n_turns, want_lp, history, tstates, drafts)
-        except Exception as exc:
-            self._contain_verify_failure(active, exc)
-            return "stop"
-        fam = self._family_of(key)
-        prog = self._sent(DECODE, fam, history, n_steps * n_turns,
-                          witness=payload)
-        seq = self._next_seq()
-        self._inflight.append(
-            _InflightChunk(payload, active, n_steps, t0, history, depth,
-                           constrained, n_turns, spec_turn=True,
-                           drafted=drafted, stacked=fused,
-                           family=fam, seq=seq, prog=prog,
-                           moe=self._moe_snapshot()))
-        FLIGHT.record("dispatch", engine=self._tag, loop="decode", t=t0,
-                      seq=seq, family=fam, depth=depth, chunks=n_turns,
-                      steps=n_steps, drafted=drafted,
-                      rids=[r.rid for _, r in active])
-        for _, r in active:
-            r.n_inflight += 1
-        if depth > 0:
-            self.n_overlapped += 1
-            self.n_spec_overlapped += 1
-        obs.PIPELINE_DEPTH.set(len(self._inflight))
-        return "dispatched"
-
-    def _dispatch_spec(self, active, g: int, n_turns: int, want_lp: bool,
-                       history: int, tstates: int, drafts):
-        """Enqueue one speculative dispatch (non-blocking): a verify turn
-        over host-formed drafts, or — with a draft model — ``n_turns``
-        fused draft→verify turns whose drafts the device generates itself.
-        Chains the per-slot device state (and the draft runtime's cache)
-        exactly like :meth:`_dispatch_chunk`; returns ``(payload, drafted
-        tokens per turn)``."""
-        faults.fire("engine.verify")
-        constrained = tstates > 0
-        mask = np.zeros((self._rows,), np.int32)
-        for i, _ in active:
-            mask[i] = 1
-        mask = jax.device_put(mask, self._rep)
-        if self._draft_rt is not None:
-            rt = self._draft_rt
-            rt.ensure_chain(g, self._rep)
-            for i, r in active:
-                if rt.reqs[i] is not r:
-                    rt.resync(i, r, g)
-            spec_ok = np.zeros((self._rows,), bool)
-            n_ok = 0
-            for i, r in active:
-                spec_ok[i] = r.spec_draft_ok
-                n_ok += int(r.spec_draft_ok)
-            spec_ok = jax.device_put(spec_ok, self._rep)
-            out = self._spec_loop_fn(g, n_turns, history, want_lp,
-                                     tstates=tstates)(
-                self.weights, rt.params, mask, spec_ok, self._eos,
-                self._g_trans, self._g_accept, self._ck, self._cv,
-                rt._ck, rt._cv, rt._chain, rt._chain_n, self._token,
-                self._lengths, self._keys, self._temp, self._topp,
-                self._topk, self._pp, self._fp, self._counts, self._bias,
-                self._live, self._budget, self._dfa)
-            n_pay = len(out) - 13
-            payload, tail = out[:n_pay], out[n_pay:]
-            (self._ck, self._cv, rt._ck, rt._cv, rt._chain, rt._chain_n,
-             self._token, self._lengths, self._keys, self._counts,
-             self._live, self._budget, self._dfa) = tail
-            return tuple(payload), g * n_ok
-        draft = np.full((self._rows, g), -1, np.int32)
-        drafted = 0
-        for i, d in drafts.items():
-            draft[i, : len(d)] = d
-            drafted += sum(1 for t in d if t >= 0)
-        draft = jax.device_put(draft, self._rep)
-        if constrained:
-            out = self._verify_fn(g, history, want_lp, tstates=tstates)(
-                self.weights, mask, self._eos, draft, self._g_trans,
-                self._g_accept, self._ck, self._cv, self._token,
-                self._lengths, self._keys, self._temp, self._topp,
-                self._topk, self._pp, self._fp, self._counts, self._bias,
-                self._live, self._budget, self._dfa)
-            n_pay = len(out) - 9
-            payload, tail = out[:n_pay], out[n_pay:]
-            (self._ck, self._cv, self._token, self._lengths, self._keys,
-             self._counts, self._live, self._budget, self._dfa) = tail
-            return tuple(payload), drafted
-        out = self._verify_fn(g, history, want_lp)(
-            self.weights, mask, self._eos, draft, self._ck, self._cv,
-            self._token, self._lengths, self._keys, self._temp, self._topp,
-            self._topk, self._pp, self._fp, self._counts, self._bias,
-            self._live, self._budget)
-        n_pay = len(out) - 8
-        payload, tail = out[:n_pay], out[n_pay:]
-        (self._ck, self._cv, self._token, self._lengths, self._keys,
-         self._counts, self._live, self._budget) = tail
-        return tuple(payload), drafted
-
-    def _contain_verify_failure(self, active, exc: Exception) -> None:
-        """A speculative dispatch failed (fault injection, host-side
-        error) BEFORE advancing the chained device state: doom only this
-        turn's rows. Older in-flight dispatches reap normally — their
-        tokens for the released rows count as overrun — and pending
-        requests keep their place; the ring is never drained. A failure
-        that consumed donated buffers escalates to the scheduler's
-        :meth:`_fail_all` instead (the co-batched KV went with them)."""
-        if not self._device_state_ok():
-            raise exc
-        FLIGHT.record("containment", engine=self._tag, loop="decode",
-                      site="verify",
-                      error=f"{type(exc).__name__}: {exc}"[:200],
-                      rids=[r.rid for _, r in active])
-        FLIGHT.dump("containment")
-        self.n_failures += len(active)
-        for _, r in active:
-            now = time.perf_counter()
-            r.span("engine-failure", now, now,
-                   error=type(exc).__name__, contained=True)
-            r.out.put(("err", exc))
-        with self._cond:
-            for i, r in active:
-                if self._slots[i] is r:
-                    self._release_slot(i, r)
-
     def _reap_oldest(self) -> None:
         """Block on the oldest in-flight chunk and deliver its tokens.
 
@@ -6994,7 +5840,7 @@ class InferenceEngine:
         accounting (histograms, recorder, spans) and the finished rows'
         release."""
         t0 = time.perf_counter()
-        done, n_exec, delivered = self._emit_chunk(c)
+        done, n_exec = self._emit_chunk(c)
         t1 = time.perf_counter()
         obs.DECODE_CHUNK.observe(t1 - t0)
         # The ledger booked the chunk at its landing (_fetch_landing, or
@@ -7008,49 +5854,12 @@ class InferenceEngine:
                       t_start=round(c.prog.t0, 6),
                       t_ready=round(c.prog.t1, 6),
                       booked_s=round(c.prog.seconds, 6), chunks=n_exec,
-                      spec=c.spec_turn,
                       rids=[r.rid for _, r in c.active])
         obs.PIPELINE_DEPTH.set(len(self._inflight))
         if self.disagg:
             obs.DECODE_GROUP_ACTIVE.set(len(c.active))
         self.n_decode_chunks += 1
         self.n_decode_rows += len(c.active)
-        for _, req in c.active:
-            req.n_inflight = max(0, req.n_inflight - 1)
-        if c.spec_turn:
-            # One spec turn per EXECUTED segment (a fused dispatch covers
-            # n_chunks turns; the early exit skips the all-dead tail). The
-            # per-turn latency feeds the same EWMA the deadline clamp
-            # estimates fused dispatch lengths from.
-            per_turn = (t1 - c.t0) / max(1, n_exec)
-            self._chunk_ewma_s = (
-                per_turn if self._chunk_ewma_s == 0.0
-                else (1 - CHUNK_EWMA_ALPHA) * self._chunk_ewma_s
-                + CHUNK_EWMA_ALPHA * per_turn)
-            self.n_spec_turns += n_exec
-            obs.SPEC_TURNS.inc(n_exec)
-            self.n_spec_drafted += c.drafted * n_exec
-            obs.SPEC_DRAFT_TOKENS.inc(c.drafted * n_exec)
-            g = c.n_steps - 1
-            for i, req in c.active:
-                got, segs = delivered.get(i, (0, 0))
-                if req.spec_state is not None and (
-                        segs < c.n_chunks or got < segs * c.n_steps):
-                    # Any rejection breaks the optimistic full-accept
-                    # assumption every pipelined draft was formed under.
-                    req.spec_state = None
-                if self._slots[i] is req or i in done:
-                    self._turn_span(req, "spec-verify", t0, t1, drafted=g,
-                                    accepted=max(0, got - max(1, segs)),
-                                    occupancy=len(c.active),
-                                    depth=c.depth,
-                                    inflight=round(t0 - c.t0, 6))
-            if done:
-                with self._cond:
-                    for i, req in c.active:
-                        if i in done and self._slots[i] is req:
-                            self._release_slot(i, req)
-            return
         # Megachunk accounting: chunk segments this dispatch actually
         # produced tokens for (the early exit skips the all-dead tail),
         # plus the per-chunk latency EWMA the deadline clamp estimates
@@ -7103,8 +5912,8 @@ class InferenceEngine:
                         self._release_slot(i, req)
 
     def _drain_inflight(self) -> None:
-        """Reap every in-flight chunk — the pipeline's drain point before
-        host-synchronous turns (speculative verify) and on shutdown."""
+        """Reap every in-flight chunk: the pipeline's drain point when no
+        row is active and on shutdown."""
         while self._inflight:
             self._reap_oldest()
 
@@ -7205,9 +6014,7 @@ class InferenceEngine:
         the same loop.
 
         Returns ``(slots that finished in THIS dispatch, segments that
-        produced any token, per-row (tokens delivered, segments with a
-        delivery))`` — the trailing stats drive the speculative-turn
-        accounting (accepted = delivered − 1 per executed turn)."""
+        produced any token)``."""
         active, payload = c.active, c.payload
         with self._phase("reap_block"):
             # The landing: the first observation of the payload in (the
@@ -7234,14 +6041,13 @@ class InferenceEngine:
             toks, n_valid = fetched
             s_lp = top_ix = top_lp = None
         toks, n_valid = np.asarray(toks), np.asarray(n_valid)
-        if not c.stacked:
+        if c.n_chunks == 1:
             toks, n_valid = toks[None], n_valid[None]
             if s_lp is not None:
                 s_lp, top_ix, top_lp = (
                     np.asarray(s_lp)[None], np.asarray(top_ix)[None],
                     np.asarray(top_lp)[None])
         done: set[int] = set()
-        delivered: dict[int, tuple[int, int]] = {}
         n_exec = 0
         for ci in range(toks.shape[0]):
             nv = n_valid[ci]
@@ -7266,38 +6072,10 @@ class InferenceEngine:
                     if self._emit(req, int(toks[ci, i, j])):
                         done.add(i)
                         break
-                got = req.emitted - before
-                self.n_overrun += k - got
-                if got:
-                    d0, s0 = delivered.get(i, (0, 0))
-                    delivered[i] = (d0 + got, s0 + 1)
-                    if c.spec_turn:
-                        # Accepted drafts per executed turn: everything the
-                        # stream got beyond the model's own first token.
-                        acc = max(0, got - 1)
-                        self.n_spec_accepted += acc
-                        obs.SPEC_ACCEPTED_TOKENS.inc(acc)
-                        obs.SPEC_ACCEPTANCE.observe(acc)
+                self.n_overrun += k - (req.emitted - before)
         # Host-drain gap: payload-on-host to last token in consumer queues.
         self.drain_gap_s += time.perf_counter() - t_fetch
-        return done, n_exec, delivered
-
-    @staticmethod
-    def _draft(req: _Request, g: int) -> list[int] | None:
-        """Prompt-lookup draft: the most recent earlier occurrence of the
-        trailing 2-gram, continued for g tokens. O(1) via the request's
-        incrementally-maintained n-gram index (the lagged update means the
-        stored position always has ≥ 1 continuation token). Drafts are
-        suggestions only — verification accepts a draft token iff it equals
-        what the model itself emits at that position."""
-        hist = req.hist
-        if len(hist) < 4:
-            return None
-        pos = req.ngram.get((hist[-2], hist[-1]))
-        if pos is None:
-            return None
-        cont = hist[pos + 1 : pos + 1 + g]
-        return cont + [cont[-1]] * (g - len(cont))
+        return done, n_exec
 
     def _emit(self, req: _Request, tok: int) -> bool:
         """Deliver one token; returns True when the request just finished.
@@ -7305,9 +6083,9 @@ class InferenceEngine:
         Preemption replay (``req.replay`` non-None): the resumed row is
         regenerating tokens the consumer already received. Each one is
         byte-compared against the recorded expectation and swallowed —
-        host state (hist, n-gram index, DFA shadow) advances exactly as on
-        first delivery, but nothing reaches ``out`` and nothing counts as
-        a new token. A mismatch means the determinism contract broke
+        host state (``hist``) advances exactly as on first delivery, but
+        nothing reaches ``out`` and nothing counts as a new token. A
+        mismatch means the determinism contract broke
         (token sequence = f(prompt, seed, sampler)); the stream fails
         loudly rather than silently forking the delivered text."""
         if req.cancel.is_set():
@@ -7326,15 +6104,7 @@ class InferenceEngine:
                 req.cancel.set()
                 return True
         req.emitted += 1
-        hist = req.hist
-        hist.append(tok)
-        if len(hist) >= 3:  # lagged n-gram index update (see _Request)
-            req.ngram[(hist[-3], hist[-2])] = len(hist) - 2
-        if req.grammar is not None and req.dfa_host >= 0 and tok != req.eos_id:
-            # Host DFA shadow (LOCAL state) for the grammar-aware draft
-            # filter; a masked-sampled token is always allowed, so a dead
-            # transition here means the shadow lost sync — park unknown.
-            req.dfa_host = int(req.grammar.trans[req.dfa_host, tok])
+        req.hist.append(tok)
         if replaying:
             # Already delivered before the preemption: swallowed, not
             # re-queued, not re-counted (an EOS never appears in a replay
@@ -7468,25 +6238,6 @@ def release_engine(engine: "InferenceEngine", timeout: float = 30.0) -> None:
     engine.shutdown(timeout=timeout)
 
 
-def _load_draft_ckpt(draft_ckpt: str, target_max_seq: int,
-                     dtype: str | None = None):
-    """(spec, params) for a draft checkpoint, window-matched to the target.
-
-    The draft cache must hold every position the target can reach, so the
-    draft spec's ``max_seq`` is raised to the target's (RoPE tables extend;
-    positions beyond the draft's trained range can only lower acceptance —
-    drafts are speed-only). Vocab equality is enforced downstream by
-    ``_DraftRuntime``."""
-    import dataclasses
-
-    from quorum_tpu.models.hf_loader import load_hf_checkpoint
-
-    dspec, dparams = load_hf_checkpoint(draft_ckpt, dtype=dtype)
-    if dspec.max_seq < target_max_seq:
-        dspec = dataclasses.replace(dspec, max_seq=target_max_seq)
-    return dspec, dparams
-
-
 def get_engine(
     spec: ModelSpec,
     mesh: Mesh | None = None,
@@ -7497,7 +6248,6 @@ def get_engine(
     n_slots: int = DEFAULT_SLOTS,
     prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
     max_pending: int = DEFAULT_MAX_PENDING,
-    spec_decode: int = 0,
     quant: str | None = None,
     prefix_cache: bool = True,
     prefix_store: str | None = None,
@@ -7505,9 +6255,6 @@ def get_engine(
     prefix_store_chunk: int = 0,
     members: int = 1,
     kv_quant: str | None = None,
-    draft_spec: ModelSpec | None = None,
-    draft_seed: int = 0,
-    draft_ckpt: str | None = None,
     sp_impl: str = "ring",
     prefill_mesh: Mesh | None = None,
     zero_drain: bool = False,
@@ -7520,16 +6267,14 @@ def get_engine(
     prepare: bool = False,
 ) -> InferenceEngine:
     """Engines are keyed by weight identity (spec, seed, mesh, quant,
-    members, draft model) plus the cache representation (kv_quant) —
+    members) plus the cache representation (kv_quant) —
     dispatch knobs like decode_chunk are per-call, so two backends that differ
     only in chunking share one set of weights on device. ``n_slots``/
     ``prefill_chunk``/``max_pending``/``decode_pipeline``/``decode_loop``/
     ``prefix_store*``
     (structural properties of the preallocated cache and the scheduler)
     apply at first construction; later callers share the existing engine
-    as-is. ``spec_decode`` and
-    ``prefix_cache`` are NOT structural: a shared engine runs with the
-    maximum draft length any of its backends requested, and a
+    as-is. ``prefix_cache`` is NOT structural: a
     ``prefix_cache=0`` from ANY backend disables reuse on the shared engine
     (an explicit opt-out wins over a sharing default). ``qos`` is not
     structural either — the scheduler policy is pure host state, no device
@@ -7540,11 +6285,6 @@ def get_engine(
     ``prepare`` (engine/prepare.py: the stored programs loaded on a pool
     of threads, beside the weights' init) is not structural either, and
     acts where the engine is built: every serving caller passes the same."""
-    import os
-
-    if draft_ckpt and draft_spec is not None:
-        raise ValueError("draft_spec and draft_ckpt are mutually exclusive")
-    draft_ckpt = os.path.realpath(draft_ckpt) if draft_ckpt else None
     mesh = mesh or single_device_mesh()
     from quorum_tpu.parallel.mesh import AXIS_SP as _SP
 
@@ -7552,8 +6292,7 @@ def get_engine(
     # equivalent configs share one engine (and one set of weights).
     sp_key = sp_impl if dict(mesh.shape).get(_SP, 1) > 1 else None
     key = (spec, seed, quant or None,
-           max(1, int(members)), kv_quant or None,
-           draft_spec, draft_seed, draft_ckpt, sp_key,
+           max(1, int(members)), kv_quant or None, sp_key,
            tuple(sorted(mesh.shape.items())),
            tuple(map(str, mesh.devices.flat)),
            # disagg is structural: the prefill group carries a second
@@ -7583,22 +6322,16 @@ def get_engine(
     with _ENGINES_LOCK:
         eng = _ENGINES.get(key)
         if eng is None:
-            draft_params = None
-            if draft_ckpt:
-                draft_spec, draft_params = _load_draft_ckpt(
-                    draft_ckpt, spec.max_seq)
             eng = InferenceEngine(
                 spec, mesh, seed=seed, n_slots=n_slots,
                 decode_pipeline=decode_pipeline,
                 decode_loop=decode_loop,
                 prefill_chunk=prefill_chunk, max_pending=max_pending,
-                spec_decode=spec_decode, quant=quant,
+                quant=quant,
                 prefix_cache=prefix_cache, prefix_store=prefix_store,
                 prefix_store_bytes=prefix_store_bytes,
                 prefix_store_chunk=prefix_store_chunk,
-                members=members, kv_quant=kv_quant,
-                draft_spec=draft_spec, draft_seed=draft_seed,
-                draft_params=draft_params, sp_impl=sp_impl,
+                members=members, kv_quant=kv_quant, sp_impl=sp_impl,
                 prefill_mesh=prefill_mesh, zero_drain=zero_drain,
                 kv_pages=kv_pages, kv_page_size=kv_page_size,
                 kv_pool_pages=kv_pool_pages, qos=qos,
@@ -7607,8 +6340,6 @@ def get_engine(
             )
             _ENGINES[key] = eng
         else:
-            eng.spec_decode = max(eng.spec_decode,
-                                  max(0, min(spec_decode, 16)))
             eng.prefix_cache = eng.prefix_cache and bool(prefix_cache)
             eng.qos = eng.qos or bool(qos)  # an explicit opt-in wins
         return eng
@@ -7624,14 +6355,12 @@ def get_engine_from_ckpt(
     n_slots: int = DEFAULT_SLOTS,
     prefill_chunk: int = DEFAULT_PREFILL_CHUNK,
     max_pending: int = DEFAULT_MAX_PENDING,
-    spec_decode: int = 0,
     quant: str | None = None,
     prefix_cache: bool = True,
     prefix_store: str | None = None,
     prefix_store_bytes: int = DEFAULT_PREFIX_STORE_BYTES,
     prefix_store_chunk: int = 0,
     kv_quant: str | None = None,
-    draft_ckpt: str | None = None,
     sp_impl: str = "ring",
     prefill_mesh: Mesh | None = None,
     zero_drain: bool = False,
@@ -7641,11 +6370,9 @@ def get_engine_from_ckpt(
     qos: bool = False,
     prepare: bool = False,
 ) -> InferenceEngine:
-    """Engine over a local HF checkpoint; keyed by (resolved path, mesh,
-    draft checkpoint) so N backends pointing at one checkpoint with the
-    same draft configuration share the loaded weights on device (a backend
-    that adds spec_ckpt= constructs its own engine — and re-loads the
-    target)."""
+    """Engine over a local HF checkpoint; keyed by (resolved path, mesh)
+    so N backends pointing at one checkpoint share the loaded weights on
+    device."""
     import os
 
     from quorum_tpu.models.hf_loader import load_hf_checkpoint
@@ -7655,12 +6382,11 @@ def get_engine_from_ckpt(
     # Normalize: dtype=None and an explicit dtype equal to the default must
     # hit the same cache entry (else the checkpoint sits in HBM twice).
     eff_dtype = dtype or ModelSpec().dtype
-    draft_resolved = os.path.realpath(draft_ckpt) if draft_ckpt else None
     from quorum_tpu.parallel.mesh import AXIS_SP as _SP
 
     sp_key = sp_impl if dict(mesh.shape).get(_SP, 1) > 1 else None
     key = ("ckpt", resolved, eff_dtype, quant or None, kv_quant or None,
-           draft_resolved, sp_key,
+           sp_key,
            tuple(sorted(mesh.shape.items())),
            tuple(map(str, mesh.devices.flat)),
            tuple(map(str, prefill_mesh.devices.flat))
@@ -7672,24 +6398,16 @@ def get_engine_from_ckpt(
         eng = _ENGINES.get(key)
         if eng is None:
             spec, params = load_hf_checkpoint(resolved, dtype=dtype)
-            draft_spec = draft_params = None
-            if draft_resolved:
-                # The draft follows the target's dtype= override: a mixed
-                # f32/bf16 pair would round differently and lower
-                # acceptance for no reason.
-                draft_spec, draft_params = _load_draft_ckpt(
-                    draft_resolved, spec.max_seq, dtype=dtype)
             eng = InferenceEngine(
                 spec, mesh, params=params, n_slots=n_slots,
                 decode_pipeline=decode_pipeline,
                 decode_loop=decode_loop,
                 prefill_chunk=prefill_chunk, max_pending=max_pending,
-                spec_decode=spec_decode, quant=quant,
+                quant=quant,
                 prefix_cache=prefix_cache, prefix_store=prefix_store,
                 prefix_store_bytes=prefix_store_bytes,
                 prefix_store_chunk=prefix_store_chunk,
                 kv_quant=kv_quant,
-                draft_spec=draft_spec, draft_params=draft_params,
                 sp_impl=sp_impl, prefill_mesh=prefill_mesh,
                 zero_drain=zero_drain,
                 kv_pages=kv_pages, kv_page_size=kv_page_size,
@@ -7697,8 +6415,6 @@ def get_engine_from_ckpt(
             )
             _ENGINES[key] = eng
         else:
-            eng.spec_decode = max(eng.spec_decode,
-                                  max(0, min(spec_decode, 16)))
             eng.prefix_cache = eng.prefix_cache and bool(prefix_cache)
             eng.qos = eng.qos or bool(qos)  # an explicit opt-in wins
         return eng

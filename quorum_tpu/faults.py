@@ -35,7 +35,6 @@ SITES = (
     "engine.admit",
     "engine.prefill_segment",
     "engine.decode",
-    "engine.verify",
     "engine.snapshot",
     "engine.kv_handoff",
     "engine.preempt",

@@ -1,7 +1,7 @@
 """The attention sub-layer of a patterned spec with ``kv_lora_rank`` set
 (family ``dots3_note``): latent attention of two geometries, a learned
 selection on the full layers, a gate per head. models/patterned.py hands its
-four served paths' attention over to the four functions at the end of this
+three served paths' attention over to the three functions at the end of this
 file; the depth loop, the expert layer, the counters, the ring and the decode
 step's cache write are patterned.py's own and the same for both families.
 
@@ -34,8 +34,7 @@ shapes and never by an option:
     attended latents summed and only that sum taken through ``W_vb``:
     ``row_width + kv_rank`` a (query, position, head), 3.6 times the
     materialised form's at the published sizes, and nothing to make. One
-    cached row serves every head. What a decode step runs, and a few
-    selecting queries (speculative verification).
+    cached row serves every head. What a decode step runs.
 
 **The selection.** ``index_scores`` gives every (query, earlier position)
 pair ``sum_j w_j relu(qI_j . kI)``: the products of bfloat16 operands
@@ -449,7 +448,7 @@ def window_keys(ring, first, window: int):
     return rows, at
 
 
-# ---- the four served paths' attention ---------------------------------------
+# ---- the three served paths' attention --------------------------------------
 #
 # Each returns patterned._layers' ``attend(h, lyr, kind, leaves) -> (output
 # [B, H, T, v], leaves)``: a full layer's leaves are ``(rows, index keys)``
@@ -598,40 +597,6 @@ def decode_attend(spec: ModelSpec, lengths, allow, hist: int, write,
         with _core(kind):
             out = absorbed(q_n, q_r, ring[:, 0], ring_keep, lyr,
                            spec.latent(kind))
-        return gate(out, h, lyr), (ring,)
-
-    return _unit_heads(attend)
-
-
-def multi_attend(spec: ModelSpec, pos, rope_pos, lengths, n_write, ok,
-                 hist: int, write_full, keys: list):
-    """``write_full(cache, new, idx, n)`` is patterned.decode_multi's
-    clamped block write, over the rows."""
-    from quorum_tpu.models import patterned
-
-    rope = tables(spec)
-
-    def attend(h, lyr, kind, leaves):
-        q_n, q_r, rows, c_q, h = project(h, lyr, spec, kind, rope[kind],
-                                         rope_pos)
-        if kind == "G":
-            q_i, k_i, w = index_parts(h, c_q, lyr, spec, rope[kind],
-                                      rope_pos)
-            with jax.named_scope("attn.cache_write"):
-                leaves = tuple(
-                    write_full(c, new[:, None].astype(c.dtype), lengths,
-                               n_write)
-                    for c, new in zip(leaves, (rows, k_i)))
-            with _core(kind):
-                out = full_attention(
-                    q_n, q_r, q_i, w, tuple(c[:, 0] for c in leaves), 0,
-                    hist, pos, ok, lyr, spec, keys)
-            return gate(out, h, lyr), leaves
-        (ring,) = leaves
-        with _core(kind):
-            out = _window_block(q_n, q_r, rows, ring, pos, lengths, lyr, spec)
-        with jax.named_scope("attn.cache_write"):
-            ring = patterned.ring_write(ring, rows[:, None], lengths, n_write)
         return gate(out, h, lyr), (ring,)
 
     return _unit_heads(attend)

@@ -383,20 +383,19 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
     return out, counts
 
 
-def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list,
-         dense: bool | None = None):
+def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list):
     from quorum_tpu.models.transformer import _dense_mlp
 
     if i < spec.first_dense:
         return _dense_mlp(x.astype(jnp.dtype(spec.dtype)), lyr, spec)
-    out, c = moe_layer(x, lyr, spec, token_ok, dense)
+    out, c = moe_layer(x, lyr, spec, token_ok)
     counts.append(c)
     return out
 
 
 def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
-            attend, token_ok, dense: bool | None = None, keys=()):
-    """The depth loop, written out, shared by the four served paths and the
+            attend, token_ok, keys=()):
+    """The depth loop, written out, shared by the three served paths and the
     two families: ``attend(h, lyr, kind, leaves) -> (attention output,
     leaves)`` is what differs between them, ``leaves`` the layer's own of the
     cache: ``(K, V)``, or a latent spec's ``(rows, index keys)`` and
@@ -425,8 +424,7 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
 
         x = _sub(x, lyr["attn_norm_w"], attn, spec)
         x = _sub(x, lyr["mlp_norm_w"],
-                 lambda h: _mlp(h, lyr, spec, i, token_ok, counts, dense),
-                 spec)
+                 lambda h: _mlp(h, lyr, spec, i, token_ok, counts), spec)
     stats = cache_k.stats
     if counts and stats is not None:
         moe = jnp.stack(counts)
@@ -449,7 +447,7 @@ def _scope(kind: str):
     return jax.named_scope("attn.window" if kind == "L" else "attn.full")
 
 
-# ---- the four served paths ----------------------------------------------------
+# ---- the three served paths -------------------------------------------------
 
 
 def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
@@ -610,68 +608,3 @@ def decode_step(params, spec: ModelSpec, token, lengths, cache_k, cache_v,
         params, spec, x.astype(jnp.float32), lengths, cache_k, cache_v,
         write_mask=write_mask, history=history)
     return _head(params, spec, x[:, 0, :]), cache_k, cache_v
-
-
-def decode_multi(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
-                 write_mask=None, history=None):
-    """T positions per row in one forward (speculative verification). A
-    window layer attends over its ring as it stood and the T new positions,
-    then writes them; positions past ``max_seq`` are dropped. The experts
-    run in the form the one-position step of as many rows takes, whose
-    tokens this has to reproduce. As transformer.decode_multi."""
-    from quorum_tpu.models import transformer as tr
-
-    b, t = tokens.shape
-    pos = lengths[:, None] + jnp.arange(t)[None, :]
-    rope_pos = jnp.minimum(pos, spec.max_seq - 1)
-    with jax.named_scope("embed"):
-        x = tr._emb_rows(params["tok_emb"], tokens, jnp.float32)
-    cos, sin = rope_cos_sin_for(spec)
-    hist = spec.max_seq if history is None else min(history, spec.max_seq)
-    allow = jnp.ones((b,), bool) if write_mask is None else write_mask
-    n_write = jnp.where(allow, jnp.clip(spec.max_seq - lengths, 0, t), 0)
-    keep_full = (jnp.arange(hist)[None, None, :] <= pos[:, :, None]
-                 )[:, None, None, :, :]
-
-    def write_full(cache_row, new_row, idx, n):
-        # positions idx .. idx + n - 1 of new_row [K, T, hd]; the rest of the
-        # touched span keeps what it held (and a span past max_seq is moved
-        # back, its values rolled with it)
-        delta = jnp.maximum(idx + t - spec.max_seq, 0)
-        old = lax.dynamic_slice(cache_row, (0, idx - delta, 0), new_row.shape)
-        at = jnp.arange(t) - delta
-        keep = ((at >= 0) & (at < n))[None, :, None]
-        return lax.dynamic_update_slice(
-            cache_row, jnp.where(keep, jnp.roll(new_row, delta, axis=1), old),
-            (0, idx - delta, 0))
-
-    write = jax.vmap(write_full)
-    ok = jnp.broadcast_to(allow[:, None], (b, t))
-    keys: list = []
-
-    def attend(h, lyr, kind, leaves):
-        ck, cv = leaves
-        q, k, v = _qkv(h, lyr, spec, kind, cos, sin, rope_pos)
-        if kind == "G":
-            with jax.named_scope("attn.cache_write"):
-                ck = write(ck, k.astype(ck.dtype), lengths, n_write)
-                cv = write(cv, v.astype(cv.dtype), lengths, n_write)
-            with jax.named_scope("attn.core"), _scope(kind):
-                out = attention(
-                    q, lax.slice_in_dim(ck, 0, hist, axis=2),
-                    lax.slice_in_dim(cv, 0, hist, axis=2), keep_full)
-            return out, (ck, cv)
-        with jax.named_scope("attn.core"), _scope(kind):
-            out = _block_window_attn(q, k, v, ck, cv, pos,
-                                     spec.sliding_window)
-        with jax.named_scope("attn.cache_write"):
-            return out, (ring_write(ck, k, lengths, n_write),
-                         ring_write(cv, v, lengths, n_write))
-
-    if spec.kv_lora_rank:
-        attend = latent.multi_attend(spec, pos, rope_pos, lengths, n_write,
-                                     ok, hist, write, keys)
-    x, cache_k, cache_v = _layers(
-        params, spec, x, cache_k, cache_v, attend, ok,
-        dense=dense_experts(spec, b), keys=keys)
-    return _head(params, spec, x), cache_k, cache_v

@@ -56,7 +56,6 @@ from quorum_tpu.cache.paging import (
     kv_is_paged,
     page_read,
     page_read_row,
-    page_write_multi,
     page_write_prefill,
     page_write_seg,
     page_write_step,
@@ -97,7 +96,7 @@ Params = dict[str, Any]
 # machinery (lax.scan carries, jit donation, vmap) handles the tuple leaves
 # transparently. Decode — the bandwidth-bound path — contracts an int8 side
 # NATIVELY in int8 (ops.attention.decode_attention_q8); the cold
-# prefill-segment / verify paths dequantize their bounded history window
+# prefill-segment path dequantizes its bounded history window
 # instead. A paged pool (cache/paging.py) keeps its own K-major pages.
 
 
@@ -1032,142 +1031,6 @@ def decode_loop(
     token, lengths, live, budget, cache_k, cache_v, sample_carry = carry
     return (toks, n_valid, token, live, budget, cache_k, cache_v, lengths,
             sample_carry, aux)
-
-
-def decode_multi(
-    params: Params,
-    spec: ModelSpec,
-    tokens: jnp.ndarray,   # [B, T] current token + T-1 proposed continuations
-    lengths: jnp.ndarray,  # [B] position of tokens[:, 0] per row
-    cache_k: jnp.ndarray,  # [L, B, max_seq, K·hd]
-    cache_v: jnp.ndarray,
-    write_mask: jnp.ndarray | None = None,  # [B] bool
-    history: int | None = None,
-    clamp_writes: bool = False,
-):
-    """T-token decode: logits for positions lengths..lengths+T-1 of each row
-    in ONE forward. Returns (logits [B,T,V], cache_k, cache_v).
-
-    The speculative-verification step: decode is HBM-bandwidth-bound on the
-    weights, so scoring T candidate tokens costs nearly the same bytes as
-    one — if a draft (e.g. prompt-lookup) guessed the continuation, the
-    accepted prefix advances T tokens for one dispatch's worth of weight
-    reads. Each row's tokens sit at its own offset (``lengths[r] + i``);
-    K/V for all T positions is written into the cache (rejected positions
-    land beyond the advanced length — masked by every later read and
-    overwritten as generation proceeds). ``decode_step`` ≡ T = 1.
-
-    ``clamp_writes`` makes the per-row window cap-safe: a row whose write
-    span ``[lengths, lengths+T)`` runs past ``max_seq`` drops exactly the
-    out-of-range positions instead of letting ``dynamic_update_slice``
-    clamp the start backwards and silently corrupt earlier (valid) cache
-    entries. The ring-resident verify path uses this so near-cap rows can
-    ride every speculative dispatch — their emission is bounded by the
-    on-device budget (always ≤ the remaining window), so a dropped
-    position is never one that gets accepted.
-    """
-    if spec.layer_pattern:
-        return patterned.decode_multi(params, spec, tokens, lengths, cache_k,
-                                      cache_v, write_mask=write_mask,
-                                      history=history)
-    if spec.ssm_heads:
-        raise NotImplementedError(
-            "a spec with a mixer (ssm_heads) has no multi-token decode: a "
-            "rejected draft's positions cannot be taken out of its state")
-    b, t = tokens.shape
-    pos = lengths[:, None] + jnp.arange(t)[None, :]              # [B,T]
-    with jax.named_scope("embed"):
-        x = _emb_rows(params["tok_emb"], tokens, jnp.dtype(spec.dtype))  # [B,T,D]
-        if spec.emb_scale != 1.0:
-            x = x * jnp.asarray(spec.emb_scale, x.dtype)
-        if spec.pos == "learned":
-            # clamp_writes implies positions may (transiently) run past the
-            # table; those positions' logits are never accepted (budget-
-            # bounded emission), so the clamped gather is only shape safety.
-            p_ix = jnp.minimum(pos, spec.max_seq - 1) if clamp_writes else pos
-            x = x + params["pos_emb"][p_ix].astype(x.dtype)
-    cos, sin = rope_cos_sin_for(spec)
-    hist = spec.max_seq if history is None else min(history, spec.max_seq)
-    allow = (jnp.ones((b,), bool) if write_mask is None else write_mask)
-
-    paged = kv_is_paged(cache_k)
-
-    def write_row(cache_row, new_row, idx, w):
-        # cache_row [max_seq, K·hd] (or [max_seq, K] scale), new_row [T, ..]
-        if clamp_writes:
-            # Shift the window start back so the slice stays in bounds, and
-            # roll the values right by the same amount so each kept value
-            # still lands at its intended position; slice indices below the
-            # shift write the OLD contents back (those intended positions
-            # are >= max_seq — dropped).
-            delta = jnp.maximum(idx + t - spec.max_seq, 0)
-            start = (idx - delta, 0)
-            old = lax.dynamic_slice(cache_row, start, new_row.shape)
-            rolled = jnp.roll(new_row, delta, axis=0)
-            keep = (jnp.arange(t) >= delta)[:, None]
-            return lax.dynamic_update_slice(
-                cache_row, jnp.where(keep & w, rolled, old), start)
-        start = (idx, 0)
-        old = lax.dynamic_slice(cache_row, start, new_row.shape)
-        return lax.dynamic_update_slice(
-            cache_row, jnp.where(w, new_row, old), start)
-
-    write = jax.vmap(write_row, in_axes=(0, 0, 0, 0))
-
-    @jax.named_scope("attn.cache_write")
-    def multi_write(cache, value):
-        if paged:
-            # OOB positions drop exactly — subsumes clamp_writes (the dense
-            # path's roll trick exists only because dynamic_update_slice
-            # clamps its start backwards; a page scatter has no start).
-            return page_write_multi(cache, value, lengths, allow, spec.max_seq)
-        return jax.tree.map(lambda leaf, new: write(leaf, new, lengths, allow),
-                            cache, _kv_lines(cache, value))
-
-    def multi_read(cache, dtype):
-        if paged:
-            r = page_read(cache, hist)
-            return _kv_dequant(r[0], r[1], dtype) if kv_is_q8(cache) else r
-        window = jax.tree.map(
-            lambda leaf: lax.slice_in_dim(leaf, 0, hist, axis=1), cache)
-        return _kv_rows(window, spec.n_kv_heads, dtype)
-
-    # per-row causal mask over the cache prefix: key j visible to query i of
-    # row r iff j <= lengths[r] + i
-    ki = jnp.arange(hist)[None, None, :]
-    keep = ki <= pos[:, :, None]
-    if spec.sliding_window > 0:
-        keep = keep & (ki > pos[:, :, None] - spec.sliding_window)
-    mask = keep[:, None, None, :, :]  # [B,1,1,T,hist]
-
-    def body(carry_x, per_layer):
-        block, ck, cv = per_layer
-        h = _norm(carry_x, block["attn_norm_w"], block.get("attn_norm_b"), spec)
-        q, k, v = _qkv(h, block, spec)  # q [B,H,T,hd], k/v [B,K,T,hd]
-        if spec.pos == "rope":
-            rope_row = jax.vmap(
-                lambda xr, p: apply_rope(xr[None], cos, sin, p)[0])
-            q = rope_row(q, pos)
-            k = rope_row(k, pos)
-        new_ck = multi_write(ck, k)
-        new_cv = multi_write(cv, v)
-        with jax.named_scope("attn.core"):
-            read_k = multi_read(new_ck, q.dtype)
-            read_v = multi_read(new_cv, q.dtype)
-            attn = attention(q, read_k, read_v, mask, rows_major=not paged)
-        carry_x = carry_x + _attn_out(attn, block, carry_x.dtype)
-        h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)
-        # dense MoE (not grouped): verification logits must be numerically
-        # identical to what the T=1 decode path would produce, or a
-        # near-tie argmax could accept a token normal decode wouldn't emit
-        mlp = (_moe_mlp_dense(h2, block, spec)
-               if spec.is_moe else _dense_mlp(h2, block, spec))
-        carry_x = carry_x + mlp
-        return carry_x, (new_ck, new_cv)
-
-    x, (cache_k, cache_v) = lax.scan(body, x, (params["blocks"], cache_k, cache_v))
-    x = _final_norm(params, spec, x)
-    return _unembed(params, spec, x), cache_k, cache_v
 
 
 def _layer_body(carry_x, block, spec: ModelSpec, positions, cos, sin, attn_fn,

@@ -298,31 +298,6 @@ CONSTRAIN_COMPILE = METRICS.histogram(
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0, 10.0))
 
-# Speculative decoding (engine._verify_core / _spec_loop_fn — grammar-
-# aware, row-wise gated, ring-resident; docs/tpu_backends.md): turn and
-# token accounting plus the per-turn acceptance histogram the bench's
-# acceptance-rate number is the ratio form of.
-SPEC_TURNS = METRICS.counter(
-    "quorum_tpu_spec_turns_total",
-    "Speculative verify turns executed (one per verify dispatch; a fused "
-    "draft-model dispatch counts each executed turn of its on-device "
-    "scan).")
-SPEC_DRAFT_TOKENS = METRICS.counter(
-    "quorum_tpu_spec_draft_tokens_total",
-    "Real (non-sentinel) draft tokens proposed to verify turns, summed "
-    "over rows — prompt-lookup continuations or draft-model tokens.")
-SPEC_ACCEPTED_TOKENS = METRICS.counter(
-    "quorum_tpu_spec_accepted_tokens_total",
-    "Draft tokens accepted by verification and delivered to a consumer "
-    "(the turn's own first sampled token is the model's step, not a "
-    "draft — it never counts).")
-SPEC_ACCEPTANCE = METRICS.histogram(
-    "quorum_tpu_spec_accepted_per_turn",
-    "Accepted draft tokens per row per executed verify turn (0 = only "
-    "the model's own token emitted; the bucket spread IS the acceptance "
-    "profile speculation's tok/s win depends on).",
-    buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0))
-
 # Recompile sentinel (quorum_tpu/analysis/compile_watch.py, docs/
 # static_analysis.md): XLA compiles observed AFTER the process served its
 # first completed request. First-of-shape traffic still legitimately ticks

@@ -14,8 +14,7 @@ pattern, in three layers that compose but ship independently:
   2. **In-engine aggregation hop** (strategy tier): the aggregator's
      synthesis runs as an ordinary engine request with its own QoS class
      (``aggregator_priority``), optionally streamed live as the client
-     response (``stream_aggregate``) and optionally drafted through the
-     prompt-lookup speculation machinery (``speculative_aggregation``).
+     response (``stream_aggregate``).
      Lives in :mod:`quorum_tpu.strategies.aggregate`.
 
   3. **Cross-cell quorum** (router tier, this package): a ``quorum=M``

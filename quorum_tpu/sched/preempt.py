@@ -55,8 +55,7 @@ class PreemptionController:
         already delivered; replay would have to suppress re-records across
         every emit path — not worth the risk for an observability knob).
         Everything else replays exactly: penalties rebuild from history,
-        constrained rows re-advance their DFA on device, speculative rows
-        verify with the same per-token RNG chain.
+        constrained rows re-advance their DFA on device.
         """
         return (req is not None
                 and not req.cancel.is_set()

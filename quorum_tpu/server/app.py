@@ -176,41 +176,6 @@ async def _stream_with_role(
     yield sse.encode_done()
 
 
-def _validate_speculative_aggregation(cfg: Config, reg) -> None:
-    """Boot-time check for ``speculative_aggregation: true`` (docs/quorum.md).
-
-    There is no per-request speculation lever — spec_decode is an engine
-    boot knob — so the opt-in is an assertion: the aggregator must be a
-    local ``tpu://`` backend whose engine runs prompt-lookup speculation
-    (the aggregation prompt quotes the members' tails verbatim, which is
-    exactly what prompt lookup drafts the aggregate from). Failing at boot
-    beats silently aggregating unaccelerated."""
-    try:
-        if cfg.strategy_name != "aggregate" or not cfg.aggregate.speculative_aggregation:
-            return
-    except ValueError:
-        raise  # invalid aggregate block: let from_dict's error surface
-    p = cfg.aggregate
-    agg = reg.get(p.aggregator_backend) if p.aggregator_backend else None
-    if agg is None:
-        raise ValueError(
-            "speculative_aggregation: true requires an aggregator_backend "
-            f"(got {p.aggregator_backend!r})")
-    engine = getattr(agg, "engine", None)
-    if engine is None:
-        raise ValueError(
-            f"speculative_aggregation: true requires a tpu:// aggregator "
-            f"(backend {agg.name!r} is {type(agg).__name__}; an HTTP "
-            "upstream's speculation cannot be asserted from here)")
-    if int(getattr(engine, "spec_decode", 0) or 0) <= 0:
-        raise ValueError(
-            f"speculative_aggregation: true but aggregator {agg.name!r} "
-            "runs no speculation (spec_decode=0). Add spec_decode=G "
-            "(e.g. spec_decode=4) to its tpu:// URL — the aggregation "
-            "prompt quotes the members' outputs, which is what "
-            "prompt-lookup speculation drafts from.")
-
-
 def create_app(
     config: Config | None = None,
     registry: BackendRegistry | None = None,
@@ -230,7 +195,8 @@ def create_app(
     """
     cfg = config if config is not None else load_config()
     reg = registry if registry is not None else build_registry(cfg, **backend_overrides)
-    _validate_speculative_aggregation(cfg, reg)
+    if cfg.strategy_name == "aggregate":
+        _ = cfg.aggregate  # parsed so that an invalid block fails at boot
 
     from quorum_tpu.server.reload import ConfigWatcher, Runtime
 

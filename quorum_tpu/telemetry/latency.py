@@ -4,7 +4,7 @@ Generalizes the PR 6 effective-C clamp EWMA (one scalar per engine — the
 per-chunk dispatch-to-reap estimate) into per-program-family statistics:
 the seconds the engine's device ledger (``device_ledger.py``) books to each
 program at its landing are attributed to its ``compile_budget.json`` family
-("plain", "loop", "verify", "dfa", ...; admission-path programs under their
+("plain", "loop", "dfa", ...; admission-path programs under their
 admit-cache family names), and each family keeps an EWMA, running totals,
 and a bounded sample reservoir for exact p50/p99.
 

@@ -1,7 +1,7 @@
 """Engine flight recorder: an always-on, bounded ring of structured events.
 
 PRs 6-10 deliberately erased the host-visible execution boundaries (megachunk
-scans, fused draft→verify turns, dual disagg loops, zero-drain injection) —
+scans, dual disagg loops, zero-drain injection) —
 one opaque "decode" blob per dispatch is all a request trace sees. This ring
 is the post-hoc answer: every engine records its scheduling decisions here as
 small structured events — dispatch issued/reaped per ring entry (tagged with

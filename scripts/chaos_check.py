@@ -1216,64 +1216,6 @@ async def _run(quick: bool) -> None:
                   == peng.kv_pool_pages)
             peng.shutdown()
 
-        # ---- phase 4c: speculative verify fault site ---------------------
-        # A spec_decode engine beside the main one (the main engine's
-        # deadline/breaker phases count on engine.decode dispatches, so it
-        # stays spec-free): a failed verify dispatch dooms only its own
-        # turn's rows — the queued bystander keeps its place and admits,
-        # NO device-state rebuild happens (the ring's chained state was
-        # never consumed), and the engine keeps serving identically. The
-        # logit_bias-forced periodic stream makes prompt-lookup drafting
-        # (and therefore the engine.verify site) fire deterministically.
-        if not quick:
-            print("phase 4c: speculative verify", flush=True)
-            import numpy as np
-
-            from quorum_tpu.engine.engine import InferenceEngine
-            from quorum_tpu.models.model_config import resolve_spec
-            from quorum_tpu.ops.sampling import SamplerConfig
-
-            tiny = resolve_spec("llama-tiny", {"n_kv_heads": "4"})
-            seng = InferenceEngine(tiny, decode_chunk=4, n_slots=1,
-                                   decode_pipeline=2, spec_decode=4,
-                                   seed=78)
-            samp = SamplerConfig(temperature=0.0)
-            sbias = np.zeros((tiny.vocab_size,), np.float32)
-            sbias[7] = 1e9
-
-            def srun(n=12):
-                req = seng.submit([7, 7, 7, 7], max_new_tokens=n,
-                                  sampler=samp, logit_bias=sbias)
-                return list(seng.stream_results(req))
-
-            sbase = srun()
-            check("verify: workload speculates", seng.n_spec_turns > 0,
-                  f"turns={seng.n_spec_turns}")
-            faults.reset_counts()
-            faults.arm("engine.verify", times=1)
-            bad = seng.submit([7, 7, 7, 7], max_new_tokens=12,
-                              sampler=samp, logit_bias=sbias)
-            bystander = seng.submit([7, 7, 7, 7], max_new_tokens=12,
-                                    sampler=samp, logit_bias=sbias)
-            err = None
-            try:
-                list(seng.stream_results(bad))
-            except Exception as e:
-                err = e
-            by_toks = list(seng.stream_results(bystander))
-            faults.disarm()
-            check("verify: fault fired",
-                  faults.fired("engine.verify") >= 1)
-            check("verify: failed dispatch dooms its own turn's rows",
-                  isinstance(err, faults.FaultInjected), repr(err))
-            check("verify: queued bystander completes unchanged",
-                  by_toks == sbase, f"{by_toks} != {sbase}")
-            check("verify: no device-state rebuild (ring never doomed)",
-                  seng.n_rebuilds == 0, f"rebuilds={seng.n_rebuilds}")
-            check("verify: follow-up matches baseline", srun() == sbase)
-            _flight_dump_check("verify", "engine.verify")
-            seng.shutdown()
-
         # ---- phase 4d: zero-drain injection-path faults ------------------
         # A colocated zero_drain=1 engine (ISSUE 11): an engine.admit or
         # engine.prefill_segment failure while the decode ring is full

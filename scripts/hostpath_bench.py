@@ -142,82 +142,6 @@ def run(tokens: int = 64, chunk: int = 4, depth: int = 4,
     return out
 
 
-def spec(tokens: int = 64, chunk: int = 4, depth: int = 4,
-         g: int = 4) -> dict:
-    """Speculative-decoding A/B (ISSUE 10): acceptance rate, tok/s and
-    dispatches/request with spec_decode on vs off, on a repetitive leg and
-    a CONSTRAINED repetitive leg, tokens asserted identical.
-
-    The workload forces a periodic stream with ``logit_bias`` (greedy +
-    one dominating token), so prompt-lookup drafting engages by
-    construction and acceptance measures the verify machinery, not the
-    random tiny model's self-repetition. The constrained leg runs the same
-    stream under a wildcard regex grammar — the dfa-verify program variant
-    with its table gathers and per-position draft-prefix masking — which
-    before this ISSUE fell back to the plain chunked path. Verify turns
-    are ring-resident: the ``*_spec_overlapped`` counters show dispatches
-    issued onto a non-empty decode_pipeline ring."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-    import numpy as np
-
-    from quorum_tpu.constrain import compile_response_format
-    from quorum_tpu.engine.engine import InferenceEngine
-    from quorum_tpu.engine.tokenizer import ByteTokenizer
-    from quorum_tpu.models.model_config import MODEL_PRESETS
-    from quorum_tpu.ops.sampling import SamplerConfig
-
-    mspec = MODEL_PRESETS["llama-tiny"]
-    tok = ByteTokenizer(mspec.vocab_size)
-    greedy = SamplerConfig(temperature=0.0)
-    bias = np.zeros((mspec.vocab_size,), np.float32)
-    bias[7] = 1e9  # period-1 stream: every prompt-lookup draft can accept
-    wildcard = compile_response_format(
-        {"type": "regex", "pattern": "[\\x00-\\xff]*"},
-        tok, mspec.vocab_size)
-    out: dict = {"spec_tokens": tokens, "spec_g": g}
-    for leg, grammar in (("rep", None), ("crep", wildcard)):
-        streams = {}
-        for arm, sd in (("off", 0), ("on", g)):
-            eng = InferenceEngine(mspec, decode_chunk=chunk,
-                                  decode_pipeline=depth, spec_decode=sd)
-
-            def one():
-                req = eng.submit(
-                    [7, 7, 7, 7], max_new_tokens=tokens, sampler=greedy,
-                    seed=0, logit_bias=bias,
-                    eos_id=tok.eos_id if grammar is not None else None,
-                    grammar=grammar)
-                return [t for t in eng.stream_results(req)]
-
-            one()  # warm every program/bucket the measured pass dispatches
-            c0, t0 = eng.n_decode_chunks, eng.n_spec_turns
-            a0, d0, o0 = (eng.n_spec_accepted, eng.n_spec_drafted,
-                          eng.n_spec_overlapped)
-            w0 = time.perf_counter()
-            streams[arm] = one()
-            wall = time.perf_counter() - w0
-            pre = f"spec_{leg}_{arm}"
-            out[f"{pre}_tok_s"] = round(tokens / wall, 1)
-            out[f"{pre}_dispatches_per_request"] = eng.n_decode_chunks - c0
-            if sd:
-                out[f"{pre}_acceptance"] = round(
-                    (eng.n_spec_accepted - a0)
-                    / max(1, eng.n_spec_drafted - d0), 3)
-                out[f"{pre}_spec_turns"] = eng.n_spec_turns - t0
-                out[f"{pre}_spec_overlapped"] = eng.n_spec_overlapped - o0
-            # Per-family attribution: the spec-on arm's device time lives
-            # under the verify/dfa_verify families, the off arm's under
-            # plain/dfa — the A/B win is attributable by family.
-            out[f"{pre}_device_seconds"] = eng.latency.snapshot()
-            eng.shutdown()
-        out[f"spec_{leg}_tokens_match"] = streams["off"] == streams["on"]
-        out[f"spec_{leg}_speedup"] = round(
-            out[f"spec_{leg}_on_tok_s"]
-            / max(1e-9, out[f"spec_{leg}_off_tok_s"]), 2)
-    return out
-
-
 def interference(tokens: int = 64, chunk: int = 4, depth: int = 4,
                  loop: int = 4, churn: int = 4,
                  churn_prompt_tokens: int = 48) -> dict:
@@ -700,10 +624,6 @@ def main() -> int:
     ap.add_argument("--loop", type=int, default=4,
                     help="decode_loop=C for the megachunk leg (>= 2)")
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--spec-g", type=int, default=4,
-                    help="draft length for the speculative A/B legs")
-    ap.add_argument("--skip-spec", action="store_true",
-                    help="skip the speculative-decoding A/B legs")
     ap.add_argument("--skip-interference", action="store_true",
                     help="skip the colocated-vs-disagg interference legs")
     ap.add_argument("--skip-sharded", action="store_true",
@@ -713,9 +633,6 @@ def main() -> int:
                     help="run ONLY the interference legs (bench.py's "
                          "subprocess phase — the depth/megachunk sweep "
                          "would be compiled and thrown away)")
-    ap.add_argument("--only-spec", action="store_true",
-                    help="run ONLY the speculative A/B legs (bench.py's "
-                         "subprocess phase)")
     ap.add_argument("--only-sharded", action="store_true",
                     help="run ONLY the per-group-sharding legs (bench.py's "
                          "subprocess phase)")
@@ -761,15 +678,6 @@ def main() -> int:
             _print_sharded(msh)
         print(json.dumps(msh), flush=True)
         return 0
-    if args.only_spec:
-        ms = spec(args.tokens, args.chunk, args.depth, args.spec_g)
-        for leg in ("rep", "crep"):
-            print(f"  spec {leg}: {ms[f'spec_{leg}_off_tok_s']} -> "
-                  f"{ms[f'spec_{leg}_on_tok_s']} tok/s, acceptance "
-                  f"{ms[f'spec_{leg}_on_acceptance']:.0%}, tokens "
-                  f"identical: {ms[f'spec_{leg}_tokens_match']}")
-        print(json.dumps(ms), flush=True)
-        return 0
     if args.only_interference:
         mi = interference(args.tokens, args.chunk, args.depth, args.loop)
         print("prefill interference (streaming inter-token gap under "
@@ -803,8 +711,7 @@ def main() -> int:
         fams = m.get(f"{tag}_device_seconds", {})
         decode_fams = {f: s for f, s in fams.items()
                        if f in ("plain", "loop", "dfa", "loop_dfa",
-                                "verify", "dfa_verify", "spec_loop",
-                                "spec_loop_dfa", "unknown")}
+                                "unknown")}
         if decode_fams:
             parts = ", ".join(
                 f"{f} p50 {s['p50_ms']}ms / p99 {s['p99_ms']}ms "
@@ -818,24 +725,6 @@ def main() -> int:
     print(f"  dispatch reduction at decode_loop={c}: "
           f"{m['loop_dispatch_reduction']:.1f}x")
     print(f"  token-for-token identical: {m['tokens_match']}")
-    if not args.skip_spec:
-        ms = spec(args.tokens, args.chunk, args.depth, args.spec_g)
-        m.update(ms)
-        print(f"speculative decoding A/B (g={args.spec_g}, forced-periodic "
-              "stream, spec on vs off):")
-        for leg, label in (("rep", "repetitive "), ("crep", "constrained")):
-            print(f"  {label}: "
-                  f"{ms[f'spec_{leg}_off_tok_s']} -> "
-                  f"{ms[f'spec_{leg}_on_tok_s']} tok/s "
-                  f"({ms[f'spec_{leg}_speedup']:.2f}x), "
-                  f"{ms[f'spec_{leg}_off_dispatches_per_request']} -> "
-                  f"{ms[f'spec_{leg}_on_dispatches_per_request']} "
-                  f"dispatches/req, acceptance "
-                  f"{ms[f'spec_{leg}_on_acceptance']:.0%}, "
-                  f"{ms[f'spec_{leg}_on_spec_overlapped']} of "
-                  f"{ms[f'spec_{leg}_on_spec_turns']} verify turns "
-                  "overlapped the ring, tokens identical: "
-                  f"{ms[f'spec_{leg}_tokens_match']}")
     if not args.skip_interference:
         mi = interference(args.tokens, args.chunk, args.depth, args.loop)
         m.update(mi)

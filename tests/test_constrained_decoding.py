@@ -10,9 +10,6 @@
 - the constrained-vs-unconstrained determinism pin: a grammar the
   unconstrained stream already satisfies masks nothing, so the token
   streams are identical — at K=1 and K=4;
-- spec-decode composition: constrained requests SPECULATE (grammar-aware
-  drafts through the dfa-verify program variant) and the emitted stream
-  equals the non-speculative constrained stream token for token;
 - members=M stacking: per-member rows carry independent DFA states.
 
 Everything runs the tiny preset on CPU — the same compiled code paths as
@@ -161,42 +158,6 @@ def test_noop_masking_is_token_identical_at_k1_and_k4():
     finally:
         e1.shutdown()
         e4.shutdown()
-
-
-def test_spec_decode_composes_and_matches_token_for_token():
-    """Spec-decode composition (ISSUE 10): a constrained request on a
-    spec_decode engine SPECULATES — the dfa-verify variant masks each
-    position with its draft-prefix DFA state — and its stream equals the
-    non-speculative constrained stream bit for bit, with drafts actually
-    accepted (the oracle proposes the reference continuation)."""
-    plain = InferenceEngine(TINY, decode_chunk=4, decode_pipeline=2)
-    spec = InferenceEngine(TINY, decode_chunk=4, decode_pipeline=2,
-                           spec_decode=4)
-    try:
-        g = _grammar()
-        want = _run(plain, g, seed=9)
-        # Oracle drafting (the suite's spec-decode idiom): the draft IS
-        # the constrained reference continuation, so acceptance is bounded
-        # only by the verify program's own masking/sampling parity.
-        body = [t for t in want if t != TOK.eos_id]
-        spec._draft = lambda req, g_: (
-            body[req.emitted: req.emitted + g_]
-            if req.emitted + g_ <= len(body) else None)
-        turns0 = spec.n_spec_turns
-        acc0 = spec.n_spec_accepted
-        got = _run(spec, g, seed=9)
-        assert got == want, (
-            "constrained + spec_decode diverged from the non-speculative "
-            "constrained stream")
-        assert spec.n_spec_turns > turns0, (
-            "constrained rows must take speculative verify turns now")
-        assert spec.n_spec_accepted > acc0, (
-            "oracle drafts under a grammar were never accepted")
-        fams = budget.decode_families(spec._decode_cache)
-        assert "dfa_verify" in fams, fams
-    finally:
-        plain.shutdown()
-        spec.shutdown()
 
 
 def test_mixed_batch_constrains_only_grammar_rows():

@@ -132,21 +132,6 @@ def test_admission_pressure_drains_and_matches():
 
 
 @pytest.mark.slow
-def test_spec_verify_turns_drain_the_ring():
-    """Speculative verification (host-synchronous turns) interleaved with
-    pipelined chunks: output parity holds, and the repetitive prompt still
-    finishes in fewer dispatches than tokens (speculation engaged)."""
-    e1 = InferenceEngine(TINY, decode_chunk=4, decode_pipeline=1,
-                         spec_decode=4)
-    e4 = InferenceEngine(TINY, decode_chunk=4, decode_pipeline=4,
-                         spec_decode=4)
-    prompt = [7, 8, 7, 8, 7, 8, 7, 8]
-    a = e1.generate(list(prompt), max_new_tokens=24, sampler=GREEDY)
-    b = e4.generate(list(prompt), max_new_tokens=24, sampler=GREEDY)
-    assert a.token_ids == b.token_ids
-
-
-@pytest.mark.slow
 def test_dispatch_accounting_counters():
     """The acceptance counters: a >=8-chunk generation at K=4 must block
     the host on strictly fewer dispatches than K=1 (n_decode_chunks -

@@ -105,14 +105,32 @@ def test_engine_disagg_sharding_rejections():
         InferenceEngine(TINY, dm2, prefill_mesh=pm2, prefill_chunk=16)
 
 
-@pytest.mark.parametrize("how", ["url-ensemble", "url-pp", "mesh-pp"])
+REMOVED_URL_OPTIONS = {
+    # how: (option, a value that once asked for it, PR, what the error says)
+    "url-ensemble": ("ensemble", "2", 32, "members=M"),
+    "url-pp": ("pp", "2", 32, "tp="),
+    "url-spec_decode": ("spec_decode", "4", 51, "by chunks only"),
+    "url-spec_model": ("spec_model", "llama-tiny", 51, "by chunks only"),
+    "url-spec_ckpt": ("spec_ckpt", "/nowhere", 51, "by chunks only"),
+    "url-spec_seed": ("spec_seed", "3", 51, "by chunks only"),
+}
+
+
+@pytest.mark.parametrize("how", sorted(REMOVED_URL_OPTIONS) + [
+    "mesh-pp", "url-off-values", "config-speculative_aggregation"])
 def test_removed_decode_forms_raise(how):
-    """``ensemble=M`` and ``pp=K`` left the URL grammar in PR 32: a URL that
-    still sets one fails at config time naming the removal (never a quiet
-    single unsharded model), and a decode mesh with a pp axis over 1 fails
-    at engine construction naming tp."""
+    """``ensemble=M`` and ``pp=K`` left the URL grammar in PR 32, the four
+    speculation options and the ``speculative_aggregation`` key in PR 51: a
+    URL or an aggregate block that still sets one fails at config time naming
+    the removal (never a quiet other model), the value that always meant
+    "off" is accepted, and a decode mesh with a pp axis over 1 fails at
+    engine construction naming tp."""
     from quorum_tpu.backends.tpu_backend import TpuBackend
-    from quorum_tpu.config import BackendSpec
+    from quorum_tpu.config import AggregateParams, BackendSpec
+
+    def build(query):
+        return TpuBackend.from_spec(BackendSpec(
+            name="t", url=f"tpu://llama-tiny?{query}", model="m"))
 
     if how == "mesh-pp":
         import jax
@@ -120,21 +138,34 @@ def test_removed_decode_forms_raise(how):
         with pytest.raises(ValueError, match="removed in PR 32.*tp="):
             InferenceEngine(TINY, make_mesh(MeshConfig(pp=2),
                                             jax.devices()[:2]))
-        return
-    opt, instead = {"url-ensemble": ("ensemble", "members=M"),
-                    "url-pp": ("pp", "tp=")}[how]
-    with pytest.raises(ValueError,
-                       match=f"{opt}=2.*removed in PR 32.*{instead}"):
-        TpuBackend.from_spec(BackendSpec(
-            name="t", url=f"tpu://llama-tiny?{opt}=2", model="m"))
-    # the default value is what every engine is: accepted
-    TpuBackend.from_spec(BackendSpec(
-        name="t", url=f"tpu://llama-tiny?{opt}=1&slots=2", model="m"))
+    elif how == "url-off-values":
+        # what every engine is: accepted
+        build("ensemble=1&pp=1&spec_decode=0&spec_seed=0&slots=2")
+    elif how == "config-speculative_aggregation":
+        with pytest.raises(ValueError,
+                           match="removed in PR 51.*by chunks only"):
+            AggregateParams.from_dict({"speculative_aggregation": True})
+        AggregateParams.from_dict({"speculative_aggregation": False})
+    else:
+        opt, value, pr, says = REMOVED_URL_OPTIONS[how]
+        with pytest.raises(
+                ValueError,
+                match=f"{opt}={value}.*removed in PR {pr}.*{says}"):
+            build(f"{opt}={value}")
 
 
 def test_pp_tagged_decode_key_is_unknown():
     with pytest.raises(budget.UnbudgetedProgramKey, match="no compile_budget"):
         budget.classify_decode_key(("pp", 4, False, 128))
+
+
+def test_speculation_tagged_decode_key_is_unknown():
+    """The verify and spec_loop families left the budget with the programs."""
+    for key in (("verify", 4, False, 128), ("dfa_verify", 4, False, 128, 2),
+                ("spec_loop", 2, 4, False, 128),
+                ("paged", "verify", 4, False, 128)):
+        with pytest.raises(budget.UnbudgetedProgramKey):
+            budget.classify_decode_key(key)
 
 
 def test_disagg_url_knob_validation():
@@ -153,7 +184,6 @@ def test_disagg_url_knob_validation():
         ("tpu://llama-tiny?disagg=1+1&dp=2", "dp= does not compose"),
         ("tpu://llama-tiny?disagg=1+1&prefill_chunk=0", "chunked prefill"),
         ("tpu://llama-tiny?disagg=9+9", "devices"),
-        ("tpu://llama-tiny?disagg=1+1&spec_model=llama-tiny", "draft"),
     ]:
         with pytest.raises(ValueError, match=frag.replace("/", ".")):
             build(url)

@@ -232,25 +232,6 @@ async def test_live_metrics_exposition_validates():
     assert ("# TYPE quorum_tpu_engine_constrain_masked_tokens_total "
             "counter" in text)
 
-    # speculative-decoding families (ISSUE 10, docs/tpu_backends.md): the
-    # turn/draft/accepted counters and the per-turn acceptance histogram
-    # expose even at zero (spec may not engage for this traffic), and the
-    # engine block carries the per-engine split incl. the ring-resident
-    # overlap counter
-    for counter in ("quorum_tpu_spec_turns_total",
-                    "quorum_tpu_spec_draft_tokens_total",
-                    "quorum_tpu_spec_accepted_tokens_total"):
-        assert f"# TYPE {counter} counter" in text, counter
-    fam = "quorum_tpu_spec_accepted_per_turn"
-    assert f"# TYPE {fam} histogram" in text
-    assert f'{fam}_bucket{{le="+Inf"}}' in text
-    assert f"{fam}_sum" in text and f"{fam}_count" in text
-    for counter in ("quorum_tpu_engine_spec_turns_total",
-                    "quorum_tpu_engine_spec_accepted_total",
-                    "quorum_tpu_engine_spec_draft_tokens_total",
-                    "quorum_tpu_engine_spec_overlapped_total"):
-        assert f"# TYPE {counter} counter" in text, counter
-
     # QoS scheduler families (ISSUE 18, docs/scheduling.md): the
     # preemption counters and the per-class queue-depth gauge expose even
     # at zero (no preemption happened for this traffic), and the engine

@@ -351,8 +351,6 @@ REFUSED = {
     "prefix_store": dict(prefix_store="host"),
     "members>1": dict(members=2),
     "zero_drain=1": dict(zero_drain=True),
-    "spec_decode=2": dict(spec_decode=2),
-    "spec_model=": dict(draft_spec=MODEL_PRESETS["llama-tiny"]),
     "tp>1": dict(tp=2),
     "sp>1": dict(sp=2),
 }
@@ -547,11 +545,3 @@ def test_a_traced_program_logs_its_mixer_path(model32, caplog):
              if r.getMessage().startswith("mixer-path")]
     assert any("form=chunked" in ln and "positions=16" in ln for ln in lines)
     assert any("form=step" in ln and "positions=1 " in ln for ln in lines)
-
-
-def test_multi_token_decode_is_refused(model32):
-    spec, params = model32
-    ck, cv = tr.init_cache(spec, SLOTS)
-    with pytest.raises(NotImplementedError, match="state"):
-        tr.decode_multi(params, spec, jnp.zeros((SLOTS, 3), jnp.int32),
-                        jnp.zeros((SLOTS,), jnp.int32), ck, cv)

@@ -17,14 +17,10 @@ into every suite run), and pins the dispatch accounting the bench reports:
     stall on the zero-drain arm (the p99-gap ORDERING is the bench's
     printed acceptance number, not a suite assertion — wall-clock
     percentiles on a shared CI core flake)
-  - the speculative A/B legs (ISSUE 10): acceptance rate > 0 on the
-    repetitive AND the constrained repetitive leg, tokens identical spec
-    on vs off, verify turns overlapping the ring (tok/s ORDERING is the
-    printed number — wall-clock on a shared CI core flakes)
 """
 
 from scripts.hostpath_bench import (dedup, interference, paged, qos, run,
-                                    sharded, spec)
+                                    sharded)
 
 
 def test_hostpath_bench_counters():
@@ -52,21 +48,6 @@ def test_hostpath_bench_counters():
         for fam, stats in m[f"{leg}_device_seconds"].items():
             assert stats["count"] > 0, (leg, fam)
             assert 0.0 <= stats["p50_ms"] <= stats["p99_ms"], (leg, fam)
-
-
-def test_spec_bench_smoke():
-    m = spec(tokens=24, chunk=4, depth=4, g=4)
-    for leg in ("rep", "crep"):
-        assert m[f"spec_{leg}_tokens_match"] is True, leg
-        assert m[f"spec_{leg}_on_acceptance"] > 0.0, (leg, m)
-        assert m[f"spec_{leg}_on_spec_turns"] > 0, (leg, m)
-        # ring-resident verify: speculative dispatches overlap the ring
-        assert m[f"spec_{leg}_on_spec_overlapped"] > 0, (leg, m)
-        # fewer dispatches than the spec-off arm for the same tokens (the
-        # wall-clock speedup is the printed number; dispatch counts are
-        # the machine-stable form of the same win)
-        assert (m[f"spec_{leg}_on_dispatches_per_request"]
-                < m[f"spec_{leg}_off_dispatches_per_request"]), (leg, m)
 
 
 def test_interference_bench_smoke():

@@ -4,7 +4,7 @@ Representation contract (models/transformer.py): each cache side becomes
 ``(int8 values, f32 per-token scales)`` with ``value ≈ q8 * scale``; decode
 attention contracts natively in int8 (ops.attention.decode_attention_q8 —
 never dequantize-into-dot, the measured lesson from weight quant, PERF.md
-§2), while the cold prefill-segment/verify paths dequantize their bounded
+§2), while the cold prefill-segment path dequantizes its bounded
 history window.
 """
 

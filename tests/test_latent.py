@@ -438,28 +438,6 @@ def test_the_mask_keeps_what_top_k_keeps_equal_scores_included():
         assert (got.sum(-1) == np.minimum(np.asarray(pos) + 1, k)).all()
 
 
-def test_decode_multi_is_the_decode_step_over_all_three_leaves(
-        model32, tokens):
-    """T positions in one forward give the logits T single steps give, on a
-    row past the selection, the window and a ring wrap, and an idle row's
-    leaves are left as they were."""
-    spec, params = model32
-    ck, cv = segments(spec, params, tokens, 16, *tr.init_cache(spec, SLOTS))
-    t = 5
-    block = np.zeros((SLOTS, t), np.int32)
-    block[SLOT] = tokens[N_PROMPT - 1:N_PROMPT - 1 + t]
-    lens = np.zeros((SLOTS,), np.int32)
-    lens[SLOT] = N_PROMPT - 1
-    live = np.arange(SLOTS) == SLOT
-    logits, ck2, _ = tr.decode_multi(
-        params, spec, jnp.asarray(block), jnp.asarray(lens), ck, cv,
-        write_mask=jnp.asarray(live), history=64)
-    got = np.asarray(jax.nn.log_softmax(logits[SLOT].astype(jnp.float32)))
-    assert np.abs(got - _served32()[:t]).max() < TIGHT
-    for leaf in ck2.full + ck2.window + ck2.index:
-        assert (np.asarray(leaf)[0] == 0).all()
-
-
 def test_a_latent_program_carries_its_scopes(model32):
     """Device operations of a latent program name their part: the two
     latents, the indexer, the selection, the attention in the latent space,

@@ -24,8 +24,7 @@ async def test_metrics_exposition():
         assert 'quorum_tpu_engine_members{backend="LLM1"} 1' in before
         assert "# TYPE quorum_tpu_engine_members gauge" in before
         # round-3 counters, typed as counters in the exposition
-        for key in ("cancellations_total", "spec_turns_total",
-                    "spec_accepted_total"):
+        for key in ("cancellations_total",):
             assert f"# TYPE quorum_tpu_engine_{key} counter" in before
             assert f'quorum_tpu_engine_{key}{{backend="LLM1"}} 0' in before
 

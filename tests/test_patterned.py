@@ -162,33 +162,6 @@ def test_a_control_comes_out_as_not_the_served_model(model32, tokens, change):
     assert np.median(err) > 50 * TIGHT, (change, err)
 
 
-def test_decode_multi_is_the_decode_step_over_a_wrapped_ring(model32, tokens):
-    """T positions in one forward give the logits T single steps give, on a
-    row whose ring has wrapped and on one that is idle."""
-    spec, params = model32
-    ck, cv = tr.init_cache(spec, SLOTS)
-    for off in range(0, N_PROMPT, 16):
-        n = min(16, N_PROMPT - off)
-        seg = np.zeros((1, 16), np.int32)
-        seg[0, :n] = tokens[off:off + n]
-        ck, cv = _segment(params, spec, jnp.asarray(seg), jnp.int32(off),
-                          jnp.int32(n), ck, cv)
-    t = 5
-    block = np.zeros((SLOTS, t), np.int32)
-    block[SLOT] = tokens[N_PROMPT - 1:N_PROMPT - 1 + t]
-    lens = np.zeros((SLOTS,), np.int32)
-    lens[SLOT] = N_PROMPT - 1
-    live = np.arange(SLOTS) == SLOT
-    logits, ck2, _ = tr.decode_multi(
-        params, spec, jnp.asarray(block), jnp.asarray(lens), ck, cv,
-        write_mask=jnp.asarray(live), history=64)
-    got = np.asarray(jax.nn.log_softmax(logits[SLOT].astype(jnp.float32)))
-    want, _ = served(spec, params, tokens, segment=16)
-    assert np.abs(got - want[:t]).max() < TIGHT
-    # an idle row's rings are left as they were
-    assert all((np.asarray(a)[0] == 0).all() for a in ck2.window)
-
-
 @pytest.mark.parametrize("preset", ["k-exaone-tiny", "dots3-tiny"])
 def test_the_shares_add_up_to_the_uncut_layer(preset):
     """The routed parts that the four shares of four experts give, plus the
@@ -295,7 +268,6 @@ REFUSED = {
     "prefix_store": dict(prefix_store="host"),
     "members>1": dict(members=2),
     "zero_drain=1": dict(zero_drain=True),
-    "spec_decode=4": dict(spec_decode=4),
 }
 
 
@@ -307,11 +279,8 @@ def test_a_patterned_spec_refuses_what_does_not_compose(option, preset):
     from quorum_tpu.engine.engine import InferenceEngine
 
     spec = resolve_spec(preset)
-    asked = dict(REFUSED[option])
-    if "spec_decode" in asked:  # the fewest drafts the ring cannot hold
-        asked["spec_decode"] = max(4, spec.ring - spec.sliding_window)
     with pytest.raises(ValueError, match="layer_pattern spec"):
-        InferenceEngine(spec, n_slots=2, **asked)
+        InferenceEngine(spec, n_slots=2, **REFUSED[option])
 
 
 def test_the_benchmarks_comparison_runs_over_this_reference_by_name(tmp_path):

@@ -290,13 +290,6 @@ def test_guarded_map_covers_documented_scheduler_state():
 
 def test_budget_classifies_every_documented_family():
     assert budget.classify_decode_key((4, False, 32)) == "plain"
-    assert budget.classify_decode_key(("verify", 4, False, 64)) == "verify"
-    assert budget.classify_decode_key(
-        ("dfa_verify", 4, False, 64, 8)) == "dfa_verify"
-    assert budget.classify_decode_key(
-        ("spec_loop", 2, 4, False, 64)) == "spec_loop"
-    assert budget.classify_decode_key(
-        ("spec_loop_dfa", 2, 4, False, 64, 8)) == "spec_loop_dfa"
     assert budget.classify_decode_key(("dfa", 4, False, 32, 8)) == "dfa"
     assert budget.classify_decode_key(("loop", 4, 4, False, 64)) == "loop"
     assert budget.classify_decode_key(

@@ -140,9 +140,9 @@ def test_flash_kernels_match_reference_with_window():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_int8_kv_and_spec_decode_respect_window():
-    """kv_quant=int8 decode and speculative verification run the same
-    window: both must reproduce the plain windowed engine's output."""
+def test_int8_kv_respects_window():
+    """kv_quant=int8 decode runs the same window: it must reproduce the
+    plain windowed engine's output."""
     spec = resolve_spec("llama-tiny", WSPEC)
     prompt = [(i % 83) + 3 for i in range(30)]
 
@@ -158,12 +158,6 @@ def test_int8_kv_and_spec_decode_respect_window():
     # identical prefixes rather than exact equality.
     agree = sum(a == b for a, b in zip(got8, ref))
     assert agree >= 6, (got8, ref)
-
-    spec_eng = InferenceEngine(spec, decode_chunk=4, n_slots=2, seed=5,
-                               spec_decode=4)
-    gots = spec_eng.generate(prompt, max_new_tokens=8, sampler=GREEDY).token_ids
-    spec_eng.shutdown()
-    assert gots == ref, "speculative verification ignored the window"
 
 
 def test_sp_mesh_rejects_windowed_spec():

@@ -83,19 +83,6 @@ def call_decode_step():
     return one, (token, lengths, live)
 
 
-def call_decode_multi():
-    tokens = jnp.arange(MEMBERS * ROWS * 4, dtype=jnp.int32).reshape(
-        MEMBERS, ROWS, 4) + 3
-    lengths = ints([3, 17], [4, 30], [9, 61])
-    live = jnp.asarray([[True, True], [True, False], [True, True]])
-
-    def one(p, k, v, t, ps, w):
-        return tr.decode_multi(p, TINY, t, ps, k, v, write_mask=w,
-                               history=64, clamp_writes=True)
-
-    return one, (tokens, lengths, live)
-
-
 def call_prefill():
     tokens = jnp.arange(MEMBERS * 16, dtype=jnp.int32).reshape(
         MEMBERS, 1, 16) + 3
@@ -124,7 +111,6 @@ def call_prefill_segment():
 
 CALLS = {
     "decode_step": call_decode_step,
-    "decode_multi": call_decode_multi,
     "prefill": call_prefill,
     "prefill_segment": call_prefill_segment,
 }

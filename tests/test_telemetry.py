@@ -261,19 +261,23 @@ def test_every_compiled_decode_family_appears_in_device_seconds():
     """Acceptance: every family in compile_budget.json that EXECUTES
     appears in quorum_tpu_dispatch_device_seconds — checked as: every
     family classified from this engine's decode program cache has a
-    labeled series after traffic (spec engine adds the verify family)."""
-    eng = _tiny_engine(decode_chunk=4, decode_pipeline=2, spec_decode=4)
-    import numpy as np
+    labeled series after traffic (a constrained request adds the dfa
+    family)."""
+    from quorum_tpu.constrain import compile_response_format
+    from quorum_tpu.engine.tokenizer import ByteTokenizer
 
-    bias = np.zeros((eng.spec.vocab_size,), np.float32)
-    bias[7] = 1e9  # forced-periodic stream: prompt-lookup drafting engages
-    req = eng.submit([7, 7, 7, 7], max_new_tokens=16, sampler=_greedy(),
-                     logit_bias=bias)
-    toks = list(eng.stream_results(req))
-    assert len(toks) == 16
-    assert eng.n_spec_turns > 0
+    eng = _tiny_engine(decode_chunk=4, decode_pipeline=2)
+    tok = ByteTokenizer(eng.spec.vocab_size)
+    wildcard = compile_response_format(
+        {"type": "regex", "pattern": "[\\x00-\\xff]*"}, tok,
+        eng.spec.vocab_size)
+    for grammar in (None, wildcard):
+        req = eng.submit([7, 7, 7, 7], max_new_tokens=16, sampler=_greedy(),
+                         eos_id=tok.eos_id if grammar is not None else None,
+                         grammar=grammar)
+        assert list(eng.stream_results(req))
     compiled = budget.decode_families(eng._decode_cache)
-    assert "verify" in compiled
+    assert compiled == {"plain", "dfa"}
     observed = {dict(k).get("family")
                 for k in obs.DISPATCH_DEVICE_SECONDS.snapshot()}
     missing = compiled - observed

@@ -14,13 +14,12 @@ retired C=1/K=1 coupling is measurable where it still applies).
 
 Slow tier: the full acceptance pins at ``decode_pipeline=4 ×
 decode_loop=4`` across the greedy / sampled / EOS-mid-chunk /
-constrained / members / spec / prefix-restore legs, each against the
+constrained / members / prefix-restore legs, each against the
 drain-based engine.
 """
 
 import asyncio
 
-import numpy as np
 import pytest
 
 from quorum_tpu import faults
@@ -307,33 +306,6 @@ def test_zero_drain_members_pin():
         eng_m.shutdown()
         for e in singles:
             e.shutdown()
-
-
-@pytest.mark.slow
-def test_zero_drain_spec_decode_pin():
-    """Speculative decoding composes: a forced-periodic stream speculates
-    on both engines (ring-resident verify turns entering the same ring
-    the injections land on) and the zero-drain stream equals the
-    drain-based one token for token."""
-    kw = dict(decode_chunk=4, n_slots=2, decode_pipeline=4,
-              prefill_chunk=16, spec_decode=4, seed=11340)
-    eng_c = InferenceEngine(TINY, **kw)
-    eng_z = InferenceEngine(TINY, zero_drain=True, **kw)
-    try:
-        bias = np.zeros((TINY.vocab_size,), np.float32)
-        bias[7] = 1e9
-
-        def run(eng):
-            req = eng.submit([7, 7, 7, 7], max_new_tokens=16,
-                             sampler=GREEDY, logit_bias=bias)
-            return list(eng.stream_results(req))
-
-        assert run(eng_z) == run(eng_c)
-        assert eng_z.n_spec_turns > 0
-        assert eng_z.admission_stall_s == 0.0
-    finally:
-        eng_c.shutdown()
-        eng_z.shutdown()
 
 
 @pytest.mark.slow
