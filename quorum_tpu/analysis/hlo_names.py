@@ -5,8 +5,8 @@ A profile names device operations as XLA numbered them (``fusion.277``,
 without its metadata. The metadata is in XLA's text of the optimized
 module: every instruction there has ``metadata={op_name="jit(chunk)/…/
 mlp/dot_general"}``, and the ``jax.named_scope`` parts of
-``models/transformer.py``, ``models/patterned.py`` and ``models/ssm.py``
-(:data:`PARTS`) are components of that path. This
+``models/transformer.py``, ``models/patterned.py``, ``models/ssm.py`` and
+``models/shortconv.py`` (:data:`PARTS`) are components of that path. This
 module reads that text, from ``compiled.as_text()`` or from the files an
 ``--xla_dump_to`` run leaves behind::
 
@@ -48,8 +48,13 @@ LATENT = ("attn.latent_q", "attn.latent_kv", "attn.index", "attn.select",
 # output projection
 MIXER = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.step", "ssm.gate_norm",
          "ssm.out_proj")
+# a short-convolution layer (models/shortconv.py) adds: the fused input
+# projection and the input gate, the depthwise taps over the carried tail and
+# the output gate, the output projection
+SHORTCONV = ("conv.in_proj", "conv.taps", "conv.out_proj")
 PARTS = ("embed", "norm", "attn.qkv", "attn.cache_write", "attn.core",
-         "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT + MIXER
+         "attn.out", "mlp", "lm_head", "sample") + PATTERNED + LATENT + MIXER \
+    + SHORTCONV
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(

@@ -1200,12 +1200,14 @@ class InferenceEngine:
             # Slot-resident prefix reuse reads a row's first positions back:
             # a window layer's ring holds the row's last ones.
             self.prefix_cache = False
-        if self.spec.ssm_heads:
-            # A spec with a mixer (models/ssm.py) keeps a recurrent state a
-            # row and layer beside K and V. The state is the whole of what
-            # the row has read: it has no prefix to take up again, cannot be
-            # taken back a position, and is not copied or split by what
-            # copies or splits the K/V rectangle (ROADMAP.md).
+        if self.spec.row_state:
+            # A row that holds a state: a mixer's recurrence and convolution
+            # tail a layer beside K and V (models/ssm.py), a short
+            # convolution's tail in a layer with no K and V at all
+            # (models/shortconv.py). The state is the whole of what the row
+            # has read: it has no prefix to take up again, cannot be taken
+            # back a position, and is not copied or split by what copies or
+            # splits the K/V rectangle (ROADMAP.md).
             mesh_shape = dict(self.mesh.shape)
             refused = [
                 ("kv_pages=1", self.kv_pages,
@@ -1228,10 +1230,10 @@ class InferenceEngine:
             for what, asked, why in refused:
                 if asked:
                     raise ValueError(
-                        f"{what} does not compose with a spec that has a "
-                        f"mixer ({self.spec.family}, ssm_heads="
-                        f"{self.spec.ssm_heads}): {why} the recurrent state "
-                        "a row keeps beside its K and V")
+                        f"{what} does not compose with a spec whose rows "
+                        f"hold a state ({self.spec.family}): {why} what a "
+                        "row keeps beside its K and V (a mixer's recurrent "
+                        "state, a short convolution's tail)")
             # Slot-resident prefix reuse starts a row at a position past 0:
             # the state there is the last tenant's, at its own last position.
             self.prefix_cache = False
@@ -1572,9 +1574,12 @@ class InferenceEngine:
         rep = self.device_report = device_report(self.mesh)
         logger.info(
             "engine up: d_model=%d layers=%d members=%d slots=%d max_seq=%d "
+            "row_state=%s "
             "on platform=%s device_kind=%r device_count=%d mesh=%s",
             self.spec.d_model, self.spec.n_layers, self.members,
-            self.n_slots, self.spec.max_seq, rep["platform"],
+            self.n_slots, self.spec.max_seq,
+            "%dB" % self._state_row_bytes if self.spec.row_state else "none",
+            rep["platform"],
             rep["device_kind"], rep["device_count"], rep["mesh"])
 
     @property
@@ -1694,11 +1699,12 @@ class InferenceEngine:
         materialization or transfer of the multi-GB buffer.
         """
         self._ck, self._cv = self._zero_cache(self._cache_sh)
-        # what one row holds of a mixer's state and tail, all layers: a
-        # constant of the cache's shape (a ``prefill`` span's ``state_bytes``)
+        # what one row holds of state (a mixer's state and tail, a short
+        # convolution's tail), all layers: a constant of the cache's shape
+        # (a ``prefill`` span's ``state_bytes``)
         self._state_row_bytes = (
             self._kv_cache_bytes()["state"] // self._rows
-            if self.spec.ssm_heads else 0)
+            if self.spec.row_state else 0)
         # the expert counters ride the cache, so they start over with it;
         # snapshots of the cache that was are not to be counted
         self._moe_last = None
@@ -3751,6 +3757,10 @@ class InferenceEngine:
             # less the rows the grouped products say they took
             "moe_dropped_picks_total": int(
                 rest[:, MOE_STATS.index("dropped")].sum()),
+            # rows the expert products multiplied: tiles x 128 on the
+            # grouped path, held experts x counted rows on the dense one
+            "moe_tile_rows_total": int(
+                rest[:, MOE_STATS.index("tile_rows")].sum()),
             # per layer the most-picked held expert's count, summed over the
             # layers: over moe_picks_held_total / experts held it is how
             # uneven the load on this chip's experts is
@@ -3782,11 +3792,11 @@ class InferenceEngine:
 
     def _state_carried(self, carried: bool) -> dict:
         """A ``prefill`` span's ``state_carried`` and ``state_bytes``, where
-        the spec has a mixer: whether the row's recurrent state went from
-        one program to the next on the way (a chunked admission's segments
-        and its register's decode step; a single-shot admit makes it in one
-        program), and the bytes of state and tail the row holds."""
-        if not self.spec.ssm_heads:
+        a row holds a state: whether it went from one program to the next
+        on the way (a chunked admission's segments and its register's
+        decode step; a single-shot admit makes it in one program), and the
+        bytes of state the row holds."""
+        if not self.spec.row_state:
             return {}
         return {"state_carried": carried,
                 "state_bytes": self._state_row_bytes}
@@ -3802,9 +3812,11 @@ class InferenceEngine:
 
         ck, cv = getattr(self, "_ck", None), getattr(self, "_cv", None)
         if isinstance(ck, KindKV):
+            # ``state``: the short convolutions' tails, where there are any
             return {"full": nbytes(ck.full) + nbytes(cv.full),
                     "window": nbytes(ck.window) + nbytes(cv.window),
-                    "index": nbytes(ck.index)}
+                    "index": nbytes(ck.index),
+                    **({"state": nbytes(ck.conv)} if ck.conv else {})}
         if isinstance(ck, StateKV):
             # ``state``: the mixer's state and convolution tail, per layer
             # and row, beside the K and V rectangles
@@ -5072,7 +5084,7 @@ class InferenceEngine:
             # token in segments: the register's decode step runs that token
             # again (for K and V a second, equal write; a state would take
             # the position twice).
-            end = len(prompt) - (1 if self.spec.ssm_heads else 0)
+            end = len(prompt) - (1 if self.spec.row_state else 0)
             seg = prompt[adm.offset : min(adm.offset + self.prefill_chunk,
                                           end)]
             bucket = prefill_bucket(len(seg), self.prefill_chunk)

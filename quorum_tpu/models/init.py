@@ -34,7 +34,9 @@ def init_params(spec: ModelSpec, seed: int = 0) -> Params:
 
 def init_patterned_from_key(spec: ModelSpec, key) -> Params:
     """A patterned spec's weights (models/patterned.py): one dict per layer
-    under ``layers``, every leaf with a leading dim of 1.
+    under ``layers``, every leaf with a leading dim of 1; where the spec has
+    whole periods (``spec.periods``), one dict a slot of the period, every
+    leaf with a leading dim of their count (``patterned.period_key``).
 
     The scales give the residual stream the proportions of a deep model and
     not of a shallow toy, whatever ``n_layers`` is cut to: embedding rows at
@@ -49,7 +51,17 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
     in pre-norm blocks it is on ``wo``, ``w_down`` and the experts'
     down-projections, and the norms' gains are 1.
     The router's selection bias (``spec.init_bias_dev`` times a normal) is
-    small and non-zero, so that the score and the score-plus-bias differ."""
+    small and non-zero, so that the score and the score-plus-bias differ.
+    A short convolution's three matrices are at ``1/sqrt(fan_in)`` like the
+    rest (the taps' fan-in is their count), its output product carrying the
+    sub-layer's scale. Where the head is the embedding (``tied_lm_head``)
+    the final norm's gain is ``1/sqrt(D)`` with a random sign a channel and
+    not 1: the embedding's rows stay of unit rms and the larger part of the
+    float32 stream, as in the other patterned specs, the logits come out of
+    unit deviation, and the signs take the token just read out of them;
+    with a gain of one sign the stream's own embedding row would meet itself
+    in the head and every position's logits would be one spike of sqrt(D)
+    deviations on that token, which no fault moves."""
     dt = jnp.dtype(spec.dtype)
     D, V = spec.d_model, spec.vocab_size
     H = spec.n_heads * spec.head_dim
@@ -116,12 +128,24 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
                 w_iw=w(next(ks), 1, D, ih, fan_in=D))
         return out_w
 
+    def short_conv(ks) -> dict:
+        """models/shortconv.py's leaves: ``conv_in``'s columns are the input
+        gate, the output gate and the convolution's input, in that order."""
+        taps = spec.conv_taps
+        return {
+            "conv_in": w(next(ks), 1, D, 3 * D, fan_in=D),
+            "conv_w": w(next(ks), 1, taps, D, fan_in=taps),
+            "conv_out": w(next(ks), 1, D, D, fan_in=D, scale=out),
+        }
+
     def layer(i: int, key) -> dict:
         ks = iter(jax.random.split(key, 16))
         out_w = {
             "attn_norm_w": jnp.full((1, D), norm_gain, dt),
             "mlp_norm_w": jnp.full((1, D), norm_gain, dt),
-            **(latent_heads(i, next(ks)) if latent else kv_heads(ks)),
+            **(latent_heads(i, next(ks)) if latent
+               else short_conv(ks) if spec.attn_kind(i) == "C"
+               else kv_heads(ks)),
         }
         if i < spec.first_dense:
             out_w.update(
@@ -146,13 +170,25 @@ def init_patterned_from_key(spec: ModelSpec, key) -> Params:
         return out_w
 
     k_emb, k_head, k_layers = jax.random.split(key, 3)
+    keys = jax.random.split(k_layers, spec.n_layers)
+    start, length, count = spec.periods
+    layers = {f"{i:02d}": layer(i, keys[i]) for i in range(start)}
+    from quorum_tpu.models.patterned import period_key
+
+    for i in range(start, start + length):
+        # a slot of the period: its layers' leaves stacked ``[count, ...]``,
+        # each layer from its own key, as written out
+        layers[period_key(i, count)] = jax.vmap(
+            lambda k, i=i: jax.tree.map(lambda a: a[0], layer(i, k)))(
+            keys[i::length])
     return {
         "tok_emb": jax.random.normal(k_emb, (V, D), jnp.float32).astype(dt),
-        "final_norm_w": jnp.ones((D,), dt),
+        "final_norm_w": ((jax.random.rademacher(k_head, (D,), jnp.float32)
+                          * D ** -0.5).astype(dt) if spec.tied_lm_head
+                         else jnp.ones((D,), dt)),
         "lm_head": (None if spec.tied_lm_head
                     else w(k_head, D, V, fan_in=D)),
-        "layers": {f"{i:02d}": layer(i, k) for i, k in enumerate(
-            jax.random.split(k_layers, spec.n_layers))},
+        "layers": layers,
     }
 
 

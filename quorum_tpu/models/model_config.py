@@ -37,7 +37,7 @@ class Latent(NamedTuple):
 
 @dataclass(frozen=True)
 class ModelSpec:
-    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe" | "dots3_note" | "falcon_h1"
+    family: str = "llama"          # "gpt2" | "llama" | "mixtral" | "gemma" | "exaone_moe" | "dots3_note" | "falcon_h1" | "lfm2_moe"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -51,18 +51,25 @@ class ModelSpec:
     # patterned families): one letter per layer, repeated over the depth
     # (or written out for every layer, where the model's list is no repeated
     # period), "L" a sliding-window layer (the last ``sliding_window``
-    # positions, kept in a ring of ``ring`` positions per row) and "G" a
-    # full-attention layer (every position). A patterned spec runs
+    # positions, kept in a ring of ``ring`` positions per row), "G" a
+    # full-attention layer (every position) and "C" a gated short
+    # convolution (models/shortconv.py; family "lfm2_moe"): no attention,
+    # no positions, a depthwise causal convolution of ``conv_taps`` taps
+    # between two elementwise gates, whose cache is the row's last
+    # ``conv_taps - 1`` inputs whatever its length. A patterned spec runs
     # models/patterned.py: per-layer weights, a cache per layer kind, one
     # depth loop and one expert layer for its two families, whose
     # attention conventions are written in and not chosen by the spec: K/V
     # heads, RMSNorm over each q and k head and no rotary embedding on full
-    # layers; or, with ``kv_lora_rank`` set, latent attention
+    # layers unless ``rope_full``; or, with ``kv_lora_rank`` set, latent
+    # attention
     # (models/latent.py). ``post_norm``: a patterned spec's blocks normalise
     # what a sub-layer gives before the add (``x + norm(f(x))``, exaone_moe)
     # and not what it takes (``x + f(norm(x))``).
     layer_pattern: str = ""
     post_norm: bool = False
+    rope_full: bool = False
+    conv_taps: int = 3
     # Latent attention (``kv_lora_rank`` > 0; family "dots3_note"): a query
     # latent of ``q_lora_rank`` and a key/value latent of ``kv_lora_rank``
     # per position, both normalised; ``n_heads`` heads of ``qk_nope_head_dim``
@@ -178,6 +185,28 @@ class ModelSpec:
         return self.experts_held or self.n_experts
 
     @property
+    def row_state(self) -> bool:
+        """Whether a row holds a state beside (or in place of) its cached
+        positions: a mixer's recurrence and convolution tail, a short
+        convolution's tail. Such a row has no prefix to take up again and
+        cannot be taken back a position; the engine's rules for it
+        (engine/engine.py) read this and nothing else."""
+        return bool(self.ssm_heads
+                    or self.layer_pattern and self.layers_of("C"))
+
+    @property
+    def kv_positions_minor(self) -> bool:
+        """Whether a patterned spec's full layers keep K and V ``[slots, K,
+        head_dim, max_seq]``, the positions in the chip's 128 lanes, and not
+        ``[slots, K, max_seq, head_dim]``: where the heads are narrower than
+        the lanes (lfm2's 64). With such a head in the lanes the v5e
+        compiler keeps a side positions-minor for attention and head-minor
+        for the decode step's write loop, and copies every full side twice
+        a step (PERF.md section 6, PR 52)."""
+        return bool(self.layer_pattern and not self.kv_lora_rank
+                    and self.head_dim < 128)
+
+    @property
     def ring(self) -> int:
         """Positions a window layer keeps per row: the power of two at or
         above ``sliding_window``."""
@@ -211,6 +240,26 @@ class ModelSpec:
         return [i for i in range(self.n_layers) if self.attn_kind(i) == kind]
 
     @property
+    def periods(self) -> tuple[int, int, int]:
+        """``(start, length, count)``: the layers from ``first_dense`` on as
+        ``count`` >= 2 whole repeats of their first ``length`` kinds (the
+        shortest such), where those are full-attention and short-convolution
+        layers only; ``(n_layers, 0, 0)`` where they are not. Such layers
+        are held stacked, a period's slot a leaf ``[count, ...]``, and run
+        as one ``lax.scan`` over the periods (models/patterned.py): a third
+        of the program to compile at three periods. A window layer's ring
+        and a latent layer's rows are not addressed by period yet."""
+        start, n = self.first_dense, self.n_layers
+        kinds = [self.attn_kind(i) for i in range(start, n)] \
+            if self.layer_pattern and not self.kv_lora_rank else []
+        if set(kinds) <= {"G", "C"}:
+            for length in range(1, len(kinds) // 2 + 1):
+                if len(kinds) % length == 0 and kinds == kinds[:length] * (
+                        len(kinds) // length):
+                    return start, length, len(kinds) // length
+        return n, 0, 0
+
+    @property
     def gated_mlp(self) -> bool:
         return self.act in ("swiglu", "geglu")
 
@@ -232,9 +281,11 @@ class ModelSpec:
             assert self.pos == "rope" and self.norm == "rmsnorm"
             assert self.gated_mlp and not self.use_bias
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= {"L", "G"}, (
-                f"layer_pattern {self.layer_pattern!r}: L (window) and G "
-                "(full) only")
+            assert set(self.layer_pattern) <= {"L", "G", "C"}, (
+                f"layer_pattern {self.layer_pattern!r}: L (window), G "
+                "(full) and C (short convolution) only")
+            if "C" in self.layer_pattern:
+                assert self.conv_taps > 1 and not self.kv_lora_rank
             assert self.sliding_window > 0 or "L" not in self.layer_pattern, (
                 "a window layer needs sliding_window > 0")
             assert self.ring <= self.max_seq
@@ -392,6 +443,24 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         mlp_gate_mult=0.1767766952966369,
         mlp_down_mult=0.011160714285714284, lm_head_mult=0.0078125,
     ),
+    # LFM2-8B-A1B (LiquidAI, model_type lfm2_moe, 8.3B of which 1.5B active):
+    # 24 pre-norm blocks on a stream of 2048, the published ``layer_types``
+    # written out: 18 gated short convolutions (3 taps, no bias, no
+    # activation) and 6 full-attention layers (32 / 8 heads of 64, q and k
+    # heads normalised, then rotary, theta 1e6); two leading dense SwiGLUs of
+    # 7168, then 32 sigmoid-routed experts of 1792 (4 picked, a selection
+    # bias, weights normalised, no shared expert); embedding tied to the
+    # head. 16.7 GB in bf16: served as the first of two pipeline stages,
+    # ``?n_layers=14&max_seq=2048`` (docs/tpu_backends.md).
+    "lfm2-8b-a1b": ModelSpec(
+        family="lfm2_moe", vocab_size=65536, d_model=2048, n_layers=24,
+        n_heads=32, n_kv_heads=8, head_dim=64, d_ff=7168, max_seq=4096,
+        rope_theta=1000000.0, layer_pattern="CCGCCCGCCCGCCCGCCCGCCGCC",
+        rope_full=True, conv_taps=3, tied_lm_head=True, n_experts=32,
+        experts_per_token=4, first_dense=2, d_ff_expert=1792,
+        n_shared_experts=0, router_scale=1.0, init_depth=24,
+        post_norm=False, norm_eps=1e-5,
+    ),
     # Scaled-down test/dev presets (CPU-fast, same code paths)
     "gpt2-tiny": _gpt2(vocab_size=512, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, head_dim=16, d_ff=128, max_seq=128),
@@ -405,10 +474,12 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         experts_per_token=2, tied_lm_head=False,
     ),
     # the k-exaone family at a size the CPU runs: a dense layer and two
-    # "LLLG" periods, 16 experts of which 4 are held, top-4, window 8
+    # "LLLG" periods, 16 experts of which 4 are held, top-4, window 8; heads
+    # of the model's own 128, so that its full layers keep K and V as the
+    # model's do (``kv_positions_minor``)
     "k-exaone-tiny": ModelSpec(
         family="exaone_moe", vocab_size=512, d_model=64, n_layers=8,
-        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=192, max_seq=128,
+        n_heads=4, n_kv_heads=2, head_dim=128, d_ff=192, max_seq=128,
         sliding_window=8, layer_pattern="LLLG", tied_lm_head=False,
         n_experts=16, experts_per_token=4, first_dense=1, d_ff_expert=32,
         n_shared_experts=1, router_scale=2.5, experts_held=4, init_depth=48,
@@ -430,6 +501,18 @@ MODEL_PRESETS: dict[str, ModelSpec] = {
         swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=50000.0,
         index_n_heads=4, index_head_dim=16, index_topk=16,
         init_bias_dev=0.01,
+    ),
+    # the lfm2_moe family at a size the CPU runs: two leading dense
+    # convolution layers, then two periods "GCC" over expert layers, which
+    # run as a scan (``?n_layers=7`` leaves no whole periods: written out);
+    # 8 experts, all held, top-2
+    "lfm2-moe-tiny": ModelSpec(
+        family="lfm2_moe", vocab_size=512, d_model=64, n_layers=8,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, max_seq=128,
+        rope_theta=1000000.0, layer_pattern="CCGCCGCC", rope_full=True,
+        conv_taps=3, tied_lm_head=True, n_experts=8, experts_per_token=2,
+        first_dense=2, d_ff_expert=32, n_shared_experts=0, router_scale=1.0,
+        init_depth=24, post_norm=False,
     ),
     # the falcon_h1 family at a size the CPU runs: every multiplier off 1
     "falcon-h1-tiny": ModelSpec(

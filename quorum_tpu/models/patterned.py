@@ -30,9 +30,30 @@ spec over to the functions of the same name here:
   - **Expert layers over a held share.** The router scores all ``n_experts``
     in float32 and picks the top k; only the picks that fall on the experts
     held here are computed, beside the shared expert, and no pick is dropped.
-  - The family's conventions, written in and not chosen by the spec:
-    post-norm blocks (``h + norm(f(h))``), RMSNorm over each q and k head,
-    rotary embedding on the window layers only, a sigmoid router.
+  - **Whole periods run as a scan** (``spec.periods``: the layers from
+    ``first_dense`` on, where they are two or more repeats of one sequence
+    of full-attention and short-convolution layers): their weights are held
+    stacked, a period's slot a leaf ``[count, ...]``, and so are their
+    tails; a slot's K and V are one array with the periods' heads side by
+    side, ``[slots, count x K, ...]`` (with the period as a fifth, leading
+    dim the v5e compiler re-lays both sides at a chunk's entry and exit,
+    3.2 GB of temporaries). One ``lax.scan`` over the periods carries the
+    stream and the cache, which every write updates in place at its
+    period's index; a weight is read where it lies, at ``(period, ...)``
+    (as the scan's ``xs`` a slot's 32 experts were copied out for the tile
+    loop every period of every step). The
+    program the compiler sees has one period's layers and not ``count``
+    times them (fourteen written-out layers of lfm2 took 405 s of a cold
+    set-up, PERF.md section 6). Every other spec's loop is written out.
+  - **A layer kind that is not attention** (``"C"``, models/shortconv.py): a
+    gated short convolution in the place of attention and its output
+    product, whose cache is the row's last ``conv_taps - 1`` inputs
+    (``KindKV.conv``), taken at the row's true length, zero where a row
+    starts and kept by a row a decode step may not write.
+  - The families' conventions: RMSNorm over each q and k head and a sigmoid
+    router written in; post-norm blocks (``h + norm(f(h))``) or pre-norm and
+    rotary embedding on the window layers only or on full layers too
+    (``spec.rope_full``) by the spec.
   - **The residual stream is float32**; each sub-layer computes in the spec's
     dtype and its normalised output is added in float32. The router reads the
     float32 stream: with a bfloat16 stream (rounded to 2**-9 at each of 16
@@ -53,7 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from quorum_tpu.models import latent
+from quorum_tpu.models import latent, shortconv
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.ops.attention import attention, decode_attention
 from quorum_tpu.ops.flash_attention import flash_prefill_attention
@@ -74,9 +95,13 @@ DENSE_ROWS = 64
 
 def dense_experts(spec: ModelSpec, rows: int) -> bool:
     """Whether a program of ``rows`` rows runs every held expert over every
-    row: few rows, that under even routing pick at least half of them."""
+    row: few rows, that under even routing pick at least half of them, and
+    expert layers that are written out. Where they are a period's slots
+    (``spec.periods``, which start at the first expert layer) every held
+    expert at once would be a copy of the slot's experts, so an expert is
+    read where it lies, a tile at a time (:meth:`Slot.expert`)."""
     share = 1.0 - (1.0 - spec.experts_per_token / spec.n_experts) ** rows
-    return rows <= DENSE_ROWS and share >= 0.5
+    return not spec.periods[2] and rows <= DENSE_ROWS and share >= 0.5
 
 
 # Rows of a grouped tile: the picks on held experts are sorted by expert into
@@ -87,9 +112,12 @@ def dense_experts(spec: ModelSpec, rows: int) -> bool:
 # 512-token segment that is the dense form's cost again.)
 TILE = 128
 # The counters' columns after the held experts' own: picks made (k a real
-# token), and picks on a held expert that no product computed: held picks
-# less the rows the products say they took.
-STATS = ("picks", "dropped")
+# token), picks on a held expert that no product computed (held picks less
+# the rows the products say they took), and the rows the expert products
+# multiplied: tiles x TILE on the grouped path, held experts x counted rows on
+# the dense one. Held picks less dropped over tile rows is the share of the
+# multiplied rows that were picks.
+STATS = ("picks", "dropped", "tile_rows")
 # Three more where the full layers select what they attend (models/latent.py),
 # summed over those layers into the first row: the positions their queries
 # attended, the positions their histories held, and the positions their
@@ -107,7 +135,9 @@ def stats_of(spec: ModelSpec) -> tuple:
 class KindKV:
     """One side (K or V) of a patterned spec's cache.
 
-    ``full``: one ``[slots, K, max_seq, hd]`` array per full-attention layer.
+    ``full``: one ``[slots, K, max_seq, hd]`` array per full-attention layer
+    (``[slots, K, hd, max_seq]`` where ``spec.kv_positions_minor``: heads
+    narrower than the chip's lanes).
     ``window``: one ``[slots, K, ring, hd]`` ring per window layer.
     ``stats``: on the K side, int32 ``[expert layers, held + len(stats_of)]``,
     counted up by every program since the cache was made: picks per held
@@ -116,12 +146,21 @@ class KindKV:
     index_head_dim]`` per full layer. Such a spec (models/latent.py) has no
     K and V: its ``full`` and ``window`` are the cached latent rows,
     ``[slots, T, latent.row_width]``, all on the first side, and its second
-    side is empty."""
+    side is empty.
+    ``conv``: on the K side, a short convolution's tail, one ``[slots,
+    conv_taps - 1, d_model]`` per ``"C"`` layer: the row's last inputs,
+    whatever its length.
+    Where the spec has whole periods (``spec.periods``), ``full`` and
+    ``conv`` hold the written-out layers' leaves first and then one leaf a
+    slot of the period: that slot's tails stacked, ``[count, slots, ...]``;
+    its K or V with the periods' heads side by side, ``[slots, count x K,
+    ...]``, period ``r``'s at heads ``r K .. (r + 1) K``."""
 
     full: tuple
     window: tuple
     stats: Any = None
     index: tuple = ()
+    conv: tuple = ()
 
 
 def init_cache(spec: ModelSpec, batch: int, dtype=None):
@@ -142,15 +181,29 @@ def init_cache(spec: ModelSpec, batch: int, dtype=None):
                              for _ in spec.layers_of("G"))),
                 KindKV((), ()))
 
-    def side(stats):
-        return KindKV(
-            tuple(jnp.zeros(rows + (spec.max_seq, spec.head_dim), dt)
-                  for _ in spec.layers_of("G")),
-            tuple(jnp.zeros(rows + (spec.ring, spec.head_dim), dt)
-                  for _ in spec.layers_of("L")),
-            stats)
+    start, length, count = spec.periods
 
-    return side(stats), side(None)
+    def leaves(kind: str, shape: tuple) -> tuple:
+        """A leaf a written-out layer of ``kind``, then one a slot of the
+        period for its ``count`` layers: K and V heads side by side, tails
+        stacked."""
+        slot = ((shape[0], count * shape[1]) + shape[2:] if kind == "G"
+                else (count,) + shape)
+        return tuple(jnp.zeros(shape, dt)
+                     for i in spec.layers_of(kind) if i < start) + tuple(
+            jnp.zeros(slot, dt) for j in range(length)
+            if spec.attn_kind(start + j) == kind)
+
+    def side(stats, conv=()):
+        return KindKV(
+            leaves("G", rows + ((spec.head_dim, spec.max_seq)
+                                if spec.kv_positions_minor else
+                                (spec.max_seq, spec.head_dim))),
+            leaves("L", rows + (spec.ring, spec.head_dim)),
+            stats, (), conv)
+
+    return side(stats, leaves(
+        "C", (batch, spec.conv_taps - 1, spec.d_model))), side(None)
 
 
 def layer_of(params, i: int):
@@ -170,11 +223,33 @@ def ring_positions(last, ring: int):
 
 
 @jax.named_scope("attn.cache_write")
-def write_from_start(cache, value, row):
+def write_from_start(cache, value, row, head0=0):
     """A prompt block's K or V (``value`` [B, K, T, ..]) into a full layer's
-    K-major side from position 0 of row ``row`` (B = 1 with a slot)."""
+    K-major side from position 0 of row ``row`` (B = 1 with a slot), its
+    heads from ``head0`` of the leaf's."""
     return lax.dynamic_update_slice(cache, value.astype(cache.dtype),
-                                    (row, 0, 0, 0))
+                                    (row, head0, 0, 0))
+
+
+def _lanes(value, spec: ModelSpec, lanes: int = 128):
+    """A prompt's keys or values ``[B, K, T, hd]`` as a full side takes them
+    from position 0: where the positions are the side's lanes, in whole
+    lanes (zeros behind a bucket under 128; no position behind a prompt is
+    read before a decode step has written it). A narrower block written
+    inside the scan over periods made the v5e compiler re-lay both whole
+    sides around the program (3.2 GB of temporaries at a bucket of 32)."""
+    value = _as_kept(value, spec)
+    if not spec.kv_positions_minor:
+        return value
+    t = value.shape[3]
+    short = min(-(-t // lanes) * lanes, spec.max_seq) - t
+    return jnp.pad(value, ((0, 0),) * 3 + ((0, short),)) if short else value
+
+
+def _as_kept(value, spec: ModelSpec):
+    """New keys or values ``[B, K, T, hd]`` as a full layer's side keeps
+    them."""
+    return jnp.swapaxes(value, 2, 3) if spec.kv_positions_minor else value
 
 
 def ring_write(ring_kv, value, offset, n_valid):
@@ -190,6 +265,40 @@ def ring_write(ring_kv, value, offset, n_valid):
     new = jnp.take_along_axis(value, src[:, None, :, None], axis=2)
     return jnp.where(take[:, None, :, None], new.astype(ring_kv.dtype),
                      ring_kv)
+
+
+def _head0(spec: ModelSpec, lead: tuple):
+    """The first head of a layer's K or V in its leaf: 0, or ``r K`` where
+    the leaf is a period's slot and the layer its ``r``-th (``lead``)."""
+    return lead[0] * spec.n_kv_heads if lead else 0
+
+
+class Slot:
+    """A period's slot of stacked weight leaves, read as the layer at
+    period ``r``: ``slot[name]`` is that layer's leaf, sliced where it lies.
+    :meth:`expert` takes one expert's matrix in one slice, so that no loop
+    over experts is handed a copy of all of them."""
+
+    def __init__(self, stacked: dict, r):
+        self.stacked, self.r = stacked, r
+
+    def __contains__(self, name) -> bool:
+        return name in self.stacked
+
+    def __getitem__(self, name):
+        leaf = self.stacked[name]
+        if isinstance(leaf, dict):
+            return Slot(leaf, self.r)
+        return lax.dynamic_index_in_dim(leaf, self.r, 0, keepdims=False)
+
+    def get(self, name, default=None):
+        return self[name] if self.stacked.get(name) is not None else default
+
+    def expert(self, name, e):
+        leaf = self.stacked[name]
+        return lax.dynamic_slice(
+            leaf, (self.r, e, 0, 0), (1, 1) + leaf.shape[2:]).reshape(
+            leaf.shape[2:])
 
 
 def _rows_of(cache, slot, n: int):
@@ -234,7 +343,7 @@ def _qkv(h, lyr, spec: ModelSpec, kind: str, cos, sin, pos):
     with jax.named_scope("norm"):
         q = rmsnorm(q, lyr["q_norm_w"], spec.norm_eps)
         k = rmsnorm(k, lyr["k_norm_w"], spec.norm_eps)
-    if kind == "L":
+    if kind == "L" or spec.rope_full:
         q, k = _rope(q, cos, sin, pos), _rope(k, cos, sin, pos)
     return q, k, v
 
@@ -294,7 +403,7 @@ def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on):
     matrices and adds the weighted rows to their tokens. ``w_pick`` /
     ``local`` / ``on`` ``[N, k]``: a pick's weight, its expert's index among
     the held, and whether it counts. Returns ``(out [N, D] float32, rows the
-    products took)``."""
+    products took, tiles multiplied)``."""
     n, d = x.shape
     k, held = spec.experts_per_token, spec.held
     p = n * k
@@ -320,6 +429,8 @@ def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on):
         held - 1)
 
     def matrix(name, e):
+        if isinstance(lyr, Slot):
+            return lyr.expert(name, e)
         return lax.dynamic_index_in_dim(lyr[name], e, 0, keepdims=False)
 
     def tile(i, carry):
@@ -339,8 +450,9 @@ def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on):
         return (out.at[tok].add(y * w[:, None], mode="drop"),
                 taken + jnp.sum(tok < n))
 
-    return lax.fori_loop(0, ends[-1], tile,
-                         (jnp.zeros((n, d), jnp.float32), jnp.int32(0)))
+    return lax.fori_loop(
+        0, ends[-1], tile,
+        (jnp.zeros((n, d), jnp.float32), jnp.int32(0))) + (ends[-1],)
 
 
 def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
@@ -369,9 +481,11 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
         if dense if dense is not None else dense_experts(spec, n):
             w_held = jnp.einsum("nk,nke->ne", w_pick, one_hot)
             routed = _experts_dense(xf, lyr, w_held)
+            tile_rows = jnp.sum(ok) * spec.held
         else:
-            routed, computed = _experts_grouped(xf, lyr, spec, w_pick, local,
-                                                on)
+            routed, computed, tiles = _experts_grouped(xf, lyr, spec, w_pick,
+                                                       local, on)
+            tile_rows = tiles * TILE
     out = routed.astype(x.dtype).reshape(b, t, d)
     if spec.n_shared_experts:
         with jax.named_scope("moe.shared"):
@@ -379,7 +493,8 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
     counts = jnp.concatenate([
         per_expert,
         (jnp.sum(ok) * spec.experts_per_token).astype(jnp.int32)[None],
-        (held_picks - computed)[None]])
+        (held_picks - computed)[None],
+        tile_rows.astype(jnp.int32)[None]])
     return out, counts
 
 
@@ -394,15 +509,18 @@ def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list):
 
 
 def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
-            attend, token_ok, keys=()):
+            attend, token_ok, keys=(), conv=None):
     """The depth loop, written out, shared by the three served paths and the
-    two families: ``attend(h, lyr, kind, leaves) -> (attention output,
-    leaves)`` is what differs between them, ``leaves`` the layer's own of the
-    cache: ``(K, V)``, or a latent spec's ``(rows, index keys)`` and
-    ``(ring,)``. ``x`` is the float32 stream; returns it with the two
-    caches, the first side's counters counted up (``keys``: what a latent
-    spec's ``attend`` leaves there of :data:`DSA_STATS`, a triple a full
-    layer)."""
+    families: ``attend(h, lyr, kind, leaves) -> (attention output, leaves)``
+    is what differs between them, ``leaves`` the layer's own of the cache:
+    ``(K, V)``, or a latent spec's ``(rows, index keys)`` and ``(ring,)``;
+    ``conv(h, lyr, leaf, lead) -> (sub-layer output, leaf)`` is a ``"C"``
+    layer's whole first sub-layer over its tail leaf (:func:`_conv_of`);
+    ``lead``, given to ``attend`` too where it is not empty, is the index of
+    the layer in leaves that are a period's slot. ``x`` is the
+    float32 stream; returns it with the two caches, the first side's
+    counters counted up (``keys``: what a latent spec's ``attend`` leaves
+    there of :data:`DSA_STATS`, a triple a full layer)."""
     from quorum_tpu.models import transformer as tr
 
     latent = bool(spec.kv_lora_rank)
@@ -410,24 +528,64 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
                "L": [(ring,) for ring in cache_k.window]} if latent else
               {"G": list(zip(cache_k.full, cache_v.full)),
                "L": list(zip(cache_k.window, cache_v.window))})
-    seen = {"G": 0, "L": 0}
-    counts: list = []
-    for i in range(spec.n_layers):
-        lyr = layer_of(params, i)
+    caches["C"] = list(cache_k.conv)
+    if cache_k.conv:
+        shortconv.log_conv_path(spec, x.shape)
+    start, length, n_periods = spec.periods
+    seen = {"G": 0, "L": 0, "C": 0}
+
+    def layer(x, lyr, i: int, j: int, lead: tuple, counts: list):
+        """Layer ``i``, its cache leaves the ``j``-th of its kind (at index
+        ``lead`` of them, where they are a period's slot)."""
         kind = spec.attn_kind(i)
-        j, of_kind = seen[kind], caches[kind]
-        seen[kind] += 1
+        of_kind = caches[kind]
 
         def attn(h):
-            out, of_kind[j] = attend(h, lyr, kind, of_kind[j])
+            if kind == "C":
+                out, of_kind[j] = conv(h, lyr, of_kind[j], lead)
+                return out
+            out, of_kind[j] = (attend(h, lyr, kind, of_kind[j], lead) if lead
+                               else attend(h, lyr, kind, of_kind[j]))
             return tr._attn_out(out, lyr, jnp.dtype(spec.dtype))
 
         x = _sub(x, lyr["attn_norm_w"], attn, spec)
-        x = _sub(x, lyr["mlp_norm_w"],
-                 lambda h: _mlp(h, lyr, spec, i, token_ok, counts), spec)
+        return _sub(x, lyr["mlp_norm_w"],
+                    lambda h: _mlp(h, lyr, spec, i, token_ok, counts), spec)
+
+    counts: list = []
+    for i in range(start):
+        kind = spec.attn_kind(i)
+        x = layer(x, layer_of(params, i), i, seen[kind], (), counts)
+        seen[kind] += 1
+    moe = jnp.stack(counts) if counts else None
+    if n_periods:
+        slots = []  # a slot's (layer index, index among its kind's leaves)
+        for j in range(length):
+            kind = spec.attn_kind(start + j)
+            slots.append((start + j, seen[kind]))
+            seen[kind] += 1
+        names = ("G", "C")
+
+        def period(carry, r):
+            x, kept = carry
+            for kind, leaves in zip(names, kept):
+                caches[kind] = list(leaves)
+            counted: list = []
+            for i, j in slots:
+                lyr = Slot(params["layers"][period_key(i, n_periods)], r)
+                x = layer(x, lyr, i, j, (r,), counted)
+            return (x, tuple(tuple(caches[kind]) for kind in names)), \
+                jnp.stack(counted)
+
+        (x, kept), per = lax.scan(
+            period, (x, tuple(tuple(caches[kind]) for kind in names)),
+            jnp.arange(n_periods))
+        for kind, leaves in zip(names, kept):
+            caches[kind] = list(leaves)
+        per = per.reshape((-1,) + per.shape[2:])      # layer order
+        moe = per if moe is None else jnp.concatenate([moe, per])
     stats = cache_k.stats
-    if counts and stats is not None:
-        moe = jnp.stack(counts)
+    if moe is not None and stats is not None:
         if keys:
             moe = jnp.pad(moe, ((0, 0), (0, len(DSA_STATS)))).at[
                 0, -len(DSA_STATS):].add(sum(keys).astype(jnp.int32))
@@ -439,8 +597,24 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
     if latent:
         return (x, KindKV(of("G", 0), of("L", 0), stats, of("G", 1)),
                 KindKV((), ()))
-    return (x, KindKV(of("G", 0), of("L", 0), stats),
+    return (x, KindKV(of("G", 0), of("L", 0), stats, (), tuple(caches["C"])),
             KindKV(of("G", 1), of("L", 1), None))
+
+
+def period_key(i: int, count: int) -> str:
+    """The key of ``params["layers"]`` that holds layer ``i`` and the same
+    slot of the ``count - 1`` periods after it, stacked."""
+    return f"{i:02d}x{count}"
+
+
+def _conv_of(spec: ModelSpec, row, n_valid, fresh):
+    """``_layers``' ``conv`` of one program: the short convolution over rows
+    ``row ..`` of a layer's tail leaf, ``n_valid`` real positions a row, from
+    zeros where ``fresh``."""
+    def conv(h, lyr, leaf, lead=()):
+        return shortconv.rows(h, lyr, spec, leaf, row, n_valid, fresh, lead)
+
+    return conv
 
 
 def _scope(kind: str):
@@ -454,7 +628,8 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
             slot=None):
     """Single-shot admission: attention over the prompt itself, a full layer's
     keys and values written from position 0, a window layer's last ring's
-    worth written into its ring. As transformer.prefill."""
+    worth written into its ring, a short convolution's tail taken at the
+    prompt's true end, from zeros. As transformer.prefill."""
     from quorum_tpu.models import transformer as tr
 
     b, t = tokens.shape
@@ -466,7 +641,7 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
     zero = jnp.zeros((b,), jnp.int32)
     keys: list = []
 
-    def attend(h, lyr, kind, leaves):
+    def attend(h, lyr, kind, leaves, lead=()):
         ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         with jax.named_scope("attn.core"), _scope(kind):
@@ -475,8 +650,9 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
                 window=spec.sliding_window if kind == "L" else 0)
         with jax.named_scope("attn.cache_write"):
             if kind == "G":
-                return out, (write_from_start(ck, k, row),
-                             write_from_start(cv, v, row))
+                h0 = _head0(spec, lead)
+                return out, (write_from_start(ck, _lanes(k, spec), row, h0),
+                             write_from_start(cv, _lanes(v, spec), row, h0))
             return out, tuple(
                 lax.dynamic_update_slice_in_dim(
                     c, ring_write(_rows_of(c, row, b), new, zero, lengths),
@@ -485,8 +661,9 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
 
     if spec.kv_lora_rank:
         attend = latent.prefill_attend(spec, pos, lengths, row, keys)
-    x, cache_k, cache_v = _layers(params, spec, x, cache_k, cache_v, attend,
-                                  token_ok, keys=keys)
+    x, cache_k, cache_v = _layers(
+        params, spec, x, cache_k, cache_v, attend, token_ok, keys=keys,
+        conv=_conv_of(spec, row, lengths, True))
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return _head(params, spec, last), cache_k, cache_v
 
@@ -497,8 +674,10 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
     layer writes the segment and attends over the row's first ``history``
     positions; a window layer attends over its ring as the segments before
     left it (the up to window - 1 positions before ``offset``) and the
-    segment itself, then writes the segment's real positions into the ring.
-    As transformer.prefill_segment."""
+    segment itself, then writes the segment's real positions into the ring;
+    a short convolution starts from the tail the segment before left (from
+    zeros at offset 0) and leaves its own at the segment's last real
+    position. As transformer.prefill_segment."""
     from quorum_tpu.models import transformer as tr
 
     _, t = tokens.shape
@@ -511,20 +690,24 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
     off1, valid1 = offset[None], n_valid[None]
     keys: list = []
 
-    def attend(h, lyr, kind, leaves):
+    def attend(h, lyr, kind, leaves, lead=()):
         ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         if kind == "G":
+            lanes, h0 = spec.kv_positions_minor, _head0(spec, lead)
+            at = (slot, h0, 0, offset) if lanes else (slot, h0, offset, 0)
             with jax.named_scope("attn.cache_write"):
                 ck = lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (slot, 0, offset, 0))
+                    ck, _as_kept(k, spec).astype(ck.dtype), at)
                 cv = lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (slot, 0, offset, 0))
+                    cv, _as_kept(v, spec).astype(cv.dtype), at)
             with jax.named_scope("attn.core"), _scope(kind):
-                size = (1, spec.n_kv_heads, hist, spec.head_dim)
+                size = (1, spec.n_kv_heads) + (
+                    (spec.head_dim, hist) if lanes else (hist, spec.head_dim))
                 out = attention(
-                    q, lax.dynamic_slice(ck, (slot, 0, 0, 0), size),
-                    lax.dynamic_slice(cv, (slot, 0, 0, 0), size), causal)
+                    q, lax.dynamic_slice(ck, (slot, h0, 0, 0), size),
+                    lax.dynamic_slice(cv, (slot, h0, 0, 0), size), causal,
+                    positions_minor=lanes)
             return out, (ck, cv)
         rk, rv = _rows_of(ck, slot, 1), _rows_of(cv, slot, 1)
         with jax.named_scope("attn.core"), _scope(kind):
@@ -541,7 +724,8 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
         attend = latent.segment_attend(spec, pos, offset, n_valid, slot,
                                        hist, keys)
     return _layers(params, spec, x, cache_k, cache_v, attend, token_ok,
-                   keys=keys)[1:]
+                   keys=keys,
+                   conv=_conv_of(spec, slot, valid1, offset == 0))[1:]
 
 
 def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
@@ -557,11 +741,11 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
     hist = (history if history is not None and history < spec.max_seq
             else spec.max_seq)
 
-    def write(caches: tuple, news: tuple, at):
+    def write(caches: tuple, news: tuple, at, lanes: bool = False, head0=0):
         # row by row through scalar starts, a layer's leaves in one loop (the
         # module docstring says why; a loop a side is 0.47 ms a step slower)
         def put(cache, new, r):
-            start = (r, 0, at[r], 0)
+            start = (r, head0, 0, at[r]) if lanes else (r, head0, at[r], 0)
             held = lax.dynamic_slice(cache, start, (1,) + new.shape[1:])
             mine = lax.dynamic_slice_in_dim(new, r, 1, axis=0)
             return lax.dynamic_update_slice(
@@ -577,18 +761,31 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
 
     keys: list = []
 
-    def attend(h, lyr, kind, leaves):
+    def window(cache, lanes: bool, lead: tuple):
+        """A layer's first ``hist`` positions of a full side, every row."""
+        if not lead:
+            return lax.slice_in_dim(cache, 0, hist, axis=3 if lanes else 2)
+        return lax.dynamic_slice(
+            cache, (0, _head0(spec, lead), 0, 0),
+            (b, spec.n_kv_heads) + ((spec.head_dim, hist) if lanes
+                                    else (hist, spec.head_dim)))
+
+    def attend(h, lyr, kind, leaves, lead=()):
         ck, cv = leaves
         q, k, v = _qkv(h, lyr, spec, kind, cos, sin, pos)
         at = lengths if kind == "G" else lengths % spec.ring
+        lanes = kind == "G" and spec.kv_positions_minor
+        if lanes:
+            k, v = _as_kept(k, spec), _as_kept(v, spec)
         with jax.named_scope("attn.cache_write"):
             ck, cv = write((ck, cv),
-                           (k.astype(ck.dtype), v.astype(cv.dtype)), at)
+                           (k.astype(ck.dtype), v.astype(cv.dtype)), at,
+                           lanes, _head0(spec, lead))
         with jax.named_scope("attn.core"), _scope(kind):
             if kind == "G":
                 out = decode_attention(
-                    q, lax.slice_in_dim(ck, 0, hist, axis=2),
-                    lax.slice_in_dim(cv, 0, hist, axis=2), lengths + 1)
+                    q, window(ck, lanes, lead), window(cv, lanes, lead),
+                    lengths + 1, positions_minor=lanes)
             else:
                 out = attention(q, ck, cv, ring_keep)
         return out, (ck, cv)
@@ -596,7 +793,8 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
     if spec.kv_lora_rank:
         attend = latent.decode_attend(spec, lengths, allow, hist, write, keys)
     return _layers(params, spec, x, cache_k, cache_v, attend,
-                   allow[:, None], keys=keys)
+                   allow[:, None], keys=keys,
+                   conv=_conv_of(spec, 0, allow.astype(jnp.int32), False))
 
 
 def decode_step(params, spec: ModelSpec, token, lengths, cache_k, cache_v,

@@ -47,6 +47,7 @@ from jax import lax
 
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.quant import qeinsum
+from quorum_tpu.models.shortconv import causal_taps
 from quorum_tpu.ops.flash_attention import traced_program
 
 logger = logging.getLogger(__name__)
@@ -123,15 +124,8 @@ def _conv(xbc, tail, block, n_valid):
     ``xbc`` [B, T, C], ``tail`` [B, K-1, C] the row's last inputs before this
     program's first position. Returns the activations [B, T, C] and the tail
     after each row's ``n_valid`` real positions (``n_valid`` 0: unchanged)."""
-    taps, t = tail.shape[1] + 1, xbc.shape[1]
-    seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    w = block["ssm_conv_w"].astype(jnp.float32)
-    out = block["ssm_conv_b"].astype(jnp.float32)
-    for k in range(taps):
-        out = out + w[k] * seq[:, k:k + t].astype(jnp.float32)
-    new_tail = jax.vmap(
-        lambda row, n: lax.dynamic_slice_in_dim(row, n, taps - 1, axis=0))(
-        seq, n_valid)
+    out, new_tail = causal_taps(xbc, tail, block["ssm_conv_w"], n_valid,
+                                block["ssm_conv_b"])
     return jax.nn.silu(out).astype(xbc.dtype), new_tail
 
 

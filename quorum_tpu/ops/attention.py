@@ -32,9 +32,10 @@ def attention(
     v: jnp.ndarray,  # [B, K, S_kv, hd]
     mask: jnp.ndarray | None = None,  # broadcastable to [B, 1, 1, S, S_kv], bool (True=keep)
     rows_major: bool = False,  # k/v are [B, S_kv, K, hd]
+    positions_minor: bool = False,  # k/v are [B, K, hd, S_kv]
 ) -> jnp.ndarray:
     """Full attention over the given K/V. Returns [B, H, S, hd]."""
-    kv = "btkd" if rows_major else "bktd"
+    kv = "btkd" if rows_major else "bkdt" if positions_minor else "bktd"
     n_kv = k.shape[2 if rows_major else 1]
     qg = _group_heads(q, n_kv)  # [B, K, G, S, hd]
     scale = q.shape[-1] ** -0.5
@@ -85,11 +86,16 @@ def decode_attention(
     length: jnp.ndarray,  # [B] or scalar: #valid cache entries (incl. current token)
     window: int = 0,
     rows_major: bool = False,
+    positions_minor: bool = False,  # the caches are [B, K, hd, max_seq]
 ) -> jnp.ndarray:
     """One decode step against the KV cache (static max_seq, masked by
     length; ``window`` > 0 restricts to the last ``window`` positions)."""
-    mask = _decode_keep(length, k_cache.shape[1 if rows_major else 2], window)
-    return attention(q, k_cache, v_cache, mask, rows_major=rows_major)
+    mask = _decode_keep(
+        length,
+        k_cache.shape[1 if rows_major else 3 if positions_minor else 2],
+        window)
+    return attention(q, k_cache, v_cache, mask, rows_major=rows_major,
+                     positions_minor=positions_minor)
 
 
 def _decode_keep(length, n_keys: int, window: int) -> jnp.ndarray:

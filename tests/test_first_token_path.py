@@ -360,7 +360,7 @@ def test_profile_holds_engine_phases_and_returns_soon(tmp_path):
 # the mixer's)
 SCOPES = tuple(p for p in hlo_names.PARTS
                if p not in hlo_names.PATTERNED + hlo_names.LATENT
-               + hlo_names.MIXER)
+               + hlo_names.MIXER + hlo_names.SHORTCONV)
 
 
 def _lowered(program):

@@ -470,7 +470,7 @@ def test_the_cache_is_latent_rows_index_keys_and_rings():
         (5, spec.max_seq, spec.index_head_dim)] * 3
     assert [a.shape for a in ck.window] == [
         (5, spec.ring, latent.row_width(w))] * 3
-    assert ck.stats.shape == (5, spec.held + 5)
+    assert ck.stats.shape == (5, spec.held + 6)  # picks, dropped, tile rows, 3 of the selection
     assert jax.tree.leaves(cv) == []
 
 

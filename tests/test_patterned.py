@@ -216,7 +216,8 @@ def test_no_pick_is_dropped_when_every_token_picks_the_same_experts(
     dense, _ = patterned.moe_layer(x, lyr, spec, ok, dense=True)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
                                atol=1e-5)
-    assert np.asarray(counts).tolist() == [128] * 4 + [128 * 4, 0]
+    # four tiles of 128 rows, every row a pick
+    assert np.asarray(counts).tolist() == [128] * 4 + [128 * 4, 0, 4 * 128]
 
 
 def test_a_tile_the_loop_leaves_out_counts_as_dropped(monkeypatch):
@@ -240,8 +241,9 @@ def test_grouped_experts_are_the_dense_ones_under_even_routing():
     dense, _ = patterned.moe_layer(x, lyr, spec, ok, dense=True)
     np.testing.assert_allclose(np.asarray(grouped)[:, :80],
                                np.asarray(dense)[:, :80], atol=1e-5)
+    # every held expert's picks fit one tile, part-filled
     assert np.asarray(counts)[spec.held:].tolist() == [
-        80 * spec.experts_per_token, 0]
+        80 * spec.experts_per_token, 0, spec.held * patterned.TILE]
 
 
 def test_ring_positions_and_write():
