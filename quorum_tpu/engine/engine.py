@@ -2133,7 +2133,8 @@ class InferenceEngine:
             with tracing_program(f"admit/{bucket}"):
                 logits, ck, cv = prefill(
                     params, spec, tokens, lengths1, ck, cv, slot=slot,
-                    mesh=mesh, sp_impl=self.sp_impl, tp_mesh=tp_mesh)
+                    mesh=mesh, sp_impl=self.sp_impl, tp_mesh=tp_mesh,
+                    sharded=self._sharded)
             # First sampled token: no generated text yet → penalties are
             # zero; only the logit bias applies.
             with jax.named_scope("sample"):
@@ -2399,7 +2400,7 @@ class InferenceEngine:
         def seg(params, tokens, offset, n_valid, slot, ck, cv):
             return prefill_segment(
                 params, spec, tokens, offset, n_valid, ck, cv, slot,
-                history=history)
+                history=history, sharded=self._sharded)
 
         return jax.jit(seg, donate_argnames=("ck", "cv"))
 

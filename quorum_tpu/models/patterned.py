@@ -68,6 +68,7 @@ functions: the engine refuses them for a patterned spec at start-up.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -76,6 +77,7 @@ from jax import lax
 
 from quorum_tpu.models import latent, shortconv
 from quorum_tpu.models.model_config import ModelSpec
+from quorum_tpu.ops import grouped_experts
 from quorum_tpu.ops.attention import attention, decode_attention
 from quorum_tpu.ops.flash_attention import flash_prefill_attention
 from quorum_tpu.ops.norms import rmsnorm
@@ -99,24 +101,48 @@ def dense_experts(spec: ModelSpec, rows: int) -> bool:
     expert layers that are written out. Where they are a period's slots
     (``spec.periods``, which start at the first expert layer) every held
     expert at once would be a copy of the slot's experts, so an expert is
-    read where it lies, a tile at a time (:meth:`Slot.expert`)."""
+    read where it lies, a tile at a time (:func:`_experts_grouped`)."""
     share = 1.0 - (1.0 - spec.experts_per_token / spec.n_experts) ** rows
     return not spec.periods[2] and rows <= DENSE_ROWS and share >= 0.5
 
 
-# Rows of a grouped tile: the picks on held experts are sorted by expert into
-# tiles that each belong to one expert, and as many tiles are multiplied as
-# the picks fill, so the work follows the load whatever its skew and no
-# buffer can overflow. (XLA's ragged product on this chip walks 512-row
+# The most rows of a grouped tile: the picks on held experts are sorted by
+# expert into tiles that each belong to one expert, and as many tiles are
+# multiplied as the picks fill, so the work follows the load whatever its skew
+# and no buffer can overflow. (XLA's ragged product on this chip walks 512-row
 # tiles, one visit a group: at the 30-odd rows a held expert gets of a
-# 512-token segment that is the dense form's cost again.)
+# 512-token segment that is the dense form's cost again.) Where the program
+# is lowered for a TPU one Pallas call a layer walks the tiles
+# (ops/grouped_experts.py): an expert's three matrices are copied to fast
+# memory block by block while the blocks before them multiply, the tiles'
+# rows come out in sorted order and each token gathers its picks'. A loop of
+# XLA's products, three and a scatter-add a turn, walks them elsewhere, and on
+# the chip where the kernel refuses the call (`grouped_experts.refusal`:
+# widths that do not tile, a program partitioned over devices, a layer most of
+# whose experts are held elsewhere).
 TILE = 128
+
+
+def tile_rows(spec: ModelSpec, n: int) -> int:
+    """Rows of a tile for a program of ``n`` rows: four times what even
+    routing gives an expert, as a power of two from 16 (a bfloat16 tile's
+    sublanes) to :data:`TILE`. The walk is bound by the experts' bytes, and
+    a tile's empty rows are read, multiplied and written all the same: 64
+    rows at 8 picks an expert took 1.70 ms a layer through 128-row tiles
+    and 1.29 through 32-row ones as the loop, 1.28 and 1.10 as the kernel;
+    an expert whose picks overflow its tile reads its matrices once more,
+    which at four times the mean a hot expert seldom does (my chip run,
+    PR 53)."""
+    mean = n * spec.experts_per_token / spec.n_experts
+    return min(TILE, max(16, 1 << max(0, math.ceil(math.log2(4 * mean)))))
+
+
 # The counters' columns after the held experts' own: picks made (k a real
 # token), picks on a held expert that no product computed (held picks less
 # the rows the products say they took), and the rows the expert products
-# multiplied: tiles x TILE on the grouped path, held experts x counted rows on
-# the dense one. Held picks less dropped over tile rows is the share of the
-# multiplied rows that were picks.
+# multiplied: tiles x their rows on the grouped path, held experts x counted
+# rows on the dense one. Held picks less dropped over tile rows is the share
+# of the multiplied rows that were picks.
 STATS = ("picks", "dropped", "tile_rows")
 # Three more where the full layers select what they attend (models/latent.py),
 # summed over those layers into the first row: the positions their queries
@@ -276,8 +302,9 @@ def _head0(spec: ModelSpec, lead: tuple):
 class Slot:
     """A period's slot of stacked weight leaves, read as the layer at
     period ``r``: ``slot[name]`` is that layer's leaf, sliced where it lies.
-    :meth:`expert` takes one expert's matrix in one slice, so that no loop
-    over experts is handed a copy of all of them."""
+    What walks the experts takes ``stacked`` and ``r`` themselves and reads
+    one expert where it lies: a slice of every expert of the layer would be
+    a copy of them (:func:`_experts_grouped`)."""
 
     def __init__(self, stacked: dict, r):
         self.stacked, self.r = stacked, r
@@ -293,12 +320,6 @@ class Slot:
 
     def get(self, name, default=None):
         return self[name] if self.stacked.get(name) is not None else default
-
-    def expert(self, name, e):
-        leaf = self.stacked[name]
-        return lax.dynamic_slice(
-            leaf, (self.r, e, 0, 0), (1, 1) + leaf.shape[2:]).reshape(
-            leaf.shape[2:])
 
 
 def _rows_of(cache, slot, n: int):
@@ -396,71 +417,130 @@ def _experts_dense(x, lyr, w_held):
     return jnp.einsum("ne,end->nd", w_held.astype(out.dtype), out)
 
 
-def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on):
+def _experts_grouped(x, lyr, spec: ModelSpec, w_pick, local, on, *,
+                     sharded: bool = False, interpret: bool = False):
     """The picks that fall on held experts, sorted by expert into tiles of
-    :data:`TILE` rows, an expert's picks filling whole tiles of its own; a
-    loop over the tiles that hold any multiplies each by its expert's
-    matrices and adds the weighted rows to their tokens. ``w_pick`` /
-    ``local`` / ``on`` ``[N, k]``: a pick's weight, its expert's index among
-    the held, and whether it counts. Returns ``(out [N, D] float32, rows the
-    products took, tiles multiplied)``."""
+    :func:`tile_rows` rows, an expert's picks filling whole tiles of its own;
+    the tiles that hold any are multiplied by their expert's matrices and each
+    token sums its picks' weighted rows: by ops/grouped_experts.py's kernel
+    where the program is lowered for a TPU and :func:`grouped_experts.refusal`
+    names nothing against it, by a loop of XLA's products elsewhere.
+    ``w_pick`` / ``local`` / ``on`` ``[N, k]``: a pick's weight, its
+    expert's index among the held, and whether it counts. Returns ``(out
+    [N, D] float32, rows the products took, tiles multiplied)``."""
     n, d = x.shape
     k, held = spec.experts_per_token, spec.held
-    p = n * k
+    p, t = n * k, tile_rows(spec, n)
     e_p = jnp.where(on, local, held).reshape(p)             # held: not here
     oh = jax.nn.one_hot(e_p, held, dtype=jnp.int32)            # [P, held]
     rank = jnp.take_along_axis(jnp.cumsum(oh, axis=0) - 1,
                                jnp.minimum(e_p, held - 1)[:, None], 1)[:, 0]
-    tiles_of = -(-jnp.sum(oh, axis=0) // TILE)                 # [held]
+    tiles_of = -(-jnp.sum(oh, axis=0) // t)                    # [held]
     ends = jnp.cumsum(tiles_of)
-    # a token picks an expert once, so at most min(k, held) of its picks
-    # land here; every expert may leave one tile part-filled
-    max_tiles = n * min(k, held) // TILE + held
-    row = ((ends - tiles_of)[jnp.minimum(e_p, held - 1)] * TILE + rank)
-    row = jnp.where(e_p < held, row, max_tiles * TILE)
-    pick_of_row = jnp.full((max_tiles * TILE,), p, jnp.int32).at[row].set(
+    max_tiles = max_tiles_of(spec, n)
+    row = ((ends - tiles_of)[jnp.minimum(e_p, held - 1)] * t + rank)
+    row = jnp.where(e_p < held, row, max_tiles * t)
+    pick_of_row = jnp.full((max_tiles * t,), p, jnp.int32).at[row].set(
         jnp.arange(p, dtype=jnp.int32), mode="drop")
     tok_of_row = jnp.where(pick_of_row < p, pick_of_row // k, n)
-    w_of_row = jnp.where(pick_of_row < p,
-                         w_pick.reshape(p)[jnp.minimum(pick_of_row, p - 1)],
-                         0.0)
     expert_of_tile = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(max_tiles), side="right"),
         held - 1)
+    # the matrices [periods, held, ...] and the period read: a slot's stacked
+    # leaves whole, a written-out layer's own as one period's
+    stacked = isinstance(lyr, Slot)
+    leaves = tuple(lyr.stacked[name] if stacked else lyr[name][None]
+                   for name in ("moe_w_gate", "moe_w_up", "moe_w_down"))
+    r = lyr.r if stacked else 0
 
-    def matrix(name, e):
-        if isinstance(lyr, Slot):
-            return lyr.expert(name, e)
-        return lax.dynamic_index_in_dim(lyr[name], e, 0, keepdims=False)
+    def loop(x, w_pick, leaves, r):
+        """Tile after tile: three products and a scatter-add a turn."""
+        w_of_row = jnp.where(
+            pick_of_row < p, w_pick.reshape(p)[jnp.minimum(pick_of_row,
+                                                           p - 1)], 0.0)
 
-    def tile(i, carry):
-        out, taken = carry
-        e = expert_of_tile[i]
-        tok = lax.dynamic_slice_in_dim(tok_of_row, i * TILE, TILE)
-        w = lax.dynamic_slice_in_dim(w_of_row, i * TILE, TILE)
-        rows = x[jnp.minimum(tok, n - 1)]
-        gate = jnp.dot(rows, matrix("moe_w_gate", e),
-                       preferred_element_type=jnp.float32)
-        up = jnp.dot(rows, matrix("moe_w_up", e),
-                     preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
-        y = jnp.dot(h, matrix("moe_w_down", e),
-                    preferred_element_type=jnp.float32)
-        # an empty row's token is n: its add falls outside and is dropped
-        return (out.at[tok].add(y * w[:, None], mode="drop"),
-                taken + jnp.sum(tok < n))
+        def matrix(leaf, e):
+            return lax.dynamic_slice(
+                leaf, (r, e, 0, 0), (1, 1) + leaf.shape[2:]).reshape(
+                leaf.shape[2:])
 
-    return lax.fori_loop(
-        0, ends[-1], tile,
-        (jnp.zeros((n, d), jnp.float32), jnp.int32(0))) + (ends[-1],)
+        def tile(i, carry):
+            out, taken = carry
+            e = expert_of_tile[i]
+            tok = lax.dynamic_slice_in_dim(tok_of_row, i * t, t)
+            w = lax.dynamic_slice_in_dim(w_of_row, i * t, t)
+            rows = x[jnp.minimum(tok, n - 1)]
+            gate = jnp.dot(rows, matrix(leaves[0], e),
+                           preferred_element_type=jnp.float32)
+            up = jnp.dot(rows, matrix(leaves[1], e),
+                         preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(gate) * up).astype(x.dtype)
+            y = jnp.dot(h, matrix(leaves[2], e),
+                        preferred_element_type=jnp.float32)
+            # an empty row's token is n: its add falls outside and is dropped
+            return (out.at[tok].add(y * w[:, None], mode="drop"),
+                    taken + jnp.sum(tok < n))
+
+        return lax.fori_loop(0, ends[-1], tile,
+                             (jnp.zeros((n, d), jnp.float32), jnp.int32(0)))
+
+    def kernel(x, w_pick, leaves, r):
+        """One call over the tiles, then each token's picks gathered."""
+        y = grouped_experts.grouped_product(
+            x[jnp.minimum(tok_of_row, n - 1)], expert_of_tile, ends[-1],
+            *leaves, r, tile_rows=t, interpret=interpret)
+        # a pick that is not here reads some row and counts as zero
+        mine = y[jnp.minimum(row, max_tiles * t - 1)] * w_pick.reshape(
+            p, 1)
+        out = jnp.sum(jnp.where((e_p < held)[:, None], mine, 0.0).reshape(
+            n, k, d), axis=1)
+        # the picks among the rows of the tiles the grid walked
+        return out, jnp.sum((tok_of_row < n) & (
+            jnp.arange(max_tiles * t) < ends[-1] * t), dtype=jnp.int32)
+
+    args = (x, w_pick, leaves, jnp.asarray(r, jnp.int32))
+    if grouped_experts.refusal(t, d, leaves[0].shape[-1], x.dtype,
+                               held_share=held / spec.n_experts,
+                               sharded=sharded, interpret=interpret):
+        return loop(*args) + (ends[-1],)
+    if interpret:
+        return kernel(*args) + (ends[-1],)
+    return lax.platform_dependent(*args, tpu=kernel, default=loop) + (
+        ends[-1],)
 
 
-def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
+def log_moe_path(spec: ModelSpec, n: int, *, sharded: bool = False,
+                 interpret: bool = False) -> None:
+    """One line a traced program with expert layers: which form its ``n``
+    rows' expert products take and, for the loop, why not the kernel."""
+    t, d, f = tile_rows(spec, n), spec.d_model, spec.d_ff_expert
+    refused = grouped_experts.refusal(
+        t, d, f, spec.dtype, held_share=spec.held / spec.n_experts,
+        sharded=sharded, interpret=interpret)
+    path = ("dense" if dense_experts(spec, n) else
+            "loop" if refused else "kernel")
+    grouped_experts.log_moe_path(
+        path, refused if path == "loop" else "", n, t, max_tiles_of(spec, n),
+        spec.held, d, f, interpret=interpret)
+
+
+def max_tiles_of(spec: ModelSpec, n: int) -> int:
+    """The tiles ``n`` rows can fill: a token picks an expert once, so at
+    most min(k, held) of its picks land here; every held expert may leave
+    one tile part-filled."""
+    return (n * min(spec.experts_per_token, spec.held) // tile_rows(spec, n)
+            + spec.held)
+
+
+def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None,
+              *, sharded: bool = False, interpret: bool = False):
     """An expert layer on x ``[B, T, D]``: the routed part over the experts
     held here plus the shared expert. Returns ``(out, counts)``, the counts
     one row of ``KindKV.stats``; ``token_ok`` ``[B, T]`` keeps padding and
     idle rows out of the counts (and out of the tiles). ``dense`` None
-    chooses by the rows."""
+    chooses by the rows. ``sharded``: the caller's program is partitioned
+    over devices, which the grouped tiles' kernel cannot be; ``interpret``
+    runs that kernel through the Pallas interpreter, for tests."""
     from quorum_tpu.models.transformer import _dense_mlp_core
 
     b, t, d = x.shape
@@ -481,11 +561,12 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
         if dense if dense is not None else dense_experts(spec, n):
             w_held = jnp.einsum("nk,nke->ne", w_pick, one_hot)
             routed = _experts_dense(xf, lyr, w_held)
-            tile_rows = jnp.sum(ok) * spec.held
+            multiplied = jnp.sum(ok) * spec.held
         else:
-            routed, computed, tiles = _experts_grouped(xf, lyr, spec, w_pick,
-                                                       local, on)
-            tile_rows = tiles * TILE
+            routed, computed, tiles = _experts_grouped(
+                xf, lyr, spec, w_pick, local, on, sharded=sharded,
+                interpret=interpret)
+            multiplied = tiles * tile_rows(spec, n)
     out = routed.astype(x.dtype).reshape(b, t, d)
     if spec.n_shared_experts:
         with jax.named_scope("moe.shared"):
@@ -494,22 +575,22 @@ def moe_layer(x, lyr, spec: ModelSpec, token_ok, dense: bool | None = None):
         per_expert,
         (jnp.sum(ok) * spec.experts_per_token).astype(jnp.int32)[None],
         (held_picks - computed)[None],
-        tile_rows.astype(jnp.int32)[None]])
+        multiplied.astype(jnp.int32)[None]])
     return out, counts
 
 
-def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list):
+def _mlp(x, lyr, spec: ModelSpec, i: int, token_ok, counts: list, **how):
     from quorum_tpu.models.transformer import _dense_mlp
 
     if i < spec.first_dense:
         return _dense_mlp(x.astype(jnp.dtype(spec.dtype)), lyr, spec)
-    out, c = moe_layer(x, lyr, spec, token_ok)
+    out, c = moe_layer(x, lyr, spec, token_ok, **how)
     counts.append(c)
     return out
 
 
 def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
-            attend, token_ok, keys=(), conv=None):
+            attend, token_ok, keys=(), conv=None, **how):
     """The depth loop, written out, shared by the three served paths and the
     families: ``attend(h, lyr, kind, leaves) -> (attention output, leaves)``
     is what differs between them, ``leaves`` the layer's own of the cache:
@@ -520,7 +601,8 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
     the layer in leaves that are a period's slot. ``x`` is the
     float32 stream; returns it with the two caches, the first side's
     counters counted up (``keys``: what a latent spec's ``attend`` leaves
-    there of :data:`DSA_STATS`, a triple a full layer)."""
+    there of :data:`DSA_STATS`, a triple a full layer). ``how``: what
+    :func:`moe_layer` is told of its caller (``sharded``, ``interpret``)."""
     from quorum_tpu.models import transformer as tr
 
     latent = bool(spec.kv_lora_rank)
@@ -531,6 +613,8 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
     caches["C"] = list(cache_k.conv)
     if cache_k.conv:
         shortconv.log_conv_path(spec, x.shape)
+    if spec.first_dense < spec.n_layers:
+        log_moe_path(spec, x.shape[0] * x.shape[1], **how)
     start, length, n_periods = spec.periods
     seen = {"G": 0, "L": 0, "C": 0}
 
@@ -550,7 +634,8 @@ def _layers(params, spec: ModelSpec, x, cache_k: KindKV, cache_v: KindKV,
 
         x = _sub(x, lyr["attn_norm_w"], attn, spec)
         return _sub(x, lyr["mlp_norm_w"],
-                    lambda h: _mlp(h, lyr, spec, i, token_ok, counts), spec)
+                    lambda h: _mlp(h, lyr, spec, i, token_ok, counts, **how),
+                    spec)
 
     counts: list = []
     for i in range(start):
@@ -625,7 +710,7 @@ def _scope(kind: str):
 
 
 def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
-            slot=None):
+            slot=None, sharded: bool = False):
     """Single-shot admission: attention over the prompt itself, a full layer's
     keys and values written from position 0, a window layer's last ring's
     worth written into its ring, a short convolution's tail taken at the
@@ -663,13 +748,14 @@ def prefill(params, spec: ModelSpec, tokens, lengths, cache_k, cache_v,
         attend = latent.prefill_attend(spec, pos, lengths, row, keys)
     x, cache_k, cache_v = _layers(
         params, spec, x, cache_k, cache_v, attend, token_ok, keys=keys,
-        conv=_conv_of(spec, row, lengths, True))
+        conv=_conv_of(spec, row, lengths, True), sharded=sharded)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return _head(params, spec, last), cache_k, cache_v
 
 
 def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
-                    cache_k, cache_v, slot, history=None):
+                    cache_k, cache_v, slot, history=None,
+                    sharded: bool = False):
     """Chunked prefill of positions [offset, offset + T) of one slot. A full
     layer writes the segment and attends over the row's first ``history``
     positions; a window layer attends over its ring as the segments before
@@ -725,11 +811,12 @@ def prefill_segment(params, spec: ModelSpec, tokens, offset, n_valid,
                                        hist, keys)
     return _layers(params, spec, x, cache_k, cache_v, attend, token_ok,
                    keys=keys,
-                   conv=_conv_of(spec, slot, valid1, offset == 0))[1:]
+                   conv=_conv_of(spec, slot, valid1, offset == 0),
+                   sharded=sharded)[1:]
 
 
 def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
-                       write_mask=None, history=None):
+                       write_mask=None, history=None, **how):
     """One position per row: a full layer writes at the row's position and
     reads its first ``history``; a window layer writes at position mod ring
     and reads the ring whole. As transformer.decode_step_blocks, but over
@@ -794,15 +881,18 @@ def decode_step_blocks(params, spec: ModelSpec, x, lengths, cache_k, cache_v,
         attend = latent.decode_attend(spec, lengths, allow, hist, write, keys)
     return _layers(params, spec, x, cache_k, cache_v, attend,
                    allow[:, None], keys=keys,
-                   conv=_conv_of(spec, 0, allow.astype(jnp.int32), False))
+                   conv=_conv_of(spec, 0, allow.astype(jnp.int32), False),
+                   **how)
 
 
 def decode_step(params, spec: ModelSpec, token, lengths, cache_k, cache_v,
-                write_mask=None, history=None):
+                write_mask=None, history=None, sharded: bool = False,
+                interpret: bool = False):
     from quorum_tpu.models import transformer as tr
 
     x = tr.decode_token_embed(params, spec, token, lengths)
     x, cache_k, cache_v = decode_step_blocks(
         params, spec, x.astype(jnp.float32), lengths, cache_k, cache_v,
-        write_mask=write_mask, history=history)
+        write_mask=write_mask, history=history, sharded=sharded,
+        interpret=interpret)
     return _head(params, spec, x[:, 0, :]), cache_k, cache_v

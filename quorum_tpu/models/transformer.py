@@ -460,6 +460,7 @@ def prefill(
     sp_impl: str = "ring",  # "ring" | "ulysses" — SP attention strategy
     tp_mesh=None,  # the caller's mesh when heads are sharded over tp > 1
     block_member: int | None = None,  # blocks are stacked [L, M, …]: take m
+    sharded: bool = False,  # the caller's program is partitioned over devices
 ):
     """Process the full prompt; returns (last-token logits [B,V], cache_k, cache_v).
 
@@ -492,7 +493,7 @@ def prefill(
     """
     if spec.layer_pattern:
         return patterned.prefill(params, spec, tokens, lengths, cache_k,
-                                 cache_v, slot=slot)
+                                 cache_v, slot=slot, sharded=sharded)
     b, t = tokens.shape
     cache_row = slot if slot is not None else 0
     if mesh is not None and spec.sliding_window > 0 and sp_impl == "ring":
@@ -582,6 +583,7 @@ def prefill_segment(
     slot: jnp.ndarray,     # scalar int32
     history: int | None = None,  # static: attend over cache[:history] only
     write_gate: jnp.ndarray | None = None,  # scalar bool: False → cache unchanged
+    sharded: bool = False,  # the caller's program is partitioned over devices
 ):
     """Chunked prefill: process prompt positions [offset, offset+T) of one slot.
 
@@ -611,7 +613,7 @@ def prefill_segment(
     if spec.layer_pattern:
         return patterned.prefill_segment(params, spec, tokens, offset,
                                          n_valid, cache_k, cache_v, slot,
-                                         history=history)
+                                         history=history, sharded=sharded)
     b, t = tokens.shape
     hist = spec.max_seq if history is None else min(history, spec.max_seq)
     positions = offset + jnp.arange(t)
@@ -742,7 +744,7 @@ def decode_step(
     if spec.layer_pattern:
         return patterned.decode_step(params, spec, token, lengths, cache_k,
                                      cache_v, write_mask=write_mask,
-                                     history=history)
+                                     history=history, sharded=sharded)
     x = decode_token_embed(params, spec, token, lengths)
     x, cache_k, cache_v = decode_step_blocks(
         params["blocks"], spec, x, lengths, cache_k, cache_v,

@@ -12,6 +12,7 @@ import dataclasses
 import functools
 import json
 import os
+import re
 import sys
 import types
 
@@ -318,6 +319,14 @@ def test_many_rows_group_their_picks_and_few_run_every_expert(model32):
                                 jnp.asarray(lens[:rows]), ck, cv)
         outs[rows] = np.asarray(logits), np.asarray(ck.stats)
     assert np.abs(outs[128][0][:4] - outs[4][0]).max() < TIGHT
+    # the same 128 rows through the tiles' kernel (the Pallas interpreter):
+    # the decode step as a chip serves it, the counters the loop's
+    ck, cv = tr.init_cache(spec, 128)
+    logits, ck, _ = jax.jit(lambda t, n, ck, cv: patterned.decode_step(
+        params, spec, t, n, ck, cv, history=64, interpret=True))(
+            jnp.asarray(tok), jnp.asarray(lens), ck, cv)
+    assert np.abs(np.asarray(logits) - outs[128][0]).max() < TIGHT
+    assert (np.asarray(ck.stats) == outs[128][1]).all()
     names = patterned.stats_of(spec)
     col = {n: spec.held + names.index(n) for n in names}
     few, many = outs[4][1], outs[128][1]
@@ -325,11 +334,14 @@ def test_many_rows_group_their_picks_and_few_run_every_expert(model32):
     assert (few[:, col["picks"]] == 4 * k).all()
     assert (few[:, col["tile_rows"]] == held_experts * 4).all()
     assert (many[:, col["picks"]] == 128 * k).all()
-    assert (many[:, col["tile_rows"]] % patterned.TILE == 0).all()
+    # 128 rows' picks are 32 an expert of sixteen: whole tiles of 128 rows
+    tile = patterned.tile_rows(spec, 128)
+    assert tile == patterned.TILE
+    assert patterned.tile_rows(spec, 4) == 16 < patterned.tile_rows(spec, 32)
+    assert (many[:, col["tile_rows"]] % tile == 0).all()
     # every expert's picks fill whole tiles of its own
     assert (many[:, col["tile_rows"]] >= 128 * k).all()
-    assert (many[:, col["tile_rows"]]
-            <= 128 * k + held_experts * patterned.TILE).all()
+    assert (many[:, col["tile_rows"]] <= 128 * k + held_experts * tile).all()
     assert (many[:, col["dropped"]] == 0).all()
     assert (few[:, col["dropped"]] == 0).all()
 
@@ -435,8 +447,9 @@ def test_the_engine_serves_it_and_counts_its_state():
         assert m["moe_dropped_picks_total"] == 0
         # the period's slots group their picks into tiles whatever the rows
         # (an expert is read where it lies, one at a time); nothing here
-        # fills a tile of 128, so the rows multiplied are many times the picks
-        assert m["moe_tile_rows_total"] % patterned.TILE == 0
+        # fills a tile, so the rows multiplied are many times the picks; a
+        # tile's rows follow the program's, from 16 up
+        assert m["moe_tile_rows_total"] % 16 == 0
         assert m["moe_tile_rows_total"] > 4 * m["moe_picks_held_total"]
     finally:
         eng.shutdown()
@@ -587,6 +600,61 @@ def test_a_conv_program_carries_its_scopes(model32):
         for scope in hlo_names.SHORTCONV:
             assert f"{scope}/" in text, scope
             assert hlo_names.part_of(f"jit(f)/while/body/{scope}/mul") == scope
+
+
+def _location(text: str, ref: str) -> str:
+    """What a ``#locN`` of a lowered module's text stands for, the
+    locations it names in their place."""
+    found = re.search(rf"^{re.escape(ref)} = loc\((.*)\)$", text, re.M)
+    body = found.group(1) if found else ""
+    return body + "".join(_location(text, inner)
+                          for inner in re.findall(r"#loc\d+", body))
+
+
+def test_the_tiles_kernel_carries_the_experts_scope(caplog):
+    """A decode step of the cell's shape (64 rows, layers 2-13 a scan over
+    three periods, published widths), lowered for a TPU without one: a
+    Mosaic call a period's expert slot, each handed the stacked leaves whole
+    and each inside ``moe.experts``, the scope ``hlo_names`` reads a profile
+    by; the program says once which form its tiles take."""
+    caplog.set_level("INFO", logger="quorum_tpu.ops.grouped_experts")
+    spec = resolve_spec("lfm2-8b-a1b", {
+        "n_layers": "14", "max_seq": "256", "vocab_size": "1024"})
+    params = jax.eval_shape(lambda: init_params(spec, 0))
+    ck, cv = jax.eval_shape(lambda: tr.init_cache(spec, 64))
+    rows = jax.ShapeDtypeStruct((64,), jnp.int32)
+    text = jax.jit(lambda p, t, n, ck, cv: tr.decode_step(
+        p, spec, t, n, ck, cv, history=128)).trace(
+            params, rows, rows, ck, cv).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln
+             and "tensor<3x32x2048x1792xbf16>" in ln]
+    assert len(calls) == 4          # the period's four expert slots
+    for line in calls:
+        assert "tensor<3x32x1792x2048xbf16>" in line     # no slice before it
+        where = _location(text, re.search(r"loc\((#loc\d+)\)$",
+                                          line).group(1))
+        assert "moe.experts/" in where and "grouped_experts" in where
+        op_name = re.search(r'"([^"]*moe\.experts/[^"]*)"', where).group(1)
+        assert hlo_names.part_of(op_name) == "moe.experts"
+    # as the compiler's text names the call after optimisation
+    table = hlo_names.instructions(
+        "ENTRY %main.1 (p: f32[2]) -> f32[2] {\n"
+        "  %custom-call.7 = f32[4352,2048]{1,0} custom-call(s32[34]{0} %a), "
+        'custom_call_target="tpu_custom_call", backend_config={"x": {}}, '
+        'metadata={op_name="jit(chunk)/while/body/moe.experts/'
+        'grouped_experts" source_file="x.py"}\n}')
+    assert table == {"custom-call.7": (
+        "custom-call", "jit(chunk)/while/body/moe.experts/grouped_experts")}
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("moe_tiles")]
+    assert len(lines) == 1          # one a program, not one a layer
+    assert "path=kernel" in lines[0] and "rows=64 tile_rows=32" in lines[0]
+    assert "experts=32x[2048, 1792]" in lines[0]
+    # the CPU's lowering of the same program keeps the loop: no Mosaic call
+    assert "tpu_custom_call" not in jax.jit(lambda p, t, n, ck, cv: (
+        tr.decode_step(p, spec, t, n, ck, cv, history=128))).lower(
+            params, rows, rows, ck, cv).as_text()
 
 
 def test_a_traced_program_logs_its_conv_path(model32, caplog):

@@ -33,8 +33,10 @@ N_STEPS = 8
 
 
 def oracle_step_blocks(params, spec, x, lengths, cache_k, cache_v,
-                       write_mask=None, history=None):
-    """``patterned.decode_step_blocks`` as it was: the vmapped write."""
+                       write_mask=None, history=None, **how):
+    """``patterned.decode_step_blocks`` as it was: the vmapped write (``how``:
+    what the expert layers are told of the caller, which a CPU's loop does
+    not read)."""
     b = x.shape[0]
     cos, sin = patterned.rope_cos_sin_for(spec)
     allow = jnp.ones((b,), bool) if write_mask is None else write_mask
