@@ -29,7 +29,12 @@ step loop or at the chunk's entry and exit) and ``SLAB MOVE`` on a ``copy``,
 ``reshape`` or ``dynamic-slice`` of one layer's slab (:func:`slab_moves`: what
 a read that re-lays its history window leaves, and a kernel handed a layout
 it cannot take: that one's ``op_name`` was ``attn.cache_write/scatter``). An
-in-place update of the carry has the cache's shape and no mark. Nothing runs,
+in-place update of the carry has the cache's shape and no mark. For a spec
+with a mixer (``falcon-h1-34b 'n_layers=6&max_seq=2048&slots=64'``) the
+recurrent state's leaf and slab are listed too, and ``STATE READ`` marks
+every operation of a loop body that takes the leaf (:func:`readers`: one
+Pallas call a layer, ops/ssm_step.py; XLA's form is an update fusion and a
+reduction that reads the slab a second time). Nothing runs,
 so this gives no time; the scan's program does not depend on depth, and the
 full-depth int8 member compiles in some ten seconds.
 
@@ -56,7 +61,11 @@ from urllib.parse import parse_qsl
 
 from quorum_tpu.analysis.hlo_names import _COMPUTATION, _INSTRUCTION, _OP_NAME
 
-_SHAPE = re.compile(r"=\s+([a-z]+\d*)\[([\d,]*)\]")
+# the arrays of a result, a tuple's (a Pallas call that also hands back the
+# buffer it updated in place) or the one; layouts hold no "dtype[" of theirs
+_RESULT = re.compile(r"=\s+(\(.*?\)|\S+)\s+[a-z][a-z\-]*\(")
+_ARRAY = re.compile(r"([a-z]+\d*)\[([\d,]+)\]")
+_OPERANDS = re.compile(r"\s[a-z][a-z\-]*\(([^()]*)\)")
 _CALLED = re.compile(
     r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
@@ -267,20 +276,68 @@ def program_ops(text: str, sizes: "set[int]", *,
     out = []
     for name in sorted(names):
         for line in comps[name]:
-            found, shape = _INSTRUCTION.match(line), _SHAPE.search(line)
-            if not (found and shape and shape.group(2)):
+            found, arrays = _INSTRUCTION.match(line), _result_arrays(line)
+            if not (found and arrays):
                 continue
-            dims = [int(d) for d in shape.group(2).split(",")]
-            opcode = found.group(2)
-            if opcode == "custom-call":
-                target = re.search(r'custom_call_target="([^"]*)"', line)
-                opcode = target.group(1) if target else opcode
-            if opcode == _KERNEL or (math.prod(dims) in sizes
-                                     and opcode not in _NO_DEVICE_OP):
-                op_name = _OP_NAME.search(line)
-                out.append((name, found.group(1), opcode,
-                            f"{shape.group(1)}[{shape.group(2)}]",
-                            op_name.group(1) if op_name else ""))
+            opcode = _opcode(found.group(2), line)
+            if opcode == _KERNEL or (
+                    opcode not in _NO_DEVICE_OP and len(arrays) == 1
+                    and math.prod(_dims(arrays[0])) in sizes):
+                out.append(_row(name, found.group(1), opcode, arrays, line))
+    return out
+
+
+def _result_arrays(line: str) -> "list[str]":
+    """``["f32[64,32]", ...]``: the arrays an instruction's result holds, a
+    tuple's one by one (a scalar has no dimensions and is left out)."""
+    result = _RESULT.search(line)
+    return [f"{dtype}[{dims}]" for dtype, dims
+            in _ARRAY.findall(result.group(1))] if result else []
+
+
+def _opcode(opcode: str, line: str) -> str:
+    """A custom call goes by its target (``tpu_custom_call``)."""
+    if opcode == "custom-call":
+        target = re.search(r'custom_call_target="([^"]*)"', line)
+        return target.group(1) if target else opcode
+    return opcode
+
+
+def _row(computation: str, operation: str, opcode: str,
+         arrays: "list[str]", line: str) -> tuple:
+    op_name = _OP_NAME.search(line)
+    return (computation, operation, opcode, "+".join(arrays),
+            op_name.group(1) if op_name else "")
+
+
+def state_leaf(spec, rows: int) -> str:
+    """The recurrent state's carried leaf as the text writes it."""
+    return (f"f32[{spec.n_layers},{rows},{spec.ssm_heads},"
+            f"{spec.ssm_head_dim},{spec.ssm_state}]")
+
+
+def readers(text: str, array: str) -> "list[tuple]":
+    """The rows (as :func:`program_ops`') of the loop bodies' operations that
+    take ``array`` (``"f32[6,64,32,128,256]"``) as an operand: what reads a
+    carried leaf a step. A loop, a tuple and the like pass it on and are no
+    readers; a fusion that slices a layer's slab out of it inside is one."""
+    comps = _computations(text)
+    out = []
+    for name in sorted(_loop_computations(comps)):
+        held = set()
+        for line in comps[name]:
+            found = _INSTRUCTION.match(line)
+            if found and _result_arrays(line) == [array]:
+                held.add(found.group(1))
+        for line in comps[name]:
+            found, taken = _INSTRUCTION.match(line), _OPERANDS.search(line)
+            if not (found and taken) or found.group(2) in _NO_DEVICE_OP + (
+                    "while", "call", "conditional"):
+                continue
+            if held & set(re.findall(r"%([\w.\-]+)", taken.group(1))):
+                out.append(_row(name, found.group(1),
+                                _opcode(found.group(2), line),
+                                _result_arrays(line), line))
     return out
 
 
@@ -358,12 +415,18 @@ def cache_sizes(spec, rows: int, members: int = 1) -> "tuple[tuple, tuple]":
     carries its cache in, and of the smaller pieces worth listing beside
     them. One stacked side and a layer's slab of it; for a spec with a
     ``layer_pattern`` (a cache per layer kind, models/patterned.py) one
-    full-attention layer's side and one window layer's ring, no slab."""
+    full-attention layer's side and one window layer's ring, no slab; for a
+    spec with a mixer (``ssm_heads``, a ``StateKV`` a side) also the
+    recurrent state's leaf ``[L, rows, H, P, N]`` and a layer's slab of
+    it."""
     row = rows * spec.n_kv_heads * spec.head_dim
     if spec.layer_pattern:
         return (row * spec.max_seq, row * spec.ring), ()
     side = members * spec.n_layers * row * spec.max_seq
-    return (side,), (side // spec.n_layers,)
+    carried = (side,)
+    if spec.ssm_heads:
+        carried += (spec.n_layers * rows * spec.ssm_width * spec.ssm_state,)
+    return carried, tuple(size // spec.n_layers for size in carried)
 
 
 def main(argv: "list[str]") -> int:
@@ -405,6 +468,9 @@ def main(argv: "list[str]") -> int:
             print(*row, "WHOLE-CACHE MOVE" if row in whole
                   else "SLAB MOVE" if row in slab
                   else "WEIGHT MOVE" if row in weight else "", sep="\t")
+        if spec.ssm_heads:  # one Pallas call a layer body, and no other
+            for row in readers(text, state_leaf(spec, rows)):
+                print(*row, "STATE READ", sep="\t")
     return 0
 
 

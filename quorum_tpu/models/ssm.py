@@ -24,14 +24,20 @@ its length: the float32 state ``[H, P, N]`` and the convolution's last
 on each side of the slot cache (:class:`StateKV`), per layer and row, beside
 the K and V rectangles.
 
-Two forms of the recurrence, both ``jax.numpy`` for XLA. A program of more
-than one position (an admit, a prefill segment, the cache-free forward) runs
-it in chunks of ``ssm_chunk`` (:func:`_scan_chunked`): inside a chunk matrix
-products, from chunk to chunk the state. A decode step updates the state
-once (:func:`_scan_step`). Positions past a row's ``n_valid`` (the pad of a
-bucket, a row a decode step may not write) leave state and tail as the last
-real position left them: their ``dt`` and input are zero, so the state decays
-by ``exp(0)`` and gains nothing, and the tail is taken at the true length.
+Two forms of the recurrence. A program of more than one position (an
+admit, a prefill segment, the cache-free forward) runs it in chunks of
+``ssm_chunk`` (:func:`_scan_chunked`): inside a chunk matrix products, from
+chunk to chunk the state. A decode step updates the state once
+(:func:`_scan_step`), and where it is handed the carried leaf and its layer
+(:func:`_step_in_leaf`, the decode step's layer scan) one Pallas call
+updates the layer's slab where it lies and reads it out in the same pass
+(``ops/ssm_step.py``: on a TPU, a float32 state of whole tiles, an
+unpartitioned program; elsewhere :func:`_scan_step` over the slab, which is
+also what the kernel is tested against). Positions past a row's ``n_valid``
+(the pad of a bucket, a row a decode step may not write) leave state and
+tail as the last real position left them: their ``dt`` and input are zero,
+so the state decays by ``exp(0)`` and gains nothing, and the tail is taken
+at the true length.
 """
 
 from __future__ import annotations
@@ -48,6 +54,7 @@ from jax import lax
 from quorum_tpu.models.model_config import ModelSpec
 from quorum_tpu.models.quant import qeinsum
 from quorum_tpu.models.shortconv import causal_taps
+from quorum_tpu.ops import ssm_step
 from quorum_tpu.ops.flash_attention import traced_program
 
 logger = logging.getLogger(__name__)
@@ -90,10 +97,17 @@ def rows_write(leaf, value, layer, row):
         (layer, row) + (0,) * (leaf.ndim - 2))
 
 
-def log_mixer_path(form: str, shape: tuple, chunk: int) -> None:
-    """One line a traced program: which form of the recurrence it runs."""
-    logger.info("mixer-path program=%s form=%s rows=%d positions=%d chunk=%d",
-                traced_program(), form, shape[0], shape[1], chunk)
+def log_mixer_path(form: str, shape: tuple, chunk: int, why: str = "") -> None:
+    """One line a traced program: which form of the recurrence it runs.
+    ``chunked`` (more than one position), ``fused`` (a step, the kernel of
+    ``ops/ssm_step.py`` where the program is lowered for a TPU) or ``step``
+    and ``why`` the kernel does not take it."""
+    logger.info(
+        "mixer-path program=%s form=%s rows=%d positions=%d chunk=%d "
+        "reason=%s", traced_program(), form, shape[0], shape[1], chunk,
+        why or {"chunked": "more than one position",
+                "fused": "pallas where lowered for a tpu, xla's step "
+                         "elsewhere"}[form])
 
 
 @jax.named_scope("ssm.in_proj")
@@ -205,6 +219,38 @@ def _scan_step(x, bm, cm, dt, a, state):
     return y[:, None], state
 
 
+@jax.named_scope("ssm.step")
+def _step_in_leaf(x, bm, cm, dt, a, leaf, layer, *, refused: str,
+                  interpret: bool):
+    """:func:`_scan_step` over layer ``layer`` of the carried leaf ``[L, B,
+    H, P, N]``, the leaf handed back with the slab updated in place: one
+    kernel call where ``refused`` is empty and the program is lowered for a
+    TPU (``ops/ssm_step.py``), else the XLA form over the slab."""
+    b, _, g, per, p = x.shape
+
+    def step(x, bm, cm, dt, leaf, layer):
+        slab = lax.dynamic_index_in_dim(leaf, layer, 0, False)
+        y, state = _scan_step(
+            x, bm, cm, dt, a, slab.reshape((b, g, per) + slab.shape[2:]))
+        return y, rows_write(leaf, state.reshape(slab.shape), layer, 0)
+
+    def fused(x, bm, cm, dt, leaf, layer):
+        f32 = jnp.float32
+        dt1 = dt[:, 0]
+        y, leaf = ssm_step.step_in_place(
+            leaf, layer, jnp.exp(dt1 * a).reshape(b, g * per),
+            (dt1[..., None] * x[:, 0].astype(f32)).reshape(b, g * per, p),
+            bm[:, 0].astype(f32), cm[:, 0].astype(f32), interpret=interpret)
+        return y.reshape(b, 1, g, per, p), leaf
+
+    args = (x, bm, cm, dt, leaf, layer)
+    if refused:
+        return step(*args)
+    if interpret:
+        return fused(*args)
+    return lax.platform_dependent(*args, tpu=fused, default=step)
+
+
 @jax.named_scope("ssm.gate_norm")
 def _gate_norm(y, z, block, spec: ModelSpec, dtype):
     """``RMSNorm_grouped(y * silu(z))``: y [B, T, d] float32."""
@@ -224,17 +270,31 @@ def _out_proj(v, block, spec: ModelSpec):
     return out.astype(v.dtype)
 
 
-def mixer(u, block, spec: ModelSpec, state, tail, n_valid):
+def mixer(u, block, spec: ModelSpec, state, tail, n_valid, *, layer=None,
+          sharded: bool = False, interpret: bool = False):
     """The mixer's branch of a block over ``u`` [B, T, D], the block's normed
     input. ``state`` [B, H, P, N] float32 and ``tail`` [B, ssm_conv - 1,
     d + 2GN] are what each row carried in; ``n_valid`` [B] int32 counts each
     row's real positions, the first ones. Returns what the branch adds to
     the stream [B, T, D] and the rows' state and tail after their last real
-    position."""
+    position.
+
+    With ``layer`` (the decode step, T = 1) ``state`` is the carried leaf
+    ``[L, B, H, P, N]`` whole and comes back whole, layer ``layer``'s slab
+    updated where it lies (:func:`_step_in_leaf`); ``sharded`` says the
+    caller's program is partitioned over devices, ``interpret`` runs the
+    kernel through the Pallas interpreter, for tests."""
     b, t, _ = u.shape
     d, gn = spec.ssm_width, spec.ssm_groups * spec.ssm_state
     g, per = spec.ssm_groups, spec.ssm_heads // spec.ssm_groups
-    log_mixer_path("step" if t == 1 else "chunked", (b, t), spec.ssm_chunk)
+    if layer is None:
+        form, refused = ("chunked", "") if t > 1 else (
+            "step", "the caller holds the rows' state sliced out")
+    else:
+        refused = ssm_step.refusal(state.shape, state.dtype, sharded=sharded,
+                                   interpret=interpret)
+        form = "step" if refused else "fused"
+    log_mixer_path(form, (b, t), spec.ssm_chunk, refused)
     z, xbc, dt = _in_proj(u, block, spec)
     xbc, tail = _conv(xbc, tail, block, n_valid)
     real = jnp.arange(t)[None, :] < n_valid[:, None]             # [B, T]
@@ -244,12 +304,16 @@ def mixer(u, block, spec: ModelSpec, state, tail, n_valid):
     a = -jnp.exp(block["ssm_a_log"].astype(jnp.float32)).reshape(g, per)
     xg, bg, cg, dtg = _grouped(x, xbc[..., d:d + gn], xbc[..., d + gn:], dt,
                                spec)
-    grouped = state.reshape((b, g, per) + state.shape[2:])
-    if t == 1:
-        y, grouped = _scan_step(xg, bg, cg, dtg, a, grouped)
+    if layer is not None:
+        y, grouped = _step_in_leaf(xg, bg, cg, dtg, a, state, layer,
+                                   refused=refused, interpret=interpret)
     else:
-        y, grouped = _scan_chunked(xg, bg, cg, dtg, a, grouped,
-                                   spec.ssm_chunk)
+        grouped = state.reshape((b, g, per) + state.shape[2:])
+        if t == 1:
+            y, grouped = _scan_step(xg, bg, cg, dtg, a, grouped)
+        else:
+            y, grouped = _scan_chunked(xg, bg, cg, dtg, a, grouped,
+                                       spec.ssm_chunk)
     y = y + (block["ssm_d"].astype(jnp.float32).reshape(g, per, 1)
              * xg.astype(jnp.float32))
     v = _gate_norm(y.reshape(b, t, d), z, block, spec, u.dtype)

@@ -855,15 +855,15 @@ def decode_step_blocks(
                               lengths + 1, window=spec.sliding_window)
         added = _attn_out(attn, block, carry_x.dtype, spec.attn_out_mult)
         if spec.ssm_heads:
-            # every row's state is read and written back; a row the step
+            # every row's state is read and written once, in the carried leaf
+            # where it lies (the mixer takes leaf and layer); a row the step
             # may not write takes no position, which leaves it as it was
-            state, tail = (lax.dynamic_index_in_dim(leaf, layer, 0, False)
-                           for leaf in carried)
+            tail = lax.dynamic_index_in_dim(carried[1], layer, 0, False)
             mix_out, state, tail = ssm.mixer(
-                h, block, spec, state, tail, allow.astype(jnp.int32))
-            added = added + mix_out
-            with jax.named_scope("ssm.step"):  # the update lands in place
-                ck = StateKV(ck, ssm.rows_write(carried[0], state, layer, 0))
+                h, block, spec, carried[0], tail, allow.astype(jnp.int32),
+                layer=layer, sharded=sharded)
+            added, ck = added + mix_out, StateKV(ck, state)
+            with jax.named_scope("ssm.step"):  # the tail lands in place
                 cv = StateKV(cv, ssm.rows_write(carried[1], tail, layer, 0))
         carry_x = carry_x + added
         h2 = _norm(carry_x, block["mlp_norm_w"], block.get("mlp_norm_b"), spec)

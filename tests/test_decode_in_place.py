@@ -37,6 +37,7 @@ from quorum_tpu.models import transformer as tr
 from quorum_tpu.models.init import init_params
 from quorum_tpu.models.model_config import MODEL_PRESETS
 from quorum_tpu.models.quant import quantize_params
+from quorum_tpu.ops import ssm_step
 from quorum_tpu.parallel import sharding
 from quorum_tpu.parallel.sharding import stack_members
 
@@ -511,3 +512,62 @@ def test_a_selecting_segment_reads_the_carried_rows_through_one_pallas_call(
     (call,) = calls
     assert "attn.tiled" in call[4] and "latent_tile_attention" in call[4]
     assert not decode_static.slab_moves(text, hist * width)
+
+
+# ---- a spec with a mixer: the recurrent state is passed over once ------------
+
+# a slab of 64 MB: one of 32 MB the compiler prefetches into fast memory whole
+MIXER_ROWS = 16
+
+
+@pytest.fixture(scope="module")
+def mixer_programs(v5e):
+    """Falcon-H1-34B's widths at 2 layers, 16 rows of 512 positions: the
+    decode chunk as served (``fused``) and with the kernel refused
+    (``step``: XLA's two fusions): ``(text, temp bytes)``."""
+    spec = dataclasses.replace(MODEL_PRESETS["falcon-h1-34b"], n_layers=2,
+                               max_seq=512).validate()
+    done = {}
+
+    def program(form, monkeypatch):
+        if form not in done:
+            with monkeypatch.context() as patch:
+                if form == "step":
+                    patch.setattr(ssm_step, "refusal",
+                                  lambda *a, **kw: "refused by the test")
+                compiled = within(
+                    COMPILE_LIMIT_S, decode_static.compile_decode_chunk,
+                    spec, v5e, rows=MIXER_ROWS, history=512)
+            done[form] = (compiled.as_text(),
+                          compiled.memory_analysis().temp_size_in_bytes)
+        return done[form]
+
+    return spec, program
+
+
+def test_a_mixer_s_state_is_read_by_one_pallas_call_a_layer_and_not_moved(
+        mixer_programs, monkeypatch):
+    """One Mosaic call a layer body takes the state leaf, under
+    ``ssm.step``, and nothing else does; no copy, reshape, slice or
+    allocation of the leaf or of a layer's slab anywhere; the temporaries
+    are not above XLA's form's by more than the kernel's blocks. The reader
+    is shown to find XLA's two readers first: the in-place update and the
+    readout that reads the slab again."""
+    spec, program = mixer_programs
+    leaf = decode_static.state_leaf(spec, MIXER_ROWS)
+    (_, state), (_, slab) = decode_static.cache_sizes(spec, MIXER_ROWS)
+    text, xla_temp = program("step", monkeypatch)
+    update, readout = sorted(
+        (row for row in decode_static.readers(text, leaf)
+         if row[2] == "fusion"), key=lambda row: row[3] != leaf)
+    assert "ssm.step/dynamic_update_slice" in update[4]
+    assert "ssm.step/reduce_sum" in readout[4]
+    text, temp = program("fused", monkeypatch)
+    (call,) = decode_static.readers(text, leaf)
+    assert call[2] == "tpu_custom_call" and leaf in call[3]
+    assert "ssm.step" in call[4] and "ssm_step_in_place" in call[4]
+    attention, mixer = decode_static.kernel_calls(text)
+    assert mixer == call and "attn.core" in attention[4]
+    assert not decode_static.slab_moves(text, slab)
+    assert not decode_static.whole_cache_moves(text, state, loops_only=False)
+    assert temp <= xla_temp + 4 * ssm_step.BLOCK_BYTES

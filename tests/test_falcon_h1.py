@@ -7,6 +7,7 @@ the counters and the scopes."""
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import os
@@ -541,7 +542,26 @@ def test_a_traced_program_logs_its_mixer_path(model32, caplog):
     jax.eval_shape(lambda: tr.decode_step(
         params, spec, jnp.zeros((SLOTS,), jnp.int32),
         jnp.zeros((SLOTS,), jnp.int32), ck, cv, history=64))
-    lines = [r.getMessage() for r in caplog.records
-             if r.getMessage().startswith("mixer-path")]
-    assert any("form=chunked" in ln and "positions=16" in ln for ln in lines)
-    assert any("form=step" in ln and "positions=1 " in ln for ln in lines)
+    jax.eval_shape(lambda: tr.decode_step(
+        params, spec, jnp.zeros((SLOTS,), jnp.int32),
+        jnp.zeros((SLOTS,), jnp.int32), ck, cv, history=64, sharded=True))
+    chunked, step, sharded = [r.getMessage() for r in caplog.records
+                              if r.getMessage().startswith("mixer-path")]
+    assert "form=chunked " in chunked and "positions=16 " in chunked
+    assert "reason=more than one position" in chunked
+    # the tiny preset's heads of [16, 16] are no tiles of Mosaic's
+    assert "form=step " in step and "positions=1 " in step
+    assert "reason=heads of [16, 16]" in step
+    assert "form=step " in sharded and "partitioned over devices" in sharded
+    # a float32 state of whole tiles takes the kernel where it is lowered
+    # for a TPU (tests/test_ssm_step.py has the CPU's side of it)
+    tiled = dataclasses.replace(spec, ssm_state=128).validate()
+    caplog.clear()
+    jax.eval_shape(lambda: tr.decode_step(
+        init_params(tiled, 3), tiled, jnp.zeros((SLOTS,), jnp.int32),
+        jnp.zeros((SLOTS,), jnp.int32), *tr.init_cache(tiled, SLOTS),
+        history=64))
+    (fused,) = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("mixer-path")]
+    assert "form=fused " in fused and "positions=1 " in fused
+    assert "reason=pallas where lowered for a tpu" in fused
